@@ -13,7 +13,7 @@ rebuilds, layer by layer.
 __version__ = "0.1.0"
 
 # Deliberately light — and jax-free: entrypoints must be able to read
-# config (e.g. config.machine_cache_dir for JAX_COMPILATION_CACHE_DIR)
+# config (e.g. config.compile_cache_dir for JAX_COMPILATION_CACHE_DIR)
 # BEFORE their first `import jax`, since jax snapshots env vars at import.
 # The two jax-heavy re-exports resolve lazily (PEP 562).
 from locust_tpu.config import (  # noqa: F401

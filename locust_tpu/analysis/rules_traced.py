@@ -1,7 +1,7 @@
 """R002/R003/R010 — purity, sync and donation discipline around traced code.
 
 R002 (traced-purity, interprocedural): functions handed to ``jax.jit`` /
-``shard_map`` / ``compat_shard_map`` / ``pallas_call`` (as calls or
+``shard_map`` / ``pallas_call`` (as calls or
 decorators) run under tracing: side effects execute ONCE at trace time
 and then silently never again — or, for Pallas interpret mode on CPU,
 can crash the XLA compiler outright (the bitonic-under-mesh segfault

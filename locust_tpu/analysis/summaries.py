@@ -41,7 +41,7 @@ from locust_tpu.analysis.core import call_name, unparse
 _LOCKISH = ("lock", "mutex", "semaphore", "cond")
 
 _TRACER_RE = re.compile(
-    r"(^|\.)(jit|shard_map|compat_shard_map|pallas_call)$"
+    r"(^|\.)(jit|shard_map|pallas_call)$"
 )
 _IMPURE_PREFIXES = ("time.", "random.", "np.random.", "numpy.random.",
                     "socket.", "os.environ")

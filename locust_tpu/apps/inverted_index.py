@@ -195,7 +195,7 @@ def build_inverted_index(
 
 
 class DistributedInvertedIndex:
-    """Mesh-parallel inverted index (VERDICT.md round-1 #7).
+    """Mesh-parallel inverted index.
 
     The same collective recipe as parallel/shuffle.DistributedMapReduce —
     hash-partition, equal bins, one ``lax.all_to_all`` per round, carried
@@ -215,7 +215,7 @@ class DistributedInvertedIndex:
     ):
         from jax.sharding import PartitionSpec as P
 
-        from locust_tpu.parallel.mesh import DATA_AXIS, compat_shard_map
+        from locust_tpu.parallel.mesh import DATA_AXIS
         from locust_tpu.parallel.shuffle import partition_to_bins, sized_bins
 
         axis = axis_name or DATA_AXIS
@@ -322,7 +322,7 @@ class DistributedInvertedIndex:
 
         kv_spec = KVBatch(key_lanes=P(axis), values=P(axis), valid=P(axis))
         self._step = jax.jit(
-            compat_shard_map(
+            jax.shard_map(
                 local_step,
                 mesh=mesh,
                 in_specs=(P(axis), P(axis), kv_spec, kv_spec),

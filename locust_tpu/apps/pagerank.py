@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from locust_tpu.parallel.mesh import DATA_AXIS, compat_shard_map
+from locust_tpu.parallel.mesh import DATA_AXIS
 
 
 def _contributions(src, dst, ranks, inv_deg, num_nodes):
@@ -138,7 +138,7 @@ class DistributedPageRank:
             return ranks_new
 
         self._step = jax.jit(
-            compat_shard_map(
+            jax.shard_map(
                 step,
                 mesh=mesh,
                 in_specs=(P(axis_name), P(axis_name), P(axis_name), P(), P(), P()),
@@ -326,7 +326,7 @@ class ShardedPageRank:
 
         spec = P(axis)
         step_j = jax.jit(
-            compat_shard_map(
+            jax.shard_map(
                 step,
                 mesh=self.mesh,
                 in_specs=(spec,) * 8,

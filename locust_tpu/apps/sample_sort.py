@@ -35,7 +35,7 @@ from locust_tpu.config import EngineConfig
 from locust_tpu.core import bytes_ops, packing
 from locust_tpu.core.kv import KVBatch
 from locust_tpu.ops.process_stage import sort_and_compact
-from locust_tpu.parallel.mesh import DATA_AXIS, compat_shard_map, shard_rows
+from locust_tpu.parallel.mesh import DATA_AXIS, shard_rows
 from locust_tpu.parallel.shuffle import partition_to_bins
 
 
@@ -130,7 +130,7 @@ class DistributedSort:
 
         kv_spec = KVBatch(key_lanes=P(axis), values=P(axis), valid=P(axis))
         self._step = jax.jit(
-            compat_shard_map(
+            jax.shard_map(
                 local_sort,
                 mesh=mesh,
                 in_specs=(P(axis), P(axis), P(axis)),

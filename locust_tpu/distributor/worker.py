@@ -1099,6 +1099,9 @@ def main(argv=None) -> int:
     p.add_argument("--serve-max-engines", type=int, default=4,
                    help="warm engines kept by the serve cache (LRU)")
     args = p.parse_args(argv)
+    from locust_tpu.config import compile_cache_dir
+
+    compile_cache_dir()  # before the first `import jax` (and for children)
     faultplan.install(args.fault_plan)
     secret = os.environ.get(args.secret_env, "").encode()
     if not secret:

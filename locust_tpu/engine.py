@@ -464,7 +464,7 @@ class MapReduceEngine:
             """Whole-corpus pipeline in ONE dispatch: fold blocks with lax.scan.
 
             One device dispatch per corpus instead of per block — essential
-            when dispatch latency is high (remote TPU tunnels) and the XLA-
+            when per-dispatch latency matters (many small blocks) and the XLA-
             idiomatic way to loop without data-dependent Python control flow.
             The init accumulator arrives as an ARGUMENT so the jit below
             can donate it into the scan carry (cfg.donate_fold): even the

@@ -1,13 +1,16 @@
 """ctypes bindings for the native ingest library (native/ingest.cpp).
 
 Builds the shared object on first use with the system g++ (cached in
-``native/build/``); callers go through io/loader.load_rows which falls back
-to the pure-Python path if the toolchain is unavailable.
+``native/build/`` under a name keyed by the source's SHA-256); callers go
+through io/loader.load_rows which falls back to the pure-Python path if
+the toolchain is unavailable.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import os
 import pathlib
 import subprocess
 import threading
@@ -16,26 +19,50 @@ import numpy as np
 
 _NATIVE_DIR = pathlib.Path(__file__).resolve().parents[2] / "native"
 _SRC = _NATIVE_DIR / "ingest.cpp"
-_SO = _NATIVE_DIR / "build" / "libingest.so"
 
 _lock = threading.Lock()
 _lib = None
 
 
+def so_path() -> pathlib.Path:
+    """``native/build/libingest-<sha12>.so``, keyed by the SOURCE's
+    content: a copied checkout carries arbitrary mtimes (and possibly a
+    stale untracked .so), so only a content key can say whether a built
+    file matches ``ingest.cpp``."""
+    sha = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:12]
+    return _NATIVE_DIR / "build" / f"libingest-{sha}.so"
+
+
 def _build() -> pathlib.Path:
-    _SO.parent.mkdir(parents=True, exist_ok=True)
-    if _SO.exists() and _SO.stat().st_mtime >= _SRC.stat().st_mtime:
-        return _SO
+    so = so_path()
+    if so.exists():
+        return so
+    so.parent.mkdir(parents=True, exist_ok=True)
+    # Build to a private name, then rename: a concurrent builder (xdist
+    # workers, serve pool) never loads a half-written file.
+    tmp = so.with_name(f"{so.name}.tmp.{os.getpid()}")
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-o", str(_SO), str(_SRC)],
+            ["g++", "-O3", "-shared", "-fPIC", "-o", str(tmp), str(_SRC)],
             check=True,
             capture_output=True,
         )
+        os.replace(tmp, so)
     except (subprocess.CalledProcessError, FileNotFoundError) as e:
         # Surface as OSError so io/loader falls back to the Python path.
         raise OSError(f"native ingest build failed: {e}") from e
-    return _SO
+    return so
+
+
+def available() -> bool:
+    """Did (or does) the native library load?  io/loader's callers fall
+    back to the Python path when it does not; chip_smoke.py prints which
+    path ingest took."""
+    try:
+        _load()
+    except OSError:
+        return False
+    return True
 
 
 def _load() -> ctypes.CDLL:

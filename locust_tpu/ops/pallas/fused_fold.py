@@ -129,6 +129,22 @@ from locust_tpu.core.kv import KVBatch
 # drifted copy would silently model the wrong residual traffic.
 RESID_PAD = FUSED_RESID_PAD
 
+FUSED_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+# Every contraction in the kernel carries integers < 2^24 in f32, and the
+# exactness story needs each one exact.  Mosaic's DEFAULT precision feeds
+# the MXU bf16 operands (f32 accumulate): exact for bytes (<= 255) and
+# one-hots — every operand of the Gram product, the key-plane scatters and
+# the plane gathers (a chimera sum > 255 may round, but never down to a
+# byte, so a mismatch stays a mismatch) — and WRONG for the three operands
+# that exceed 8 significant bits: the squared norms (< 2^21), and the
+# per-tile counts (<= emits * tile) in the count scatter and the residual
+# compaction.  Those contractions ask for full f32 (_EXACT).  The
+# interpreter and the CPU compute f32 dots exactly, so only the chip shows
+# the difference (first chip run, PR 22: the dedupe mis-paired keys and
+# the block overflowed its residual).
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 
 def _fmix32(h):
@@ -173,11 +189,14 @@ def _fused_kernel(
     for c in DELIMITERS + b"\n\r":
         is_delim = is_delim | (xi == c)
     in_tok = ~is_delim
-    zeros_col = jnp.zeros((x.shape[0], 1), dtype=jnp.bool_)
-    prev = jnp.concatenate([zeros_col, in_tok[:, :-1]], axis=1)
-    nxt = jnp.concatenate([in_tok[:, 1:], zeros_col], axis=1)
-    starts = in_tok & ~prev
-    ends = in_tok & ~nxt
+    # Shift the int32 widening, compare afterwards: Mosaic cannot shift
+    # an i1 mask vector across lanes (see ops/pallas/tokenize.py).
+    tok_i = in_tok.astype(jnp.int32)
+    zeros_col = jnp.zeros((x.shape[0], 1), dtype=jnp.int32)
+    prev = jnp.concatenate([zeros_col, tok_i[:, :-1]], axis=1)
+    nxt = jnp.concatenate([tok_i[:, 1:], zeros_col], axis=1)
+    starts = in_tok & (prev == 0)
+    ends = in_tok & (nxt == 0)
     csum = starts.astype(jnp.int32)
     shift = 1
     while shift < width:
@@ -235,24 +254,26 @@ def _fused_kernel(
     n_rows = bf.shape[0]
     ones_col = jnp.ones((n_rows, 1), dtype=jnp.float32)
 
-    def row_bcast(col):
+    def row_bcast(col, precision=None):
         """[N, 1] -> [N, N] carrying col[m] at (n, m): a rank-1 ones x
         col contraction — the lane-major broadcast WITHOUT an in-kernel
-        transpose (Mosaic-safe), exact for one-hot/byte magnitudes."""
+        transpose (Mosaic-safe).  Default precision is exact for
+        one-hot/byte magnitudes only; pass _EXACT above 255."""
         return jax.lax.dot_general(
             ones_col, col, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=precision,
         )
 
     # ---- 2. exact within-tile dedupe via the Gram matrix ----
     gram = jax.lax.dot_general(
         bf, bf, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )                                                       # [N, N]
+    )                                                       # [N, N] bytes: exact
     norm = jnp.zeros((n_rows, 1), dtype=jnp.float32)
     for c in bcols:
         norm = norm + c * c                                 # [N, 1]
-    d2 = norm + row_bcast(norm) - 2.0 * gram                # exact: < 2^24
+    d2 = norm + row_bcast(norm, _EXACT) - 2.0 * gram        # exact: < 2^24
     eq = (d2 == 0.0) & valid & (row_bcast(valid.astype(jnp.float32)) > 0.0)
     ridx = jax.lax.broadcasted_iota(jnp.int32, (n_rows, n_rows), 0)
     cidx = jax.lax.broadcasted_iota(jnp.int32, (n_rows, n_rows), 1)
@@ -291,11 +312,13 @@ def _fused_kernel(
         )                                                   # [N, t_hi]
         return jnp.sum(oh_hi * g, axis=1, keepdims=True)    # [N, 1]
 
-    def scatter_plane(p, oh_lo, oh_hi, w):
-        """tab plane ``p`` += one-hot scatter of per-row weights ``w``."""
+    def scatter_plane(p, oh_lo, oh_hi, w, precision=None):
+        """tab plane ``p`` += one-hot scatter of per-row weights ``w``
+        (weights above 255 need ``precision=_EXACT``)."""
         delta = jax.lax.dot_general(
             oh_hi * w, oh_lo, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=precision,
         )                                                   # [t_hi, t_lo]
         rows = tab_ref[p * t_hi:(p + 1) * t_hi, :]
         tab_ref[p * t_hi:(p + 1) * t_hi, :] = rows + delta
@@ -322,7 +345,7 @@ def _fused_kernel(
         for k in range(key_w):
             match = match & (gather_plane(k, oh_lo, oh_hi) == bcols[k])
         scatter_plane(key_w + 1, oh_lo, oh_hi,
-                      cnt * match.astype(jnp.float32))
+                      cnt * match.astype(jnp.float32), _EXACT)
         unres = unres & ~match
 
     # ---- 5. residual stream: rank-compact stranded leaders ----
@@ -337,10 +360,11 @@ def _fused_kernel(
     iota_r = jax.lax.broadcasted_iota(jnp.int32, (n_rows, r_cap), 1)
     place = ((rank == iota_r) & unres).astype(jnp.float32)  # [N, r_cap]
 
-    def compact(cols):
+    def compact(cols, precision=None):
         return jax.lax.dot_general(
             place, cols, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=precision,
         )                                                   # [r_cap, .]
 
     # One full-width store (no partial lane-dim ref slices): bytes,
@@ -348,7 +372,7 @@ def _fused_kernel(
     resid_ref[:] = jnp.concatenate(
         [
             compact(bf),
-            compact(cnt),
+            compact(cnt, _EXACT),
             compact(unres.astype(jnp.float32)),
             jnp.zeros((r_cap, RESID_PAD - 2), dtype=jnp.float32),
         ],
@@ -547,6 +571,12 @@ def fused_block_preagg(
             jax.ShapeDtypeStruct((n_tiles * r_cap, rw), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        ),
+        # The unrolled per-(slot, byte) columns keep ~24 MB of [T, 1]
+        # vregs live — over Mosaic's 16 MB default scoped-VMEM budget,
+        # well inside a v5e core's 128 MiB.
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES
         ),
         interpret=interpret,
     )(lines)

@@ -45,11 +45,15 @@ def _tokenize_kernel(x_ref, keys_ref, valid_ref, ovf_ref, *, emits, key_w, width
         is_delim = is_delim | (xi == c)
     in_tok = ~is_delim
 
-    zeros_col = jnp.zeros((x.shape[0], 1), dtype=jnp.bool_)
-    prev = jnp.concatenate([zeros_col, in_tok[:, :-1]], axis=1)
-    nxt = jnp.concatenate([in_tok[:, 1:], zeros_col], axis=1)
-    starts = in_tok & ~prev
-    ends = in_tok & ~nxt
+    # Neighbour shifts run on the int32 widening and compare afterwards:
+    # Mosaic has no lane shift of an i1 mask vector ("Invalid vector
+    # register cast ... tpu.bitcast_vreg (vector<8x128xi1>)").
+    tok_i = in_tok.astype(jnp.int32)
+    zeros_col = jnp.zeros((x.shape[0], 1), dtype=jnp.int32)
+    prev = jnp.concatenate([zeros_col, tok_i[:, :-1]], axis=1)
+    nxt = jnp.concatenate([tok_i[:, 1:], zeros_col], axis=1)
+    starts = in_tok & (prev == 0)
+    ends = in_tok & (nxt == 0)
     # Inclusive prefix sum along the line, as a statically-unrolled
     # Hillis-Steele doubling scan: log2(W) shift-adds.  (jnp.cumsum has no
     # Pallas TPU lowering; this form is plain vector adds.)

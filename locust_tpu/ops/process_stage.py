@@ -48,36 +48,10 @@ logger = logging.getLogger("locust_tpu")
 _warned_bitonic_fallback = False
 _warned_bitonic_interpret = False
 
-# Trace-time "inside a mesh engine's shard_map step" marker.  On jax
-# versions WITH ``jax.typeof`` the vma machinery already tells
-# _bitonic_sort it is under a check_vma=True manual trace; on 0.4.x there
-# is no vma to inspect (and compat_shard_map forces the replication check
-# off), so the mesh engines mark their step bodies explicitly and the
-# off-TPU segfault guard keys on this instead (CLAUDE.md: the interpret
-# bitonic kernel inside a full mesh program crashes XLA's CPU compiler).
-import contextlib as _contextlib
-import contextvars as _contextvars
-
-_IN_MESH_STEP = _contextvars.ContextVar("locust_in_mesh_step", default=False)
-
-
-@_contextlib.contextmanager
-def mesh_step_scope():
-    """Engines wrap their shard_map step BODIES in this (active exactly
-    while jax traces the per-device program)."""
-    tok = _IN_MESH_STEP.set(True)
-    try:
-        yield
-    finally:
-        _IN_MESH_STEP.reset(tok)
-
-
 def _vma_of(x) -> frozenset:
-    """The array's varying-manual-axes set; empty on jax without typeof."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
-    return getattr(typeof(x), "vma", None) or frozenset()
+    """The array's varying-manual-axes set (empty outside shard_map)."""
+    return getattr(jax.typeof(x), "vma", None) or frozenset()
+
 
 # Largest padded element count the INTERPRET-mode bitonic kernel (the
 # off-TPU test vehicle) is allowed to trace: the interpreter re-traces
@@ -162,10 +136,11 @@ def _hashp_sort(batch: KVBatch) -> KVBatch:
 
     Same 3 sort keys as "hash" but the key lanes and values travel through
     ``lax.sort`` as payload operands instead of being gathered by a sorted
-    index afterwards.  On TPU v5e at 720k rows this is ~19% faster than the
-    gather form (artifacts/tpu_runs.jsonl sort_variants: C 67.4ms vs
-    B 82.6ms) — the gather's random-access HBM reads cost more than
-    carrying 9 extra payload operands through the sort's sequential passes.
+    index afterwards.  On a TPU v5e at 720k rows this measured ~19% faster
+    than the gather form (2026-07, an earlier set-up: 67.4 ms vs 82.6 ms;
+    not re-measured on the current machine) — the gather's random-access
+    HBM reads cost more than carrying 9 extra payload operands through the
+    sort's sequential passes.
     Collision/correctness story identical to "hash".
     """
     lanes, values, valid = batch.key_lanes, batch.values, batch.valid
@@ -225,7 +200,7 @@ def _hashp1_sort(batch: KVBatch) -> KVBatch:
     pairs interleave within a hash run, the segment reduce's full-lane
     boundary compare splits them into duplicate table rows, and the next
     fold or the host finalize re-merges those — never a wrong count.
-    Hardware A/B rides scripts/opp_resume.py phase 3.
+    Not measured on the current machine.
     """
     lanes, values = batch.key_lanes, batch.values
     n_lanes = lanes.shape[-1]
@@ -310,16 +285,7 @@ def _bitonic_sort(batch: KVBatch) -> KVBatch:
     vma = frozenset().union(
         *(_vma_of(x) for x in (folded, lanes, values))
     )
-    # Legacy jax (no typeof/vma): the engines' explicit mesh-step marker
-    # stands in for the vma signal — off-TPU mesh programs must take the
-    # same stock fallback (the interpret kernel inside a full mesh
-    # program is the CPU-compiler segfault class, CLAUDE.md).
-    legacy_mesh_cpu = (
-        not hasattr(jax, "typeof")
-        and _IN_MESH_STEP.get()
-        and jax.default_backend() != "tpu"
-    )
-    if vma or legacy_mesh_cpu:
+    if vma:
         # Loud once: evidence recorded as sort_mode="bitonic" on a mesh
         # engine measured THIS stock formulation, not the Pallas kernel —
         # a silent substitution would let a future A/B conclude the
@@ -345,31 +311,13 @@ def _bitonic_sort(batch: KVBatch) -> KVBatch:
 
     interpret = jax.default_backend() != "tpu"
     n_pad = max(1 << 10, 1 << max(batch.size - 1, 1).bit_length())
-    if interpret and not hasattr(jax, "typeof"):
-        # Legacy jax (0.4.x): the engines share one process with mesh
-        # programs there (compat_shard_map), and the interpret kernel's
-        # re-trace alongside accumulated mesh-program state has crashed
-        # XLA's CPU compiler at FUZZ shapes too (the CLAUDE.md segfault
-        # class, reproduced suite-order-dependently on 0.4.37) — so
-        # engine dispatch never takes interpret mode on legacy jax; the
-        # kernel's interpret traceability stays covered by the direct
-        # small tests (tests/test_bitonic.py, test_distributed.py).
-        global _warned_bitonic_interpret  # locust: noqa[R002] deliberate warn-once AT TRACE TIME: the legacy-jax interpret-skip notice must fire exactly when tracing takes this branch
-        if not _warned_bitonic_interpret:
-            _warned_bitonic_interpret = True
-            logger.warning(
-                "sort_mode='bitonic' off-TPU on jax %s: interpret-mode "
-                "kernel skipped on legacy jax (CPU-compiler crash risk "
-                "alongside mesh programs); using the equivalent stock "
-                "lax.sort formulation", jax.__version__,
-            )
-        return _hashp1_sort(batch)
     if interpret and n_pad > BITONIC_INTERPRET_MAX:
         # Interpret mode is the off-TPU TEST vehicle; at production
         # shapes its re-trace of every fused launch crashes the CPU
         # XLA compiler (SIGSEGV at mesh-merge shapes).  Off-TPU big
         # sorts take the stock formulation — loudly, so no CPU timing
         # can be mistaken for a kernel measurement.
+        global _warned_bitonic_interpret  # locust: noqa[R002] deliberate warn-once AT TRACE TIME: the interpret-skip notice must fire exactly when tracing takes this branch
         if not _warned_bitonic_interpret:
             _warned_bitonic_interpret = True
             logger.warning(

@@ -29,32 +29,6 @@ DATA_AXIS = "data"
 SLICE_AXIS = "slice"
 
 
-def compat_shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions — the ONE wrapper every mesh
-    engine uses.  jax >= 0.6 exposes ``jax.shard_map(..., check_vma=)``;
-    0.4.x ships it as ``jax.experimental.shard_map.shard_map(...,
-    check_rep=)`` (same semantics, pre-rename).  Without this shim the
-    whole mesh tier dies with AttributeError on 0.4.x (the seed state).
-
-    On the legacy path ``check_rep`` is forced off regardless of
-    ``check_vma``: 0.4.x's replication checker has no rule for
-    ``lax.while_loop`` (NotImplementedError), and every round engine
-    drains its shuffle backlog in one — the check is a diagnostic, not a
-    semantic, so losing it on old jax only loses the extra policing the
-    engines' oracle tests re-cover anyway."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 def make_mesh(n_devices: int | None = None, axis_name: str = DATA_AXIS) -> jax.sharding.Mesh:
     """1-D mesh over the first ``n_devices`` (default: all) devices."""
     devs = jax.devices()

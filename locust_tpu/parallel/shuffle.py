@@ -40,7 +40,7 @@ from locust_tpu.core.kv import KVBatch
 from locust_tpu.io.snapshot import AsyncCheckpointWriter, finalize_snapshot
 from locust_tpu.ops.map_stage import wordcount_map
 from locust_tpu.ops.hash_table import fold_into, reduce_into
-from locust_tpu.parallel.mesh import DATA_AXIS, compat_shard_map
+from locust_tpu.parallel.mesh import DATA_AXIS
 
 logger = logging.getLogger("locust_tpu")
 
@@ -597,12 +597,6 @@ def build_shuffle_step(
         return new_acc, new_leftover, shuf_ovf, distinct, backlog
 
     def local_step(lines: jax.Array, acc: KVBatch, leftover: KVBatch):
-        from locust_tpu.ops.process_stage import mesh_step_scope
-
-        with mesh_step_scope():
-            return _local_step_body(lines, acc, leftover)
-
-    def _local_step_body(lines: jax.Array, acc: KVBatch, leftover: KVBatch):
         """Per-device body (runs under shard_map): feed + on-device drain.
 
         VERDICT r2 weak #3: the drain loop used to live on the HOST,
@@ -901,7 +895,7 @@ class DistributedMapReduce:
         # engine's round step takes the same conditional, and this
         # engine's outputs are oracle-tested per mode.
         self._step = jax.jit(
-            compat_shard_map(
+            jax.shard_map(
                 local_step,
                 mesh=mesh,
                 in_specs=(P(axis), kv_spec, kv_spec),

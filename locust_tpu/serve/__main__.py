@@ -317,6 +317,9 @@ def _stats_main(argv, cmd: str) -> int:
 
 
 def main(argv=None) -> int:
+    from locust_tpu.config import compile_cache_dir
+
+    compile_cache_dir()  # before the daemon's first `import jax`
     argv = sys.argv[1:] if argv is None else list(argv)
     if argv and argv[0] in _CLIENT_CMDS:
         from locust_tpu.serve.client import ServeError

@@ -2,7 +2,7 @@
 
 The per-block merge sorts ``table_size + emits_per_block`` rows, so table
 capacity is a throughput knob as well as a truncation knob
-(VERDICT.md round-1 #9: pick the default from data, not vibes).
+(pick the default from data, not vibes).
 
 Usage: python scripts/bench_table_size.py [--backend auto|cpu|tpu]
 Prints one JSON line per (table_size, vocab) cell.
@@ -16,9 +16,9 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from locust_tpu.config import machine_cache_dir  # noqa: E402 - jax-free
+from locust_tpu.config import compile_cache_dir  # noqa: E402 - jax-free
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", machine_cache_dir())
+compile_cache_dir()
 
 
 def corpus_lines(n_vocab: int, total_tokens: int, seed: int = 0) -> list[bytes]:

@@ -102,7 +102,7 @@ def main() -> int:
 
     from locust_tpu.backend import select_backend
 
-    backend = select_backend(args.backend, probe_timeout_s=90, retries=2)
+    backend = select_backend(args.backend)
     print(f"[stream] backend: {backend}", file=sys.stderr)
 
     import bench

@@ -150,7 +150,6 @@ def test_r002_fires_on_print_and_time_in_jitted_fn(tmp_path):
 def test_r002_fires_on_global_write_in_shard_map_body(tmp_path):
     _write(tmp_path, "mod.py", """
         import jax
-        from locust_tpu.parallel.mesh import compat_shard_map
 
         calls = 0
 
@@ -159,7 +158,7 @@ def test_r002_fires_on_global_write_in_shard_map_body(tmp_path):
             calls += 1
             return x
 
-        step = jax.jit(compat_shard_map(body, None, None, None))
+        step = jax.jit(jax.shard_map(body, None, None, None))
     """)
     res = _run(tmp_path, ["R002"], ["mod.py"])
     assert len(res.new) == 1
@@ -2328,10 +2327,13 @@ def test_cli_sarif_writes_parseable_log(tmp_path):
 
 def test_full_repo_run_is_fast_and_parses_each_file_once():
     """The analyzer self-perf pin: the two-phase engine must stay cheap
-    enough to live inside tier-1 (< 10 s on the CPU container) and keep
-    the one-parse-per-file economy — phase 2 runs over summaries, and
-    the registry rules reuse phase-1 trees instead of re-reading their
-    anchor modules."""
+    enough to live inside tier-1 (< 10 s of CPU on the CPU container)
+    and keep the one-parse-per-file economy — phase 2 runs over
+    summaries, and the registry rules reuse phase-1 trees instead of
+    re-reading their anchor modules.  The clock is this process's CPU
+    time: the analyzer is single-threaded, and under the six-worker
+    tier-1 run its WALL time doubled (4.7 s alone, 10.6 s loaded) with
+    the analyzer itself unchanged."""
     import time as _time
 
     from locust_tpu.analysis import core as acore
@@ -2339,10 +2341,10 @@ def test_full_repo_run_is_fast_and_parses_each_file_once():
 
     acore.reset_parse_count()
     arpc.reset_build_count()
-    t0 = _time.perf_counter()
+    t0 = _time.process_time()
     res = run_analysis(root=REPO)
-    elapsed = _time.perf_counter() - t0
-    assert elapsed < 10.0, f"full-repo analysis took {elapsed:.1f}s"
+    elapsed = _time.process_time() - t0
+    assert elapsed < 10.0, f"full-repo analysis took {elapsed:.1f}s of CPU"
     assert acore.parse_count() == res.n_files, (
         f"{acore.parse_count()} parses for {res.n_files} files — "
         "a rule is re-parsing instead of reusing phase-1 trees"

@@ -111,7 +111,7 @@ def test_inverted_index_mismatched_doc_ids_raises():
 # ------------------------------------------------------- distributed index
 
 def test_distributed_inverted_index_matches_oracle():
-    """VERDICT.md round-1 #7: the mesh index must match the single-device
+    """The mesh index must match the single-device
     oracle on a corpus spanning several shuffle rounds."""
     from locust_tpu.apps.inverted_index import build_inverted_index_mesh
     from locust_tpu.parallel import make_mesh

@@ -131,3 +131,25 @@ def test_roofline_counts_the_shared_schedule():
     assert roofline.sort_pass_count(n, "bitonic") == len(
         bitonic_schedule(k, m)
     )
+
+
+def test_engine_mode_over_the_interpret_cap_serves_the_stock_sort(monkeypatch):
+    """Off-TPU, a bitonic-mode sort above BITONIC_INTERPRET_MAX must take
+    the equivalent stock formulation (hashp1) with its one-time warning —
+    the branch every CPU run at production block sizes takes."""
+    from locust_tpu.core.kv import KVBatch
+    from locust_tpu.ops import process_stage
+
+    monkeypatch.setattr(process_stage, "BITONIC_INTERPRET_MAX", 1024)
+    monkeypatch.setattr(process_stage, "_warned_bitonic_interpret", False)
+    rng = np.random.default_rng(0)
+    keys = rng.integers(97, 101, size=(4096, 8), dtype=np.uint8)
+    batch = KVBatch.from_bytes(
+        jnp.asarray(keys), jnp.ones(4096, jnp.int32),
+        jnp.asarray(rng.random(4096) < 0.8),
+    )
+    got = process_stage.sort_and_compact(batch, "bitonic")
+    want = process_stage.sort_and_compact(batch, "hashp1")
+    assert process_stage._warned_bitonic_interpret is True
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

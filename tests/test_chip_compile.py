@@ -1,0 +1,193 @@
+"""Compile the main path's kernels for a DESCRIBED v5e — no chip attached.
+
+Interpret mode cannot say whether Mosaic accepts a kernel: both kernels the
+fused path rests on passed every interpret-mode test and were refused by
+the chip's compiler (an i1 vector shifted across lanes; a scoped-VMEM
+budget).  The TPU compiler is installed here and compiles for a topology
+that is described and not attached, so these cases guard every later PR at
+no chip time.  A compile that passes is not a chip run (chip_smoke.py is).
+
+Rules of this file (on-chip-measurement guide §2): the topology is
+described INSIDE a module-scoped fixture that skips when it cannot be —
+never at import, in a skipif or in parametrize arguments (only one process
+may load libtpu; a module that decides at import breaks xdist collection);
+every compile runs in this process with the persistent cache off around
+it (a described-chip executable cannot be read back without a chip).
+Code that asks ``jax.default_backend()`` sees the CPU here, so each case
+compiles the kernel or jitted function itself and steers the TPU branch
+from the test (``map_impl="einsum"``, ``interpret=False``).
+
+Left out, with the measured reason (described v5e, no chip, PR 22): one
+``hasht`` ``fold_into`` of a 4096-line block compiles in 152 s, and any
+``hash*`` Process program (one multi-operand ``lax.sort``) in ~200 s —
+whole-program compiles are a scratch script's job, not a test's.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from locust_tpu.config import EngineConfig
+
+# The published widths (CLI defaults; reference EMITS_PER_LINE=20).
+WIDTHS = dict(line_width=128, emits_per_line=20, key_width=32)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def cache_off():
+    """A described-chip executable is written to the persistent cache but
+    cannot be read back without a chip (the next compile would warn)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _block(one_chip, block_lines):
+    return jax.ShapeDtypeStruct(
+        (block_lines, WIDTHS["line_width"]), jnp.uint8, sharding=one_chip
+    )
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("block_lines", [4096, 32768])
+def test_tokenize_block_pallas_compiles(one_chip, block_lines):
+    from locust_tpu.ops.pallas.tokenize import tokenize_block_pallas
+
+    cfg = EngineConfig(block_lines=block_lines, **WIDTHS)
+    compiled = tokenize_block_pallas.lower(
+        _block(one_chip, block_lines), cfg, False
+    ).compile()
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("block_lines", [4096, 32768])
+def test_fused_block_preagg_compiles_at_smoke_widths(one_chip, block_lines):
+    """Every op of the megakernel (mask shifts, Gram dedupe, hash, probe
+    scatters, residual compaction) at both block sizes and the real
+    line_width — at emits_per_line=4 / key_width=8, because Mosaic takes
+    ~150 s on the fully unrolled published widths (the slow case below)."""
+    from locust_tpu.ops.pallas.fused_fold import fused_block_preagg
+
+    cfg = EngineConfig(block_lines=block_lines, line_width=128,
+                       emits_per_line=4, key_width=8, sort_mode="fused")
+    compiled = fused_block_preagg.lower(
+        _block(one_chip, block_lines), cfg, False
+    ).compile()
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("block_lines", [4096, 32768])
+def test_fused_block_preagg_compiles_at_published_widths(one_chip, block_lines):
+    """The kernel chip_smoke.py runs.  ~150 s a case (Mosaic, 640 unrolled
+    (slot, byte) reductions + 270 probe contractions), so outside tier-1;
+    this is the case that found the 16 MB scoped-VMEM refusal."""
+    from locust_tpu.ops.pallas.fused_fold import fused_block_preagg
+
+    cfg = EngineConfig(block_lines=block_lines, sort_mode="fused", **WIDTHS)
+    compiled = fused_block_preagg.lower(
+        _block(one_chip, block_lines), cfg, False
+    ).compile()
+    assert _has_kernel(compiled)
+
+
+def _dot_precisions(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _dot_precisions(inner, out)
+    return out
+
+
+def test_fused_kernel_asks_full_f32_where_operands_exceed_a_byte():
+    """What only the chip shows, pinned structurally: Mosaic's default
+    precision feeds the MXU bf16 operands — exact for bytes and one-hots,
+    wrong for the squared norms and the per-tile counts.  Exactly the
+    norm broadcast, the count scatter of each probe round and the
+    residual count compaction carry Precision.HIGHEST; on the CPU and in
+    the interpreter f32 dots are exact, so dropping one of these passes
+    every other test and miscounts on the chip (first chip run, PR 22)."""
+    from locust_tpu.config import HASHT_PROBES
+    from locust_tpu.ops.pallas.fused_fold import fused_block_preagg
+
+    cfg = EngineConfig(block_lines=64, line_width=128, emits_per_line=4,
+                       key_width=8, sort_mode="fused")
+    jaxpr = jax.make_jaxpr(
+        lambda x: fused_block_preagg(x, cfg, False)
+    )(jnp.zeros((64, 128), jnp.uint8))
+    exact = [p for p in _dot_precisions(jaxpr.jaxpr, []) if p is not None]
+    assert len(exact) == 1 + HASHT_PROBES + 1
+    assert all(
+        p == (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+        for p in exact
+    )
+
+
+def test_bitonic_sort_compiles(one_chip):
+    from locust_tpu.ops.pallas.sort import bitonic_sort
+
+    n = 1 << 17
+    arr = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(
+        functools.partial(bitonic_sort, interpret=False)
+    ).lower(arr, (arr,)).compile()
+    assert _has_kernel(compiled)
+
+
+def test_tokenize_block_einsum_branch_compiles(one_chip):
+    """The MXU formulation of the map stage — the branch a TPU takes
+    (``map_impl="auto"`` asks jax.default_backend(), which is the CPU
+    here, so the test names it) and no CPU test ever compiled."""
+    from locust_tpu.ops.map_stage import tokenize_block
+
+    cfg = EngineConfig(block_lines=4096, map_impl="einsum", **WIDTHS)
+    compiled = jax.jit(tokenize_block, static_argnums=1).lower(
+        _block(one_chip, 4096), cfg
+    ).compile()
+    assert not _has_kernel(compiled)  # pure XLA
+    assert "convolution" in compiled.as_text() or "dot" in compiled.as_text()
+
+
+def test_check_kernels_match_chip_smoke():
+    """Every kernel chip_smoke.py runs on the chip has a compile case
+    above, by name."""
+    import chip_smoke
+
+    have = {
+        name[len("test_"):].split("_compiles")[0]
+        for name in globals()
+        if name.startswith("test_") and "_compiles" in name
+    }
+    assert set(chip_smoke.CHIP_KERNELS) <= have

@@ -142,7 +142,7 @@ def test_distributed_overflow_accumulates_across_rounds():
 
 
 def test_distributed_skew_beyond_bins_is_lossless():
-    """VERDICT.md round-1 #3: distinct-key skew exceeding bin_capacity used
+    """Distinct-key skew exceeding bin_capacity used
     to silently drop counts.  retry mode drains the backlog in extra
     all-to-all rounds: the result must match the oracle EXACTLY."""
     mesh = make_mesh(8)
@@ -181,7 +181,7 @@ def test_distributed_drop_mode_preserves_reference_behavior():
 
 
 def test_distributed_truncation_flag_on_shard_table_overflow():
-    """VERDICT.md round-1 #5: a vocabulary exceeding a shard's table used to
+    """A vocabulary exceeding a shard's table used to
     drop keys with NO signal; now DistributedResult.truncated reports it."""
     mesh = make_mesh(8)
     cfg = small_cfg()
@@ -218,7 +218,7 @@ def test_distributed_shard_capacity_decoupled_from_round_volume():
 
 
 def test_distributed_checkpoint_resume(tmp_path):
-    """VERDICT.md round-1 #6: crash mid-corpus on the 8-device mesh; a
+    """Crash mid-corpus on the 8-device mesh; a
     re-run resumes after the last completed round and matches exactly."""
     mesh = make_mesh(8)
     cfg = small_cfg(block_lines=4)  # 32 lines/round -> several rounds
@@ -406,9 +406,7 @@ def test_bitonic_kernel_traces_under_shard_map():
     k = (jnp.arange(8 * 2048, dtype=jnp.uint32)
          * jnp.uint32(2654435761)) % jnp.uint32(977)
     v = jnp.arange(8 * 2048, dtype=jnp.uint32)
-    from locust_tpu.parallel.mesh import compat_shard_map
-
-    f = jax.jit(compat_shard_map(
+    f = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("d"), P("d")),
         out_specs=(P("d"), P("d")), check_vma=False,
     ))

@@ -503,8 +503,6 @@ def test_fused_kernel_traces_under_shard_map():
     exercised: it is the CPU-compiler segfault class.)"""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from locust_tpu.parallel.mesh import compat_shard_map
-
     cfg = EngineConfig(block_lines=32, line_width=128, key_width=8,
                        emits_per_line=4, sort_mode="fused")
     per = [
@@ -522,7 +520,7 @@ def test_fused_kernel_traces_under_shard_map():
         )
         return tab.values, tab.key_lanes, tab.valid
 
-    f = jax.jit(compat_shard_map(
+    f = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("d"),), out_specs=(P("d"), P("d"), P("d")),
         check_vma=False,
     ))

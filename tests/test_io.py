@@ -61,6 +61,39 @@ def test_native_ingest_matches_python(corpus_file):
         )
 
 
+def test_native_library_is_keyed_by_the_source_sha(tmp_path, monkeypatch):
+    """A copied checkout carries arbitrary mtimes and possibly a stale
+    untracked .so: the built file's NAME says which source it matches."""
+    import hashlib
+
+    from locust_tpu.io import native_ingest
+
+    src = native_ingest._SRC.read_bytes()
+    sha = hashlib.sha256(src).hexdigest()[:12]
+    so = native_ingest.so_path()
+    assert so.name == f"libingest-{sha}.so"
+    assert so.parent == native_ingest._NATIVE_DIR / "build"
+    if not native_ingest.available():
+        pytest.skip("native build unavailable")
+    assert so.exists() and native_ingest._build() == so
+    # An edited source is a different file name, whatever the mtimes say.
+    edited = tmp_path / "ingest.cpp"
+    edited.write_bytes(src + b"\n// edited\n")
+    monkeypatch.setattr(native_ingest, "_SRC", edited)
+    assert native_ingest.so_path().name != so.name
+
+
+def test_native_stale_unkeyed_library_is_never_loaded():
+    """The old un-keyed name, however new its mtime, is not what loads."""
+    from locust_tpu.io import native_ingest
+
+    if not native_ingest.available():
+        pytest.skip("native build unavailable")
+    stale = native_ingest._NATIVE_DIR / "build" / "libingest.so"
+    assert native_ingest._build() != stale
+    assert native_ingest._build().name.startswith("libingest-")
+
+
 def test_native_ingest_long_line_truncates(tmp_path):
     from locust_tpu.io import native_ingest
 

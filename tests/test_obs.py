@@ -350,14 +350,9 @@ def test_attributed_run_joins_families_onto_stage_spans(tmp_path):
     assert joins and joins[0]["args"]["spans_annotated"] == len(proc)
 
 
-def test_attribution_record_rows_on_cpu(tmp_path, monkeypatch):
-    """The evidence path: record_stage_device_row(force=True) lands a
-    ledger row off-TPU with backend 'cpu' — CPU-fallback evidence that
-    can never masquerade as TPU rows (readers filter on backend)."""
-    monkeypatch.setenv("LOCUST_ARTIFACTS_DIR", str(tmp_path / "art"))
-    from locust_tpu.engine import StageTimes
-    from locust_tpu.utils.artifacts import ledger_rows
-
+def test_attribution_family_join_pairs_hasht_mxu_with_all_three_families():
+    """hasht-mxu's Process time is sort + scatter + the one-hot dots:
+    pairing its bytes with a dot-free time would inflate utilization."""
     join = attribution.family_join(
         {"sort_ms": 5.0, "scatter_ms": 2.0, "dot_ms": 1.0,
          "device_total_ms": 10.0, "device_plane": "/host:CPU"},
@@ -365,62 +360,6 @@ def test_attribution_record_rows_on_cpu(tmp_path, monkeypatch):
     )
     assert join["process_family"] == "scatter+sort+dot"
     assert join["process_device_ms"] == 8.0
-    row = attribution.record_stage_device_row(
-        join, {"sort_mode": "hasht-mxu", "block_lines": 8},
-        times=StageTimes(1.0, 2.0, 3.0), force=True,
-    )
-    assert row["source"] == "obs_attribution"
-    rows = ledger_rows(str(tmp_path / "art" / "tpu_runs.jsonl"))
-    assert len(rows) == 1
-    assert rows[0]["kind"] == "stage_device_time"
-    assert rows[0]["backend"] == "cpu"
-    assert rows[0]["process_device_ms"] == 8.0
-    assert rows[0]["process_wall_ms"] == 2.0
-
-
-def test_phase_profile_emits_both_rows_through_attribution_on_cpu(
-    tmp_path, monkeypatch,
-):
-    """The sweep's profiled phase (scripts/opp_resume.phase_profile) must
-    leave BOTH evidence rows — profiled_roofline and the attribution
-    stage_device_time — through the new path on a CPU fallback, with no
-    extra sweep phases."""
-    import importlib.util
-    import sys
-
-    monkeypatch.setenv("LOCUST_ARTIFACTS_DIR", str(tmp_path / "art"))
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, repo)
-    spec = importlib.util.spec_from_file_location(
-        "opp_resume_obs_test", os.path.join(repo, "scripts", "opp_resume.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    mod._ENGINES.clear()
-
-    # Default line_width: the phase builds its engine via
-    # bench.bench_engine_config, whose row shape the staging must match.
-    eng = MapReduceEngine(
-        EngineConfig(block_lines=8, key_width=8, emits_per_line=4)
-    )
-    rows = eng.rows_from_lines([b"alpha beta alpha", b"beta gamma"] * 8)
-    mod.phase_profile(
-        rows, 400, "hash", 8,
-        caps={"key_width": 8, "emits_per_line": 4},
-    )
-    from locust_tpu.utils.artifacts import ledger_rows
-
-    led = ledger_rows(str(tmp_path / "art" / "tpu_runs.jsonl"))
-    kinds = {r["kind"] for r in led}
-    assert {"profiled_roofline", "stage_device_time"} <= kinds, kinds
-    sd = next(r for r in led if r["kind"] == "stage_device_time")
-    assert sd["backend"] == "cpu"
-    assert sd["source"] == "obs_attribution"
-    assert sd["process_family"] == "sort"
-    pr = next(r for r in led if r["kind"] == "profiled_roofline")
-    assert pr["backend"] == "cpu"
-    assert pr.get("xplane_skipped"), "CPU capture must not claim a TPU blob"
-    assert pr.get("process_family") == "sort"
 
 
 def test_engine_config_trace_knob_enables_process_tracer():

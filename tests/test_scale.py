@@ -1,6 +1,6 @@
 """Scale: corpora whose vocabulary exceeds the default table capacity.
 
-VERDICT.md round-1 #9: nothing exercised >65,536 distinct keys (the
+Once nothing exercised >65,536 distinct keys (the
 default ``resolved_table_size``), where truncation semantics actually
 bite.  These tests build a synthetic corpus with a unique-heavy Zipf-ish
 vocabulary larger than 2^16 and push it through the fused single-device
