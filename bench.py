@@ -87,9 +87,8 @@ _PER_BACKEND = {
     # rows left the tree with PR 22) had payload-carry (C_hash3_payload 67.4ms)
     # beating the gather form ("hash", B 82.6ms) by 18% at the stage that
     # dominates the pipeline — so the static default follows the
-    # measurement (VERDICT r3 weak #2).  An engine-level
-    # engine_sort_mode_ab row supersedes this the moment a window lands
-    # one (_evidence_tuned_tpu_defaults).
+    # measurement.  An engine-level engine_sort_mode_ab row supersedes
+    # this once a run records one (_evidence_tuned_tpu_defaults).
     "tpu": {"block_lines": 32768, "sort_mode": "hashp", "use_pallas": False},
     # CPU: the sort-free hash-table fold wins the driver-policy grid
     # decisively (artifacts/bench_block_cpu_r4.jsonl, 2026-07-31:
@@ -193,10 +192,9 @@ def _evidence_tuned_tpu_defaults(defaults: dict, caps: dict | None = None) -> di
         """Joint-measurement rule for the capacity axes: the row's
         recorded caps (older rows predate the field = engine defaults)
         must equal the caps this bench run assembles, and the row's
-        corpus size must match the size THIS bench runs at — the
-        farm loop's second-sourcing sweeps (8MB / 64MB, VERDICT r4 next
-        #9) append to the same ledger kinds, and an off-shape winner
-        must not steer the 32MB headline config (code review, r5)."""
+        corpus size must match the size THIS bench runs at — sweeps at
+        other sizes (8MB / 64MB) append to the same ledger kinds, and an
+        off-shape winner must not steer the 32MB headline config (code review, r5)."""
         if caps is None:
             return True
         row_caps = row.get("caps") or {"key_width": 32, "emits_per_line": 20}
@@ -221,8 +219,8 @@ def _evidence_tuned_tpu_defaults(defaults: dict, caps: dict | None = None) -> di
         return -1.0
 
     def lossless_sides(sides: dict) -> dict:
-        """Drop A/B sides that measured a semantically DIFFERENT run
-        (VERDICT r4 weak #5 / next #8): nonzero overflow_tokens, or
+        """Drop A/B sides that measured a semantically DIFFERENT run:
+        nonzero overflow_tokens, or
         fewer distinct keys than the best side in the same row — losing
         tokens or truncating the table can only shrink distinct, so the
         within-row maximum is the exact anchor.  A faster-but-lossy side
@@ -256,7 +254,7 @@ def _evidence_tuned_tpu_defaults(defaults: dict, caps: dict | None = None) -> di
     # Evidence must never break a run (same stance as utils/artifacts.py).
     def newest_matching(rows, extra=None):
         """Newest row passing the joint-measurement rules — NOT just
-        rows[-1]: the farm's second-sourcing sweeps (8MB/64MB) append
+        rows[-1]: sweeps at other sizes (8MB/64MB) append
         off-shape rows to the same kinds, and an off-shape LAST row must
         skip back to the newest headline-shaped one, not knock the whole
         kind out (code review, r5)."""
@@ -402,7 +400,7 @@ def _evidence_tuned_tpu_defaults(defaults: dict, caps: dict | None = None) -> di
 
 def load_corpus(target_bytes: int) -> list[bytes]:
     here = os.path.dirname(os.path.abspath(__file__))
-    # Realism knob (VERDICT r2 weak #7): replicated hamlet has only ~5.6k
+    # Realism knob: replicated hamlet has only ~5.6k
     # distinct words, which stresses neither the 65,536-row table nor skew
     # handling.  LOCUST_BENCH_VOCAB=<n> switches to the Zipf generator at
     # that vocabulary, making the headline number harder to game.
@@ -1860,7 +1858,7 @@ def run_bench(backend: str) -> dict:
         f"distinct={res.num_segments}, truncated={res.truncated}",
         file=sys.stderr,
     )
-    # Roofline calibration (VERDICT r3 next #3): how hard does the sort —
+    # Roofline calibration: how hard does the sort —
     # the pipeline's dominant consumer — work the chip's memory system,
     # judged against the device's peak HBM bandwidth rather than against
     # the reference's 2016 GPU.
@@ -1927,8 +1925,7 @@ def run_bench(backend: str) -> dict:
         ab = _best_tpu_ab_row()
         if ab:
             payload["last_tpu_ab"] = ab
-    # Opportunistic TPU evidence (VERDICT r2 #1): every TPU bench run leaves
-    # a committed-able row in artifacts/tpu_runs.jsonl, independent of
+    # TPU evidence: every TPU bench run leaves a committed-able row in artifacts/tpu_runs.jsonl, independent of
     # whether the driver captures this process's stdout.
     from locust_tpu.utils import artifacts
 

@@ -59,12 +59,8 @@ KERNEL_BLOCK_LINES = 32768       # one real block, the kernels phase
 BITONIC_N = 1 << 17
 # The chip check allows a cold run 1200 s, nearly all of it compilation
 # (measured cold on a v5e, PR 22: ~745 s to the end of the kernel
-# comparisons, ~135 s more for the `--sort-mode fused` CLI run).  That
-# last run is the first thing shed: it starts only if the process is
-# younger than this, so a host that compiles 1.3x slower still ends in
-# time — and says on the phase's line that it shed.
-FUSED_CLI_START_BY_S = 900.0
-_T0 = time.monotonic()
+# comparisons, ~135 s more for the `--sort-mode fused` CLI run).  Every
+# phase always runs: a host too slow for that fails at the limit, loudly.
 
 _SPLIT = re.compile(b"[" + re.escape(FULL_DELIMITERS) + b"]+")
 # Anything the CLI says about lost or re-routed work fails the phase.
@@ -340,7 +336,7 @@ def phase_kernels(path: str, tmpdir: str, backend: str = "tpu") -> None:
     """Every CHIP_KERNELS entry compiled by Mosaic (interpret=False) on one
     real 32768-line block, bit for bit against its XLA formulation; then
     ``--sort-mode fused`` through the CLI on the corpus's first 4 MiB
-    (shed when the process is already older than FUSED_CLI_START_BY_S)."""
+    against the oracle."""
     import jax.numpy as jnp
 
     cfg = EngineConfig(block_lines=KERNEL_BLOCK_LINES, line_width=128,
@@ -355,13 +351,6 @@ def phase_kernels(path: str, tmpdir: str, backend: str = "tpu") -> None:
     if "fused_block_preagg" not in CHIP_KERNELS:
         say("kernels --sort-mode fused CLI run: left out "
             "(fused_block_preagg is not in CHIP_KERNELS)")
-        return
-    age = time.monotonic() - _T0
-    if age > FUSED_CLI_START_BY_S:
-        say(f"kernels --sort-mode fused CLI run: SHED — the process is "
-            f"{age:.0f} s old (> {FUSED_CLI_START_BY_S:.0f} s) and this "
-            "run's cold compiles would pass the smoke's time limit; a "
-            "second run, on the cache this one filled, includes it")
         return
     head = os.path.join(tmpdir, "head.txt")
     with open(path, "rb") as f, open(head, "wb") as g:

@@ -173,8 +173,8 @@ class ShardedPageRank:
     """Node-partitioned PageRank: rank state sharded, not replicated.
 
     ``DistributedPageRank`` replicates dense ``[num_nodes]`` rank/degree
-    vectors on every device, capping graph size at one device's HBM
-    (VERDICT r1 weak #5 / r2 missing #5).  Here device ``d`` owns the
+    vectors on every device, capping graph size at one device's HBM.
+    Here device ``d`` owns the
     contiguous node block ``[d*npd, (d+1)*npd)`` and only ever holds
 
       * its rank/degree block                  O(nodes / n_dev)
@@ -217,7 +217,7 @@ class ShardedPageRank:
         Fully vectorized — ONE lexsort over (owner, dest_shard, dst) plus
         run-length boundaries; the per-(device, shard) ``np.unique`` loop
         it replaces was O(n_dev^2) host work, quadratic in devices on a
-        real pod (VERDICT r3 weak #6).  A dst's slot id is its rank among
+        real pod.  A dst's slot id is its rank among
         the distinct dsts of its (owner, dest_shard) pair, which after
         the lexsort is a prefix count of run starts — identical to the
         old builder's ``searchsorted(uniq, dst)`` because uniq was

@@ -467,7 +467,7 @@ def _run_mesh(args, cfg, timer, prof, preloaded_rows=None,
     README.md:12-24) but its shipped entrypoint is single-GPU; here one
     ``--mesh`` flag routes the same positional contract through the
     all-to-all shuffle (parallel/shuffle.py), so a multi-chip host uses
-    every chip (VERDICT r2 missing #3).
+    every chip.
     """
     import time as _time
 

@@ -68,7 +68,7 @@ def trace_stamp(shard: int | None = None) -> dict | None:
     return stamp
 
 # fetch window sizing: intermediates larger than one frame stream in
-# offset-addressed chunks (VERDICT r2 missing #6).  Raw bytes per chunk;
+# offset-addressed chunks.  Raw bytes per chunk;
 # base64 expands 4/3, so even the max chunk is well under MAX_FRAME.
 FETCH_CHUNK = 8 * 1024 * 1024
 FETCH_CHUNK_MAX = 32 * 1024 * 1024

@@ -699,8 +699,8 @@ class MapReduceEngine:
     ) -> RunResult:
         """Fold an ITERABLE of ``[<=block_lines, width]`` host row blocks.
 
-        Bounded-memory ingest for corpora that don't fit RAM (VERDICT r2
-        missing #4): pair with ``io.loader.StreamingCorpus`` and only one
+        Bounded-memory ingest for corpora that don't fit RAM: pair
+        with ``io.loader.StreamingCorpus`` and only one
         file window plus the accumulator table are ever resident.  Device
         counters stay on device across blocks (same pipelining as
         ``run``); blocks shorter than ``cfg.block_lines`` are zero-padded

@@ -56,7 +56,7 @@ def write_corpus(
     chunk so the Zipf draw differs, keeping the corpus deterministic for
     a given target) — a multi-GB corpus never materializes in RAM, so
     north-star-scale streaming artifacts can pre-generate in a separate
-    process without a giant RSS of their own (VERDICT r3 next #4).
+    process without a giant RSS of their own.
     Returns bytes written.
     """
     if chunk_bytes < 1:

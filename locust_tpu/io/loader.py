@@ -276,7 +276,7 @@ def count_lines(path: str) -> int:
     The canonical trailing-fragment rule (Q1 semantics): a final line
     without a newline still counts.  Single source of truth — the
     distributor master and the native ingest parity tests both use this
-    (VERDICT r2 weak #6: two drifting copies).
+    (two drifting copies).
     """
     n = 0
     last = b"\n"
@@ -311,7 +311,7 @@ def load_rows(
 
 class StreamingCorpus:
     """Iterate ``[<=block_lines, line_width]`` row blocks of a file in
-    bounded memory (VERDICT r2 missing #4).
+    bounded memory.
 
     ``load_rows`` materializes the whole corpus — fine for hamlet, fatal
     for the 1GB+ north star (BASELINE.json).  This reader holds one

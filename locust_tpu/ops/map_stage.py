@@ -62,7 +62,7 @@ def tokenize_block(lines: jax.Array, cfg: EngineConfig) -> TokenizeResult:
     valid = slot[None, :] < jnp.minimum(ntok, emits)[:, None]
 
     # keys[l,e,k] = lines[l, start[l,e]+k], formulated per backend
-    # (cfg.map_impl; VERDICT r3 weak #4).
+    # (cfg.map_impl).
     padded = jnp.pad(lines, ((0, 0), (0, key_w)))
     impl = cfg.map_impl
     if impl == "auto":

@@ -1,4 +1,4 @@
-"""Pallas TPU bitonic sort for the Process stage (VERDICT r3 next #2).
+"""Pallas TPU bitonic sort for the Process stage.
 
 The Process-stage sort is where the reference's target is won or lost
 (94% of its GPU runtime: reference MapReduce/src/main.cu:414-415 region);
@@ -111,7 +111,7 @@ def _local_stages_kernel(*refs, stages, tile_rows, n_ops):
                 # selected.  Rotation is spelled slice+concat rather than
                 # jnp.roll: roll's lowering drops the varying-manual-axes
                 # type under shard_map(check_vma=True), poisoning every
-                # downstream compare (jax issue; VERDICT r4 next #7) —
+                # downstream compare (jax issue) —
                 # slice/concat propagate vma correctly and lower the same.
                 def _rot(a, k):  # left-rotate lanes by k
                     return jnp.concatenate([a[:, k:], a[:, :k]], axis=1)

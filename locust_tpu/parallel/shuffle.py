@@ -599,7 +599,7 @@ def build_shuffle_step(
     def local_step(lines: jax.Array, acc: KVBatch, leftover: KVBatch):
         """Per-device body (runs under shard_map): feed + on-device drain.
 
-        VERDICT r2 weak #3: the drain loop used to live on the HOST,
+        The drain loop used to live on the HOST,
         costing one blocking device_get per feed round even when the
         backlog was empty — serializing dispatch on high-latency
         remote-TPU links.  Folding it into lax.while_loop makes the
@@ -876,8 +876,8 @@ class DistributedMapReduce:
         # without touching non-addressable shards.
         #
         # check_vma: disabled for sort_mode="bitonic" ON TPU so the
-        # hand-written Pallas kernel actually RUNS on mesh engines
-        # (VERDICT r4 next #7).  Under check_vma=True the kernel cannot
+        # hand-written Pallas kernel actually RUNS on mesh engines.
+        # Under check_vma=True the kernel cannot
         # trace — jax's vma machinery breaks inside the pallas interpret
         # re-trace (verified this jax version: "Primitive lt requires
         # varying manual axes to match") — and process_stage._bitonic_sort
@@ -979,8 +979,8 @@ class DistributedMapReduce:
         the next stats sync.  The drain loop runs ON DEVICE
         (lax.while_loop inside the step) and stats accumulate on device,
         synced to the host only every ``stats_sync_every`` rounds — round
-        dispatch pipelines with no per-round host round-trip (VERDICT r2
-        weak #3).  Invariant violations (data loss, undrained backlog)
+        dispatch pipelines with no per-round host round-trip.
+        Invariant violations (data loss, undrained backlog)
         therefore surface up to ``stats_sync_every - 1`` rounds late, but
         no less loudly.
 
@@ -1015,8 +1015,8 @@ class DistributedMapReduce:
         stats_sync_every: int = 16,
     ) -> "DistributedResult":
         """Like ``run`` but over an ITERABLE of ``[<=lines_per_round, width]``
-        host row blocks — bounded-memory ingest at mesh scale (VERDICT r2
-        missing #4).  Pair with ``io.loader.StreamingCorpus(path, width,
+        host row blocks — bounded-memory ingest at mesh scale.
+        Pair with ``io.loader.StreamingCorpus(path, width,
         block_lines=self.lines_per_round)``; pass its ``fingerprint()`` to
         enable checkpoint/resume (resume re-reads but does not re-process
         already-folded rounds).

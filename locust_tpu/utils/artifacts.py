@@ -32,10 +32,9 @@ def artifacts_dir() -> str:
 
 # Ledger kinds whose rows DRIVE bench.py's evidence-tuned configuration
 # (bench._evidence_tuned_tpu_defaults reads exactly these).  Shared here
-# (jax-free) so the farm loop's bench-staleness check and bench's tuning
-# can never drift: a kind added to one but not the other either leaves
-# the committed headline stale or burns windows re-running an unchanged
-# config.  emits_per_line_ab / key_width_ab are deliberately absent —
+# (jax-free) so whatever writes these rows and bench's tuning can never
+# drift: a kind added to one but not the other leaves the headline
+# config stale.  emits_per_line_ab / key_width_ab are deliberately absent —
 # they are verification phases; bench auto-sizes caps from the corpus.
 CONFIG_AB_KINDS = (
     "engine_sort_mode_ab",
@@ -64,23 +63,22 @@ BENCH_SUBDICT_KINDS = {
 def ledger_rows(path: str | None = None) -> list[dict]:
     """Parsed rows of the evidence ledger (malformed lines skipped).
 
-    The single ledger reader: the farm loop's harvest schedule, the
-    sweep's phase skips, and bench's evidence tuning all decide off this
+    The single ledger reader: bench's evidence tuning decides off this
     file, and it is appended by concurrent processes and merged across
     machines via git — every consumer must treat it as untrusted,
     per-line.  One shared copy so a hardening fix can't miss a caller.
 
     ``path`` pins an explicit ledger file; default is the live
-    ``artifacts_dir()`` ledger.  Callers whose WRITES are pinned (the
-    farm loop git-commits the repo ledger) must pin their reads to the
-    same file or the two silently diverge under $LOCUST_ARTIFACTS_DIR.
+    ``artifacts_dir()`` ledger.  Callers whose WRITES are pinned to one
+    file must pin their reads to the same file or the two silently
+    diverge under $LOCUST_ARTIFACTS_DIR.
     """
     rows: list[dict] = []
     try:
         # errors="replace": a torn binary write or merge artifact must
         # cost ONE line (json.loads rejects the U+FFFD), not the whole
         # scan — UnicodeDecodeError from line iteration would otherwise
-        # escape the per-line guard and kill the farm supervisor.
+        # escape the per-line guard and kill the caller.
         with open(
             path or os.path.join(artifacts_dir(), "tpu_runs.jsonl"),
             encoding="utf-8",

@@ -48,7 +48,7 @@ def validate_batch(batch: KVBatch, expect_sorted: bool = False, expect_compact: 
         if valid.any():
             last_valid = np.max(np.nonzero(valid)[0])
             assert valid[: last_valid + 1].all(), "valid rows not a prefix"
-    # Vectorized throughout (VERDICT r2 weak #4): Python per-row loops made
+    # Vectorized throughout: Python per-row loops made
     # LOCUST_DEBUG_CHECKS cost seconds on a 65k-row table; these numpy row
     # ops keep it in the low milliseconds, same assertions.
     if expect_sorted:

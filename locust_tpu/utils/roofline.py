@@ -1,4 +1,4 @@
-"""Roofline accounting for the Process-stage sort (VERDICT r3 next #3).
+"""Roofline accounting for the Process-stage sort.
 
 "15x a GTX 1060" says nothing about how much of a TPU the pipeline uses.
 This module converts a bench run's configuration + elapsed time into an

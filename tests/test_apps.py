@@ -273,7 +273,7 @@ def test_inverted_index_capacity_exceeded_raises():
 
 
 class TestShardedPageRank:
-    """Node-partitioned PageRank (VERDICT r2 missing #5): rank state is
+    """Node-partitioned PageRank: rank state is
     sharded O(nodes/n_dev) per device; routing is a static sparse plan."""
 
     def _mesh(self):
@@ -310,7 +310,7 @@ class TestShardedPageRank:
     def _build_plan_loop(spr, src, dst):
         """The pre-r4 O(n_dev^2) per-(device, shard) np.unique builder,
         kept verbatim as the regression oracle for the vectorized
-        lexsort builder (VERDICT r3 next #6)."""
+        lexsort builder."""
         n_dev, npd = spr.n_dev, spr.npd
         src = np.asarray(src, np.int64)
         dst = np.asarray(dst, np.int64)

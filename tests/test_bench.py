@@ -290,7 +290,7 @@ def test_evidence_tuning_guards_each_kind_independently(
 
 
 def test_evidence_tuning_rejects_off_shape_corpus(tmp_path, monkeypatch, capsys):
-    """The farm loop's second-sourcing sweeps record A/B rows at 8MB /
+    """Sweeps at other sizes record A/B rows at 8MB /
     64MB into the same ledger kinds; a row measured at a different
     corpus size than the headline bench runs must not steer its config
     (code review, r5).  Legacy rows without corpus_mb still count."""
@@ -358,8 +358,8 @@ def test_evidence_tuning_reaches_past_off_shape_rows(
 
 
 def test_evidence_tuning_rejects_lossy_sides(tmp_path, monkeypatch, capsys):
-    """A faster-but-lossy A/B side must never steer the headline config
-    (VERDICT r4 next #8): nonzero overflow_tokens, or fewer distinct
+    """A faster-but-lossy A/B side must never steer the headline config:
+    nonzero overflow_tokens, or fewer distinct
     keys than the best side of the same row (= dropped tokens or a
     truncated table), disqualify a side; the best LOSSLESS side wins
     instead."""

@@ -159,7 +159,7 @@ def test_cli_auto_caps_with_stream(corpus_file, capsysbinary):
 
 def test_cli_mesh_mode_matches_oracle(corpus_file, capsysbinary):
     """--mesh routes stage 0 through the all-to-all engine on all 8
-    virtual devices and must match the oracle exactly (VERDICT r2 #3)."""
+    virtual devices and must match the oracle exactly."""
     rc = cli.main([corpus_file, "--mesh"] + _cfg_args())
     assert rc == 0
     got = _parse_table(capsysbinary.readouterr().out)

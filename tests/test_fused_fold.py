@@ -496,7 +496,7 @@ def test_fused_engine_scan_lowers_for_tpu():
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 (virtual) devices")
 def test_fused_kernel_traces_under_shard_map():
     """The shard_map traceability a future TPU mesh integration relies
-    on (the bitonic precedent, VERDICT r4 next #7): a direct small
+    on (the bitonic precedent): a direct small
     interpret-mode kernel call under shard_map(check_vma=False) must
     trace, run per-shard, and pre-aggregate exactly.  (The
     full-mesh-program interpret combination is deliberately NOT

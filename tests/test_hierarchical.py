@@ -273,7 +273,7 @@ def test_cross_engine_checkpoint_not_resumed(tmp_path):
 
 def test_debug_checks_verify_slice_replication(monkeypatch):
     """LOCUST_DEBUG_CHECKS makes the check_vma=False replication claim
-    self-policing (VERDICT r3 next #8): a healthy run passes the
+    self-policing: a healthy run passes the
     per-slice table-equality check; a combine that leaks slice-varying
     data into the merge fires it loudly."""
     monkeypatch.setenv("LOCUST_DEBUG_CHECKS", "1")

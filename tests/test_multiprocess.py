@@ -8,7 +8,7 @@ parts a single-process 8-device mesh cannot exercise.  The reference's
 analogous layer (TCP slave + missing master, SURVEY.md C11/C12) had no
 test at all.
 
-Round 3 (VERDICT r2 missing #8): the r2 features now run under
+Round 3: the r2 features now run under
 ``process_count > 1`` too — distributed checkpoint/resume (multihost
 snapshot gather + resume scatter), the mesh inverted index, and the
 sample sort's multihost result gather.
@@ -191,8 +191,8 @@ def test_two_process_hierarchical(tmp_path):
 def test_two_process_sharded_pagerank(tmp_path):
     """ShardedPageRank with the device axis across processes: plan
     scatter via make_array_from_callback, per-iteration all_to_all over
-    process boundaries, result via process_allgather (VERDICT r3 weak #5:
-    the newest mesh program had no multi-process scenario)."""
+    process boundaries, result via process_allgather (the newest mesh
+    program had no multi-process scenario)."""
     result = _run_workers(tmp_path, "spagerank")
     import numpy as np
 
@@ -210,8 +210,7 @@ def test_two_process_sharded_pagerank(tmp_path):
 def test_four_process_checkpoint_resume(tmp_path):
     """The crash+resume scenario at 4 processes x 2 devices: catches
     process-count-dependent assumptions (snapshot file fan-out, gather
-    shapes, shard alignment) the 2-process rig cannot (VERDICT r3 next
-    #9)."""
+    shapes, shard alignment) the 2-process rig cannot."""
     ckpt = tmp_path / "ckpt4"
     ckpt.mkdir()
     result = _run_workers(tmp_path, "checkpoint", (str(ckpt),), n_procs=4)
@@ -227,7 +226,7 @@ def test_cli_pod_launch(tmp_path):
     """The pod-launch CLI contract end-to-end: the SAME command line on
     every process (own --process-id), coordination via --coordinator,
     and exactly one table on the pod's combined stdout (process 0's).
-    VERDICT r3 missing #5: multi-process launch existed only inside the
+    Multi-process launch existed only inside the
     test rig, with no CLI surface."""
     corpus = tmp_path / "pod.txt"
     corpus.write_bytes(b"\n".join(BASE * 8) + b"\n")

@@ -51,7 +51,7 @@ def test_tokenize_block_extracts_exact_tokens():
 
 def test_tokenize_map_impls_equivalent():
     """The MXU einsum formulation (TPU default) and the scatter+gather
-    formulation (CPU default, VERDICT r3 weak #4) must produce identical
+    formulation (CPU default) must produce identical
     keys/valid/overflow — including overflow lines, empty lines, NUL
     bytes mid-line, and tokens longer than key_width."""
     rng = np.random.default_rng(7)
@@ -292,7 +292,7 @@ def test_engine_checkpoint_fingerprint_mismatch_starts_fresh(tmp_path):
 @pytest.mark.parametrize("mode", list(SORT_MODES))
 def test_engine_oracle_exact_across_sort_modes(mode):
     """Every Process-stage sort strategy must produce the identical table
-    (VERDICT r2 missing #2: hash1/radix are the optimized-sort attempts)."""
+    (hash1/radix are the optimized-sort attempts)."""
     from locust_tpu.config import EngineConfig
     from locust_tpu.engine import MapReduceEngine
 

@@ -1,5 +1,5 @@
 """Property tests for ops.radix_sort.radix_argsort (the optimized
-Process-stage sort attempt, VERDICT r2 missing #2)."""
+Process-stage sort attempt)."""
 
 import numpy as np
 import pytest

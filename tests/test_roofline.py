@@ -1,5 +1,5 @@
 """utils/roofline.py — the sort-traffic/bandwidth model behind the bench's
-chip-utilization claim (VERDICT r3 next #3)."""
+chip-utilization claim."""
 
 import json
 import os
