@@ -119,16 +119,27 @@ def test_fused_block_preagg_compiles_at_published_widths(one_chip, block_lines):
     assert _has_kernel(compiled)
 
 
-def _dot_precisions(jaxpr, out):
+def _eqns(jaxpr, primitive, out):
+    """Every equation of one primitive, nested jaxprs included."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            out.append(eqn.params["precision"])
+        if eqn.primitive.name == primitive:
+            out.append(eqn)
         for v in eqn.params.values():
             for sub in v if isinstance(v, (list, tuple)) else [v]:
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    _dot_precisions(inner, out)
+                    _eqns(inner, primitive, out)
     return out
+
+
+def _fused_jaxpr():
+    from locust_tpu.ops.pallas.fused_fold import fused_block_preagg
+
+    cfg = EngineConfig(block_lines=64, line_width=128, emits_per_line=4,
+                       key_width=8, sort_mode="fused")
+    return jax.make_jaxpr(
+        lambda x: fused_block_preagg(x, cfg, False)
+    )(jnp.zeros((64, 128), jnp.uint8)).jaxpr
 
 
 def test_fused_kernel_asks_full_f32_where_operands_exceed_a_byte():
@@ -140,19 +151,27 @@ def test_fused_kernel_asks_full_f32_where_operands_exceed_a_byte():
     the interpreter f32 dots are exact, so dropping one of these passes
     every other test and miscounts on the chip (first chip run, PR 22)."""
     from locust_tpu.config import HASHT_PROBES
-    from locust_tpu.ops.pallas.fused_fold import fused_block_preagg
 
-    cfg = EngineConfig(block_lines=64, line_width=128, emits_per_line=4,
-                       key_width=8, sort_mode="fused")
-    jaxpr = jax.make_jaxpr(
-        lambda x: fused_block_preagg(x, cfg, False)
-    )(jnp.zeros((64, 128), jnp.uint8))
-    exact = [p for p in _dot_precisions(jaxpr.jaxpr, []) if p is not None]
+    dots = _eqns(_fused_jaxpr(), "dot_general", [])
+    exact = [e.params["precision"] for e in dots
+             if e.params["precision"] is not None]
     assert len(exact) == 1 + HASHT_PROBES + 1
     assert all(
         p == (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
         for p in exact
     )
+
+
+def test_fused_kernel_asks_for_the_vmem_the_published_widths_need():
+    """The published-width compile is ``-m slow`` (150 s a case), so in
+    tier-1 only this guards the repair of Mosaic's refusal there —
+    ``Scoped allocation with size 23.78M and limit 16.00M exceeded scoped
+    vmem limit``: the kernel's pallas_call must carry a limit above that
+    need.  (A change that RAISES the need is caught only by the slow
+    cases: run them before touching ops/pallas/fused_fold.py.)"""
+    (call,) = _eqns(_fused_jaxpr(), "pallas_call", [])
+    params = call.params["compiler_params"]["mosaic_tpu"]
+    assert params.vmem_limit_bytes >= 32 << 20
 
 
 def test_bitonic_sort_compiles(one_chip):
