@@ -58,9 +58,9 @@ FUSED_CLI_BYTES = 4 << 20        # the `--sort-mode fused` CLI run's prefix
 KERNEL_BLOCK_LINES = 32768       # one real block, the kernels phase
 BITONIC_N = 1 << 17
 # The chip check allows a cold run 1200 s, nearly all of it compilation
-# (measured cold on a v5e, PR 22: ~745 s to the end of the kernel
-# comparisons, ~135 s more for the `--sort-mode fused` CLI run).  Every
-# phase always runs: a host too slow for that fails at the limit, loudly.
+# (measured cold on a v5e, PR 22: 871 s for the whole default run, the
+# last 128 s of it the `--sort-mode fused` CLI run).  Every phase always
+# runs: a host too slow for that fails at the limit, loudly.
 
 _SPLIT = re.compile(b"[" + re.escape(FULL_DELIMITERS) + b"]+")
 # Anything the CLI says about lost or re-routed work fails the phase.
