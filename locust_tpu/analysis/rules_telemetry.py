@@ -7,7 +7,8 @@ call-site that nobody runs traced would record nothing, silently,
 forever.  This rule closes the loop statically, both directions:
 
   * every literal name at an obs emission site — ``obs.span(...)``,
-    ``obs.event(...)``, ``obs.metric_inc/metric_set/metric_observe(...)``
+    ``obs.span_at(...)``, ``obs.event(...)``,
+    ``obs.metric_inc/metric_set/metric_observe(...)``
     — must exist in NAMES, with the kind the hook implies (a counter
     incremented as a histogram is the same drift one step subtler);
   * every registered name must be EMITTED somewhere under ``locust_tpu/``
@@ -32,6 +33,7 @@ OBS_NAMES_REL = "locust_tpu/obs/names.py"
 # hook attribute -> the registry kind it emits.
 _EMIT_KINDS = {
     "span": "span",
+    "span_at": "span",
     "event": "event",
     "metric_inc": "counter",
     "metric_set": "gauge",

@@ -92,9 +92,15 @@ def compile_cache_dir(sub: str = ".jax_cache") -> str:
       ``sub=".jax_cache_cpu"`` so entries compiled for the test host's
       CPU stay apart from what a chip run caches.
 
+    It also lowers jax's floor for WRITING an entry
+    (``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``, 1 s by default) to 0
+    unless the variable is already set: a job drives two sub-second
+    helper programs beside its sorts, and under jax's floor every new
+    process compiled those anew (PERF.md section 2).
+
     jax-free (never imports it) and idempotent: every entry point calls
-    it before its first ``import jax``, which reads the variable; a jax
-    that is already imported gets the same directory through its config.
+    it before its first ``import jax``, which reads both variables; a jax
+    that is already imported gets the same values through its config.
     """
     d = _os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not d:
@@ -103,11 +109,17 @@ def compile_cache_dir(sub: str = ".jax_cache") -> str:
             sub,
         )
         _os.environ["JAX_COMPILATION_CACHE_DIR"] = d
+    floor = _os.environ.setdefault(
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0"
+    )
     import sys
 
     jax = sys.modules.get("jax")
     if jax is not None:  # imported before us: its config already read env
         jax.config.update("jax_compilation_cache_dir", d)
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", float(floor)
+        )
     return d
 
 

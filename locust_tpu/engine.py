@@ -161,7 +161,8 @@ class RunResult:
         here on the final table, which is orders of magnitude smaller than
         the emit stream.
         """
-        return finalize_host_pairs(self.table, self.combine, sort)
+        with obs.span("engine.finalize", rows=self.table.size):
+            return finalize_host_pairs(self.table, self.combine, sort)
 
     def dump_intermediate(self, path: str, fmt: str = "tsv") -> None:
         """Stage-1 output plumbing: the combined local table as an
@@ -304,6 +305,9 @@ class MapReduceEngine:
             # same enable + an export at exit); idempotent, shares one
             # process timeline with any tracer already enabled.
             obs.enable()
+        # A fresh engine is where jax re-traces, re-lowers and re-reads
+        # its programs: from here on a traced run records that too.
+        obs.watch_programs()
         self.combine = combine  # user-facing semantics (host finalize)
         # "count" lowers to emit-1 + sum so the block-accumulator merge is
         # associative (reduce_stage.normalize_combine); the device pipeline
@@ -557,11 +561,15 @@ class MapReduceEngine:
         bl = self.cfg.block_lines
         n = rows.shape[0]
         for i in range(0, max(n, 1), bl):
-            blk = rows[i : i + bl]
-            if blk.shape[0] < bl:
-                pad = np.zeros((bl - blk.shape[0], rows.shape[1]), np.uint8)
-                blk = np.concatenate([blk, pad]) if blk.size else pad
-            yield jnp.asarray(blk)
+            # The span closes BEFORE the yield: a generator suspended
+            # inside it would bill the consumer's work to staging.
+            with obs.span("engine.h2d", bytes=bl * rows.shape[1]):
+                blk = rows[i : i + bl]
+                if blk.shape[0] < bl:
+                    pad = np.zeros((bl - blk.shape[0], rows.shape[1]), np.uint8)
+                    blk = np.concatenate([blk, pad]) if blk.size else pad
+                staged = jnp.asarray(blk)
+            yield staged
 
     # ------------------------------------------------------------------- run
 
@@ -662,29 +670,37 @@ class MapReduceEngine:
         for blk in self._blocks(rows):
             # obs spans shadow the t0..t4 boundaries exactly (each stage's
             # sync is inside its span), so an exported timeline and the
-            # reference-parity StageTimes report can never disagree.
+            # reference-parity StageTimes report can never disagree.  The
+            # wait itself is a child span: a stage's self time is the host
+            # launching, its engine.sync the host waiting on the device.
             t0 = time.perf_counter()
             with obs.span("engine.stage.map"):
                 kv, blk_overflow = self._map(blk)
-                jax.block_until_ready(kv.key_lanes)  # locust: noqa[R003] stage-timing boundary (reference parity): the sync IS the measurement
+                with obs.span("engine.sync", what="map"):
+                    jax.block_until_ready(kv.key_lanes)  # locust: noqa[R003] stage-timing boundary (reference parity): the sync IS the measurement
             t1 = time.perf_counter()
             with obs.span("engine.stage.process"):
                 kv = self._process(kv)
-                jax.block_until_ready(kv.key_lanes)  # locust: noqa[R003] stage-timing boundary (reference parity): the sync IS the measurement
+                with obs.span("engine.sync", what="process"):
+                    jax.block_until_ready(kv.key_lanes)  # locust: noqa[R003] stage-timing boundary (reference parity): the sync IS the measurement
             t2 = time.perf_counter()
             with obs.span("engine.stage.reduce"):
                 table = self._reduce(kv)
-                jax.block_until_ready(table.key_lanes)  # locust: noqa[R003] stage-timing boundary (reference parity): the sync IS the measurement
+                with obs.span("engine.sync", what="reduce"):
+                    jax.block_until_ready(table.key_lanes)  # locust: noqa[R003] stage-timing boundary (reference parity): the sync IS the measurement
             t3 = time.perf_counter()
             with obs.span("engine.stage.merge"):
                 acc, max_distinct = self._merge(acc, table, max_distinct)
-                jax.block_until_ready(acc.key_lanes)  # locust: noqa[R003] stage-timing boundary (reference parity): the sync IS the measurement
+                with obs.span("engine.sync", what="merge"):
+                    jax.block_until_ready(acc.key_lanes)  # locust: noqa[R003] stage-timing boundary (reference parity): the sync IS the measurement
             t4 = time.perf_counter()
             times.map_ms += (t1 - t0) * 1e3
             times.process_ms += (t2 - t1) * 1e3 + (t4 - t3) * 1e3
             times.reduce_ms += (t3 - t2) * 1e3
-            overflow += int(blk_overflow)
-        jax.block_until_ready(acc.key_lanes)
+            with obs.span("engine.sync", what="overflow"):
+                overflow += int(blk_overflow)
+        with obs.span("engine.sync", what="close"):
+            jax.block_until_ready(acc.key_lanes)
         return self._finish(acc, max_distinct, overflow, times)
 
     def run_lines(self, lines: Sequence[bytes]) -> RunResult:
@@ -1237,7 +1253,8 @@ class MapReduceEngine:
             validate_batch(
                 acc, expect_compact=self.cfg.sort_mode not in HASHT_FAMILY
             )
-        num = int(num_segments)
+        with obs.span("engine.finalize", rows=acc.size):
+            num = int(num_segments)
         truncated = num > acc.size
         if truncated:
             logger.warning(

@@ -1,13 +1,25 @@
-"""locust_tpu.obs — unified telemetry: tracing, metrics, attribution.
+"""locust_tpu.obs — unified telemetry: tracing and metrics.
 
 One subsystem replaces the fragmented observability that had accreted
-across the repo (SpanTimer wall spans, xplane parsing, per-shard stats,
-stream stall accounting): a process-wide ``Tracer`` with nested named
-spans + instant events, a closed-registry ``Metrics`` surface, Chrome-
-trace/Perfetto export, cross-node span merging over the distributor
-wire, and xplane device-time attribution (``obs.attribution``).  See
+across the repo (SpanTimer wall spans, per-shard stats, stream stall
+accounting): a process-wide ``Tracer`` with nested named spans + instant
+events, a closed-registry ``Metrics`` surface, Chrome-trace/Perfetto
+export, and cross-node span merging over the distributor wire.  See
 docs/OBSERVABILITY.md; the name registry is ``obs/names.py`` (analysis
 rule R009 keeps it honest in both directions).
+
+The span record is (name, start, end, id, parent, job): ``id`` and
+``parent`` (the span open on the same thread) ride in ``args``, a root
+span carries the tracer's ``trace_id``, and ``Tracer.self_times()`` is a
+span's duration minus what its children cover.  ONE clock: timestamps are
+``perf_counter_ns`` anchored once to the epoch, and while jax is imported
+every open span is also a ``jax.profiler.TraceAnnotation`` — a profiler
+session holds the program's spans on ``/host:CPU`` of the same
+``.xplane.pb`` as the device's ops, which is the only device-time join
+there is.  ``watch_programs()`` (called where an engine is made) adds what
+jax reports of itself: ``engine.program.trace/.lower/.load`` spans and the
+``engine.compile_requests``/``engine.cache_hits`` counters
+(``obs/programs.py``); ``disable()`` takes its listeners out again.
 
 ZERO-overhead disabled contract (same stance as ``utils.faultplan``):
 telemetry is OFF by default, and every module hook below bails before
@@ -29,6 +41,7 @@ selection, safe in jax-free supervisors.
 from __future__ import annotations
 
 import contextlib
+import sys
 import threading
 
 from locust_tpu.obs.metrics import Metrics
@@ -37,6 +50,7 @@ from locust_tpu.obs.trace import NULL_SPAN, Tracer
 
 _TRACER: Tracer | None = None
 _METRICS: Metrics | None = None
+_WATCHING = False  # obs.programs' listeners are registered with jax
 _TLS = threading.local()
 
 
@@ -51,9 +65,30 @@ def enable(process: str = "main", trace_id: str | None = None) -> Tracer:
 
 
 def disable() -> None:
-    global _TRACER, _METRICS
+    global _TRACER, _METRICS, _WATCHING
     _TRACER = None
     _METRICS = None
+    if _WATCHING:
+        _WATCHING = False
+        from locust_tpu.obs import programs
+
+        programs.uninstall()
+
+
+def watch_programs() -> None:
+    """Start recording what jax reports of its own compile pipeline
+    (``obs/programs.py``), once per enabled stretch.  Called where an
+    engine is made: a fresh CLI process enables telemetry before it
+    imports jax, so that is the first place a tracer and jax are both
+    certain.  Disabled, or jax not imported: returns having loaded
+    nothing."""
+    global _WATCHING
+    if _WATCHING or current() is None or "jax" not in sys.modules:
+        return
+    from locust_tpu.obs import programs
+
+    programs.install()
+    _WATCHING = True
 
 
 def current() -> Tracer | None:
@@ -91,6 +126,15 @@ def span(name: str, *sync_refs, **args):
     if t is None:
         return NULL_SPAN
     return t.span(name, *sync_refs, **args)
+
+
+def span_at(name: str, start_s: float, end_s: float, **args) -> None:
+    """A span that is already over, timed by its reporter on
+    ``time.time()`` (``Tracer.span_at``)."""
+    t = current()
+    if t is None:
+        return
+    t.span_at(name, start_s, end_s, **args)
 
 
 def event(name: str, **args) -> None:

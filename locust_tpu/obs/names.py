@@ -10,9 +10,10 @@ every entry here is emitted somewhere under ``locust_tpu/``).  A name
 nobody validates is a timeline nobody can correlate.
 
 Emission convention (what R009 can see): emit through the ``obs`` module
-functions with a literal name — ``obs.span("engine.stage.map")``, never a
-name built at runtime.  Kinds: ``span`` (duration), ``event`` (instant),
-``counter``/``gauge``/``histogram`` (metrics).
+functions with a literal name — ``obs.span("engine.stage.map")``,
+``obs.span_at("engine.program.load", t0, t1)`` for a span that is already
+over — never a name built at runtime.  Kinds: ``span`` (duration),
+``event`` (instant), ``counter``/``gauge``/``histogram`` (metrics).
 """
 
 from __future__ import annotations
@@ -30,6 +31,12 @@ NAMES = {
     "engine.stage.process": "span", # timed_run Process stage (per block)
     "engine.stage.reduce": "span",  # timed_run Reduce stage (per block)
     "engine.stage.merge": "span",   # timed_run cross-block table merge
+    "engine.h2d": "span",           # one block padded + staged host->device
+    "engine.sync": "span",          # host blocked on the device (arg what)
+    "engine.finalize": "span",      # table D2H + decode + host sort
+    "engine.program.trace": "span", # jax traced a program (obs/programs.py)
+    "engine.program.lower": "span", # ... lowered it to an MLIR module
+    "engine.program.load": "span",  # ... compiled it or read it from the cache
     "stream.block": "span",         # run_stream: stage+dispatch of one block
     "ckpt.write": "span",           # async writer: serialize+publish one gen
     "serve.queue_wait": "span",     # serve: dispatcher waiting on the queue
@@ -49,7 +56,6 @@ NAMES = {
     "ckpt.publish": "event",        # finalize_snapshot atomic rename landed
     "ckpt.skip": "event",           # latest-wins replaced a pending mark
     "stream.stall": "event",        # bounded-inflight backpressure sync
-    "obs.device_join": "event",     # xplane family times joined onto a stage
     "serve.admit": "event",         # serve: job admitted to the queue
     "serve.reject": "event",        # serve: admission rejected (reason code)
     "serve.retry": "event",         # serve: failed dispatch requeued w/ backoff
@@ -61,6 +67,8 @@ NAMES = {
     "backend.failover": "event",    # run resumed from checkpoint on fallback
     # --- metrics ------------------------------------------------------
     "job.workers": "gauge",         # cluster size of the running job
+    "engine.compile_requests": "counter",  # programs asked of the persistent cache
+    "engine.cache_hits": "counter",        # ... and found there (rest compiled)
     "stream.blocks": "counter",     # blocks folded by run_stream
     "stream.stall_ms": "histogram", # per-sync backpressure stall
     "ckpt.marks": "counter",        # snapshot generations marked

@@ -7,9 +7,11 @@ ships INSIDE the package (pyproject package-data) so an installed wheel
 validates the same as a repo checkout.  The container ships no
 ``jsonschema`` package, so ``validate_trace`` implements the small
 declarative subset the schema uses — type / required / properties /
-items / enum — plus the one conditional JSON Schema would need ``if``/
-``then`` for: a complete ("X") event must carry ``ts`` and ``dur``, an
-instant ("i") must carry ``ts``.
+items / enum — plus the conditionals JSON Schema would need ``if``/
+``then`` for: a complete ("X") event must carry ``ts``, ``dur`` and the
+span record's integer ``args.id`` (unique in the document; an
+``args.parent`` names another span of it), an instant ("i") must carry
+``ts``.
 
 Failures raise ``ValueError`` listing every violation (a schema gate
 that reports one error per run is a gate nobody burns down).
@@ -69,16 +71,38 @@ def validate_trace(doc: dict, schema_path: str | None = None) -> None:
     errors: list[str] = []
     _check(doc, load_schema(schema_path), "$", errors)
     if isinstance(doc, dict):
-        for i, e in enumerate(doc.get("traceEvents") or ()):
-            if not isinstance(e, dict):
-                continue
+        events = [
+            (i, e) for i, e in enumerate(doc.get("traceEvents") or ())
+            if isinstance(e, dict)
+        ]
+        ids: set[int] = set()
+        for i, e in events:
             ph = e.get("ph")
-            if ph == "X" and not ("ts" in e and "dur" in e):
-                errors.append(
-                    f"$.traceEvents[{i}]: complete event needs ts and dur"
-                )
+            if ph == "X":
+                if not ("ts" in e and "dur" in e):
+                    errors.append(
+                        f"$.traceEvents[{i}]: complete event needs ts and dur"
+                    )
+                sid = (e.get("args") or {}).get("id")
+                if not isinstance(sid, int) or isinstance(sid, bool):
+                    errors.append(
+                        f"$.traceEvents[{i}]: span needs an integer args.id"
+                    )
+                elif sid in ids:
+                    errors.append(
+                        f"$.traceEvents[{i}]: span id {sid} is not unique"
+                    )
+                else:
+                    ids.add(sid)
             elif ph == "i" and "ts" not in e:
                 errors.append(f"$.traceEvents[{i}]: instant event needs ts")
+        for i, e in events:
+            parent = (e.get("args") or {}).get("parent")
+            if e.get("ph") == "X" and parent is not None and parent not in ids:
+                errors.append(
+                    f"$.traceEvents[{i}]: parent {parent} is no span of "
+                    "this document"
+                )
     if errors:
         raise ValueError(
             "trace document fails obs/trace.schema.json:\n  "

@@ -7,9 +7,20 @@ repro had outgrown that into fragments (SpanTimer wall spans, xplane
 parsing, per-shard stats, stream stall accounting) that never composed
 into one timeline.  This module is the one timeline:
 
-  * spans are wall-clock durations (``time.time`` epoch, so cross-node
-    merge is a clock-offset shift, not a clock translation), recorded as
+  * a span is (name, start, end, id, parent, job): timestamps are
+    ``time.perf_counter_ns()`` anchored ONCE to the epoch when the
+    tracer is made — monotonic inside a process (``time.time()`` can
+    step), still epoch microseconds on the wire (cross-node merge stays
+    a clock-offset shift); ``id`` is unique in the timeline, ``parent``
+    is the span open on the same thread at entry, and a span with no
+    parent (a root) carries the job's identifier, the tracer's
+    ``trace_id``, instead.  All three ride in ``args``.  Recorded as
     Chrome ``"ph": "X"`` complete events; instants are ``"ph": "i"``;
+  * ONE clock with the device: while ``jax`` is imported, an open span
+    also holds a ``jax.profiler.TraceAnnotation`` of its name, so any
+    profiler session (the CLI's ``--profile-dir``, a benchmark's) keeps
+    the program's spans on ``/host:CPU`` of the same ``.xplane.pb`` as
+    the device's ``XLA Ops`` — no anchor, no second file;
   * a span may carry ``sync_refs`` — device arrays blocked on at span
     EXIT, reusing SpanTimer's sync-at-exit semantics (jax imported
     lazily and only then: the tracer itself is jax-free so every
@@ -30,8 +41,10 @@ Chrome thread ids.  All methods are cheap relative to what they measure
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -58,7 +71,8 @@ NULL_SPAN = _NullSpan()
 class _Span:
     """One open span; records a complete ("X") event at exit."""
 
-    __slots__ = ("_tracer", "_name", "_sync", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_sync", "_args", "_t0", "_id",
+                 "_parent", "_anno", "_stack")
 
     def __init__(self, tracer: "Tracer", name: str, sync, args: dict):
         self._tracer = tracer
@@ -67,7 +81,20 @@ class _Span:
         self._args = args
 
     def __enter__(self):
-        self._t0 = time.time()
+        stack = self._stack = self._tracer._open_spans()
+        self._parent = stack[-1] if stack else None
+        self._id = next(self._tracer._ids)
+        stack.append(self._id)
+        # The same span on the profiler's clock.  jax is looked up, never
+        # imported: obs stays jax-free, and a span opened before the
+        # entry point imported jax simply has no annotation.
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        self._anno = None
+        if profiler is not None:
+            self._anno = profiler.TraceAnnotation(self._name)
+            self._anno.__enter__()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
@@ -76,10 +103,45 @@ class _Span:
 
             for ref in self._sync:
                 jax.block_until_ready(ref)  # locust: noqa[R003] profiler span boundary: the sync IS the measurement
+        t1 = time.perf_counter_ns()
+        if self._anno is not None:
+            self._anno.__exit__(None, None, None)
+        self._stack.pop()  # the entering thread's; ``with`` nests LIFO
         self._tracer._complete(
-            self._name, self._t0, time.time() - self._t0, self._args
+            self._name, self._tracer._us(self._t0), (t1 - self._t0) / 1e3,
+            self._args, self._id, self._parent,
         )
         return False
+
+
+def self_times(events) -> dict[int, float]:
+    """``id -> self microseconds`` of every complete event of a timeline:
+    its duration minus the part of it its child spans cover (the union
+    of their intervals, cut to the parent — children may overlap one
+    another, as jax's nested trace spans do).  Works on a live tracer's
+    records and on ``traceEvents`` read back from an exported file."""
+    spans = {
+        e["args"]["id"]: e for e in events
+        if e.get("ph") == "X" and "id" in e.get("args", {})
+    }
+    children: dict[int, list[tuple[float, float]]] = {}
+    for e in spans.values():
+        parent = e["args"].get("parent")
+        if parent in spans:
+            children.setdefault(parent, []).append(
+                (e["ts"], e["ts"] + e["dur"])
+            )
+    out = {}
+    for sid, e in spans.items():
+        lo, hi = e["ts"], e["ts"] + e["dur"]
+        covered, edge = 0.0, lo
+        for a, b in sorted(children.get(sid, ())):
+            a, b = max(a, edge), min(b, hi)
+            if b > a:
+                covered += b - a
+                edge = b
+        out[sid] = e["dur"] - covered
+    return out
 
 
 class Tracer:
@@ -93,6 +155,13 @@ class Tracer:
     def __init__(self, trace_id: str | None = None, process: str = "main"):
         self.trace_id = trace_id or uuid.uuid4().hex[:16]
         self.process = process
+        # The one anchor: epoch microseconds at perf_counter reading
+        # ``_perf0``.  Every later timestamp is that plus elapsed
+        # perf_counter time, so a stepped wall clock cannot fold a span.
+        self._perf0 = time.perf_counter_ns()
+        self._epoch0_us = time.time() * 1e6
+        self._ids = itertools.count(1)
+        self._tls = threading.local()
         self._lock = threading.Lock()
         self._events: list[dict] = []
         self._pids: dict[str, int] = {process: 0}
@@ -114,47 +183,62 @@ class Tracer:
                     "cat": "locust",
                     "ph": "i",
                     "s": "t",
-                    "ts": round(time.time() * 1e6, 1),
+                    "ts": round(self._us(time.perf_counter_ns()), 1),
                     "pid": 0,
                     "tid": self._tid_locked(),
                     "args": args,
                 }
             )
 
-    def _complete(self, name: str, t0: float, dur_s: float, args: dict):
+    def span_at(self, name: str, start_s: float, end_s: float, **args):
+        """Record a span that is already over, from a reporter that timed
+        it itself on ``time.time()`` (jax.monitoring's time spans).  It is
+        placed by how long AGO it ended, read off both clocks now, so the
+        tracer's anchor stays the only one; its parent is the span open
+        on this thread, which the reported work ran inside."""
+        _names.check(name, "span")
+        now_us = self._us(time.perf_counter_ns())
+        end_us = now_us - max(0.0, time.time() - end_s) * 1e6
+        dur_us = max(0.0, end_s - start_s) * 1e6
+        stack = self._open_spans()
+        self._complete(name, end_us - dur_us, dur_us, args,
+                       next(self._ids), stack[-1] if stack else None)
+
+    def _us(self, perf_ns: int) -> float:
+        return self._epoch0_us + (perf_ns - self._perf0) / 1e3
+
+    def _open_spans(self) -> list[int]:
+        """Ids of the spans open on THIS thread, outermost first."""
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        return stack
+
+    def _complete(self, name: str, ts_us: float, dur_us: float, args: dict,
+                  span_id: int, parent: int | None):
+        link = (
+            {"id": span_id, "trace_id": self.trace_id} if parent is None
+            else {"id": span_id, "parent": parent}
+        )
         with self._lock:
             self._events.append(
                 {
                     "name": name,
                     "cat": "locust",
                     "ph": "X",
-                    "ts": round(t0 * 1e6, 1),
-                    "dur": round(dur_s * 1e6, 1),
+                    "ts": round(ts_us, 1),
+                    "dur": round(dur_us, 1),
                     "pid": 0,
                     "tid": self._tid_locked(),
-                    "args": args,
+                    "args": {**args, **link},
                 }
             )
 
-    def event_count(self) -> int:
-        """Current record count — a position marker for ``annotate``'s
-        ``since`` (so a join can target only records a specific run
-        appended)."""
+    def self_times(self) -> dict[int, float]:
+        """``id -> self microseconds`` of this tracer's spans
+        (module-level ``self_times`` has the rule)."""
         with self._lock:
-            return len(self._events)
-
-    def annotate(self, name: str, extra: dict, since: int = 0) -> int:
-        """Merge ``extra`` into the args of every span/event named
-        ``name`` recorded at position >= ``since`` (the device-time join
-        point — ``since`` keeps a capture's measurements off spans from
-        earlier, unprofiled runs); returns how many records matched."""
-        n = 0
-        with self._lock:
-            for e in self._events[since:]:
-                if e.get("name") == name and e.get("ph") != "M":
-                    e["args"] = {**e.get("args", {}), **extra}
-                    n += 1
-        return n
+            return self_times(list(self._events))
 
     def _tid_locked(self) -> int:
         ident = threading.get_ident()
@@ -188,8 +272,8 @@ class Tracer:
         """Merge a remote tracer's serialized records, shifting their
         wall-clock timestamps by ``-offset_s`` into this tracer's clock
         (``offset_s`` = remote_clock - local_clock at a common instant).
-        Each distinct ``process`` label gets its own Chrome pid.  Returns
-        records merged; malformed entries are skipped, never raised on
+        Each distinct ``process`` label gets its own Chrome pid, and every
+        span a fresh id (parents re-pointed).  Returns records merged; malformed entries are skipped, never raised on
         (telemetry must not take down a job)."""
         n = 0
         with self._lock:
@@ -197,6 +281,7 @@ class Tracer:
             if pid is None:
                 pid = self._pids[process] = max(self._pids.values()) + 1
                 self._meta_process(pid, process)
+            merged = []
             for e in events:
                 if not isinstance(e, dict) or e.get("ph") not in ("X", "i"):
                     continue
@@ -204,9 +289,23 @@ class Tracer:
                     ts = float(e["ts"]) - offset_s * 1e6
                 except (KeyError, TypeError, ValueError):
                     continue
-                merged = dict(e, pid=pid, ts=round(ts, 1))
-                self._events.append(merged)
-                n += 1
+                merged.append(dict(e, pid=pid, ts=round(ts, 1)))
+            # The remote tracer counted its span ids from 1 as this one
+            # does: give each a fresh id here and point parents at those.
+            fresh = {
+                e["args"]["id"]: next(self._ids) for e in merged
+                if isinstance(e.get("args"), dict) and "id" in e["args"]
+            }
+            for e in merged:
+                if isinstance(e.get("args"), dict) and "id" in e["args"]:
+                    args = dict(e["args"], id=fresh[e["args"]["id"]])
+                    if args.get("parent") in fresh:
+                        args["parent"] = fresh[args["parent"]]
+                    else:
+                        args.pop("parent", None)
+                    e["args"] = args
+            self._events.extend(merged)
+            n = len(merged)
         return n
 
     # --------------------------------------------------------------- export

@@ -26,5 +26,8 @@ from locust_tpu.config import compile_cache_dir
 # ambient JAX_COMPILATION_CACHE_DIR wins; otherwise the tests keep theirs
 # in <checkout>/.jax_cache_cpu, apart from what a chip run caches in
 # <checkout>/.jax_cache (a chip machine gets a copy of this checkout).
-compile_cache_dir(".jax_cache_cpu")
+# The floor is set first: compile_cache_dir lowers it to 0 only where the
+# environment says nothing, and the suite's thousands of tiny programs
+# are cheaper to recompile than to write and read back.
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
+compile_cache_dir(".jax_cache_cpu")

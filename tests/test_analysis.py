@@ -571,6 +571,35 @@ def test_r009_fires_on_kind_mismatch_and_unemitted_entry(tmp_path):
     assert "never emitted" in msgs and "'c.fired'" in msgs
 
 
+def test_r009_sees_span_at_as_a_span_emission(tmp_path):
+    """A span recorded after the fact (obs.span_at, how jax's own compile
+    time spans land) is an emission like any other: it satisfies the
+    registry, a typo'd name fires, a non-span kind drifts."""
+    _r009_tree(tmp_path, emitter="""
+        from locust_tpu import obs
+
+        def run(t0, t1):
+            obs.span_at("a.span", t0, t1, fun_name="f")
+            obs.metric_inc("b.blocks")
+            obs.event("c.fired")
+    """)
+    assert not _run(tmp_path, ["R009"], ["locust_tpu"]).new
+    _r009_tree(tmp_path, emitter="""
+        from locust_tpu import obs
+
+        def run(t0, t1):
+            obs.span_at("a.spam", t0, t1)
+            obs.span_at("b.blocks", t0, t1)
+            obs.event("c.fired")
+    """)
+    msgs = " | ".join(
+        f.message for f in _run(tmp_path, ["R009"], ["locust_tpu"]).new
+    )
+    assert "obs.span_at('a.spam', ...)" in msgs
+    assert "kind drift" in msgs and "b.blocks" in msgs
+    assert "never emitted" in msgs and "'a.span'" in msgs
+
+
 def test_r009_ignores_non_obs_span_lookalikes(tmp_path):
     # SpanTimer.span("load") and other objects' .event(...) must never
     # be claimed by the rule — only the obs module-function convention.
@@ -613,7 +642,7 @@ def test_r009_real_registry_mutation_fails_the_gate(tmp_path):
         "locust_tpu/distributor/master.py",
         "locust_tpu/distributor/worker.py",
         "locust_tpu/cli.py",
-        "locust_tpu/obs/attribution.py",
+        "locust_tpu/obs/programs.py",  # emits engine.program.* via span_at
         "locust_tpu/serve/daemon.py",  # emits the serve.* spans/metrics
         "locust_tpu/serve/journal.py",  # emits serve.journal_ms
         "locust_tpu/serve/pool.py",     # emits serve.place/affinity_hits

@@ -118,6 +118,31 @@ def test_cache_env_wins_and_is_never_wiped(
     assert sorted(p.name for p in d.iterdir()) == ["foreign_entry"]
 
 
+def test_cache_write_floor_is_lowered_unless_the_environment_sets_it(
+    monkeypatch, keep_jax_cache_dir
+):
+    """jax writes a program to the persistent cache only if it took a
+    second to compile; the program lowers that to 0 where it sets the
+    directory (a job's two sub-second helpers then compile once a
+    checkout, not once a process), and an ambient value wins."""
+    import jax
+
+    floor = "jax_persistent_cache_min_compile_time_secs"
+    before = getattr(jax.config, floor)
+    try:
+        monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                           raising=False)
+        config.compile_cache_dir(".jax_cache_cpu")
+        assert os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "0"
+        assert getattr(jax.config, floor) == 0.0
+        monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "2.5")
+        config.compile_cache_dir(".jax_cache_cpu")
+        assert os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "2.5"
+        assert getattr(jax.config, floor) == 2.5
+    finally:
+        jax.config.update(floor, before)
+
+
 @pytest.mark.parametrize("sub", [".jax_cache", ".jax_cache_cpu"])
 def test_cache_default_is_a_fixed_dir_in_the_checkout(
     sub, monkeypatch, keep_jax_cache_dir

@@ -566,45 +566,6 @@ def test_fused_registered_in_mode_tables():
     assert p_hi % FUSED_SUBLANE == 0 or p_hi == FUSED_SUBLANE
 
 
-def test_family_join_pairs_kernel_time_with_fused():
-    """The profiled-roofline pairing rule: fused's modeled bytes include
-    the kernel's (est_kernel_bytes), so its measured Process device time
-    must include the kernel custom-call's ms — the hasht-mxu dot-family
-    rule applied to the Pallas op (utils/profiling
-    FUSED_KERNEL_OP_FRAGMENTS)."""
-    from locust_tpu.obs import attribution
-
-    join = attribution.family_join(
-        {"sort_ms": 5.0, "scatter_ms": 2.0, "dot_ms": 1.0,
-         "kernel_ms": 4.0, "device_total_ms": 20.0,
-         "device_plane": "/host:CPU"},
-        "fused",
-    )
-    assert join["process_family"] == "scatter+sort+kernel"
-    assert join["process_device_ms"] == 11.0  # kernel in, dots out
-    assert join["kernel_device_ms"] == 4.0
-    from locust_tpu.utils import profiling
-
-    assert any(
-        "fused_kernel" in f for f in profiling.FUSED_KERNEL_OP_FRAGMENTS
-    )
-    # Families must be DISJOINT for the kernel op: a Mosaic wrapper name
-    # carrying the kernel name lands in kernel_ms only — counting it in
-    # sort_ms too would double-bill it through scatter+sort+kernel.
-    totals = {
-        "tpu_custom_call _fused_kernel": 4.0,
-        "tpu_custom_call bitonic": 2.0,
-        "sort.3": 5.0,
-    }
-    assert profiling.family_ms(
-        totals, profiling.SORT_OP_FRAGMENTS,
-        exclude=profiling.FUSED_KERNEL_OP_FRAGMENTS,
-    ) == 7.0
-    assert profiling.family_ms(
-        totals, profiling.FUSED_KERNEL_OP_FRAGMENTS
-    ) == 4.0
-
-
 # ----------------------------------------------- roofline byte model
 
 

@@ -25,7 +25,7 @@ from locust_tpu.config import EngineConfig
 from locust_tpu.distributor import master, protocol
 from locust_tpu.distributor.worker import Worker
 from locust_tpu.engine import MapReduceEngine
-from locust_tpu.obs import attribution
+from locust_tpu.obs import trace as obs_trace
 from locust_tpu.obs.schema import validate_trace
 from locust_tpu.utils import faultplan
 
@@ -155,8 +155,12 @@ def test_disabled_path_is_noop_and_within_bench_noise():
     assert obs.current() is None
     s = obs.span("stream.block", i=0)
     assert s is obs.span("engine.stage.map") is obs.span("cli.run")
+    assert s is obs.span("engine.h2d", bytes=1) is obs.span("engine.finalize")
+    assert s is obs.span("engine.sync", what="map")
     assert obs.event("stream.stall", ms=0.0) is None
     assert obs.metric_inc("stream.blocks") is None
+    assert obs.span_at("engine.program.load", 0.0, 1.0) is None
+    assert obs.watch_programs() is None
 
     n = 20_000
     t0 = time.perf_counter()
@@ -166,6 +170,12 @@ def test_disabled_path_is_noop_and_within_bench_noise():
         obs.event("stream.stall", block=i, ms=0.0)
         obs.metric_inc("stream.blocks")
         obs.metric_observe("stream.stall_ms", 0.0)
+        # timed_run's additions per block: staging, five waits.
+        with obs.span("engine.h2d", bytes=i):
+            pass
+        for what in ("map", "process", "reduce", "merge", "overflow"):
+            with obs.span("engine.sync", what=what):
+                pass
     per_block_s = (time.perf_counter() - t0) / n
     assert per_block_s < 50e-6, (
         f"disabled telemetry costs {per_block_s*1e6:.1f}µs per block — "
@@ -315,51 +325,385 @@ def test_untraced_job_has_no_timeline_and_no_trace_keys(tmp_path):
         w._shutdown.set()
 
 
-# -------------------------------------------------- device-time attribution
+# ------------------------------------------------ the span record (PR 24)
 
 
-def test_attributed_run_joins_families_onto_stage_spans(tmp_path):
-    eng = MapReduceEngine(
-        EngineConfig(block_lines=8, line_width=32, key_width=8,
-                     emits_per_line=4, sort_mode="hash")
-    )
+def _spans(doc_or_tracer):
+    doc = (doc_or_tracer if isinstance(doc_or_tracer, dict)
+           else doc_or_tracer.to_chrome())
+    return [e for e in doc["traceEvents"] if e["ph"] == "X"]
+
+
+def _encloses(outer, inner, slack_us=1.0):
+    return (outer["ts"] - slack_us <= inner["ts"]
+            and inner["ts"] + inner["dur"]
+            <= outer["ts"] + outer["dur"] + slack_us)
+
+
+def test_ids_are_unique_and_parents_enclose_on_the_same_thread():
+    import threading
+
+    t = obs.enable(process="ids")
+
+    def work():
+        with obs.span("cli.run"):
+            for _ in range(3):
+                with obs.span("cli.load"):
+                    with obs.span("cli.output"):
+                        pass
+
+    threads = [threading.Thread(target=work) for _ in range(4)]
+    for th in threads:
+        th.start()
+    work()
+    for th in threads:
+        th.join(timeout=30)
+        assert not th.is_alive()
+    spans = _spans(t)
+    assert len(spans) == 5 * 7
+    by_id = {e["args"]["id"]: e for e in spans}
+    assert len(by_id) == len(spans), "span ids must be unique"
+    roots = [e for e in spans if "parent" not in e["args"]]
+    assert len(roots) == 5 and all(e["name"] == "cli.run" for e in roots)
+    # The root span carries the job's identifier, and only the root.
+    assert all(e["args"]["trace_id"] == t.trace_id for e in roots)
+    for e in spans:
+        if e in roots:
+            continue
+        assert "trace_id" not in e["args"]
+        parent = by_id[e["args"]["parent"]]
+        assert parent["tid"] == e["tid"], "a parent is on the same thread"
+        assert _encloses(parent, e), (parent, e)
+        assert parent["name"] == {"cli.load": "cli.run",
+                                  "cli.output": "cli.load"}[e["name"]]
+
+
+def test_self_times_on_a_hand_built_nest():
+    """A layer's self time is its duration minus what its children cover
+    — the UNION of their intervals, cut to the parent."""
+
+    def x(sid, ts, dur, parent=None):
+        args = {"id": sid} if parent is None else {"id": sid, "parent": parent}
+        return {"name": "n", "ph": "X", "ts": ts, "dur": dur, "pid": 0,
+                "tid": 0, "args": args}
+
+    events = [
+        x(1, 0.0, 100.0),
+        x(2, 10.0, 20.0, parent=1),        # [10, 30]
+        x(3, 25.0, 15.0, parent=1),        # [25, 40] overlaps span 2
+        x(4, 90.0, 20.0, parent=1),        # [90, 110] sticks out: cut at 100
+        x(5, 12.0, 4.0, parent=2),
+        x(6, 500.0, 7.0),                  # a second root, no children
+        {"name": "i", "ph": "i", "ts": 5.0, "pid": 0, "tid": 0, "args": {}},
+    ]
+    got = obs_trace.self_times(events)
+    assert got == {1: 100.0 - 30.0 - 10.0, 2: 16.0, 3: 15.0, 4: 20.0,
+                   5: 4.0, 6: 7.0}
+    # The tracer's method is the same rule over its own records.
+    t = obs.enable(process="self")
+    with obs.span("cli.run"):
+        with obs.span("cli.load"):
+            time.sleep(0.01)
+    st = t.self_times()
+    run, load = (next(e for e in _spans(t) if e["name"] == n)
+                 for n in ("cli.run", "cli.load"))
+    assert st[load["args"]["id"]] == load["dur"] >= 10_000
+    assert abs(st[run["args"]["id"]] - (run["dur"] - load["dur"])) < 0.2
+
+
+def test_clock_is_monotonic_under_a_stepped_wall_clock(monkeypatch):
+    """Timestamps are perf_counter anchored ONCE to the epoch: a wall
+    clock stepped back an hour mid-run cannot fold a span."""
+    t = obs.enable(process="clock")
+    real = time.time
+    with obs.span("cli.run"):
+        with obs.span("cli.load"):
+            pass
+        monkeypatch.setattr(time, "time", lambda: real() - 3600.0)
+        with obs.span("cli.output"):
+            pass
+    obs.event("ckpt.mark")
+    monkeypatch.undo()
+    run, load, out = (next(e for e in _spans(t) if e["name"] == n)
+                      for n in ("cli.run", "cli.load", "cli.output"))
+    assert load["ts"] <= out["ts"] and _encloses(run, out)
+    mark = next(e for e in t.to_chrome()["traceEvents"] if e["ph"] == "i")
+    assert mark["ts"] >= out["ts"]
+    assert abs(run["ts"] / 1e6 - real()) < 60, "still epoch microseconds"
+
+
+def test_span_at_lands_inside_the_open_span_on_the_tracers_clock():
+    t = obs.enable(process="at")
+    with obs.span("engine.stage.merge"):
+        t0 = time.time()
+        time.sleep(0.02)
+        t1 = time.time()
+        obs.span_at("engine.program.load", t0, t1, fun_name="jit(f)")
+    merge, load = (next(e for e in _spans(t) if e["name"] == n)
+                   for n in ("engine.stage.merge", "engine.program.load"))
+    assert load["args"]["parent"] == merge["args"]["id"]
+    assert load["args"]["fun_name"] == "jit(f)"
+    assert abs(load["dur"] - (t1 - t0) * 1e6) < 1.0
+    assert _encloses(merge, load, slack_us=500.0)
+    with pytest.raises(ValueError, match="not in the obs NAMES registry"):
+        t.span_at("engine.program.nope", t0, t1)
+    obs.disable()
+    assert obs.span_at("engine.program.load", t0, t1) is None  # a no-op
+
+
+def test_ingest_gives_remote_spans_fresh_ids_and_repoints_parents():
+    t = obs.enable(process="master")
+    with obs.span("job.run", job="j"):
+        pass
+    w = obs.Tracer(trace_id=t.trace_id, process="worker:1")
+    with obs.scoped(w):
+        with obs.span("worker.map", shard=0):
+            with obs.span("cli.run"):
+                pass
+    t.ingest(w.serialize(), process="worker a")
+    t.ingest(w.serialize(), process="worker b")
+    spans = _spans(t)
+    ids = [e["args"]["id"] for e in spans]
+    assert len(set(ids)) == len(ids) == 5
+    for child in (e for e in spans if e["name"] == "cli.run"):
+        parent = next(e for e in spans
+                      if e["args"]["id"] == child["args"]["parent"])
+        assert parent["name"] == "worker.map"
+        assert parent["pid"] == child["pid"] != 0
+    validate_trace(t.to_chrome())
+
+
+def test_schema_accepts_the_span_record_and_rejects_a_broken_one():
+    t = obs.enable(process="schema")
+    with obs.span("cli.run", phase="x"):
+        with obs.span("engine.h2d", bytes=512):
+            pass
+        obs.span_at("engine.program.trace", time.time(), time.time(),
+                    fun_name="f")
+    doc = t.to_chrome(obs.metrics_snapshot())
+    validate_trace(doc)
+    bad = json.loads(json.dumps(doc))
+    span = next(e for e in bad["traceEvents"] if e["name"] == "engine.h2d")
+    span["args"]["id"] = "seven"
+    with pytest.raises(ValueError, match=r"args\.id: expected integer"):
+        validate_trace(bad)
+    bad = json.loads(json.dumps(doc))
+    del next(e for e in bad["traceEvents"]
+             if e["name"] == "engine.h2d")["args"]["id"]
+    with pytest.raises(ValueError, match="span needs an integer args.id"):
+        validate_trace(bad)
+    bad = json.loads(json.dumps(doc))
+    next(e for e in bad["traceEvents"]
+         if e["name"] == "engine.h2d")["args"]["parent"] = 10_000
+    with pytest.raises(ValueError, match="parent 10000 is no span"):
+        validate_trace(bad)
+
+
+# ------------------------------------------ timed_run under a tracer (PR 24)
+
+_SMALL = dict(block_lines=8, line_width=32, key_width=8, emits_per_line=4)
+
+
+def _listeners():
+    import jax._src.monitoring as m
+
+    return (len(m.get_event_time_span_listeners()),
+            len(m.get_event_listeners()))
+
+
+def test_timed_run_names_staging_waits_and_finalize_per_block():
+    """Per block exactly one engine.h2d, four stage spans and five
+    engine.sync (four of them CHILDREN of their stage span, so a stage's
+    self time is host launch), plus the closing sync and one
+    engine.finalize; children never outlast their stage."""
+    eng = MapReduceEngine(EngineConfig(**_SMALL))
+    rows = eng.rows_from_lines([b"alpha beta alpha", b"beta gamma"] * 12)
+    assert -(-rows.shape[0] // 8) == 3
+    t = obs.enable(process="timed")
+    res = eng.timed_run(rows)
+    spans = _spans(t)
+    names = [e["name"] for e in spans]
+    assert names.count("engine.h2d") == 3
+    assert [e["args"]["bytes"] for e in spans
+            if e["name"] == "engine.h2d"] == [8 * 32] * 3
+    stages = [e for e in spans if e["name"].startswith("engine.stage.")]
+    assert len(stages) == 12
+    for stage in ("map", "process", "reduce", "merge"):
+        assert names.count(f"engine.stage.{stage}") == 3
+    syncs = [e for e in spans if e["name"] == "engine.sync"]
+    assert len(syncs) == 5 * 3 + 1
+    whats = [e["args"]["what"] for e in syncs]
+    assert whats == ["map", "process", "reduce", "merge", "overflow"] * 3 + ["close"]
+    assert names.count("engine.finalize") == 1
+    by_id = {e["args"]["id"]: e for e in spans}
+    st = t.self_times()
+    for stage in stages:
+        kids = [e for e in spans
+                if e["args"].get("parent") == stage["args"]["id"]]
+        mine = [e for e in kids if e["name"] == "engine.sync"]
+        assert len(mine) == 1
+        assert mine[0]["args"]["what"] == stage["name"].rsplit(".", 1)[1]
+        assert sum(e["dur"] for e in kids) <= stage["dur"] + 0.2
+        assert all(_encloses(stage, e) for e in kids)
+        assert 0 <= st[stage["args"]["id"]] <= stage["dur"]
+    for e in syncs:
+        if e["args"]["what"] in ("overflow", "close"):
+            parent = by_id.get(e["args"].get("parent"))
+            assert parent is None or not parent["name"].startswith("engine.stage")
+    # The decode is the same span name, so a metric sums both per job.
+    assert res.to_host_pairs() == [(b"alpha", 24), (b"beta", 24), (b"gamma", 12)]
+    assert [e["name"] for e in _spans(t)].count("engine.finalize") == 2
+    validate_trace(t.to_chrome())
+
+
+def test_program_spans_in_a_fresh_engines_first_job_only():
+    """What jax reports of its own pipeline lands in the timeline: a
+    fresh engine traces, lowers and loads its programs in its first job
+    (each span names its function), a second run on the same engine
+    does none of it.  The counters are the operator's copy of the
+    benchmark's compiles_in_window."""
+    t = obs.enable(process="programs")
+    eng = MapReduceEngine(EngineConfig(**_SMALL))
+    rows = eng.rows_from_lines([b"alpha beta alpha", b"beta gamma"] * 4)
+    eng.timed_run(rows)
+    first = [e for e in _spans(t) if e["name"].startswith("engine.program.")]
+    kinds = {e["name"].rsplit(".", 1)[1] for e in first}
+    assert kinds == {"trace", "lower", "load"}
+    assert all(e["args"]["fun_name"] for e in first)
+    by_id = {e["args"]["id"]: e for e in _spans(t)}
+    under_stage = [e for e in first if "parent" in e["args"]
+                   and by_id[e["args"]["parent"]]["name"].startswith("engine.stage.")]
+    assert under_stage, "a stage's first call holds its program's reload"
+    counters = obs.metrics_snapshot()["counters"]
+    loads = sum(1 for e in first if e["name"] == "engine.program.load")
+    assert counters["engine.compile_requests"] >= 1
+    assert 0 <= counters.get("engine.cache_hits", 0) <= counters["engine.compile_requests"] <= loads
+    mark = len(_spans(t))
+    eng.timed_run(rows)
+    again = _spans(t)[mark:]
+    assert again and not [e for e in again
+                          if e["name"].startswith("engine.program.")]
+    assert obs.metrics_snapshot()["counters"] == counters
+
+
+def test_enable_disable_cycles_leave_no_monitoring_listener():
+    """The benchmark calls cli.main 130 times a process: a listener per
+    job would pile up.  One pair while tracing, none after disable()."""
+    before = _listeners()
+    for _ in range(5):
+        obs.enable(process="cycle")
+        MapReduceEngine(EngineConfig(**_SMALL))
+        MapReduceEngine(EngineConfig(**_SMALL))  # a second engine: same pair
+        assert _listeners() == (before[0] + 1, before[1] + 1)
+        obs.disable()
+        assert _listeners() == before
+    obs.disable()  # idempotent
+    assert _listeners() == before
+
+
+def test_timed_run_with_tracing_off_allocates_no_span_and_no_listener(
+    monkeypatch,
+):
+    def boom(*a, **k):
+        raise AssertionError("the disabled path built a span")
+
+    monkeypatch.setattr(obs_trace._Span, "__init__", boom)
+    monkeypatch.setattr(obs_trace.Tracer, "span_at", boom)
+    before = _listeners()
+    assert obs.current() is None
+    eng = MapReduceEngine(EngineConfig(**_SMALL))
+    assert obs.watch_programs() is None
+    res = eng.timed_run(eng.rows_from_lines([b"a b a"] * 20))
+    assert res.to_host_pairs() == [(b"a", 40), (b"b", 20)]
+    assert _listeners() == before
+
+
+def _host_annotations(xplane_path):
+    from jax.profiler import ProfileData
+
+    pd = ProfileData.from_file(xplane_path)
+    host = pd.find_plane_with_name("/host:CPU")
+    assert host is not None
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for line in host.lines for e in line.events]
+
+
+def _one_xplane(profile_dir):
+    import glob
+
+    found = glob.glob(os.path.join(str(profile_dir), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    assert len(found) == 1, found
+    return found[0]
+
+
+def test_spans_are_annotations_on_the_profilers_own_clock(tmp_path):
+    """With a jax.profiler session open, every span is an event of
+    /host:CPU in the session's .xplane.pb — same names, same nesting,
+    durations equal to the exported ones within the annotation's own
+    enter/exit cost."""
+    import jax.profiler
+
+    eng = MapReduceEngine(EngineConfig(**_SMALL))
     rows = eng.rows_from_lines([b"alpha beta alpha", b"beta gamma"] * 8)
-    eng.timed_run(rows)  # compile outside the capture
-    tracer = obs.enable(process="attr")
-    res, summary, xplane, join = attribution.attributed_run(
-        lambda: eng.timed_run(rows), str(tmp_path / "prof"), "hash"
-    )
-    assert "error" not in summary, summary
-    assert join["process_family"] == "sort"
-    # The engine's hash mode IS a sort: the family must be measured.
-    assert join["process_device_ms"] and join["process_device_ms"] > 0
-    doc = tracer.to_chrome()
-    proc = [
-        e for e in doc["traceEvents"]
-        if e["name"] == "engine.stage.process" and e["ph"] == "X"
-    ]
-    assert proc, "timed_run under the tracer must emit process spans"
-    assert all(
-        e["args"].get("process_family") == "sort"
-        and e["args"].get("process_device_ms") == join["process_device_ms"]
-        for e in proc
-    )
-    joins = [
-        e for e in doc["traceEvents"] if e["name"] == "obs.device_join"
-    ]
-    assert joins and joins[0]["args"]["spans_annotated"] == len(proc)
+    eng.timed_run(rows)  # compile outside the session
+    t = obs.enable(process="anno")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs.span("cli.run"):
+            eng.timed_run(rows)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_annotations(_one_xplane(tmp_path))
+    spans = _spans(t)
+    for name in ("cli.run", "engine.h2d", "engine.stage.map",
+                 "engine.stage.process", "engine.stage.reduce",
+                 "engine.stage.merge", "engine.sync", "engine.finalize"):
+        want = [e for e in spans if e["name"] == name]
+        got = sorted((a, b) for n, a, b in events if n == name)
+        assert len(got) == len(want) > 0, name
+        for (a, b), e in zip(got, sorted(want, key=lambda e: e["ts"])):
+            assert 0 <= (b - a) / 1e3 - e["dur"] < 500.0, (name, b - a, e)
+    [(lo, hi)] = [(a, b) for n, a, b in events if n == "cli.run"]
+    assert all(lo <= a and b <= hi for n, a, b in events
+               if n.startswith("engine."))
 
 
-def test_attribution_family_join_pairs_hasht_mxu_with_all_three_families():
-    """hasht-mxu's Process time is sort + scatter + the one-hot dots:
-    pairing its bytes with a dot-free time would inflate utilization."""
-    join = attribution.family_join(
-        {"sort_ms": 5.0, "scatter_ms": 2.0, "dot_ms": 1.0,
-         "device_total_ms": 10.0, "device_plane": "/host:CPU"},
-        "hasht-mxu",
-    )
-    assert join["process_family"] == "scatter+sort+dot"
-    assert join["process_device_ms"] == 8.0
+def test_cli_profile_dir_and_trace_out_share_one_xplane(tmp_path, capsys):
+    """python -m locust_tpu FILE --profile-dir DIR --trace-out T.json:
+    one .xplane.pb holds the program's spans beside the device's lines;
+    every exported span has an id, and a parent unless it is a root;
+    the run leaves no jax.monitoring listener behind."""
+    corpus = tmp_path / "c.txt"
+    corpus.write_bytes(CORPUS * 4)
+    before = _listeners()
+    out = tmp_path / "t.trace.json"
+    rc = cli.main([str(corpus), "--backend", "cpu", "--block-lines", "8",
+                   "--line-width", "32", "--key-width", "8",
+                   "--emits-per-line", "4", "--profile-dir",
+                   str(tmp_path / "prof"), "--trace-out", str(out)])
+    capsys.readouterr()
+    assert rc == 0 and obs.current() is None
+    assert _listeners() == before
+    doc = json.load(open(out))
+    validate_trace(doc)
+    spans = _spans(doc)
+    assert all(isinstance(e["args"]["id"], int) for e in spans)
+    roots = [e for e in spans if "parent" not in e["args"]]
+    assert {e["name"] for e in roots} <= {
+        "cli.load", "cli.run", "cli.output", "plan.optimize", "plan.compile",
+        "engine.program.trace", "engine.program.lower", "engine.program.load",
+    }
+    assert all(e["args"]["trace_id"] == doc["otherData"]["trace_id"]
+               for e in roots)
+    names = {n for n, _, _ in _host_annotations(_one_xplane(tmp_path / "prof"))}
+    assert {"cli.load", "cli.run", "plan.run", "engine.h2d",
+            "engine.stage.map", "engine.stage.process",
+            "engine.stage.reduce", "engine.stage.merge", "engine.sync",
+            "engine.finalize", "cli.output"} <= names
+    assert "engine.compile_requests" in doc["otherData"]["metrics"]["counters"]
 
 
 def test_engine_config_trace_knob_enables_process_tracer():
