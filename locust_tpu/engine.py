@@ -21,14 +21,17 @@ with three deliberate departures:
   3 key operands regardless of key width (ops/process_stage.py).
 
 Every stage is jit-compiled once per config; ``run_fused`` runs the whole
-corpus in ONE dispatch (lax.scan over blocks), ``timed_run`` dispatches
-stages separately to reproduce the reference's per-stage Map/Process/Reduce
-timing report (main.cu:405-468).
+corpus in ONE dispatch (lax.scan over blocks), ``timed_run`` — the CLI's
+default — runs map/process/reduce/merge as separate programs, stage by
+stage over a GROUP of blocks with one host wait a stage, to reproduce the
+reference's per-stage Map/Process/Reduce timing report (main.cu:405-468)
+without leaving the device idle between blocks.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import logging
 import os
 import time
@@ -292,6 +295,11 @@ class MapReduceEngine:
     # pins its staged host block).  scripts/stream_scale.py derives its
     # expected-working-set estimate from this constant — keep them linked.
     STREAM_DISPATCH_DEPTH = 4
+    # timed_run launches a stage on a GROUP of blocks between two syncs;
+    # this is the device memory one group's staged lines and stage
+    # intermediates may hold (_timed_group_blocks derives the group's
+    # size from the config's shapes).
+    TIMED_GROUP_BYTES = 384 << 20
 
     def __init__(
         self,
@@ -655,52 +663,85 @@ class MapReduceEngine:
         """
         return self.run_blocks(self.prepare_blocks(rows))
 
+    def _timed_group_blocks(self, nblocks: int) -> int:
+        """Blocks ``timed_run`` launches between two syncs of one stage:
+        as many as fit ``TIMED_GROUP_BYTES`` — a block's staged lines plus
+        three ``KVBatch`` intermediates of ``emits_per_block`` rows (map
+        output, sorted batch, block table) — at least one, at most the job."""
+        cfg = self.cfg
+        kv_row = 4 * cfg.key_lanes + 4 + 1  # key lanes, int32 value, valid
+        per_block = (cfg.block_lines * cfg.line_width
+                     + 3 * cfg.emits_per_block * kv_row)
+        return max(1, min(self.TIMED_GROUP_BYTES // per_block, nblocks))
+
     def timed_run(self, rows: np.ndarray) -> RunResult:
         """Per-stage timing parity with the reference's report (main.cu:405-468).
 
-        Stage boundaries force ``block_until_ready``, so this is slower than
-        ``run``; use it for the stage report, ``run`` for throughput.  The
-        cross-block table merge is accounted to the Process stage (it is a
-        sort), matching where the reference spends that time (main.cu:447).
+        Stage-major over GROUPS of blocks (``_timed_group_blocks``): each
+        stage's program is launched on every block of the group back to
+        back, then the host waits ONCE, so the device stays fed inside a
+        stage and a job pays four round trips a group, not five a block.
+        A stage's time is still host clock from its first launch to its
+        work being done.  The cross-block table merge is accounted to the
+        Process stage (it is a sort), matching where the reference spends
+        that time (main.cu:447).  Same four programs as ever; ``run`` stays
+        the one-program-per-block fold with no report.
         """
         acc = KVBatch.empty(self._table_size, self.cfg.key_lanes)
-        overflow = 0
+        overflows = []
         max_distinct = jnp.int32(0)
         times = StageTimes()
-        for blk in self._blocks(rows):
-            # obs spans shadow the t0..t4 boundaries exactly (each stage's
-            # sync is inside its span), so an exported timeline and the
-            # reference-parity StageTimes report can never disagree.  The
-            # wait itself is a child span: a stage's self time is the host
-            # launching, its engine.sync the host waiting on the device.
+        blocks = self._blocks(rows)
+        group = self._timed_group_blocks(
+            -(-rows.shape[0] // self.cfg.block_lines)
+        )
+        # obs spans shadow the t0..t4 boundaries exactly (each stage's one
+        # sync is inside its span), so an exported timeline and the
+        # reference-parity StageTimes report can never disagree.  The wait
+        # is a child span: a stage's self time is the host launching, its
+        # engine.sync the host waiting on the device.  Every launch lies
+        # inside a stage span, and a stage's inputs are dropped once it has
+        # launched, so a group holds two intermediates a block at a time.
+        # The next group is staged while the device works off this one's
+        # merges: its engine.h2d spans lie inside engine.stage.merge.
+        staged = list(itertools.islice(blocks, group))
+        while staged:
+            n = len(staged)
             t0 = time.perf_counter()
-            with obs.span("engine.stage.map"):
-                kv, blk_overflow = self._map(blk)
+            with obs.span("engine.stage.map", blocks=n):
+                mapped = [self._map(blk) for blk in staged]
+                del staged
                 with obs.span("engine.sync", what="map"):
-                    jax.block_until_ready(kv.key_lanes)  # locust: noqa[R003] stage-timing boundary (reference parity): the sync IS the measurement
+                    jax.block_until_ready(mapped)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
             t1 = time.perf_counter()
-            with obs.span("engine.stage.process"):
-                kv = self._process(kv)
+            overflows += [blk_overflow for _, blk_overflow in mapped]
+            with obs.span("engine.stage.process", blocks=n):
+                batches = [self._process(kv) for kv, _ in mapped]
+                del mapped
                 with obs.span("engine.sync", what="process"):
-                    jax.block_until_ready(kv.key_lanes)  # locust: noqa[R003] stage-timing boundary (reference parity): the sync IS the measurement
+                    jax.block_until_ready(batches)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
             t2 = time.perf_counter()
-            with obs.span("engine.stage.reduce"):
-                table = self._reduce(kv)
+            with obs.span("engine.stage.reduce", blocks=n):
+                tables = [self._reduce(kv) for kv in batches]
+                del batches
                 with obs.span("engine.sync", what="reduce"):
-                    jax.block_until_ready(table.key_lanes)  # locust: noqa[R003] stage-timing boundary (reference parity): the sync IS the measurement
+                    jax.block_until_ready(tables)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
             t3 = time.perf_counter()
-            with obs.span("engine.stage.merge"):
-                acc, max_distinct = self._merge(acc, table, max_distinct)
+            with obs.span("engine.stage.merge", blocks=n):
+                for table in tables:
+                    acc, max_distinct = self._merge(acc, table, max_distinct)
+                del tables
+                staged = list(itertools.islice(blocks, group))
                 with obs.span("engine.sync", what="merge"):
-                    jax.block_until_ready(acc.key_lanes)  # locust: noqa[R003] stage-timing boundary (reference parity): the sync IS the measurement
+                    jax.block_until_ready(acc)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
             t4 = time.perf_counter()
             times.map_ms += (t1 - t0) * 1e3
             times.process_ms += (t2 - t1) * 1e3 + (t4 - t3) * 1e3
             times.reduce_ms += (t3 - t2) * 1e3
-            with obs.span("engine.sync", what="overflow"):
-                overflow += int(blk_overflow)
-        with obs.span("engine.sync", what="close"):
-            jax.block_until_ready(acc.key_lanes)
+        # One read a JOB, of values the map syncs have already waited for:
+        # the copies cost no device op, and the total stays exact.
+        with obs.span("engine.sync", what="overflow"):
+            overflow = sum(int(v) for v in jax.device_get(overflows))
         return self._finish(acc, max_distinct, overflow, times)
 
     def run_lines(self, lines: Sequence[bytes]) -> RunResult:

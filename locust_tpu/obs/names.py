@@ -27,10 +27,10 @@ NAMES = {
     "cli.load": "span",             # CLI: corpus ingest
     "cli.run": "span",              # CLI: the engine run
     "cli.output": "span",           # CLI: table print / intermediate write
-    "engine.stage.map": "span",     # timed_run Map stage (per block)
-    "engine.stage.process": "span", # timed_run Process stage (per block)
-    "engine.stage.reduce": "span",  # timed_run Reduce stage (per block)
-    "engine.stage.merge": "span",   # timed_run cross-block table merge
+    "engine.stage.map": "span",     # timed_run Map stage (per GROUP of blocks, arg blocks)
+    "engine.stage.process": "span", # timed_run Process stage (per group)
+    "engine.stage.reduce": "span",  # timed_run Reduce stage (per group)
+    "engine.stage.merge": "span",   # timed_run cross-block table merge (per group)
     "engine.h2d": "span",           # one block padded + staged host->device
     "engine.sync": "span",          # host blocked on the device (arg what)
     "engine.finalize": "span",      # table D2H + decode + host sort

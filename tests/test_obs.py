@@ -170,10 +170,11 @@ def test_disabled_path_is_noop_and_within_bench_noise():
         obs.event("stream.stall", block=i, ms=0.0)
         obs.metric_inc("stream.blocks")
         obs.metric_observe("stream.stall_ms", 0.0)
-        # timed_run's additions per block: staging, five waits.
+        # timed_run's additions: staging once a block, four waits once a
+        # GROUP of blocks — charged to every block here, the worst case.
         with obs.span("engine.h2d", bytes=i):
             pass
-        for what in ("map", "process", "reduce", "merge", "overflow"):
+        for what in ("map", "process", "reduce", "merge"):
             with obs.span("engine.sync", what=what):
                 pass
     per_block_s = (time.perf_counter() - t0) / n
@@ -511,29 +512,57 @@ def _listeners():
             len(m.get_event_listeners()))
 
 
-def test_timed_run_names_staging_waits_and_finalize_per_block():
-    """Per block exactly one engine.h2d, four stage spans and five
-    engine.sync (four of them CHILDREN of their stage span, so a stage's
-    self time is host launch), plus the closing sync and one
-    engine.finalize; children never outlast their stage."""
+_SMALL_BLOCK_BYTES = 8 * 32 + 3 * (8 * 4) * (8 + 4 + 1)  # staged + 3 KVBatch
+
+
+@pytest.mark.parametrize("budget_blocks, groups", [
+    (None, [4]),       # the shipped budget: the whole job is one group
+    (2, [2, 2]),       # several full groups
+    (3, [3, 1]),       # a last group that is short
+    (0, [1, 1, 1, 1]),  # a budget under one block still makes progress
+])
+def test_timed_run_names_staging_waits_and_finalize_per_group(
+    monkeypatch, budget_blocks, groups,
+):
+    """Once a block engine.h2d; per GROUP exactly four stage spans, each
+    saying how many blocks it launched and holding exactly one engine.sync
+    CHILD of its own ``what`` (so a stage's self time is host launch plus,
+    in a merge stage, the next group's staging); every launch of a stage
+    program inside its stage span; one overflow read a JOB, under no
+    stage; one engine.finalize; children never outlast their stage."""
+    if budget_blocks is not None:
+        monkeypatch.setattr(MapReduceEngine, "TIMED_GROUP_BYTES",
+                            budget_blocks * _SMALL_BLOCK_BYTES)
     eng = MapReduceEngine(EngineConfig(**_SMALL))
-    rows = eng.rows_from_lines([b"alpha beta alpha", b"beta gamma"] * 12)
-    assert -(-rows.shape[0] // 8) == 3
+    rows = eng.rows_from_lines([b"alpha beta alpha", b"beta gamma"] * 16)
+    nblocks = -(-rows.shape[0] // 8)
+    assert nblocks == 4 == sum(groups)
     t = obs.enable(process="timed")
+    launched = []  # (stage, id of the span open at the launch)
+
+    def recording(stage, program):
+        def launch(*args):
+            launched.append((stage, t._open_spans()[-1]))
+            return program(*args)
+        return launch
+
+    for stage in ("map", "process", "reduce", "merge"):
+        monkeypatch.setattr(eng, f"_{stage}",
+                            recording(stage, getattr(eng, f"_{stage}")))
     res = eng.timed_run(rows)
     spans = _spans(t)
     names = [e["name"] for e in spans]
-    assert names.count("engine.h2d") == 3
     assert [e["args"]["bytes"] for e in spans
-            if e["name"] == "engine.h2d"] == [8 * 32] * 3
-    stages = [e for e in spans if e["name"].startswith("engine.stage.")]
-    assert len(stages) == 12
-    for stage in ("map", "process", "reduce", "merge"):
-        assert names.count(f"engine.stage.{stage}") == 3
+            if e["name"] == "engine.h2d"] == [8 * 32] * nblocks
+    stages = sorted((e for e in spans if e["name"].startswith("engine.stage.")),
+                    key=lambda e: e["ts"])
+    assert [e["name"].rsplit(".", 1)[1] for e in stages] == [
+        "map", "process", "reduce", "merge"] * len(groups)
+    assert [e["args"]["blocks"] for e in stages] == [
+        g for g in groups for _ in range(4)]
     syncs = [e for e in spans if e["name"] == "engine.sync"]
-    assert len(syncs) == 5 * 3 + 1
-    whats = [e["args"]["what"] for e in syncs]
-    assert whats == ["map", "process", "reduce", "merge", "overflow"] * 3 + ["close"]
+    assert [e["args"]["what"] for e in syncs] == [
+        "map", "process", "reduce", "merge"] * len(groups) + ["overflow"]
     assert names.count("engine.finalize") == 1
     by_id = {e["args"]["id"]: e for e in spans}
     st = t.self_times()
@@ -546,12 +575,22 @@ def test_timed_run_names_staging_waits_and_finalize_per_block():
         assert sum(e["dur"] for e in kids) <= stage["dur"] + 0.2
         assert all(_encloses(stage, e) for e in kids)
         assert 0 <= st[stage["args"]["id"]] <= stage["dur"]
-    for e in syncs:
-        if e["args"]["what"] in ("overflow", "close"):
-            parent = by_id.get(e["args"].get("parent"))
-            assert parent is None or not parent["name"].startswith("engine.stage")
+    parent = by_id.get(syncs[-1]["args"].get("parent"))
+    assert parent is None or not parent["name"].startswith("engine.stage")
+    # The first group is staged before any stage runs, each later one
+    # while the device works off the merges of the group before it.
+    staged_in = [by_id.get(e["args"].get("parent")) for e in spans
+                 if e["name"] == "engine.h2d"]
+    assert not any(p and p["name"].startswith("engine.stage")
+                   for p in staged_in[:groups[0]])
+    merges = [e for e in stages if e["name"] == "engine.stage.merge"]
+    assert [p["args"]["id"] for p in staged_in[groups[0]:]] == [
+        m["args"]["id"] for m, g in zip(merges, groups[1:]) for _ in range(g)]
+    assert len(launched) == 4 * nblocks
+    assert all(by_id[open_id]["name"] == f"engine.stage.{stage}"
+               for stage, open_id in launched)
     # The decode is the same span name, so a metric sums both per job.
-    assert res.to_host_pairs() == [(b"alpha", 24), (b"beta", 24), (b"gamma", 12)]
+    assert res.to_host_pairs() == [(b"alpha", 32), (b"beta", 32), (b"gamma", 16)]
     assert [e["name"] for e in _spans(t)].count("engine.finalize") == 2
     validate_trace(t.to_chrome())
 

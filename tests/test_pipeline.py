@@ -6,6 +6,8 @@ strtok-semantics splitting — NOT the reference binary, whose known bugs
 do not reproduce.
 """
 
+import time
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -177,7 +179,8 @@ def test_engine_output_is_key_sorted():
     assert keys == sorted(keys)
 
 
-def test_truncation_flag_survives_later_merges():
+@pytest.mark.parametrize("runner", ["run", "run_fused", "timed_run"])
+def test_truncation_flag_survives_later_merges(runner):
     """Regression: truncation in an EARLY merge must be reported even when the
     final merge's distinct count fits the table capacity."""
     # Explicit tiny table: the DEFAULT now floors at 4096 (config.py), and
@@ -191,10 +194,9 @@ def test_truncation_flag_survives_later_merges():
         b"a b c d",       # block 3: repeats, final merge fits capacity
         b"",
     ]
-    for runner in ("run", "run_fused"):
-        eng = MapReduceEngine(cfg)
-        res = getattr(eng, runner)(eng.rows_from_lines(lines))
-        assert res.truncated, runner
+    eng = MapReduceEngine(cfg)
+    res = getattr(eng, runner)(eng.rows_from_lines(lines))
+    assert res.truncated
 
 
 def test_engine_run_fused_matches_run():
@@ -208,9 +210,60 @@ def test_engine_run_fused_matches_run():
 
 def test_engine_timed_run_reports_stages():
     eng = MapReduceEngine(small_cfg())
-    res = eng.timed_run(eng.rows_from_lines(SAMPLE))
-    assert dict(res.to_host_pairs()) == dict(py_wordcount(SAMPLE, 12))
-    assert res.times.map_ms > 0 and res.times.process_ms > 0
+    rows = eng.rows_from_lines(SAMPLE * 4)
+    eng.timed_run(rows)  # compile outside the clock
+    t0 = time.perf_counter()
+    res = eng.timed_run(rows)
+    wall_ms = (time.perf_counter() - t0) * 1e3
+    assert dict(res.to_host_pairs()) == dict(py_wordcount(SAMPLE * 4, 12))
+    times = res.times
+    assert times.map_ms > 0 and times.process_ms > 0 and times.reduce_ms > 0
+    assert times.total_ms <= wall_ms
+
+
+def _block_bytes(cfg):
+    """What timed_run budgets a block at: staged lines + three KVBatch."""
+    return (cfg.block_lines * cfg.line_width
+            + 3 * cfg.emits_per_block * (cfg.key_width + 4 + 1))
+
+
+@pytest.mark.parametrize("budget_blocks", [None, 2, 3, 0])
+def test_engine_timed_run_matches_run_across_groups(monkeypatch, budget_blocks):
+    """One group, groups of two, three and a short one, groups of one: the
+    same table, distinct count and EXACT dropped-token total as ``run``,
+    on a corpus whose lines pass the per-line cap in every group."""
+    cfg = small_cfg(block_lines=2, emits_per_line=4)
+    if budget_blocks is not None:
+        monkeypatch.setattr(MapReduceEngine, "TIMED_GROUP_BYTES",
+                            budget_blocks * _block_bytes(cfg))
+    lines = [
+        b"a b c d e f", b"g h",          # block 0 drops 2
+        b"a a", b"b c d e f g h",        # block 1 drops 3
+        b"i j k l m", b"",               # block 2 drops 1
+        b"n o p q", b"a b c d e",        # block 3 drops 1
+    ]
+    eng = MapReduceEngine(cfg)
+    rows = eng.rows_from_lines(lines)
+    want = eng.run(rows)
+    got = eng.timed_run(rows)
+    assert got.overflow_tokens == want.overflow_tokens == 7
+    assert got.num_segments == want.num_segments
+    assert got.truncated is want.truncated is False
+    assert got.to_host_pairs() == want.to_host_pairs()
+    assert dict(got.to_host_pairs()) == dict(py_wordcount(lines, 4))
+
+
+@pytest.mark.parametrize("cfg_kw, nblocks, lo, hi", [
+    ({}, 470, 25, 55),                        # CLI defaults: 9-19 groups a 100 MB job
+    ({}, 2, 2, 2),                            # never more than the job has
+    ({}, 0, 1, 1),                            # an empty corpus is one padded block
+    ({"block_lines": 65536}, 470, 1, 3),      # big blocks: the same rule, small groups
+    ({"block_lines": 65536, "emits_per_line": 64, "key_width": 128},
+     10, 1, 1),                               # one block over the budget: never 0
+])
+def test_timed_group_size_follows_the_configs_shapes(cfg_kw, nblocks, lo, hi):
+    eng = MapReduceEngine(EngineConfig(**cfg_kw))
+    assert lo <= eng._timed_group_blocks(nblocks) <= hi
 
 
 @pytest.mark.parametrize("seed", [0, 1])
