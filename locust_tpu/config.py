@@ -403,14 +403,17 @@ class EngineConfig:
 
     # Accumulator table capacity: distinct keys tracked across blocks.
     # Bounds the cross-block merge cost (the merge sorts table_size +
-    # emits_per_block rows, not 2 x emits_per_block); a corpus with more
-    # distinct keys than this reports truncation (RunResult.truncated).
-    # None (default) resolves to min(65536, max(emits_per_block, 4096))
-    # (see resolved_table_size for the floor's rationale) — measured the
+    # emits_per_block rows, not 2 x emits_per_block).  None (default)
+    # resolves to min(65536, max(emits_per_block, 4096)) (see
+    # resolved_table_size for the floor's rationale) — measured the
     # fastest setting at both 5k and 100k vocabularies
-    # (artifacts/bench_table_size_cpu_r2.jsonl); vocabularies past 2^16
-    # distinct keys must raise it explicitly (tests/test_scale.py pins the
-    # loud-truncation behavior at the default).
+    # (artifacts/bench_table_size_cpu_r2.jsonl).  The default path
+    # (engine.timed_run) STARTS here and grows its table when a group of
+    # blocks counts more distinct keys than it holds, so it is exact at
+    # any vocabulary; every other path (run, run_fused, run_stream, the
+    # mesh's shards) holds this capacity for the whole job and reports
+    # truncation past it (RunResult.truncated; tests/test_scale.py pins
+    # that loud report at the default) — raise it explicitly there.
     table_size: int | None = None
 
     # Process-stage sort strategy.  "hash": sort by a 64-bit key hash —

@@ -75,13 +75,17 @@ class KVBatch:
             valid=jnp.zeros((n,), dtype=bool),
         )
 
-    def to_host_pairs(self) -> list[tuple[bytes, int]]:
-        """Host-side: decode live entries to (key bytes, value) pairs.
+    def to_host_pairs(self, sort: bool = False) -> list[tuple[bytes, int]]:
+        """Host-side: decode live entries to (key bytes, value) pairs,
+        in device order or (``sort``) ordered by key in numpy first —
+        byte order of the NUL-padded rows, which is the keys' own unless
+        a key holds a NUL inside (the caller's ``sorted`` then has the
+        last word, and is linear on a list that is already in order).
 
         ONE device_get for the whole batch (a single round trip — on remote
         TPU links per-array fetches each pay full latency), lane unpacking
-        in numpy (big-endian reinterpret), and a Python decode loop that is
-        O(live entries), not O(table capacity).
+        in numpy (big-endian reinterpret), and a decode over whole arrays
+        that is O(live entries), not O(table capacity).
         """
         lanes, values, valid = jax.device_get(
             (self.key_lanes, self.values, self.valid)
@@ -92,7 +96,7 @@ class KVBatch:
         # big-endian uint32 lanes -> the original NUL-padded key bytes
         n_live, n_lanes = live_lanes.shape
         keys = live_lanes.astype(">u4").view(np.uint8).reshape(n_live, n_lanes * 4)
-        return [
-            (k, int(v))
-            for k, v in zip(bytes_ops.rows_to_strings(keys), live_values)
-        ]
+        if sort and n_live:
+            order = np.argsort(keys.view(f"S{n_lanes * 4}").ravel(), kind="stable")
+            keys, live_values = keys[order], live_values[order]
+        return list(zip(bytes_ops.rows_to_strings(keys), live_values.tolist()))

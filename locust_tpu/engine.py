@@ -80,11 +80,13 @@ def finalize_host_pairs(
     collision in sort_mode="hash") and restores lexicographic key order —
     the reference's sorted final print (main.cu:473).
     """
-    op = _HOST_COMBINE[combine]
-    merged: dict[bytes, int] = {}
-    for k, v in table.to_host_pairs():
-        merged[k] = op(merged[k], v) if k in merged else v
-    pairs = list(merged.items())
+    pairs = table.to_host_pairs(sort=sort)
+    if len(dict(pairs)) != len(pairs):  # a duplicate row: merge by hand
+        op = _HOST_COMBINE[combine]
+        merged: dict[bytes, int] = {}
+        for k, v in pairs:
+            merged[k] = op(merged[k], v) if k in merged else v
+        pairs = list(merged.items())
     return sorted(pairs) if sort else pairs
 
 
@@ -118,6 +120,15 @@ def merge_host_pairs(
         else:
             merged[k] = v
     return sorted(merged.items())
+
+
+@partial(jax.jit, static_argnums=1)
+def _grow_table(table: KVBatch, rows: int) -> KVBatch:
+    """``table`` with empty rows appended up to ``rows`` (not donated:
+    a growth step that falls short starts from ``table`` again)."""
+    return KVBatch.concat(
+        table, KVBatch.empty(rows - table.size, table.num_lanes)
+    )
 
 
 @dataclasses.dataclass
@@ -300,6 +311,10 @@ class MapReduceEngine:
     # intermediates may hold (_timed_group_blocks derives the group's
     # size from the config's shapes).
     TIMED_GROUP_BYTES = 384 << 20
+    # timed_run's table starts at cfg.resolved_table_size and grows by
+    # this factor a step when a group's merges count more distinct keys
+    # than it holds (_regrow): a million-key job ends four steps up.
+    TABLE_GROWTH = 2
 
     def __init__(
         self,
@@ -547,9 +562,12 @@ class MapReduceEngine:
         )
 
         # Split stages for the timed path only.
+        # The capacity is the accumulator's own size, so the one jit
+        # re-traces per capacity timed_run grows to (_regrow) and the
+        # program at the starting capacity is the one it always was.
         def merge_tables(acc: KVBatch, table: KVBatch, max_distinct: jax.Array):
             merged, distinct = segment_reduce_into(
-                sort_and_compact(KVBatch.concat(acc, table), mode), tsize, combine
+                sort_and_compact(KVBatch.concat(acc, table), mode), acc.size, combine
             )
             return merged, jnp.maximum(max_distinct, distinct)
 
@@ -686,8 +704,21 @@ class MapReduceEngine:
         Process stage (it is a sort), matching where the reference spends
         that time (main.cu:447).  Same four programs as ever; ``run`` stays
         the one-program-per-block fold with no report.
+
+        The table is exact at any vocabulary: it starts at
+        ``cfg.resolved_table_size`` and, when a group's merges counted
+        more distinct keys than it holds, grows and merges that group
+        again (``_regrow``); from the third group on it grows AHEAD of a
+        group that, adding what the last one added, would pass it.  A job
+        that stays under its capacity runs the programs, shapes and waits
+        it always ran.
         """
         acc = KVBatch.empty(self._table_size, self.cfg.key_lanes)
+        # The table as the group being merged found it (_merge donates acc).
+        start = KVBatch.empty(self._table_size, self.cfg.key_lanes)
+        distinct = 0  # keys counted so far: the host's copy of max_distinct
+        added = 0     # ... of which by the last group (the job's first left out)
+        grows = 0
         overflows = []
         max_distinct = jnp.int32(0)
         times = StageTimes()
@@ -728,12 +759,43 @@ class MapReduceEngine:
                     jax.block_until_ready(tables)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
             t3 = time.perf_counter()
             with obs.span("engine.stage.merge", blocks=n):
+                if distinct:  # past the first group: acc is not empty
+                    # A text adds fewer new keys a group as it goes on: a
+                    # table that would not hold what the LAST group added
+                    # once more is grown before this one merges into it.
+                    # (The first group's count says nothing: it holds every
+                    # common key, so it is left out of ``added``.)
+                    ahead = self._rows_for(acc.size, distinct + added)
+                    if ahead > acc.size:
+                        with obs.span("engine.table.grow", from_rows=acc.size,
+                                      to_rows=ahead, distinct=distinct + added,
+                                      blocks_redone=0):
+                            start, acc = acc, _grow_table(acc, ahead)
+                        grows += 1
+                    else:
+                        # _merge donates acc, so the way back is a copy:
+                        # buffer to buffer on the device, no program.
+                        start = jax.device_put(acc, may_alias=False)
+                seen = max_distinct
                 for table in tables:
                     acc, max_distinct = self._merge(acc, table, max_distinct)
-                del tables
                 staged = list(itertools.islice(blocks, group))
                 with obs.span("engine.sync", what="merge"):
                     jax.block_until_ready(acc)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
+                    # Computed by the merges just waited for: the read
+                    # is a scalar copy, no further wait on the device.
+                    now = int(max_distinct)
+                if now > acc.size:
+                    # A merge past the capacity dropped its tail, and
+                    # every merge donated its table: this group is merged
+                    # again from what it started with, in a larger one.
+                    acc, max_distinct, now, steps = self._regrow(
+                        start, acc.size, seen, tables, now
+                    )
+                    grows += steps
+                added = now - distinct if distinct else 0
+                distinct = now
+                del tables
             t4 = time.perf_counter()
             times.map_ms += (t1 - t0) * 1e3
             times.process_ms += (t2 - t1) * 1e3 + (t4 - t3) * 1e3
@@ -742,7 +804,46 @@ class MapReduceEngine:
         # the copies cost no device op, and the total stays exact.
         with obs.span("engine.sync", what="overflow"):
             overflow = sum(int(v) for v in jax.device_get(overflows))
+        obs.metric_set("engine.table_rows", acc.size)
+        obs.metric_inc("engine.table_grows", grows)
         return self._finish(acc, max_distinct, overflow, times)
+
+    def _regrow(self, start: KVBatch, rows: int, seen: jax.Array,
+                tables: list[KVBatch], distinct: int):
+        """Merge a group of ``timed_run`` again into a table that holds it.
+
+        ``start`` is the table as the group found it, ``rows`` the capacity
+        it was merged into, ``seen`` the distinct count before the group,
+        ``distinct`` what the group's merges counted — a lower bound once
+        a merge has dropped its tail, so a step can fall short and is then
+        taken again.  The capacity grows ``TABLE_GROWTH``-fold a step to
+        the first that holds ``distinct``: a handful of merge programs
+        whatever the vocabulary, each compiled once and kept by the
+        persistent cache.  Returns the exact table, its distinct count (on
+        the device and read back) and the steps taken.
+        """
+        steps = 0
+        while distinct > rows:
+            to_rows = self._rows_for(rows, distinct)
+            with obs.span("engine.table.grow", from_rows=rows, to_rows=to_rows,
+                          distinct=distinct, blocks_redone=len(tables)):
+                acc = _grow_table(start, to_rows)
+                max_distinct = seen
+                for table in tables:
+                    acc, max_distinct = self._merge(acc, table, max_distinct)
+                with obs.span("engine.sync", what="merge"):
+                    jax.block_until_ready(acc)  # locust: noqa[R003] the redone group's one wait: the next step is decided by its count
+                    distinct = int(max_distinct)
+            rows = to_rows
+            steps += 1
+        return acc, max_distinct, distinct, steps
+
+    def _rows_for(self, rows: int, distinct: int) -> int:
+        """The first capacity, ``TABLE_GROWTH``-fold steps up from ``rows``,
+        that holds ``distinct`` keys (``rows`` itself if it does)."""
+        while rows < distinct:
+            rows *= self.TABLE_GROWTH
+        return rows
 
     def run_lines(self, lines: Sequence[bytes]) -> RunResult:
         return self.run(self.rows_from_lines(lines))
@@ -1300,8 +1401,10 @@ class MapReduceEngine:
         if truncated:
             logger.warning(
                 "distinct keys (%d) exceeded table capacity (%d); tail "
-                "dropped — raise table_size (the default capacity is "
-                "min(65536, max(one block's emits, 4096)))",
+                "dropped — this path holds a table of fixed size (the "
+                "default is min(65536, max(one block's emits, 4096))): "
+                "raise table_size, or take the default path (timed_run), "
+                "whose table grows with what it sees",
                 num,
                 acc.size,
             )

@@ -31,6 +31,7 @@ NAMES = {
     "engine.stage.process": "span", # timed_run Process stage (per group)
     "engine.stage.reduce": "span",  # timed_run Reduce stage (per group)
     "engine.stage.merge": "span",   # timed_run cross-block table merge (per group)
+    "engine.table.grow": "span",    # timed_run: table grown + its group merged again
     "engine.h2d": "span",           # one block padded + staged host->device
     "engine.sync": "span",          # host blocked on the device (arg what)
     "engine.finalize": "span",      # table D2H + decode + host sort
@@ -69,6 +70,8 @@ NAMES = {
     "job.workers": "gauge",         # cluster size of the running job
     "engine.compile_requests": "counter",  # programs asked of the persistent cache
     "engine.cache_hits": "counter",        # ... and found there (rest compiled)
+    "engine.table_rows": "gauge",   # timed_run: the table's capacity at the job's end
+    "engine.table_grows": "counter",  # timed_run: growth steps the job took
     "stream.blocks": "counter",     # blocks folded by run_stream
     "stream.stall_ms": "histogram", # per-sync backpressure stall
     "ckpt.marks": "counter",        # snapshot generations marked

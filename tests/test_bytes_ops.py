@@ -106,3 +106,26 @@ def test_fold_hash_distributes():
     assert len(np.unique(h)) == len(words)  # no collisions on this set
     buckets = np.bincount(h % 8, minlength=8)
     assert buckets.min() > 0  # every bucket hit
+
+
+def _rows_to_strings_loop(rows):
+    """The row-at-a-time decode ``rows_to_strings`` had, kept as its reference."""
+    out = []
+    for row in np.asarray(rows):
+        b = row.tobytes()
+        i = b.find(b"\x00")
+        out.append(b if i < 0 else b[:i])
+    return out
+
+
+@pytest.mark.parametrize("shape", [(0, 8), (5, 0), (1, 1), (257, 8), (1000, 32)])
+def test_rows_to_strings_equals_the_row_at_a_time_decode(shape):
+    """Full rows, empty rows, trailing NULs and NULs INSIDE a key (cut at
+    the first, whatever follows): the whole-array decode is the loop's."""
+    rng = np.random.default_rng(shape[0] + shape[1])
+    rows = rng.integers(0, 4, size=shape).astype(np.uint8) * 40  # many NULs
+    if shape[0] > 2 and shape[1]:
+        rows[0] = 65                     # a full row, no NUL at all
+        rows[1] = 0                      # an empty key
+        rows[2, 1:] = 0                  # trailing NULs only
+    assert bytes_ops.rows_to_strings(rows) == _rows_to_strings_loop(rows)
