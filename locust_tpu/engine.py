@@ -311,9 +311,18 @@ class MapReduceEngine:
     # intermediates may hold (_timed_group_blocks derives the group's
     # size from the config's shapes).
     TIMED_GROUP_BYTES = 384 << 20
+    # One merge program takes a whole group's block tables, so how many it
+    # takes is part of its shape (a multi-operand lax.sort: minutes to
+    # compile on a TPU).  A job of a full group and more always merges a
+    # full group's worth (the last, short group padded with empty tables);
+    # a shorter job pads to the next power of two — of a larger base where
+    # a full group passes 2^6 (_timed_group_blocks) — so all job sizes
+    # together run at most this many merge programs a capacity.
+    MERGE_RUNGS = 7
     # timed_run's table starts at cfg.resolved_table_size and grows by
-    # this factor a step when a group's merges count more distinct keys
-    # than it holds (_regrow): a million-key job ends four steps up.
+    # powers of this factor — to the first that holds the count — when a
+    # group's merge counts more distinct keys than it holds (_regrow): a
+    # million-key job ends at 16 times its start.
     TABLE_GROWTH = 2
 
     def __init__(
@@ -561,20 +570,28 @@ class MapReduceEngine:
             )
         )
 
-        # Split stages for the timed path only.
-        # The capacity is the accumulator's own size, so the one jit
-        # re-traces per capacity timed_run grows to (_regrow) and the
-        # program at the starting capacity is the one it always was.
-        def merge_tables(acc: KVBatch, table: KVBatch, max_distinct: jax.Array):
+        # Split stages for the timed path only: map, process and reduce
+        # run once a block, the merge once a GROUP of blocks — the running
+        # table and all of the group's block tables through one sort and
+        # one segment combine, so the table is sorted again once a group
+        # and not once a block.  The capacity is the accumulator's own
+        # size and the fan-in the length of ``tables``, so the one jit
+        # re-traces per capacity timed_run grows to (_regrow) and per rung
+        # of the fan-in ladder (_timed_group_blocks).  ``distinct`` is the
+        # TRUE count of keys in table + group, whatever the capacity.
+        # ``acc`` is not donated: it is the way back when the merge passes
+        # the capacity.
+        def merge_tables(acc: KVBatch, tables: tuple[KVBatch, ...],
+                         max_distinct: jax.Array):
             merged, distinct = segment_reduce_into(
-                sort_and_compact(KVBatch.concat(acc, table), mode), acc.size, combine
+                sort_and_compact(KVBatch.concat(acc, *tables), mode), acc.size, combine
             )
             return merged, jnp.maximum(max_distinct, distinct)
 
         self._map = jax.jit(lambda lines: map_fn(lines, cfg))
         self._process = jax.jit(partial(sort_and_compact, mode=mode))
         self._reduce = jax.jit(partial(segment_reduce, combine=combine))
-        self._merge = jax.jit(merge_tables, donate_argnums=donate)
+        self._merge = jax.jit(merge_tables)
         self._table_size = tsize
 
     # ---------------------------------------------------------------- ingest
@@ -681,16 +698,40 @@ class MapReduceEngine:
         """
         return self.run_blocks(self.prepare_blocks(rows))
 
-    def _timed_group_blocks(self, nblocks: int) -> int:
-        """Blocks ``timed_run`` launches between two syncs of one stage:
-        as many as fit ``TIMED_GROUP_BYTES`` — a block's staged lines plus
-        three ``KVBatch`` intermediates of ``emits_per_block`` rows (map
-        output, sorted batch, block table) — at least one, at most the job."""
+    def _timed_group_blocks(self, nblocks: int) -> tuple[int, int]:
+        """``(group, fan_in)`` of a job of ``nblocks`` blocks.
+
+        ``group``: blocks ``timed_run`` launches between two syncs of one
+        stage — as many as fit ``TIMED_GROUP_BYTES`` (a block's staged
+        lines plus three ``KVBatch`` intermediates of ``emits_per_block``
+        rows: map output, sorted batch, block table), at least one, at
+        most the job.  ``fan_in``: block tables a merge program takes, the
+        full group for a job of at least a full group, else the first of
+        1, b, b^2, ... that holds the job (b = 2 at CLI defaults: the least
+        base whose ``MERGE_RUNGS`` - 1 powers reach a full group), capped at
+        the full group; the tables that are missing are empty ones.
+
+        The budget is what a group HOLDS between syncs.  The merge's own
+        working set is transient and not counted: when it runs only the
+        block tables are left of the three intermediates, and its sort
+        works on (capacity + fan_in x ``emits_per_block``) rows — at CLI
+        defaults a job that ends at 2^20 rows peaked at 0.4 GB on a v5e,
+        under what the per-block merges and their kept copy held.
+        """
         cfg = self.cfg
         kv_row = 4 * cfg.key_lanes + 4 + 1  # key lanes, int32 value, valid
         per_block = (cfg.block_lines * cfg.line_width
                      + 3 * cfg.emits_per_block * kv_row)
-        return max(1, min(self.TIMED_GROUP_BYTES // per_block, nblocks))
+        full = max(1, self.TIMED_GROUP_BYTES // per_block)
+        if nblocks >= full:
+            return full, full
+        base = 2
+        while base ** (self.MERGE_RUNGS - 1) < full:
+            base += 1
+        fan_in = 1
+        while fan_in < nblocks:
+            fan_in *= base
+        return max(1, nblocks), min(fan_in, full)
 
     def timed_run(self, rows: np.ndarray) -> RunResult:
         """Per-stage timing parity with the reference's report (main.cu:405-468).
@@ -700,30 +741,31 @@ class MapReduceEngine:
         back, then the host waits ONCE, so the device stays fed inside a
         stage and a job pays four round trips a group, not five a block.
         A stage's time is still host clock from its first launch to its
-        work being done.  The cross-block table merge is accounted to the
-        Process stage (it is a sort), matching where the reference spends
-        that time (main.cu:447).  Same four programs as ever; ``run`` stays
-        the one-program-per-block fold with no report.
+        work being done.  Map, process and reduce run once a block; the
+        cross-block merge runs once a GROUP — the running table and the
+        group's block tables (padded with empty tables up to the job's
+        fan-in, ``_timed_group_blocks``) through one ``merge_tables``
+        program — and is accounted to the Process stage (it is a sort),
+        matching where the reference spends that time (main.cu:447).
+        ``run`` stays the one-program-per-block fold with no report.
 
         The table is exact at any vocabulary: it starts at
-        ``cfg.resolved_table_size`` and, when a group's merges counted
-        more distinct keys than it holds, grows and merges that group
-        again (``_regrow``); from the third group on it grows AHEAD of a
-        group that, adding what the last one added, would pass it.  A job
-        that stays under its capacity runs the programs, shapes and waits
-        it always ran.
+        ``cfg.resolved_table_size`` and, when a group's merge counted
+        more distinct keys than it holds, grows to the capacity that
+        holds them and merges that group again (``_regrow``); from the
+        third group on it grows AHEAD of a group that, adding what the
+        last one added, would pass it.
         """
         acc = KVBatch.empty(self._table_size, self.cfg.key_lanes)
-        # The table as the group being merged found it (_merge donates acc).
-        start = KVBatch.empty(self._table_size, self.cfg.key_lanes)
         distinct = 0  # keys counted so far: the host's copy of max_distinct
         added = 0     # ... of which by the last group (the job's first left out)
         grows = 0
+        merges = 0
         overflows = []
         max_distinct = jnp.int32(0)
         times = StageTimes()
         blocks = self._blocks(rows)
-        group = self._timed_group_blocks(
+        group, fan_in = self._timed_group_blocks(
             -(-rows.shape[0] // self.cfg.block_lines)
         )
         # obs spans shadow the t0..t4 boundaries exactly (each stage's one
@@ -734,7 +776,7 @@ class MapReduceEngine:
         # inside a stage span, and a stage's inputs are dropped once it has
         # launched, so a group holds two intermediates a block at a time.
         # The next group is staged while the device works off this one's
-        # merges: its engine.h2d spans lie inside engine.stage.merge.
+        # merge: its engine.h2d spans lie inside engine.stage.merge.
         staged = list(itertools.islice(blocks, group))
         while staged:
             n = len(staged)
@@ -758,7 +800,13 @@ class MapReduceEngine:
                 with obs.span("engine.sync", what="reduce"):
                     jax.block_until_ready(tables)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
             t3 = time.perf_counter()
-            with obs.span("engine.stage.merge", blocks=n):
+            with obs.span("engine.stage.merge", blocks=n, tables=fan_in) as stage:
+                if n < fan_in:
+                    # A short group (a job's last, or a job under a full
+                    # group): padded to the one shape the job's merges have.
+                    tables += [
+                        KVBatch.empty(tables[0].size, self.cfg.key_lanes)
+                    ] * (fan_in - n)
                 if distinct:  # past the first group: acc is not empty
                     # A text adds fewer new keys a group as it goes on: a
                     # table that would not hold what the LAST group added
@@ -770,32 +818,28 @@ class MapReduceEngine:
                         with obs.span("engine.table.grow", from_rows=acc.size,
                                       to_rows=ahead, distinct=distinct + added,
                                       blocks_redone=0):
-                            start, acc = acc, _grow_table(acc, ahead)
+                            acc = _grow_table(acc, ahead)
                         grows += 1
-                    else:
-                        # _merge donates acc, so the way back is a copy:
-                        # buffer to buffer on the device, no program.
-                        start = jax.device_put(acc, may_alias=False)
-                seen = max_distinct
-                for table in tables:
-                    acc, max_distinct = self._merge(acc, table, max_distinct)
+                start, seen = acc, max_distinct
+                acc, max_distinct = self._merge(start, tuple(tables), seen)
                 staged = list(itertools.islice(blocks, group))
                 with obs.span("engine.sync", what="merge"):
                     jax.block_until_ready(acc)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
-                    # Computed by the merges just waited for: the read
+                    # Computed by the merge just waited for: the read
                     # is a scalar copy, no further wait on the device.
                     now = int(max_distinct)
-                if now > acc.size:
-                    # A merge past the capacity dropped its tail, and
-                    # every merge donated its table: this group is merged
-                    # again from what it started with, in a larger one.
-                    acc, max_distinct, now, steps = self._regrow(
-                        start, acc.size, seen, tables, now
-                    )
-                    grows += steps
+                redone = now > start.size
+                if redone:
+                    # The merge dropped its tail, but counted every key:
+                    # the group is merged again from the table it started
+                    # with, grown to hold them.
+                    acc, max_distinct = self._regrow(start, seen, tables, n, now)
+                    grows += 1
+                stage.set(merges=1 + redone)
+                merges += 1 + redone
                 added = now - distinct if distinct else 0
                 distinct = now
-                del tables
+                del tables, start
             t4 = time.perf_counter()
             times.map_ms += (t1 - t0) * 1e3
             times.process_ms += (t2 - t1) * 1e3 + (t4 - t3) * 1e3
@@ -806,37 +850,32 @@ class MapReduceEngine:
             overflow = sum(int(v) for v in jax.device_get(overflows))
         obs.metric_set("engine.table_rows", acc.size)
         obs.metric_inc("engine.table_grows", grows)
+        obs.metric_inc("engine.merges", merges)
         return self._finish(acc, max_distinct, overflow, times)
 
-    def _regrow(self, start: KVBatch, rows: int, seen: jax.Array,
-                tables: list[KVBatch], distinct: int):
+    def _regrow(self, start: KVBatch, seen: jax.Array,
+                tables: list[KVBatch], blocks: int, distinct: int):
         """Merge a group of ``timed_run`` again into a table that holds it.
 
-        ``start`` is the table as the group found it, ``rows`` the capacity
-        it was merged into, ``seen`` the distinct count before the group,
-        ``distinct`` what the group's merges counted — a lower bound once
-        a merge has dropped its tail, so a step can fall short and is then
-        taken again.  The capacity grows ``TABLE_GROWTH``-fold a step to
-        the first that holds ``distinct``: a handful of merge programs
-        whatever the vocabulary, each compiled once and kept by the
-        persistent cache.  Returns the exact table, its distinct count (on
-        the device and read back) and the steps taken.
+        ``start`` is the table as the group found it, ``seen`` the distinct
+        count before the group, ``tables`` the group's block tables (padded
+        to the fan-in, ``blocks`` of them real), ``distinct`` what the
+        group's merge counted: the true count of table + group, so ONE
+        step — ``TABLE_GROWTH``-fold as often as it takes to hold
+        ``distinct`` — and one merge give the exact table.  A handful of
+        capacities whatever the vocabulary, each merge program compiled
+        once and kept by the persistent cache.  Returns the table and its
+        distinct count (on the device).
         """
-        steps = 0
-        while distinct > rows:
-            to_rows = self._rows_for(rows, distinct)
-            with obs.span("engine.table.grow", from_rows=rows, to_rows=to_rows,
-                          distinct=distinct, blocks_redone=len(tables)):
-                acc = _grow_table(start, to_rows)
-                max_distinct = seen
-                for table in tables:
-                    acc, max_distinct = self._merge(acc, table, max_distinct)
-                with obs.span("engine.sync", what="merge"):
-                    jax.block_until_ready(acc)  # locust: noqa[R003] the redone group's one wait: the next step is decided by its count
-                    distinct = int(max_distinct)
-            rows = to_rows
-            steps += 1
-        return acc, max_distinct, distinct, steps
+        to_rows = self._rows_for(start.size, distinct)
+        with obs.span("engine.table.grow", from_rows=start.size, to_rows=to_rows,
+                      distinct=distinct, blocks_redone=blocks):
+            acc, max_distinct = self._merge(
+                _grow_table(start, to_rows), tuple(tables), seen
+            )
+            with obs.span("engine.sync", what="merge"):
+                jax.block_until_ready(acc)  # locust: noqa[R003] the redone group's one wait: the stage's clock ends with its work
+        return acc, max_distinct
 
     def _rows_for(self, rows: int, distinct: int) -> int:
         """The first capacity, ``TABLE_GROWTH``-fold steps up from ``rows``,

@@ -30,7 +30,7 @@ NAMES = {
     "engine.stage.map": "span",     # timed_run Map stage (per GROUP of blocks, arg blocks)
     "engine.stage.process": "span", # timed_run Process stage (per group)
     "engine.stage.reduce": "span",  # timed_run Reduce stage (per group)
-    "engine.stage.merge": "span",   # timed_run cross-block table merge (per group)
+    "engine.stage.merge": "span",   # timed_run: a group's block tables merged into the table at once (args blocks, tables, merges)
     "engine.table.grow": "span",    # timed_run: table grown + its group merged again
     "engine.h2d": "span",           # one block padded + staged host->device
     "engine.sync": "span",          # host blocked on the device (arg what)
@@ -72,6 +72,7 @@ NAMES = {
     "engine.cache_hits": "counter",        # ... and found there (rest compiled)
     "engine.table_rows": "gauge",   # timed_run: the table's capacity at the job's end
     "engine.table_grows": "counter",  # timed_run: growth steps the job took
+    "engine.merges": "counter",     # timed_run: merge programs launched (one a group + one a group redone)
     "stream.blocks": "counter",     # blocks folded by run_stream
     "stream.stall_ms": "histogram", # per-sync backpressure stall
     "ckpt.marks": "counter",        # snapshot generations marked

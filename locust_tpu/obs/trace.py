@@ -64,6 +64,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args):
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
@@ -96,6 +99,10 @@ class _Span:
             self._anno.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
+
+    def set(self, **args):
+        """Arguments known only once the span's work has run."""
+        self._args.update(args)
 
     def __exit__(self, *exc):
         if self._sync:

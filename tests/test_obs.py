@@ -515,20 +515,21 @@ def _listeners():
 _SMALL_BLOCK_BYTES = 8 * 32 + 3 * (8 * 4) * (8 + 4 + 1)  # staged + 3 KVBatch
 
 
-@pytest.mark.parametrize("budget_blocks, groups", [
-    (None, [4]),       # the shipped budget: the whole job is one group
-    (2, [2, 2]),       # several full groups
-    (3, [3, 1]),       # a last group that is short
-    (0, [1, 1, 1, 1]),  # a budget under one block still makes progress
+@pytest.mark.parametrize("budget_blocks, groups, fan_in", [
+    (None, [4], 9),    # the shipped budget: the whole job is one group, under a full one
+    (2, [2, 2], 2),    # several full groups
+    (3, [3, 1], 3),    # a last group that is short
+    (0, [1, 1, 1, 1], 1),  # a budget under one block still makes progress
 ])
 def test_timed_run_names_staging_waits_and_finalize_per_group(
-    monkeypatch, budget_blocks, groups,
+    monkeypatch, budget_blocks, groups, fan_in,
 ):
     """Once a block engine.h2d; per GROUP exactly four stage spans, each
     saying how many blocks it launched and holding exactly one engine.sync
     CHILD of its own ``what`` (so a stage's self time is host launch plus,
     in a merge stage, the next group's staging); every launch of a stage
-    program inside its stage span; one overflow read a JOB, under no
+    program inside its stage span, the merge's once a group and taking as
+    many tables as a full group has blocks; one overflow read a JOB, under no
     stage; one engine.finalize; children never outlast their stage."""
     if budget_blocks is not None:
         monkeypatch.setattr(MapReduceEngine, "TIMED_GROUP_BYTES",
@@ -586,7 +587,10 @@ def test_timed_run_names_staging_waits_and_finalize_per_group(
     merges = [e for e in stages if e["name"] == "engine.stage.merge"]
     assert [p["args"]["id"] for p in staged_in[groups[0]:]] == [
         m["args"]["id"] for m, g in zip(merges, groups[1:]) for _ in range(g)]
-    assert len(launched) == 4 * nblocks
+    # Map, process and reduce once a block, the merge once a group.
+    assert len(launched) == 3 * nblocks + len(groups)
+    assert [(m["args"]["tables"], m["args"]["merges"]) for m in merges] == [
+        (fan_in, 1)] * len(groups)
     assert all(by_id[open_id]["name"] == f"engine.stage.{stage}"
                for stage, open_id in launched)
     # The decode is the same span name, so a metric sums both per job.
@@ -622,7 +626,8 @@ def test_program_spans_in_a_fresh_engines_first_job_only():
     again = _spans(t)[mark:]
     assert again and not [e for e in again
                           if e["name"].startswith("engine.program.")]
-    assert obs.metrics_snapshot()["counters"] == counters
+    assert counters["engine.merges"] == 1      # one group, one merge a job
+    assert obs.metrics_snapshot()["counters"] == dict(counters, **{"engine.merges": 2})
 
 
 def test_enable_disable_cycles_leave_no_monitoring_listener():

@@ -269,7 +269,7 @@ def test_engine_timed_run_matches_run_across_groups(monkeypatch, budget_blocks):
 ])
 def test_timed_group_size_follows_the_configs_shapes(cfg_kw, nblocks, lo, hi):
     eng = MapReduceEngine(EngineConfig(**cfg_kw))
-    assert lo <= eng._timed_group_blocks(nblocks) <= hi
+    assert lo <= eng._timed_group_blocks(nblocks)[0] <= hi
 
 
 @pytest.mark.parametrize("seed", [0, 1])

@@ -1,9 +1,10 @@
 """The default path's table grows with what it sees and stays exact.
 
 ``timed_run`` (the CLI's default path) starts at ``resolved_table_size``;
-when a group's merges counted more distinct keys than the table holds, it
-grows the table geometrically and merges that group again from the table
-the group started with (``MapReduceEngine._regrow``).  Tolerance: none —
+when a group's merge counted more distinct keys than the table holds, it
+grows the table — in one step, geometric, to the capacity that holds them —
+and merges that group again from the table the group started with
+(``MapReduceEngine._regrow``; tests/test_group_merge.py has the merge).  Tolerance: none —
 every table here is byte-equal to the ``py_wordcount`` oracle.  The other
 paths (``run``, ``run_fused``, ``--stream``, ``--mesh``) keep a fixed
 table and their loud report (tests/test_scale.py).
@@ -122,43 +123,58 @@ def test_landing_on_the_capacity_and_one_key_over(over):
     assert metrics["gauges"]["engine.table_rows"] == 128 * (1 + over)
 
 
-@pytest.mark.parametrize("combine", ["sum", "min", "max", "count"])
-def test_every_combine_survives_a_grow(monkeypatch, combine):
-    """Values that differ a block: a group merged twice must not fold a
-    block in twice (sum, count) nor lose its extreme (min, max)."""
+def valued_map(block, cfg):
+    """WordCount's map with value = the line's first byte, so equal keys
+    carry other values in other lines."""
     import jax.numpy as jnp
 
     from locust_tpu.core.kv import KVBatch
     from locust_tpu.ops.map_stage import wordcount_map
 
-    def valued_map(block, cfg):
-        # value = the line's first byte, so equal keys carry other values
-        kv, overflow = wordcount_map(block, cfg)
-        per_line = jnp.repeat(block[:, 0].astype(jnp.int32), cfg.emits_per_line)
-        return KVBatch(kv.key_lanes, jnp.where(kv.valid, per_line, 0), kv.valid), overflow
+    kv, overflow = wordcount_map(block, cfg)
+    per_line = jnp.repeat(block[:, 0].astype(jnp.int32), cfg.emits_per_line)
+    return KVBatch(kv.key_lanes, jnp.where(kv.valid, per_line, 0), kv.valid), overflow
 
-    monkeypatch.setattr(MapReduceEngine, "TIMED_GROUP_BYTES", 3 * _BLOCK_BYTES)
-    keys = [b"k%04d" % i for i in range(700)]
-    lines = [bytes([65 + (i * 7 + j) % 26]) + b" " + b" ".join(keys[i:i + 7])
-             for j in range(3) for i in range(0, 700, 7)]
-    folds = {"sum": sum, "min": min, "max": max, "count": len}
+
+def valued_lines(n_keys: int, rounds: int = 3) -> list[bytes]:
+    """``rounds`` passes over ``n_keys`` keys, 7 a line behind a letter
+    that differs from pass to pass: the value ``valued_map`` gives them."""
+    keys = [b"k%04d" % i for i in range(n_keys)]
+    return [bytes([65 + (i * 7 + j) % 26]) + b" " + b" ".join(keys[i:i + 7])
+            for j in range(rounds) for i in range(0, n_keys, 7)]
+
+
+def valued_oracle(lines, combine: str) -> list[tuple[bytes, int]]:
+    fold = {"sum": sum, "min": min, "max": max, "count": len}[combine]
     seen: dict[bytes, list[int]] = {}
     for ln in lines:
         for tok in ln.split():
             seen.setdefault(tok, []).append(ln[0])
-    want = sorted((k, folds[combine](v)) for k, v in seen.items())
+    return sorted((k, fold(v)) for k, v in seen.items())
+
+
+@pytest.mark.parametrize("combine", ["sum", "min", "max", "count"])
+def test_every_combine_survives_a_grow(monkeypatch, combine):
+    """Values that differ a block: a group merged twice must not fold a
+    block in twice (sum, count) nor lose its extreme (min, max)."""
+    monkeypatch.setattr(MapReduceEngine, "TIMED_GROUP_BYTES", 3 * _BLOCK_BYTES)
+    lines = valued_lines(700)
     eng = MapReduceEngine(EngineConfig(table_size=128, **_SMALL), valued_map, combine)
     res = eng.timed_run(eng.rows_from_lines(lines))
     assert res.table.size > 128 and not res.truncated
-    assert res.to_host_pairs() == want
+    assert res.to_host_pairs() == valued_oracle(lines, combine)
 
 
 def test_a_group_merged_again_is_counted_once(monkeypatch):
     """Totals equal the oracle's token count, and the per-line cap's
-    dropped tokens are counted once although their group ran twice."""
+    dropped tokens are counted once although their group ran twice.  One
+    merge program a group of four blocks and one more a group redone: 38
+    blocks are 10 merges and the redone ones, where they were 38 and four
+    a group redone."""
     monkeypatch.setattr(MapReduceEngine, "TIMED_GROUP_BYTES", 4 * _BLOCK_BYTES)
     lines = zipf_lines(6000, 1 << 14, seed=9, per_line=10)  # 10 words, cap 8
     want = py_wordcount(lines, 8)
+    tracer = obs.enable(process="once")
     eng = MapReduceEngine(EngineConfig(table_size=128, **_SMALL))
     res = eng.timed_run(eng.rows_from_lines(lines))
     pairs = res.to_host_pairs()
@@ -166,12 +182,17 @@ def test_a_group_merged_again_is_counted_once(monkeypatch):
     assert sum(v for _, v in pairs) == sum(want.values()) == 8 * len(lines)
     assert res.overflow_tokens == 2 * len(lines)
     assert dict(pairs) == dict(want)
+    redone = [s["args"]["blocks_redone"] for s in _grow_spans(tracer)]
+    assert set(redone) <= {0, 4} and redone[0] == 4
+    assert obs.metrics_snapshot()["counters"]["engine.merges"] == (
+        10 + sum(1 for r in redone if r))
 
 
 def test_under_the_capacity_nothing_grows_and_no_wait_is_added(monkeypatch):
     """A job that stays under its capacity: no ``engine.table.grow`` span,
-    the table it started with, and four waits a group plus the overflow
-    read — what the path ran before it could grow."""
+    the table it started with, four waits a group plus the overflow read,
+    and one merge program a group (three for five blocks, the last group's
+    missing table an empty one) — no wait more than before it could grow."""
     monkeypatch.setattr(MapReduceEngine, "TIMED_GROUP_BYTES", 2 * _BLOCK_BYTES)
     lines = distinct_lines(100) * 5            # 65 lines: 5 blocks, 3 groups
     tracer = obs.enable(process="steady")
@@ -182,7 +203,11 @@ def test_under_the_capacity_nothing_grows_and_no_wait_is_added(monkeypatch):
     assert not _grow_spans(tracer)
     assert [e["args"]["what"] for e in spans if e["name"] == "engine.sync"] == (
         ["map", "process", "reduce", "merge"] * 3 + ["overflow"])
-    assert obs.metrics_snapshot()["counters"]["engine.table_grows"] == 0
+    assert [(e["args"]["blocks"], e["args"]["tables"], e["args"]["merges"])
+            for e in spans if e["name"] == "engine.stage.merge"] == [
+        (2, 2, 1), (2, 2, 1), (1, 2, 1)]
+    counters = obs.metrics_snapshot()["counters"]
+    assert counters["engine.table_grows"] == 0 and counters["engine.merges"] == 3
     assert _table(res.to_host_pairs()) == _oracle(lines)
 
 
