@@ -15,7 +15,7 @@ included where the line says so) — not a benchmark.
 
 Exit status: 0 and a last stdout line
 ``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``
-only if jax initialized a TPU whose kind utils/roofline.py knows, every
+only if jax initialized a TPU whose kind benchmarks/peaks.json lists, every
 phase ran, and every comparison was equal.  Anything else — no
 accelerator, a phase raising, a table differing by one byte, a CLI
 warning about dropped tokens — exits non-zero WITHOUT that line; no
@@ -53,6 +53,8 @@ from locust_tpu.config import (  # noqa: E402 - jax-free
 CHIP_KERNELS = ("tokenize_block_pallas", "fused_block_preagg", "bitonic_sort")
 
 SAMPLE_CORPUS = os.path.join(HERE, "data", "sample_corpus.txt")
+# The one table of chips the repo keeps, keyed by device_kind (read only).
+PEAKS = os.path.join(HERE, "benchmarks", "peaks.json")
 CORPUS_BYTES = 32 << 20          # ROADMAP's wc-sample-32MB shape
 FUSED_CLI_BYTES = 4 << 20        # the `--sort-mode fused` CLI run's prefix
 KERNEL_BLOCK_LINES = 32768       # one real block, the kernels phase
@@ -414,7 +416,6 @@ def main(argv=None) -> int:
 
     cache = compile_cache_dir()  # before the first `import jax`
     from locust_tpu.backend import device_summary, select_backend
-    from locust_tpu.utils.roofline import PEAK_HBM_GB_S
 
     try:
         select_backend("tpu")
@@ -422,9 +423,11 @@ def main(argv=None) -> int:
         print(f"chip_smoke: error: {e}", file=sys.stderr)
         return 2
     dev = device_summary()
-    if dev["kind"] not in PEAK_HBM_GB_S:
+    with open(PEAKS) as f:
+        known = json.load(f)
+    if dev["kind"] not in known:
         print(f"chip_smoke: error: device kind {dev['kind']!r} is not in "
-              "utils/roofline.PEAK_HBM_GB_S", file=sys.stderr)
+              "benchmarks/peaks.json", file=sys.stderr)
         return 2
     if dev["count"] != args.chips:
         print(f"chip_smoke: error: --chips {args.chips} but jax sees "

@@ -92,9 +92,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--sort-mode", choices=list(SORT_MODES),
                    default=None,
                    help="Process-stage sort strategy (config.EngineConfig."
-                        "sort_mode); default follows the measured "
-                        "per-backend choice (config.default_sort_mode); "
-                        "variant timings in artifacts/")
+                        "sort_mode); default follows the per-backend "
+                        "choice (config.default_sort_mode)")
     p.add_argument("--mesh", action="store_true",
                    help="run stage 0/1 on ALL visible devices via the "
                         "all-to-all shuffle engine (DistributedMapReduce) "
@@ -259,7 +258,7 @@ def _run(args) -> int:
     # --auto-caps: measure the corpus once and shrink key_width /
     # emits_per_line to their lossless floors (never above the flags);
     # table_size pins to the flag-config resolution so the output table is
-    # byte-identical either way (see bench.py, 1.7x CPU on hamlet).
+    # byte-identical either way (speed not measured on this machine).
     # SpanTimer spans accumulate per name, so this preload bills to the
     # same "load" span the main path uses.
     preloaded_rows = None

@@ -15,6 +15,7 @@ power-of-two widths (lane-sized multiples of 4 for uint32 key packing).
 from __future__ import annotations
 
 import dataclasses
+import os as _os
 
 
 # Tokenization delimiter set — byte-for-byte the reference's strtok delimiters
@@ -45,15 +46,16 @@ HASHT_FAMILY = ("hasht", "hasht-mxu", "fused")
 
 
 def default_sort_mode(backend: str) -> str:
-    """Measured per-backend default Process strategy.
+    """Per-backend default Process strategy of the CLI and the daemon.
 
-    CPU: "hasht" wins the driver-policy grid decisively
-    (artifacts/bench_block_cpu_r4.jsonl: 7.94 vs hash1's 5.14 MB/s) and
-    is soak-proven (260-case battery).  TPU: "hashp2", chosen from an
-    engine-level A/B on an earlier v5e set-up (2026-07-31: 57.6 vs
-    hashp's 56.9 MB/s, within noise); NOT re-measured on the current
-    machine — chip_smoke.py runs it, the benchmark PR decides it.
-    Anything else: the portable "hash".
+    TPU: "hashp2" — the mode every cell of BENCHMARK.json runs, so the
+    only one with ledger lines (PERF_LEDGER.jsonl; PERF.md section 5);
+    no other mode has been measured against it on this machine.  CPU:
+    "hasht" (sort-free fold; ``timed_run``'s split stages take it as
+    "hashp1", process_stage.sort_and_compact); its speed is not measured
+    on this machine.  Anything else: the portable "hash".
+    ``EngineConfig.sort_mode`` itself defaults to "hash": three answers
+    to one question, ROADMAP Design item 2.
     """
     return {"cpu": "hasht", "tpu": "hashp2"}.get(backend, "hash")
 
@@ -68,15 +70,6 @@ PAD_BYTE: int = 0
 # copies of this literal would let --auto-caps under-size emits_per_line.
 TOKEN_BOUNDARY_EXTRA: bytes = b"\x00\n\r"
 FULL_DELIMITERS: bytes = DELIMITERS + TOKEN_BOUNDARY_EXTRA
-
-
-# Bitonic Pallas sort tile (rows of 128 lanes; ops/pallas/sort.py).
-# Parsed + validated HERE (jax-free) so both the kernel and the roofline
-# model (utils/roofline.py) read the one value — a drifted copy would
-# silently model the wrong HBM pass count.  Bigger tiles trade fewer HBM
-# round-trips for larger VMEM residency and longer unrolled kernels;
-# where the knee is has not been measured on the current machine.
-import os as _os
 
 
 def compile_cache_dir(sub: str = ".jax_cache") -> str:
@@ -123,28 +116,27 @@ def compile_cache_dir(sub: str = ".jax_cache") -> str:
     return d
 
 
+# --- kernel geometry: constants, part of every compiled shape that uses
+# them.  Here, jax-free, because ops/hash_table.py, ops/pallas/*.py and
+# the engines' eligibility checks all read them.  The asserts hold the
+# relations a kernel relies on.
+
+
+def _pow2(n: int) -> bool:
+    return n > 0 and not n & (n - 1)
+
+
 # Probe rounds of the sort-free hash-table aggregation (sort_mode="hasht",
 # ops/hash_table.py) before a row falls back to the exact sort path.
-# jax-free HERE so utils/roofline.py can model the pass count without
-# importing the kernel module.
-HASHT_PROBES: int = int(_os.environ.get("LOCUST_HASHT_PROBES", 4))
-if HASHT_PROBES < 1:
-    raise ValueError(f"LOCUST_HASHT_PROBES must be >= 1, got {HASHT_PROBES}")
+HASHT_PROBES: int = 4
 
 # MXU histogram geometry for the "hasht-mxu" combine scatter
 # (ops/hash_table.mxu_scatter_add): the slot id decomposes as
 # ``hi * HASHT_MXU_LANES + lo`` and the per-slot sums come out of
-# ``[t_hi, n] x [n, t_lo]`` bf16 contractions.  512 lanes (a multiple of
-# the 128-wide MXU/VPU tile) matches the measured K_mxu_hist probe
-# (scripts/bench_sort_variants.py variant_k: 65536 buckets as [128, 512],
-# 52.0 ms / 1.6 s compile on v5e, ledger ts 1785523898).  jax-free here so
-# utils/roofline.py models the one-hot traffic off the same numbers the
-# kernel runs with.
-HASHT_MXU_LANES: int = int(_os.environ.get("LOCUST_HASHT_MXU_LANES", 512))
-if HASHT_MXU_LANES < 1:
-    raise ValueError(
-        f"LOCUST_HASHT_MXU_LANES must be >= 1, got {HASHT_MXU_LANES}"
-    )
+# ``[t_hi, n] x [n, t_lo]`` bf16 contractions.  512 lanes is a multiple
+# of the 128-wide MXU/VPU tile (65,536 buckets as [128, 512]); not
+# measured on this machine.
+HASHT_MXU_LANES: int = 512
 
 # Rows per one-hot chunk: the [chunk, t_hi]+[chunk, t_lo] bf16 one-hot
 # operands are materialized per chunk (lax.scan over chunks), bounding the
@@ -152,42 +144,29 @@ if HASHT_MXU_LANES < 1:
 # whole fold's n.  The cap also carries an EXACTNESS bound: per-chunk
 # partial sums accumulate in fp32, and 8-bit value limbs stay exact there
 # while a slot's per-chunk partial < 2^24, i.e. chunk <= 2^24/255 = 65793.
-HASHT_MXU_CHUNK: int = int(_os.environ.get("LOCUST_HASHT_MXU_CHUNK", 32768))
-if not 1 <= HASHT_MXU_CHUNK <= 65536:
-    raise ValueError(
-        "LOCUST_HASHT_MXU_CHUNK must be in [1, 65536] (fp32 partial-sum "
-        f"exactness bound 2^24/255), got {HASHT_MXU_CHUNK}"
-    )
+HASHT_MXU_CHUNK: int = 32768
+assert 255 * HASHT_MXU_CHUNK < 1 << 24, "fp32 partial-sum exactness bound"
 
 
 def hasht_mxu_grid(table_size: int) -> tuple[int, int]:
     """[t_hi, t_lo] histogram grid covering ``table_size`` slots.
 
-    The ONE place the decomposition is decided: ops/hash_table.py runs it
-    and utils/roofline.py prices its one-hot operands, so the modeled
-    traffic cannot drift from what the contraction actually reads.  Grid
-    cells at/above table_size are never addressed (slot ids are < T) and
-    simply stay zero."""
+    The ONE place the decomposition is decided (ops/hash_table.py runs
+    it).  Grid cells at/above table_size are never addressed (slot ids
+    are < T) and simply stay zero."""
     t_lo = min(HASHT_MXU_LANES, table_size)
     t_hi = -(-table_size // t_lo)
     return t_hi, t_lo
 
 
-# --- fused map->aggregate megakernel knobs (ops/pallas/fused_fold.py) ---
-# jax-free HERE so utils/roofline.py prices the kernel's HBM bytes off the
-# SAME validated values the kernel runs with (the hasht-mxu precedent: a
-# drifted copy would silently model the wrong traffic).
+# --- fused map->aggregate megakernel (ops/pallas/fused_fold.py) ---
 
 # Lines per kernel grid step.  uint8 VMEM tiles are (32, 128), so the tile
 # must be a multiple of 32; each step's within-tile dedupe builds a
 # [tile*emits_per_line]^2 Gram matrix in VMEM, which is what keeps the
 # default small (32 lines x 20 emits = a 640^2 f32 Gram, ~1.6 MB).
-FUSED_TILE_LINES: int = int(_os.environ.get("LOCUST_FUSED_TILE_LINES", 32))
-if FUSED_TILE_LINES < 32 or FUSED_TILE_LINES % 32 != 0:
-    raise ValueError(
-        f"LOCUST_FUSED_TILE_LINES must be a positive multiple of 32 "
-        f"(uint8 sublane tile), got {FUSED_TILE_LINES}"
-    )
+FUSED_TILE_LINES: int = 32
+assert FUSED_TILE_LINES % 32 == 0, "uint8 sublane tile"
 
 # VMEM-resident kernel table slots (per BLOCK, rebuilt every fold): bounds
 # the distinct keys one block can pre-aggregate in VMEM; keys past it
@@ -195,30 +174,19 @@ if FUSED_TILE_LINES < 32 or FUSED_TILE_LINES % 32 != 0:
 # block back to the stock hasht fold — exact either way).  Power of two so
 # the in-kernel ``h % slots`` is a bitwise AND.  8192 slots x (key bytes +
 # occupied + count) f32 planes ~ 1.2 MB VMEM at key_width 32.
-FUSED_TABLE_SLOTS: int = int(_os.environ.get("LOCUST_FUSED_TABLE_SLOTS", 8192))
-if FUSED_TABLE_SLOTS < 512 or FUSED_TABLE_SLOTS & (FUSED_TABLE_SLOTS - 1):
-    raise ValueError(
-        f"LOCUST_FUSED_TABLE_SLOTS must be a power of two >= 512, "
-        f"got {FUSED_TABLE_SLOTS}"
-    )
+FUSED_TABLE_SLOTS: int = 8192
+assert FUSED_TABLE_SLOTS >= 512 and _pow2(FUSED_TABLE_SLOTS)
 
 # Residual rows per grid tile: per-tile distinct keys the probe rounds
 # strand (table collision/full) stream out through this bounded buffer;
 # more than this per tile sets the kernel's overflow flag and the engine
 # re-folds the block through the stock path.  Power of two.
-FUSED_RESIDUAL_ROWS: int = int(
-    _os.environ.get("LOCUST_FUSED_RESIDUAL_ROWS", 32)
-)
-if FUSED_RESIDUAL_ROWS < 8 or FUSED_RESIDUAL_ROWS & (FUSED_RESIDUAL_ROWS - 1):
-    raise ValueError(
-        f"LOCUST_FUSED_RESIDUAL_ROWS must be a power of two >= 8, "
-        f"got {FUSED_RESIDUAL_ROWS}"
-    )
+FUSED_RESIDUAL_ROWS: int = 32
+assert FUSED_RESIDUAL_ROWS >= 8 and _pow2(FUSED_RESIDUAL_ROWS)
 
 # Residual row padding lanes beyond the key bytes (count + valid flag +
 # zero tail): the kernel's residual rows are (key_width + FUSED_RESID_PAD)
-# f32 lanes wide, and those rows DO cross HBM — utils/roofline.py prices
-# exactly this width off this constant.
+# f32 lanes wide, and those rows DO cross HBM.
 FUSED_RESID_PAD: int = 8
 
 # Off-TPU the kernel runs in interpret mode (the pinned test vehicle —
@@ -227,21 +195,13 @@ FUSED_RESID_PAD: int = 8
 # minutes of XLA CPU compile.  Blocks with more lines than this take the
 # hasht-identical stock path off-TPU with a one-time notice — the same
 # stance as BITONIC_INTERPRET_MAX.  On TPU the Mosaic kernel always runs.
-FUSED_INTERPRET_MAX_LINES: int = int(
-    _os.environ.get("LOCUST_FUSED_INTERPRET_MAX_LINES", 8192)
-)
-if FUSED_INTERPRET_MAX_LINES < 0:
-    raise ValueError(
-        f"LOCUST_FUSED_INTERPRET_MAX_LINES must be >= 0, "
-        f"got {FUSED_INTERPRET_MAX_LINES}"
-    )
+FUSED_INTERPRET_MAX_LINES: int = 8192
 
 
 # f32 sublane tile rows: the kernel stores its table as stacked
 # [t_hi, t_lo] planes and slices them per plane, so the plane stride
 # (t_hi) must stay sublane-aligned for Mosaic; fused_table_layout pads
-# small tables up to this.  Shared here (jax-free) so the kernel and the
-# roofline model read ONE value.
+# small tables up to this.
 FUSED_SUBLANE: int = 8
 
 
@@ -250,11 +210,10 @@ def fused_grid(slots: int | None = None) -> tuple[int, int]:
     table's slot axis (default FUSED_TABLE_SLOTS; t_hi * t_lo == slots;
     slot = hi * t_lo + lo).
 
-    t_lo is fixed at the 512-lane width the MXU histogram measured best
-    (NOT the HASHT_MXU_LANES env knob: the kernel's hi/lo split is
-    shift+mask, so t_lo must stay a power of two).  The ONE place the
-    decomposition is decided: :func:`fused_table_layout` (the physical
-    plane layout) derives from it, so the two can never drift."""
+    t_lo is fixed at 512 lanes (NOT HASHT_MXU_LANES: the kernel's hi/lo
+    split is shift+mask, so t_lo must stay a power of two).  The ONE
+    place the decomposition is decided: :func:`fused_table_layout` (the
+    physical plane layout) derives from it, so the two can never drift."""
     s = FUSED_TABLE_SLOTS if slots is None else slots
     t_lo = min(512, s)
     t_hi = s // t_lo
@@ -267,15 +226,8 @@ def fused_grid(slots: int | None = None) -> tuple[int, int]:
 # resident across the whole segment, amortizing the per-block
 # acc->settle->acc HBM round-trip by this factor.  Clamped at runtime by
 # :func:`fused_stream_seg_blocks` (f32 count-plane exactness + off-TPU
-# interpret-cost caps), so a large value is safe — it just saturates the
-# clamp.
-FUSED_STREAM_BLOCKS: int = int(
-    _os.environ.get("LOCUST_FUSED_STREAM_BLOCKS", 8)
-)
-if FUSED_STREAM_BLOCKS < 1:
-    raise ValueError(
-        f"LOCUST_FUSED_STREAM_BLOCKS must be >= 1, got {FUSED_STREAM_BLOCKS}"
-    )
+# interpret-cost caps).
+FUSED_STREAM_BLOCKS: int = 8
 
 
 def fused_stream_seg_blocks(
@@ -289,9 +241,7 @@ def fused_stream_seg_blocks(
     segment is clamped to keep that product under 2**24 (the same bound
     fused_engine_eligible enforces per block).  Off-TPU the interpreter
     re-traces per grid step, so the segment additionally respects
-    FUSED_INTERPRET_MAX_LINES over its total line count.  jax-free so
-    utils/roofline.py amortizes the v2 stream model off the SAME clamp
-    the engine runs with."""
+    FUSED_INTERPRET_MAX_LINES over its total line count."""
     cap = max(1, ((1 << 24) - 1) // max(1, emits_per_block))
     seg = min(FUSED_STREAM_BLOCKS, cap)
     if not on_tpu and block_lines > 0:
@@ -304,21 +254,20 @@ def fused_table_layout(slots: int | None = None) -> tuple[int, int]:
     table (default FUSED_TABLE_SLOTS): the :func:`fused_grid`
     decomposition with the hi axis padded up to FUSED_SUBLANE so
     per-plane ref slices stay Mosaic-aligned.  The megakernel allocates
-    its VMEM planes from this and utils/roofline.py prices the table
-    flush off it, so the modeled bytes cannot drift from the table that
-    actually crossed HBM (the hasht_mxu_grid contract).  Padded slots
-    are never addressed (slot ids < slots) and decode as count-0 =
-    invalid."""
+    its VMEM planes from this.  Padded slots are never addressed (slot
+    ids < slots) and decode as count-0 = invalid."""
     t_hi, t_lo = fused_grid(slots)
     return max(FUSED_SUBLANE, t_hi), t_lo
 
 
-BITONIC_TILE_ROWS: int = int(_os.environ.get("LOCUST_BITONIC_TILE_ROWS", 256))
-if BITONIC_TILE_ROWS < 8 or BITONIC_TILE_ROWS & (BITONIC_TILE_ROWS - 1):
-    raise ValueError(
-        f"LOCUST_BITONIC_TILE_ROWS must be a power of two >= 8 "
-        f"(int32 min sublane tile), got {BITONIC_TILE_ROWS}"
-    )
+# --- bitonic Pallas sort (ops/pallas/sort.py) ---
+
+# Tile of the sort, in rows of 128 lanes.  Bigger tiles trade fewer HBM
+# round-trips for larger VMEM residency and longer unrolled kernels;
+# where the knee is has not been measured on this machine.  A power of
+# two (the network's strides) of at least the int32 sublane tile.
+BITONIC_TILE_ROWS: int = 256
+assert BITONIC_TILE_ROWS >= 8 and _pow2(BITONIC_TILE_ROWS)
 
 # Cap on compare-exchange substages statically unrolled into ONE Pallas
 # launch.  Unlimited fusion (the round-4 first cut) produced a ~120-substage
@@ -329,11 +278,7 @@ if BITONIC_TILE_ROWS < 8 or BITONIC_TILE_ROWS & (BITONIC_TILE_ROWS - 1):
 # is the one the local chip's compiler has accepted
 # (tests/test_chip_compile.py, chip_smoke.py); whether unlimited fusion
 # compiles there, and which is faster, is an open perf question.
-BITONIC_MAX_FUSED: int = int(_os.environ.get("LOCUST_BITONIC_MAX_FUSED", 32))
-if BITONIC_MAX_FUSED < 0:
-    raise ValueError(
-        f"LOCUST_BITONIC_MAX_FUSED must be >= 0, got {BITONIC_MAX_FUSED}"
-    )
+BITONIC_MAX_FUSED: int = 32
 
 
 def _pack_local_stages(specs, max_fused):
@@ -360,9 +305,8 @@ def bitonic_schedule(kbits: int, m: int, max_fused: int | None = None):
     """HBM-pass schedule of the Pallas bitonic sort for ``n = 2^kbits``
     elements with tile ``2^m``: a list of ``("local", ((s, t_hi, t_lo), ...))``
     fused-kernel launches and ``("cross", s, t)`` single XLA passes, in
-    execution order.  The ONE place the launch structure is decided —
-    ops/pallas/sort.py executes it and utils/roofline.py counts it, so the
-    modeled pass count can't drift from what the kernel actually does."""
+    execution order.  The ONE place the launch structure is decided
+    (ops/pallas/sort.py executes it)."""
     mf = BITONIC_MAX_FUSED if max_fused is None else max_fused
     if mf <= 0:
         mf = 1 << 30
@@ -405,9 +349,7 @@ class EngineConfig:
     # Bounds the cross-block merge cost (the merge sorts table_size +
     # emits_per_block rows, not 2 x emits_per_block).  None (default)
     # resolves to min(65536, max(emits_per_block, 4096)) (see
-    # resolved_table_size for the floor's rationale) — measured the
-    # fastest setting at both 5k and 100k vocabularies
-    # (artifacts/bench_table_size_cpu_r2.jsonl).  The default path
+    # resolved_table_size for the floor's rationale).  The default path
     # (engine.timed_run) STARTS here and grows its table when a group of
     # blocks counts more distinct keys than it holds, so it is exact at
     # any vocabulary; every other path (run, run_fused, run_stream, the
@@ -416,34 +358,30 @@ class EngineConfig:
     # that loud report at the default) — raise it explicitly there.
     table_size: int | None = None
 
-    # Process-stage sort strategy.  "hash": sort by a 64-bit key hash —
-    # 3 sort operands + one index payload + gather, ~2x faster per sort and
-    # ~6x faster to compile than full-key sort; equal keys still group
-    # adjacently (exact-key segment boundaries downstream), device order is
-    # hash order (host output re-sorts).  "hashp": same 3 hash keys but the
-    # row rides as sort PAYLOAD operands instead of a post-sort gather —
-    # 19% faster on TPU v5e at 720k rows (the gather's random HBM reads
-    # cost more than payload carriage).  "hashp2": payload carriage with
-    # only 2 key operands (validity folded into a 31-bit primary hash, h2
-    # tiebreak).  "hash1": ONE 32-bit sort operand (31 hash bits +
-    # validity bit) + gather — the CPU winner; collisions only duplicate a
-    # table row, re-merged downstream (process_stage._folded_key).
-    # "radix": same folded key sorted by O(n) LSD radix passes instead of
-    # the comparison network (ops/radix_sort.py; loses 2.5-3x on TPU).
-    # "bitonic": hand-written Pallas bitonic network (ops/pallas/sort.py)
-    # over the folded key with payload carriage — tile-local compare
-    # passes fused in VMEM, ~10x fewer HBM round-trips than the stock
-    # network's operand streaming; interpret mode off-TPU.
+    # Process-stage sort strategy (none of the speeds below is measured on
+    # this machine; the only mode with ledger lines is "hashp2", the TPU
+    # default — see default_sort_mode).  "hash": sort by a 64-bit key hash
+    # — 3 sort operands + one index payload + gather; equal keys still
+    # group adjacently (exact-key segment boundaries downstream), device
+    # order is hash order (host output re-sorts).  "hashp": same 3 hash
+    # keys but the row rides as sort PAYLOAD operands instead of a
+    # post-sort gather.  "hashp2": payload carriage with only 2 key
+    # operands (validity folded into a 31-bit primary hash, h2 tiebreak).
+    # "hash1": ONE 32-bit sort operand (31 hash bits + validity bit) +
+    # gather; collisions only duplicate a table row, re-merged downstream
+    # (process_stage._folded_key).  "radix": same folded key sorted by
+    # O(n) LSD radix passes instead of the comparison network
+    # (ops/radix_sort.py).  "bitonic": hand-written Pallas bitonic network
+    # (ops/pallas/sort.py) over the folded key with payload carriage —
+    # tile-local compare passes fused in VMEM; interpret mode off-TPU.
     # "lex": sort full big-endian key lanes — exact lexicographic device
     # order, the reference's KIVComparator semantics (KeyValue.h:20-33).
     # "hasht": the fold-level SORT-FREE hash-table aggregation
     # (ops/hash_table.py) — probe/claim/verify scatters with an exact
-    # sort fallback ladder; the measured CPU default.  "hasht-mxu": the
-    # same fold with the value-combine scatter spelled as a one-hot bf16
-    # MXU contraction (hash_table.mxu_scatter_add) instead of XLA's
-    # duplicate-index scatter — byte-identical tables, armed for the TPU
-    # engine-level A/B (the K_mxu_hist primitive measured 52.0 ms vs the
-    # J scatter's 107.6 at the fold shape, ledger ts 1785523898).
+    # sort fallback ladder; the CPU default.  "hasht-mxu": the same fold
+    # with the value-combine scatter spelled as a one-hot bf16 MXU
+    # contraction (hash_table.mxu_scatter_add) instead of XLA's
+    # duplicate-index scatter — byte-identical tables.
     # "fused": hasht semantics PLUS the Pallas map->aggregate megakernel
     # (ops/pallas/fused_fold.py) at the single-device line->fold
     # boundary — tokenize + hash + table-update in one VMEM-resident
@@ -451,8 +389,7 @@ class EngineConfig:
     # round-trips HBM; tables stay BIT-identical to "hasht" (the
     # settlement fold is hasht's own aggregate_exact).  Off the
     # wordcount map / off supported shapes / inside mesh programs the
-    # mode degrades to "hasht" exactly.  Variant timings:
-    # scripts/bench_sort_variants.py -> artifacts/.
+    # mode degrades to "hasht" exactly.
     sort_mode: str = "hash"
 
     # Overflow behavior for > emits_per_line tokens: the reference prints
@@ -556,9 +493,9 @@ class EngineConfig:
     def resolved_table_size(self) -> int:
         """Accumulator capacity with the None default resolved.
 
-        ``min(65536, emits_per_block)`` measured fastest at bench shapes
-        (artifacts/bench_table_size_cpu_r2.jsonl), but the 4096 FLOOR is
-        a usability guard the round-4 batteries earned three times over:
+        ``min(65536, emits_per_block)`` bounds the merge's sort (not
+        measured on this machine), and the 4096 FLOOR is a usability
+        guard the round-4 batteries earned three times over:
         the table is CORPUS-level state, and a small block size (e.g.
         block_lines=4 -> 32 emits) used to cap the entire vocabulary at
         32 keys — loudly, per contract, but on completely ordinary

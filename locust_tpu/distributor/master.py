@@ -254,7 +254,7 @@ def fetch_file(
     """One verified intermediate transfer; returns the per-fetch stats
     dict (payload/wire bytes, chunks, binary/zlib, elapsed, MB/s) that
     lands in ``JobResult.shards`` — also the microbench's measuring
-    primitive (scripts/bench_dataplane.py).  A custom ``rpc`` routes
+    primitive (distributor/microbench.py).  A custom ``rpc`` routes
     every chunk through it (unpipelined) so tests can intercept."""
     # Clamp to the worker's own window cap: the pipelined scheduler
     # derives offsets from the REQUESTED size, so requesting more than
@@ -424,8 +424,8 @@ class JobResult(list):
         return self._trace.to_chrome()
 
     def dataplane(self) -> dict:
-        """Aggregate data-plane stats over every completed fetch: what
-        ``bench.py`` reports as the ``dataplane`` sub-dict."""
+        """Aggregate data-plane stats over every completed fetch (the
+        master CLI prints them after a job)."""
         fetches = [
             a["fetch"]
             for s in self.shards

@@ -13,11 +13,8 @@ worker over 127.0.0.1 (docs/DATAPLANE.md):
 The staged file is shaped like a real post-combine intermediate — packed
 binary KV of sorted word keys with Zipf-ish counts (io/serde.py) — so the
 compression ratio means something.  Pure host/socket work: no jax import,
-never touches the chip, cheap enough for ``bench.py`` to embed a
-row in its one-line JSON (the ``dataplane`` sub-dict).
-
-``scripts/bench_dataplane.py`` is the CLI face; tests pin the result
-schema (tests/test_dataplane.py).
+never touches the chip.  ``run_microbench()`` is the entry; tests pin the
+result schema and a wall-clock ratio (tests/test_dataplane.py).
 """
 
 from __future__ import annotations

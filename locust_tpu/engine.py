@@ -154,7 +154,7 @@ class RunResult:
     combine: str = "sum"
     # run_stream only: hot-loop stall accounting + checkpoint-writer
     # stats (backpressure_stall_ms, ckpt.{mark_ms,written,skipped,
-    # max_lag,...}) — the numbers behind bench.py's "stream" sub-dict.
+    # max_lag,...}) — the CLI's `[locust] stream:` line prints it.
     stream: dict | None = None
     # Which megakernel formulation actually served this run (ISSUE 19
     # operator visibility): "batch" = per-block fused_block_preagg,
@@ -303,8 +303,7 @@ class MapReduceEngine:
 
     # run_stream keeps at most this many folds in flight before blocking:
     # pipeline overlap without per-corpus RSS growth (each in-flight fold
-    # pins its staged host block).  scripts/stream_scale.py derives its
-    # expected-working-set estimate from this constant — keep them linked.
+    # pins its staged host block).
     STREAM_DISPATCH_DEPTH = 4
     # timed_run launches a stage on a GROUP of blocks between two syncs;
     # this is the device memory one group's staged lines and stage
@@ -462,8 +461,7 @@ class MapReduceEngine:
             segment (fused_block_preagg already supports any
             tile-multiple line count; its constant-index table BlockSpec
             IS the persistence).  The acc->settle->acc HBM round-trip
-            and the table flush amortize by the segment length — the v2
-            traffic model in utils/roofline.py.
+            and the table flush amortize by the segment length.
 
             Bit-identity carries over from fold_block unchanged: the
             settlement folds concat(acc, table, residual) through the

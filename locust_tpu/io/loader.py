@@ -99,8 +99,7 @@ def count_distinct_tokens(lines) -> int:
     Upper-bounds the engine's distinct-key count: per-line emit
     overflow can only DROP tokens, and key-width truncation never
     applies when paired with ``auto_caps`` (key_width >= max token).  A
-    table sized >= this count therefore cannot truncate — the guarantee
-    bench.py's distinct-aware table sizing rests on.
+    table sized >= this count therefore cannot truncate.
     """
     import re
 
@@ -114,8 +113,8 @@ def count_distinct_tokens(lines) -> int:
 
 
 def auto_caps(lines, key_cap: int, emits_cap: int) -> tuple[int, int, int, int]:
-    """Lossless capacity sizing: the single policy behind bench.py and
-    ``--auto-caps`` (cli.py).
+    """Lossless capacity sizing: the policy behind ``--auto-caps``
+    (cli.py).
 
     Returns ``(key_width, emits_per_line, max_tok, max_per_line)`` with
     the caps at their measured lossless floors — max token bytes rounded

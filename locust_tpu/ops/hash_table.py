@@ -30,17 +30,13 @@ open-addressed hash table with XLA scatters:
   behavior rather than to a wrong answer.
 
 Traffic: ~4 rounds x ~11 row-sized gather/scatter sweeps vs the
-incumbent sort's ~21 passes x 6 operands x read+write — roughly 6x less
-HBM movement at the bench shape, IF the backend's duplicate-index
-scatter is not serialized (scripts/bench_sort_variants.py variant J
-measures exactly that primitive; CPU: 19x).  On TPU v5e the scatter runs
-but costs ~2.2x the sort-family primitive (J 107.6 ms, ledger ts
-1785523898), so the value combine has a second spelling: a one-hot bf16
-contraction on the systolic MXU (``mxu_scatter_add``, the productized
-K_mxu_hist probe — 52.0 ms / 1.6 s compile at the same shape), selected
-per fold by ``scatter_impl`` / engine sort mode "hasht-mxu"
-(config.HASHT_FAMILY).  Both spellings produce BIT-identical tables;
-roofline treatment in utils/roofline.py (one-hot bytes vs scatter bytes).
+incumbent sort's ~21 passes x 6 operands x read+write — less HBM
+movement IF the backend's duplicate-index scatter is not serialized
+(the fold is not measured on this machine).  The value
+combine has a second spelling: a one-hot bf16 contraction on the
+systolic MXU (``mxu_scatter_add``), selected per fold by
+``scatter_impl`` / engine sort mode "hasht-mxu" (config.HASHT_FAMILY).
+Both spellings produce BIT-identical tables.
 
 Empty-slot sentinel: lane 0 == 0.  A valid emit's key starts with a
 non-delimiter, non-NUL byte packed big-endian into lane 0, so lane 0 of
@@ -64,12 +60,9 @@ from locust_tpu.core.kv import KVBatch
 
 # How the value-combine scatter of the probe loop is spelled, keyed by the
 # sort mode that selected this fold (config.HASHT_FAMILY):
-#   "xla" — ``.at[slot].add`` duplicate-index scatter (the incumbent;
-#           measured ~2.2x the sort-family primitive on v5e, ledger
-#           J_scatter 107.6 ms vs I 50.7 at the fold shape);
+#   "xla" — ``.at[slot].add`` duplicate-index scatter (the incumbent);
 #   "mxu" — the same sum as one-hot bf16 contractions on the systolic MXU
-#           (``mxu_scatter_add``; the K_mxu_hist probe measured 52.0 ms
-#           with a 1.6 s compile at the identical shape).
+#           (``mxu_scatter_add``).  Neither is measured on this machine.
 # The claim (scatter-min over folded hashes) and key-lane writes stay XLA
 # scatters under BOTH impls — the MXU speaks only +, and those steps are
 # what make the fold exact, not what prices it.
@@ -97,17 +90,16 @@ def mxu_scatter_add(
     row landed on ``t``.  Rows with ``mask`` False (or an out-of-grid
     slot) contribute nothing.
 
-    Formulation (productized from scripts/bench_sort_variants.py
-    ``variant_k``): decompose ``slot = hi * t_lo + lo`` on the
+    Formulation: decompose ``slot = hi * t_lo + lo`` on the
     ``config.hasht_mxu_grid`` and accumulate
     ``hist[w, hi, lo] = sum_n W[n, w] * onehot_hi[n, hi] * onehot_lo[n, lo]``
     as ONE ``[t_hi * 5, n_chunk] x [n_chunk, t_lo]`` bf16 contraction per
-    chunk.  Exactness, unlike the probe's bf16-cast of raw values, is
+    chunk.  Exactness, unlike a bf16 cast of raw values, is
     unconditional: the 5 weight planes are the value's four unsigned
     8-bit limbs plus the hit count — every operand entry is <= 255 and
     hence bf16-exact, per-chunk partials accumulate in fp32 where a
     slot's limb sum stays < 255 * chunk <= 2^24 (config.HASHT_MXU_CHUNK's
-    validated ceiling), partials then convert to uint32 and accumulate
+    asserted ceiling), partials then convert to uint32 and accumulate
     with wraparound, and the final limb recombination is mod-2^32
     arithmetic — the same ring int32 scatter-add lives in.
 
@@ -119,7 +111,7 @@ def mxu_scatter_add(
     n = slot.shape[0]
     chunk = HASHT_MXU_CHUNK if chunk is None else chunk
     if not 1 <= chunk <= 65536:
-        # The SAME exactness ceiling config validates for the env knob:
+        # The SAME exactness ceiling config asserts of its constant:
         # a slot's per-chunk limb partial must stay < 255 * chunk <= 2^24
         # or the fp32 einsum accumulation rounds and the bit-identity
         # contract silently breaks for direct callers.
@@ -196,8 +188,8 @@ def mxu_scatter_add(
     hit = acc[:, 4].reshape(-1)[:out_size] > 0
     return sums, hit
 
-# DEFAULT_PROBES (config.HASHT_PROBES, default 4): at the bench load
-# factor (~5.6k distinct in 65,536 slots ≈ 0.09) the expected unresolved
+# DEFAULT_PROBES (config.HASHT_PROBES = 4): at a load factor of 0.09
+# (~5.6k distinct in 65,536 slots) the expected unresolved
 # fraction after 4 rounds is ~0.09^4 ≈ 7e-5 of KEYS — in practice zero,
 # so the engine's fallback `lax.cond` almost never fires.
 

@@ -78,7 +78,7 @@ bit-identity argument above carries over verbatim):
   launch.  Pallas double-buffers the per-tile line DMA automatically
   (indexed input BlockSpec), the bounded residual drains per tile as
   before, and the acc->settle->acc HBM round-trip plus the table flush
-  amortize by the segment length (utils/roofline.py "fused-stream").
+  amortize by the segment length.
   Exactness: the per-SEGMENT emit budget must stay < 2^24 for the f32
   count planes — :func:`config.fused_stream_seg_blocks` clamps the
   segment to that bound (and to the interpret-cost cap off-TPU).
@@ -115,18 +115,14 @@ from locust_tpu.config import (
     FUSED_TILE_LINES,
     HASHT_PROBES,
     EngineConfig,
-    # The physical [t_hi, t_lo] plane layout is decided ONCE in config
-    # (jax-free) so utils/roofline.py prices the same padded table this
-    # kernel allocates.
+    # The physical [t_hi, t_lo] plane layout is decided ONCE in config.
     fused_table_layout,
 )
 from locust_tpu.core.kv import KVBatch
 
 # Residual row layout: key bytes [0..K-1], count [K], valid flag [K+1],
 # zero padding out to K + RESID_PAD lanes.  Kept narrow deliberately:
-# residual rows DO cross HBM, and utils/roofline.py prices exactly this
-# width off the SAME config constant (config.FUSED_RESID_PAD) — a
-# drifted copy would silently model the wrong residual traffic.
+# residual rows DO cross HBM.
 RESID_PAD = FUSED_RESID_PAD
 
 FUSED_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
@@ -440,8 +436,7 @@ def fused_engine_eligible(cfg: EngineConfig, map_fn, combine: str):
         return False, (
             f"off-TPU interpret mode capped at "
             f"{FUSED_INTERPRET_MAX_LINES} lines/block "
-            f"(block_lines={cfg.block_lines}; LOCUST_FUSED_INTERPRET_"
-            "MAX_LINES overrides); folding exactly like 'hasht'"
+            f"(block_lines={cfg.block_lines}); folding exactly like 'hasht'"
         )
     return True, ""
 

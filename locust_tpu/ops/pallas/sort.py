@@ -30,8 +30,8 @@ rank/cumsum idea that made the pure-XLA radix attempt lose
 (ops/radix_sort.py: its per-pass gathers go to HBM; here they stay in
 VMEM).  That count assumes unlimited fusion; when BITONIC_MAX_FUSED
 caps the substages per launch (the Mosaic compile-size mitigation),
-the true count is ``len(config.bitonic_schedule(k, m))`` — the shared
-launch plan both this kernel and utils/roofline.py consume.
+the true count is ``len(config.bitonic_schedule(k, m))`` — the launch
+plan this kernel executes.
 
 The engine-facing mode ("bitonic", config.SORT_MODES) sorts the folded
 31-bit-hash+validity key (process_stage._folded_key, same collision
@@ -54,10 +54,9 @@ from locust_tpu.config import BITONIC_TILE_ROWS, bitonic_schedule
 
 # Default tile: 2^15 elements = 256 rows x 128 lanes.  Working set per
 # operand = 128KB; key + 9 payload operands (key_width 32) = 1.25MB of
-# VMEM — comfortable, and m=15 leaves few cross stages.  Parsed and
-# validated in config.py (jax-free, shared with the roofline model);
-# $LOCUST_BITONIC_TILE_ROWS overrides; where the knee is has not been
-# measured on the current machine.
+# VMEM — comfortable, and m=15 leaves few cross stages.  A constant of
+# config.py (with bitonic_schedule, which needs it); where the knee is
+# has not been measured on this machine.
 TILE_ROWS = BITONIC_TILE_ROWS
 
 _LANES = 128

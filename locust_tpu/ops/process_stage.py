@@ -22,8 +22,8 @@ consumers via the hashp1 formulation); see the variant functions below):
 
 * **"hash"** (default) — sort by ``(invalid, hash64(key))`` with only an
   index payload, then gather rows into place.  3 sort keys + 1 payload
-  instead of 1+key_lanes keys: measured ~2x faster per sort and ~6x faster
-  to XLA-compile on TPU v5e at 393k rows.  Equal keys still land adjacent
+  instead of 1+key_lanes keys (speed not measured on this machine).
+  Equal keys still land adjacent
   (equal keys => equal hash), which is the only property the downstream
   segment reduce needs; it compares FULL key lanes at segment boundaries, so
   hash collisions between distinct keys cannot merge counts — the worst case
@@ -60,11 +60,7 @@ def _vma_of(x) -> frozenset:
 # inside XLA at full-hamlet mesh-merge shapes, 8 shards x 2^18 rows x 10
 # operands).  Above the cap, off-TPU callers get the stock formulation
 # with a loud one-time notice; on TPU the real Mosaic kernel always runs.
-import os as _os
-
-BITONIC_INTERPRET_MAX: int = int(
-    _os.environ.get("LOCUST_BITONIC_INTERPRET_MAX", 1 << 16)
-)
+BITONIC_INTERPRET_MAX: int = 1 << 16
 
 
 def sort_and_compact(batch: KVBatch, mode: str = "hash") -> KVBatch:
@@ -136,11 +132,8 @@ def _hashp_sort(batch: KVBatch) -> KVBatch:
 
     Same 3 sort keys as "hash" but the key lanes and values travel through
     ``lax.sort`` as payload operands instead of being gathered by a sorted
-    index afterwards.  On a TPU v5e at 720k rows this measured ~19% faster
-    than the gather form (2026-07, an earlier set-up: 67.4 ms vs 82.6 ms;
-    not re-measured on the current machine) — the gather's random-access
-    HBM reads cost more than carrying 9 extra payload operands through the
-    sort's sequential passes.
+    index afterwards: sequential passes over 9 more operands in place of
+    a random-access gather (not measured on this machine).
     Collision/correctness story identical to "hash".
     """
     lanes, values, valid = batch.key_lanes, batch.values, batch.valid
@@ -168,10 +161,9 @@ def _hashp2_sort(batch: KVBatch) -> KVBatch:
     still valid-first and validity is reconstructed from the sorted key.
     Grouping tiebreak is 31+32 hash bits; as everywhere, the segment
     reduce compares full key lanes at boundaries so collisions only
-    duplicate a table row (re-merged downstream).  Micro-bench: ~19%
-    faster than "hashp" on CPU at 393k rows
-    (artifacts/sort_variants_cpu_r3.jsonl G_hash2_payload vs
-    C_hash3_payload); TPU A/B armed in scripts/bench_sort_variants.py.
+    duplicate a table row (re-merged downstream).  The TPU default and
+    the mode every benchmark cell runs (PERF.md section 5:
+    ``sort_dev_ms.tput``); not measured against "hashp" on this machine.
     """
     lanes, values, valid = batch.key_lanes, batch.values, batch.valid
     n_lanes = lanes.shape[-1]
@@ -225,7 +217,7 @@ def _folded_key(batch: KVBatch) -> jax.Array:
     the worst case is a duplicated table row which the next fold (same
     hash -> adjacent again) or the host finalize re-merges — the same
     safety argument as the 64-bit "hash" mode at half the sort-key
-    bandwidth (scripts/bench_sort_variants.py variants D/E).
+    bandwidth.
     """
     h1, _ = packing.hash_pair(batch.key_lanes)
     return jnp.where(batch.valid, h1 >> 1, jnp.uint32(0xFFFFFFFF))
@@ -323,7 +315,7 @@ def _bitonic_sort(batch: KVBatch) -> KVBatch:
             logger.warning(
                 "sort_mode='bitonic' off-TPU at %d rows (> %d): interpret-"
                 "mode kernel skipped; using the equivalent stock lax.sort "
-                "formulation (LOCUST_BITONIC_INTERPRET_MAX overrides)",
+                "formulation",
                 batch.size, BITONIC_INTERPRET_MAX,
             )
         return _hashp1_sort(batch)

@@ -28,7 +28,7 @@ Stability makes LSD correct: pass p orders by digit p preserving the order
 of passes < p, so after ceil(keybits/bits) passes the keys are fully
 sorted and ties keep their original index order (needed by the engine: the
 valid-first convention relies on padded rows sorting after real rows with
-the same sentinel key, see scripts/bench_sort_variants.variant_e).
+the same sentinel key).
 """
 
 from __future__ import annotations

@@ -1,17 +1,12 @@
-"""Aux utilities: evidence ledger, invariant checks, tracing/profiling.
+"""Aux utilities: fault plans, invariant checks, tracing/profiling.
 
 Lazy re-exports (PEP 562): ``checks``/``profiling`` import jax at module
-top, but jax-free callers (bench.py's orchestrator, the serve thin
-client) need ``utils.artifacts``'s ledger readers without pulling jax
-into the process — an eager package __init__ would do exactly that
-transitively.
+top, but jax-free callers (the serve thin client, the distributor's
+control plane) import ``utils.faultplan`` without pulling jax into the
+process — an eager package __init__ would do exactly that transitively.
 """
 
 _EXPORTS = {
-    "on_tpu": "locust_tpu.utils.artifacts",
-    "record": "locust_tpu.utils.artifacts",
-    "ledger_rows": "locust_tpu.utils.artifacts",
-    "latest_row_ts": "locust_tpu.utils.artifacts",
     "checkify_pipeline": "locust_tpu.utils.checks",
     "validate_batch": "locust_tpu.utils.checks",
     "SpanTimer": "locust_tpu.utils.profiling",

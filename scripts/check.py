@@ -158,7 +158,7 @@ def main(argv=None) -> int:
         timeout=420,
     ).returncode
 
-    # Fused-stream smoke (docs/PERF.md "Megakernel v2"): the persistent
+    # Fused-stream smoke (docs/DESIGN.md 1.7, megakernel v2): the persistent
     # STREAMING formulation of the map->aggregate megakernel — a
     # `--stream --sort-mode fused` CLI run over 20 blocks (3 segments,
     # the last partial) must be byte-identical to the one-shot hasht
@@ -827,7 +827,7 @@ assert one_shot.returncode == 0, one_shot.stderr[-800:]
 
 # The persistent streaming kernel: `--stream --sort-mode fused` folds
 # 8-block segment buffers inside one kernel dispatch each (megakernel
-# v2, docs/PERF.md) — 20 blocks = 3 segments, the last PARTIAL, so the
+# v2, docs/DESIGN.md 1.7) — 20 blocks = 3 segments, the last PARTIAL, so the
 # zero-pad path is inside the identity, not just the aligned case.
 fused = subprocess.run(
     [sys.executable, "-m", "locust_tpu", corpus_path,

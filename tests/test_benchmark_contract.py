@@ -1,0 +1,161 @@
+"""What the benchmark reads from the program is still there to be read.
+
+``benchmarks/layer_metrics/*.json`` name spans, program names and jax
+events; a refactoring that renames one turns a per-layer metric to
+``null`` on the chip, where no test runs.  These cases hold the program to
+those names on the CPU: they read ``benchmarks/`` and never write it, and
+a metric file added there is a new case here.  Beside them: ``bench.py``'s
+one line, and the environment variables the package may read.
+"""
+
+import glob
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import bench
+import chip_smoke
+from locust_tpu.config import EngineConfig, default_sort_mode
+from locust_tpu.core.kv import KVBatch
+from locust_tpu.engine import MapReduceEngine
+from locust_tpu.obs import programs
+from locust_tpu.obs.names import METRIC_KINDS, NAMES
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+METRIC_FILES = sorted(
+    glob.glob(os.path.join(REPO, "benchmarks", "layer_metrics", "*.json"))
+)
+SPANS = {name for name, kind in NAMES.items() if kind == "span"}
+METRICS = {name for name, kind in NAMES.items() if kind in METRIC_KINDS}
+JAX_EVENTS = {programs.TRACE, programs.LOWER, programs.COMPILE,
+              programs.CACHE_REQUEST, programs.CACHE_HIT}
+# Readers that take nothing from the program: the harness's own job
+# clock and the device's memory_stats().
+HARNESS_ONLY = {"job_percentile", "memory_stats"}
+
+
+@pytest.fixture(scope="module")
+def program_names() -> set[str]:
+    """Module names of the four programs of the default path, as the
+    device trace's ``XLA Modules`` line will show them: lowered at toy
+    shapes under the mode the chip runs, never compiled."""
+    cfg = EngineConfig(block_lines=8, line_width=32, key_width=8,
+                       emits_per_line=4, sort_mode=default_sort_mode("tpu"))
+    eng = MapReduceEngine(cfg)
+    lines = jax.ShapeDtypeStruct((cfg.block_lines, cfg.line_width), jnp.uint8)
+    kv, _ = jax.eval_shape(eng._map, lines)
+    batch = jax.eval_shape(eng._process, kv)
+    table = jax.eval_shape(eng._reduce, batch)
+    acc = jax.eval_shape(lambda: KVBatch.empty(eng._table_size, cfg.key_lanes))
+    seen = jax.ShapeDtypeStruct((), jnp.int32)
+    lowered = (
+        eng._map.lower(lines),
+        eng._process.lower(kv),
+        eng._reduce.lower(batch),
+        eng._merge.lower(acc, (table, table), seen),
+    )
+    return {re.match(r"module @(\S+)", low.as_text()).group(1) for low in lowered}
+
+
+@pytest.fixture(scope="module")
+def cli_stderr(tmp_path_factory) -> str:
+    """What ``cli.main`` prints beside the table for a ten-line text."""
+    path = tmp_path_factory.mktemp("contract") / "ten.txt"
+    path.write_bytes(b"".join(b"line %d of ten, said twice\n" % i for i in range(10)))
+    table, err, _ = chip_smoke.run_cli([str(path)])
+    assert table.count(b"\n") == 15  # ten numbers and five words
+    return err
+
+
+def _assert_patterns_match(patterns, names, what):
+    for pat in patterns:
+        assert any(re.search(pat, n) for n in names), (
+            f"{what}: /{pat}/ matches no program of the default path {sorted(names)}"
+        )
+
+
+@pytest.mark.parametrize("path", METRIC_FILES, ids=os.path.basename)
+def test_layer_metric_reads_a_name_the_program_still_has(path, request):
+    with open(path) as f:
+        spec = json.load(f)
+    reader = spec["reader"]
+    # Built on first use, once a module: a case that reads a span name
+    # lowers no program and runs no job.
+    fixture = request.getfixturevalue
+    if reader in ("obs_span", "span_count", "device_in_span"):
+        assert spec["span"] in SPANS, f"{spec['span']!r} is not a registered span"
+        prefix = spec.get("holds_all_work")
+        assert prefix is None or any(s.startswith(prefix) for s in SPANS), prefix
+    elif reader == "xla_module":
+        _assert_patterns_match(spec["patterns"], fixture("program_names"), "patterns")
+    elif reader == "roofline":
+        _assert_patterns_match([spec["unit"]], fixture("program_names"), "unit")
+        _assert_patterns_match(spec["programs"], fixture("program_names"), "programs")
+    elif reader == "jax_monitoring":
+        events = set(spec["events"]) | set(spec.get("minus", []))
+        assert events <= JAX_EVENTS, (
+            f"{sorted(events - JAX_EVENTS)}: not an event obs/programs.py knows"
+        )
+    elif reader == "counter":
+        assert spec["name"] in METRICS, f"{spec['name']!r} is not a registered metric"
+    elif reader == "stderr_regex":
+        err = fixture("cli_stderr")
+        assert re.search(spec["pattern"], err), (
+            f"/{spec['pattern']}/ matches nothing cli.main printed:\n{err}"
+        )
+    else:
+        assert reader in HARNESS_ONLY, (
+            f"reader kind {reader!r} is unknown to this test: say here what it "
+            "reads from the program"
+        )
+
+
+def test_the_glob_found_the_metric_files():
+    assert METRIC_FILES, "no case above: benchmarks/layer_metrics/ has moved"
+
+
+# ------------------------------------------------------------- bench.py
+
+
+def _one_line(capsys) -> dict:
+    out = capsys.readouterr().out
+    assert out.count("\n") == 1, out
+    return json.loads(out)
+
+
+def test_bench_prints_one_json_line_with_the_contract_keys(capsys):
+    assert bench.main() == 0
+    row = _one_line(capsys)
+    assert {"metric", "value", "unit", "vs_baseline"} <= set(row)
+    assert "error" not in row
+    assert row["backend"] == "cpu" and row["value"] > 0
+    assert row["distinct"] > 0 and row["truncated"] is False
+
+
+def test_bench_prints_one_line_with_error_for_a_missing_corpus(tmp_path, capsys):
+    assert bench.main(str(tmp_path / "no_such_corpus.txt")) != 0
+    row = _one_line(capsys)
+    assert {"metric", "value", "unit", "vs_baseline", "error"} <= set(row)
+    assert row["value"] == 0.0
+
+
+# ------------------------------------------------- environment variables
+
+# Credentials, the fault harness's switch, a debug switch.  jax's own
+# cache variables (config.compile_cache_dir) are not LOCUST_*.
+ALLOWED_ENV = {"LOCUST_SECRET", "LOCUST_FAULT_PLAN", "LOCUST_DEBUG_CHECKS"}
+
+
+def test_the_package_names_no_other_locust_variable():
+    found = {}
+    for path in glob.glob(os.path.join(REPO, "locust_tpu", "**", "*.py"), recursive=True):
+        with open(path) as f:
+            for name in set(re.findall(r"LOCUST_[A-Z0-9_]+", f.read())):
+                found.setdefault(name, os.path.relpath(path, REPO))
+    assert set(found) <= ALLOWED_ENV, {
+        k: v for k, v in found.items() if k not in ALLOWED_ENV
+    }

@@ -119,20 +119,6 @@ def test_bitonic_schedule_covers_every_substage_once():
                                    for _, t_hi, t_lo in step[1]) <= mf
 
 
-def test_roofline_counts_the_shared_schedule():
-    """utils/roofline.sort_pass_count('bitonic') must equal the length of
-    the plan the kernel executes (single source of truth)."""
-    from locust_tpu.config import BITONIC_TILE_ROWS, bitonic_schedule
-    from locust_tpu.utils import roofline
-
-    n = 720_896
-    k = int(np.ceil(np.log2(n)))
-    m = min(k, (BITONIC_TILE_ROWS * 128).bit_length() - 1)
-    assert roofline.sort_pass_count(n, "bitonic") == len(
-        bitonic_schedule(k, m)
-    )
-
-
 def test_engine_mode_over_the_interpret_cap_serves_the_stock_sort(monkeypatch):
     """Off-TPU, a bitonic-mode sort above BITONIC_INTERPRET_MAX must take
     the equivalent stock formulation (hashp1) with its one-time warning —

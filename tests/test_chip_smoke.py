@@ -141,7 +141,7 @@ def _stub_tpu(monkeypatch, kind="TPU v5 lite", count=1):
 @pytest.mark.parametrize(
     "kind,count,argv,match",
     [
-        ("TPU v9 imaginary", 1, [], "not in utils/roofline"),
+        ("TPU v9 imaginary", 1, [], "not in benchmarks/peaks.json"),
         ("TPU v5 lite", 1, ["--chips", "4"], "--chips 4 but jax sees 1"),
         ("TPU v5 lite", 4, [], "--chips 1 but jax sees 4"),
     ],

@@ -39,8 +39,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def corpus_lines(n_lines=700):
-    """Reference hamlet when mounted, else the shipped sample corpus —
-    same fallback chain as bench.load_corpus."""
+    """Reference hamlet when mounted, else the shipped sample corpus."""
     for path in ("/root/reference/hamlet.txt",
                  os.path.join(REPO, "data", "sample_corpus.txt")):
         if os.path.exists(path):
@@ -554,9 +553,8 @@ def test_fused_registered_in_mode_tables():
     assert t_lo & (t_lo - 1) == 0  # shift+mask split needs pow2
     assert FUSED_TILE_LINES % 32 == 0
     assert FUSED_RESIDUAL_ROWS & (FUSED_RESIDUAL_ROWS - 1) == 0
-    # ONE decider for the physical plane layout: the kernel and the
-    # roofline model both consume config.fused_table_layout, so the
-    # modeled table-flush bytes can't drift from the allocated planes.
+    # ONE decider for the physical plane layout: the kernel consumes
+    # config.fused_table_layout.
     import locust_tpu.ops.pallas.fused_fold as ff
     from locust_tpu.config import FUSED_SUBLANE, fused_table_layout
 
@@ -564,46 +562,6 @@ def test_fused_registered_in_mode_tables():
     p_hi, p_lo = fused_table_layout()
     assert p_lo == t_lo and p_hi * p_lo >= FUSED_TABLE_SLOTS
     assert p_hi % FUSED_SUBLANE == 0 or p_hi == FUSED_SUBLANE
-
-
-# ----------------------------------------------- roofline byte model
-
-
-def test_roofline_prices_fused_strictly_below_hasht_mxu():
-    """The acceptance pin: at the bench shape the fused mode's modeled
-    HBM bytes must be STRICTLY below hasht-mxu's (the one-hot operands
-    and the token tensor both disappear) — and below plain hasht's too,
-    since the settlement sweeps run over pre-aggregated rows."""
-    from locust_tpu.utils import roofline
-
-    common = dict(key_lanes=4, emits_per_block=32768 * 17,
-                  table_size=65536, n_blocks=24, elapsed_s=0.5,
-                  device_kind="TPU v5 lite")
-    fused = roofline.summarize("fused", block_lines=32768, line_width=128,
-                               **common)
-    mxu = roofline.summarize("hasht-mxu", **common)
-    base = roofline.summarize("hasht", **common)
-    assert fused["est_sort_traffic_bytes"] < mxu["est_sort_traffic_bytes"]
-    assert fused["est_sort_traffic_bytes"] < base["est_sort_traffic_bytes"]
-    assert fused["est_kernel_bytes"] > 0
-    assert fused["rows_per_sort"] < base["rows_per_sort"]
-    assert fused["hbm_utilization_pct"] is not None
-
-
-def test_roofline_fused_requires_block_geometry():
-    """The fused model is sized off the line block, not the emit count —
-    calling it without the geometry must fail loudly, never price the
-    wrong thing."""
-    from locust_tpu.utils import roofline
-
-    with pytest.raises(ValueError, match="block_lines"):
-        roofline.pipeline_sort_traffic("fused", 4, 32768 * 17, 65536, 24)
-    # Other modes are untouched by the new kwargs.
-    out = roofline.pipeline_sort_traffic(
-        "hashp2", 4, 32768 * 17, 65536, 24,
-        block_lines=32768, line_width=128,
-    )
-    assert out["est_sort_traffic_bytes"] > 0
 
 
 # ------------------------------------------------ megakernel v2: stream
@@ -853,41 +811,3 @@ def test_mesh_fused_demotion_is_explicit_not_silent(caplog):
     hr = hasht.run(rows)
     assert not hr.fused_demoted and hr.fused_kernel is None
     assert res.to_host_pairs() == hr.to_host_pairs()
-
-
-def test_roofline_stream_strictly_below_batch_at_bench_shape():
-    """The v2 acceptance pin: at the bench shape the persistent
-    streaming kernel's modeled per-stream HBM bytes are STRICTLY below
-    v1's per-block (batch) figure — the acc->settle->acc round-trip and
-    the table flush amortize across the segment — and the mesh variant
-    prices below batch too (per-shard settlement over preagg rows)."""
-    from locust_tpu.utils import roofline
-
-    common = dict(key_lanes=4, emits_per_block=32768 * 17,
-                  table_size=65536, n_blocks=24,
-                  block_lines=32768, line_width=128)
-    batch = roofline.pipeline_sort_traffic("fused", **common)
-    stream = roofline.pipeline_sort_traffic(
-        "fused", fused_variant="stream", **common
-    )
-    mesh = roofline.pipeline_sort_traffic(
-        "fused", fused_variant="mesh", **common
-    )
-    assert stream["est_sort_traffic_bytes"] < batch["est_sort_traffic_bytes"]
-    assert mesh["est_sort_traffic_bytes"] < batch["est_sort_traffic_bytes"]
-    assert batch["fused_variant"] == "batch"
-    assert stream["fused_variant"] == "stream"
-    assert stream["stream_seg_blocks"] >= 1
-    assert stream["n_segments"] == -(-24 // stream["stream_seg_blocks"])
-    # The default segment size comes from the SAME clamp the engine
-    # uses (config.fused_stream_seg_blocks) — model and runtime can't
-    # drift.
-    from locust_tpu.config import fused_stream_seg_blocks
-
-    assert stream["stream_seg_blocks"] == fused_stream_seg_blocks(
-        32768 * 17, 32768, True
-    )
-    with pytest.raises(ValueError, match="fused_variant"):
-        roofline.pipeline_sort_traffic(
-            "fused", fused_variant="nope", **common
-        )

@@ -19,10 +19,13 @@ from test_table_growth import (
 )
 
 from locust_tpu import obs
-from locust_tpu.config import EngineConfig
+from locust_tpu.config import EngineConfig, default_sort_mode
 from locust_tpu.engine import MapReduceEngine
 
 G = 4  # blocks a full group holds in these tests (the budget below)
+# The grouping mode of every benchmark cell; EngineConfig's own default
+# ("hash") is what the rest of this file runs (ROADMAP Design item 2).
+CHIP_MODE = default_sort_mode("tpu")
 
 
 @pytest.fixture(autouse=True)
@@ -55,21 +58,30 @@ def _stage_merges(tracer):
             if e.get("ph") == "X" and e["name"] == "engine.stage.merge"]
 
 
-@pytest.mark.parametrize("nblocks", [1, 2, 3, G, G + 1, 2 * G + 3])
-@pytest.mark.parametrize("combine", ["sum", "min", "max", "count"])
-def test_group_merge_gives_the_per_block_folds_table(group_of_four, combine, nblocks):
+@pytest.mark.parametrize(
+    "sort_mode, combine, nblocks",
+    [("hash", c, n) for c in ("sum", "min", "max", "count")
+     for n in (1, 2, 3, G, G + 1, 2 * G + 3)]
+    + [(CHIP_MODE, c, n) for c in ("sum", "min")
+       for n in (1, G, G + 1, 2 * G + 3)],
+)
+def test_group_merge_gives_the_per_block_folds_table(
+    group_of_four, sort_mode, combine, nblocks
+):
     """Groups of 1, 2, 3, G, G + 1 (a padded last group of one) and two
     full groups and three blocks, values that differ from line to line, a
     vocabulary past the 128-row start in all but the one-block job: the
-    table of ``run`` with room for every key, and the host's."""
+    table of ``run`` with room for every key, and the host's — under
+    ``EngineConfig``'s default mode and under the mode the chip runs."""
     n_lines = 16 * nblocks
     # Five lines in eight are new keys, the rest repeat them at other values.
     lines = valued_lines(7 * -(-n_lines * 5 // 8), rounds=2)[:n_lines]
     assert len(lines) == n_lines
     want = valued_oracle(lines, combine)
     assert (len(want) > 128) == (nblocks > 1)
-    small = MapReduceEngine(EngineConfig(table_size=128, **_SMALL), valued_map, combine)
-    roomy = MapReduceEngine(EngineConfig(table_size=4096, **_SMALL), valued_map, combine)
+    shapes = dict(_SMALL, sort_mode=sort_mode)
+    small = MapReduceEngine(EngineConfig(table_size=128, **shapes), valued_map, combine)
+    roomy = MapReduceEngine(EngineConfig(table_size=4096, **shapes), valued_map, combine)
     rows = small.rows_from_lines(lines)
     got, ref = small.timed_run(rows), roomy.run(rows)
     assert not got.truncated and not ref.truncated
@@ -78,15 +90,17 @@ def test_group_merge_gives_the_per_block_folds_table(group_of_four, combine, nbl
     assert got.to_host_pairs() == ref.to_host_pairs() == want
 
 
+@pytest.mark.parametrize("sort_mode", ["hash", CHIP_MODE])
 @pytest.mark.parametrize("first_group, to_rows", [(20, 1 << 18), (12, 1 << 17)])
-def test_real_capacities_grow_in_one_step(monkeypatch, first_group, to_rows):
+def test_real_capacities_grow_in_one_step(monkeypatch, first_group, to_rows, sort_mode):
     """The CLI's own 65,536-row start (at smaller blocks).  A first group of 163,840 new keys
     passes two capacities at once: ONE step to 2^18 and one redone merge,
     where a chain of truncating merges took two steps and three merges.  A
     first group of 98,304 takes the one step to 2^17, and the second group
     the next.  The per-block fold in a table of 2^18 rows and the host
     agree on every row."""
-    shapes = dict(block_lines=1024, line_width=64, key_width=8, emits_per_line=8)
+    shapes = dict(block_lines=1024, line_width=64, key_width=8, emits_per_line=8,
+                  sort_mode=sort_mode)
     block_bytes = 1024 * 64 + 3 * 8192 * (8 + 4 + 1)
     monkeypatch.setattr(MapReduceEngine, "TIMED_GROUP_BYTES", first_group * block_bytes)
     words = [b"k%06d" % i for i in range(170_000)]

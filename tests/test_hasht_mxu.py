@@ -1,8 +1,8 @@
 """sort_mode="hasht-mxu" — the MXU-combine spelling of the sort-free fold.
 
 The contract is BIT-identity: hash_table.mxu_scatter_add replaces the
-probe loop's duplicate-index value scatter with one-hot bf16 contractions
-(the productized K_mxu_hist probe), and because its limb arithmetic is
+probe loop's duplicate-index value scatter with one-hot bf16 contractions,
+and because its limb arithmetic is
 exact mod 2^32 — the ring int32 scatter-add lives in — every table,
 counter, and unresolved mask must equal the "hasht" impl's byte for byte,
 through every consumer path (engine fold, mesh shuffle, hierarchical
@@ -36,9 +36,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def corpus_lines(n_lines=700):
-    """Reference hamlet when mounted, else the shipped sample corpus —
-    same fallback chain as bench.load_corpus, so the oracle battery runs
-    in every environment."""
+    """Reference hamlet when mounted, else the shipped sample corpus, so
+    the oracle battery runs in every environment."""
     for path in ("/root/reference/hamlet.txt",
                  os.path.join(REPO, "data", "sample_corpus.txt")):
         if os.path.exists(path):
@@ -312,31 +311,3 @@ def test_hasht_mxu_scan_lowers_for_tpu():
     shape = jax.ShapeDtypeStruct((2, 256, cfg.line_width), jnp.uint8)
     exp = jax_export.export(eng._scan_blocks, platforms=["tpu"])(shape)
     assert len(exp.mlir_module()) > 0
-
-
-# ----------------------------------------------- roofline + sweep order
-
-
-def test_roofline_models_hasht_mxu_traffic():
-    """summarize() must price the mode (one-hot bytes split out) and
-    carry hbm_utilization_pct on a known device — the field the engine
-    A/B rows publish."""
-    from locust_tpu.utils import roofline
-
-    out = roofline.summarize(
-        "hasht-mxu", key_lanes=8, emits_per_block=32768 * 20,
-        table_size=65536, n_blocks=24, elapsed_s=0.5,
-        device_kind="TPU v5 lite",
-    )
-    assert out["hbm_utilization_pct"] is not None
-    assert out["est_onehot_bytes"] > 0
-    assert out["est_sort_traffic_bytes"] > out["est_onehot_bytes"]
-    assert out["mxu_grid"] == [128, 512]
-    # Fewer row sweeps than hasht (the combine moved to the MXU), so the
-    # row-sweep component must be strictly smaller.
-    base = roofline.summarize(
-        "hasht", key_lanes=8, emits_per_block=32768 * 20,
-        table_size=65536, n_blocks=24, elapsed_s=0.5,
-        device_kind="TPU v5 lite",
-    )
-    assert out["sort_passes"] < base["sort_passes"]

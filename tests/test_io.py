@@ -460,3 +460,12 @@ def test_native_measure_caps_parity(tmp_path):
     assert loader.measure_caps_stream(stream) == loader.measure_caps_rows(
         loader.StreamingCorpus(str(p), 128, 32)
     )
+
+
+def test_count_distinct_tokens_engine_semantics():
+    lines = [b"to be, or not to-be", b"to be, or not to-be", b"that\tis"]
+    # strtok semantics: ',' '-' '\t' split; duplicates (incl. whole
+    # duplicate lines) count once: to, be, or, not, that, is
+    assert loader.count_distinct_tokens(lines) == 6
+    assert loader.count_distinct_tokens([]) == 0
+    assert loader.count_distinct_tokens([b"", b"  , "]) == 0

@@ -373,8 +373,7 @@ def test_rss_flat_with_async_checkpoints_tier1():
     """Tier-1 RSS-flatness regression: a 13x-larger streamed corpus with
     async checkpoints enabled must not grow peak RSS by more than a
     fixed margin — staging ring + bounded inflight + latest-wins marks
-    keep the working set O(1) in corpus size (the measured flat-RSS
-    contract, artifacts/stream_scale_cpu_r4.jsonl)."""
+    keep the working set O(1) in corpus size (the flat-RSS contract)."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = REPO  # the child imports THIS checkout (R006)
@@ -388,14 +387,3 @@ def test_rss_flat_with_async_checkpoints_tier1():
     # (or buffers snapshot generations) shows up as tens of MB here.
     assert row["delta_mb"] < 25, f"streaming RSS grew with corpus: {row}"
     assert row["ckpt"]["written"] >= 1
-
-
-# ------------------------------------------------------------------ bench tie
-
-
-def test_bench_stream_stats_env_skip(monkeypatch):
-    sys.path.insert(0, REPO)
-    import bench
-
-    monkeypatch.setenv("LOCUST_BENCH_STREAM", "0")
-    assert bench._stream_stats(None, None) == {"skipped": True}
