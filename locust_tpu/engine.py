@@ -30,10 +30,12 @@ without leaving the device idle between blocks.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
 import logging
 import os
+import threading
 import time
 from functools import partial
 from typing import Callable, Sequence
@@ -298,6 +300,345 @@ class _CheckpointPump:
         return out
 
 
+@dataclasses.dataclass(frozen=True)
+class _Programs:
+    """One configuration's jitted programs and the static facts decided
+    with them (``_build_programs``); every engine of the configuration in
+    the process holds this one record (``_programs_for``)."""
+
+    map_fn: MapFn  # normalized (reduce_stage.normalize_combine)
+    map: Callable
+    process: Callable
+    reduce: Callable
+    merge: Callable
+    fold_block: Callable
+    fold_block_fallback: Callable
+    fold_segment: Callable | None
+    scan_blocks_into: Callable
+    scan_blocks: Callable
+    scan_blocks_batch: Callable
+    fused_kernel_on: bool
+    fused_demoted: bool
+    fused_stream_seg: int
+
+
+def _build_programs(cfg: EngineConfig, raw_map_fn: MapFn,
+                    raw_combine: str) -> _Programs:
+    """Define and jit every program of an engine of (``cfg``,
+    ``raw_map_fn``, ``raw_combine``) on the current backend.  Nothing
+    here names an engine: the closures hold the configuration alone, so
+    engines share them and a dead engine is no reference cycle.  Nothing
+    is traced here either; jax traces a program at its first call."""
+    # "count" lowers to emit-1 + sum so the block-accumulator merge is
+    # associative (reduce_stage.normalize_combine); the device pipeline
+    # below uses the normalized pair throughout.  The RAW map_fn is
+    # what the fused-kernel eligibility check identifies (the count
+    # wrapper emits the same 1s the kernel counts).
+    map_fn, combine = normalize_combine(raw_map_fn, raw_combine)
+    tsize = cfg.resolved_table_size
+    mode = cfg.sort_mode
+
+    from locust_tpu.ops.hash_table import fold_into
+
+    # sort_mode="fused": the Pallas map->aggregate megakernel
+    # (ops/pallas/fused_fold.py) replaces the map stage + first
+    # aggregation at THIS boundary only — everywhere else the mode
+    # is "hasht" exactly (config.HASHT_FAMILY).  Eligibility is
+    # fully static, decided (and logged) once here, never inside
+    # traced code.
+    fused_kernel_on = False
+    fused_demoted = False
+    # Persistent streaming segment length (megakernel v2): how many
+    # staged blocks run_stream groups into ONE kernel launch with the
+    # table VMEM-resident across the whole segment.  1 = per-block
+    # (the v1 formulation); the clamp keeps the per-segment emit
+    # budget f32-exact and bounds off-TPU interpret cost
+    # (config.fused_stream_seg_blocks).
+    fused_stream_seg = 1
+    if mode == "fused":
+        from locust_tpu.config import fused_stream_seg_blocks
+        from locust_tpu.ops.pallas.fused_fold import (
+            fused_engine_eligible,
+        )
+
+        ok, why = fused_engine_eligible(cfg, raw_map_fn, raw_combine)
+        fused_kernel_on = ok
+        fused_demoted = not ok
+        if not ok:
+            logger.info("sort_mode='fused': kernel not engaged — %s",
+                        why)
+        else:
+            fused_stream_seg = fused_stream_seg_blocks(
+                cfg.emits_per_block,
+                cfg.block_lines,
+                jax.default_backend() == "tpu",
+            )
+
+    def fold_block(acc: KVBatch, lines: jax.Array):
+        """Map one block and merge its emits into the running table.
+
+        Sort modes: ONE sort of (table_size + emits_per_block) rows
+        does both the block's shuffle-grouping and the cross-block
+        merge.  The hasht family ("hasht" = scatter combine,
+        "hasht-mxu" = one-hot MXU combine, "fused" = the Pallas
+        megakernel below, else hasht): the sort-free fold with its
+        exactness ladder, rebuilt per fold (ops/hash_table.fold_into
+        — see there for why the incremental variant measured worse
+        and is not wired).  Either way the running distinct-key
+        count is measured BEFORE the capacity slice so a truncation
+        in any fold is observable.
+
+        Fused kernel path: the block pre-aggregates IN VMEM (the
+        [lines, emits, key_width] token tensor never touches HBM)
+        and the settlement folds (acc + kernel table + residual)
+        through the SAME aggregate_exact as "hasht" — the final
+        table is a pure function of the distinct-key set and the
+        per-key totals, so it is bit-identical to the hasht fold
+        (ops/pallas/fused_fold.py module docstring; pinned by
+        tests/test_fused_fold.py).  A residual-buffer overflow in
+        the kernel re-folds the block through the stock path via
+        lax.cond — exact either way, and the overflow counter is
+        the kernel's under both branches (identical tokenize
+        formulation).
+        """
+        if fused_kernel_on:
+            from locust_tpu.ops.pallas.fused_fold import (
+                fused_block_preagg,
+            )
+
+            interpret = jax.default_backend() != "tpu"
+            ktab, kresid, overflow, bad = fused_block_preagg(
+                lines, cfg, interpret=interpret
+            )
+
+            def fused_path(acc_in):
+                return fold_into(
+                    acc_in, KVBatch.concat(ktab, kresid), tsize,
+                    combine, mode,
+                )
+
+            def stock_path(acc_in):
+                kv, _ = map_fn(lines, cfg)
+                return fold_into(acc_in, kv, tsize, combine, mode)
+
+            merged, distinct = jax.lax.cond(
+                bad, stock_path, fused_path, acc
+            )
+            return merged, overflow, distinct
+        return stock_fold(acc, lines)
+
+    def stock_fold(acc: KVBatch, lines: jax.Array):
+        """The kernel-free fold — fold_block's non-kernel tail, and
+        the breaker-failover executable: the CPU fallback must never
+        trace the Mosaic kernel (at failover trace time
+        jax.default_backend() is still the dead primary, so the
+        in-fold interpret switch cannot see the migration;
+        run_checkpointed dispatches THIS on the fallback device).
+        Bit-identical outputs to the kernel path by the settlement
+        argument, so mid-job migration changes nothing downstream.
+        """
+        kv, overflow = map_fn(lines, cfg)
+        merged, distinct = fold_into(acc, kv, tsize, combine, mode)
+        return merged, overflow, distinct
+
+    def fold_segment(acc: KVBatch, seg_lines: jax.Array):
+        """Persistent-kernel streaming fold (megakernel v2): ONE
+        kernel launch over ``[seg_blocks * block_lines, width]``
+        staged lines, table planes VMEM-resident across the whole
+        segment (fused_block_preagg already supports any
+        tile-multiple line count; its constant-index table BlockSpec
+        IS the persistence).  The acc->settle->acc HBM round-trip
+        and the table flush amortize by the segment length.
+
+        Bit-identity carries over from fold_block unchanged: the
+        settlement folds concat(acc, table, residual) through the
+        same aggregate_exact, and hasht's final table is a pure
+        function of the distinct-key set + per-key totals — which
+        are grouping-invariant (emit overflow is per-line, counts
+        are per-key sums).  A residual overflow re-folds the WHOLE
+        segment through the stock path (map over the segment lines
+        is exact at any length), so both cond branches stay exact.
+        """
+        from locust_tpu.ops.pallas.fused_fold import (
+            fused_block_preagg,
+        )
+
+        interpret = jax.default_backend() != "tpu"
+        ktab, kresid, overflow, bad = fused_block_preagg(
+            seg_lines, cfg, interpret=interpret
+        )
+
+        def fused_path(acc_in):
+            return fold_into(
+                acc_in, KVBatch.concat(ktab, kresid), tsize,
+                combine, mode,
+            )
+
+        def stock_path(acc_in):
+            kv, _ = map_fn(seg_lines, cfg)
+            return fold_into(acc_in, kv, tsize, combine, mode)
+
+        merged, distinct = jax.lax.cond(bad, stock_path, fused_path, acc)
+        return merged, overflow, distinct
+
+    def scan_blocks_into(acc0: KVBatch, blocks: jax.Array):
+        """Whole-corpus pipeline in ONE dispatch: fold blocks with lax.scan.
+
+        One device dispatch per corpus instead of per block — essential
+        when per-dispatch latency matters (many small blocks) and the XLA-
+        idiomatic way to loop without data-dependent Python control flow.
+        The init accumulator arrives as an ARGUMENT so the jit below
+        can donate it into the scan carry (cfg.donate_fold): even the
+        one-dispatch path allocates no second table.
+        """
+
+        def body(carry, blk):
+            acc, overflow_acc, max_distinct = carry
+            acc, overflow, distinct = fold_block(acc, blk)
+            return (
+                acc,
+                overflow_acc + overflow,
+                jnp.maximum(max_distinct, distinct),
+            ), None
+
+        init = (acc0, jnp.int32(0), jnp.int32(0))
+        (acc, overflow, num), _ = jax.lax.scan(body, init, blocks)
+        return acc, overflow, num
+
+    # Donated fold state (cfg.donate_fold): the accumulator table —
+    # the largest live array — is donated into every per-block
+    # dispatch and into the scan init, so XLA aliases its buffers
+    # input->output (updated in place, no per-fold re-allocation).
+    # Callers therefore must treat the acc they passed as consumed;
+    # every loop here rebinds it, and snapshot marks copy on device
+    # first (_CheckpointPump.mark).
+    # The two donating jits are bound here under the names the engine
+    # calls them by: the analyzer's R010 knows a donating callable by the
+    # name its jax.jit(..., donate_argnums=) is bound to.
+    donate = (0,) if cfg.donate_fold else ()
+    _fold_block = jax.jit(fold_block, donate_argnums=donate)
+    # Breaker-failover fold (run_checkpointed's on-CPU dispatch):
+    # identical to _fold_block unless the fused kernel is on — then
+    # it is the kernel-free stock fold (see stock_fold above).
+    # Traced lazily, so non-failover runs never pay its compile.
+    fold_block_fallback = (
+        jax.jit(stock_fold, donate_argnums=donate)
+        if fused_kernel_on
+        else _fold_block
+    )
+    # Streaming-segment executable (megakernel v2): traced lazily on
+    # first run_stream use; None when the kernel is off or the clamp
+    # leaves segments at one block (then run_stream's per-block loop
+    # is already optimal).
+    jit_fold_segment = (
+        jax.jit(fold_segment, donate_argnums=donate)
+        if fused_kernel_on and fused_stream_seg > 1
+        else None
+    )
+    _scan_blocks_into = jax.jit(scan_blocks_into, donate_argnums=donate)
+    # The export/compile-check surface (__graft_entry__.entry, the
+    # TPU StableHLO lowering gates) keeps the one-argument signature.
+    jit_scan_blocks = jax.jit(
+        lambda blocks: scan_blocks_into(
+            KVBatch.empty(tsize, cfg.key_lanes), blocks
+        )
+    )
+    # Batched job executable (the serve tier's coalesced dispatch,
+    # docs/SERVING.md): vmap the whole-corpus scan over a leading JOB
+    # axis, so N compatible small jobs fold in ONE device dispatch
+    # with per-job tables/counters out.  Each job slot gets its own
+    # fresh accumulator (no donation: slots are independent and the
+    # batch is rebuilt per dispatch); traced/compiled lazily on first
+    # use per [njobs, nblocks] shape — non-serve users never pay it.
+    jit_scan_blocks_batch = jax.jit(
+        jax.vmap(
+            lambda blocks: scan_blocks_into(
+                KVBatch.empty(tsize, cfg.key_lanes), blocks
+            )
+        )
+    )
+
+    # Split stages for the timed path only: map, process and reduce
+    # run once a block, the merge once a GROUP of blocks — the running
+    # table and all of the group's block tables through one sort and
+    # one segment combine, so the table is sorted again once a group
+    # and not once a block.  The capacity is the accumulator's own
+    # size and the fan-in the length of ``tables``, so the one jit
+    # re-traces per capacity timed_run grows to (_regrow) and per rung
+    # of the fan-in ladder (_timed_group_blocks).  ``distinct`` is the
+    # TRUE count of keys in table + group, whatever the capacity.
+    # ``acc`` is not donated: it is the way back when the merge passes
+    # the capacity.
+    def merge_tables(acc: KVBatch, tables: tuple[KVBatch, ...],
+                     max_distinct: jax.Array):
+        merged, distinct = segment_reduce_into(
+            sort_and_compact(KVBatch.concat(acc, *tables), mode), acc.size, combine
+        )
+        return merged, jnp.maximum(max_distinct, distinct)
+
+    return _Programs(
+        map_fn=map_fn,
+        map=jax.jit(lambda lines: map_fn(lines, cfg)),
+        process=jax.jit(partial(sort_and_compact, mode=mode)),
+        reduce=jax.jit(partial(segment_reduce, combine=combine)),
+        merge=jax.jit(merge_tables),
+        fold_block=_fold_block,
+        fold_block_fallback=fold_block_fallback,
+        fold_segment=jit_fold_segment,
+        scan_blocks_into=_scan_blocks_into,
+        scan_blocks=jit_scan_blocks,
+        scan_blocks_batch=jit_scan_blocks_batch,
+        fused_kernel_on=fused_kernel_on,
+        fused_demoted=fused_demoted,
+        fused_stream_seg=fused_stream_seg,
+    )
+
+
+# The process's programs, a record a key (_programs_for), least recently
+# used last out; guarded by the lock beside it.
+_PROGRAMS: "collections.OrderedDict[tuple, _Programs]" = collections.OrderedDict()
+_PROGRAMS_LOCK = threading.Lock()
+
+
+def _programs_for(cfg: EngineConfig, map_fn: MapFn,
+                  combine: str) -> tuple[_Programs, bool]:
+    """``(programs, built)`` for an engine of these arguments: the
+    process's one record of its key — everything ``_build_programs``
+    closes over or reads: the config, the map function object, the
+    user's combine and the backend — built here if the process holds none
+    (``built``).  Every later engine of the key takes the same jit
+    objects, so jax's in-memory cache answers its first call as it
+    answers an old engine's hundredth: nothing is traced, lowered or read
+    back.  At most ``MapReduceEngine.PROGRAM_KEYS`` keys are kept; an
+    evicted record lives as long as the engines that hold it, and jax
+    frees its executables with the last.  Built under the lock: two
+    threads of one key get one record."""
+    key = (cfg, map_fn, combine, jax.default_backend())
+    with _PROGRAMS_LOCK:
+        programs = _PROGRAMS.get(key)
+        built = programs is None
+        if built:
+            programs = _PROGRAMS[key] = _build_programs(cfg, map_fn, combine)
+            while len(_PROGRAMS) > MapReduceEngine.PROGRAM_KEYS:
+                _PROGRAMS.popitem(last=False)
+        else:
+            _PROGRAMS.move_to_end(key)
+    return programs, built
+
+
+def clear_programs() -> None:
+    """Forget every program the process has built: the next engine of any
+    configuration builds (and jax traces, lowers and loads) anew.
+
+    For tests, and for a host that changes something a program reads
+    while it is TRACED — ``LOCUST_DEBUG_CHECKS``, a monkeypatched module
+    constant: a jit object is traced under the process state of its FIRST
+    call with a shape, and every engine that shares it runs what was
+    traced then.  Engines already made keep their programs."""
+    with _PROGRAMS_LOCK:
+        _PROGRAMS.clear()
+
+
 class MapReduceEngine:
     """Blocked map/shuffle/reduce on one device (mesh version in parallel/)."""
 
@@ -323,6 +664,11 @@ class MapReduceEngine:
     # group's merge counts more distinct keys than it holds (_regrow): a
     # million-key job ends at 16 times its start.
     TABLE_GROWTH = 2
+    # Configurations whose programs the process keeps (_programs_for),
+    # least recently used out first: a daemon's handful of workloads and
+    # shapes, a plan's stages.  An engine past it builds, as every engine
+    # did before the programs were shared.
+    PROGRAM_KEYS = 8
 
     def __init__(
         self,
@@ -336,261 +682,33 @@ class MapReduceEngine:
             # same enable + an export at exit); idempotent, shares one
             # process timeline with any tracer already enabled.
             obs.enable()
-        # A fresh engine is where jax re-traces, re-lowers and re-reads
-        # its programs: from here on a traced run records that too.
+        # A configuration's first engine is where jax traces, lowers and
+        # reads back its programs: a traced run records that too.
         obs.watch_programs()
         self.combine = combine  # user-facing semantics (host finalize)
-        # "count" lowers to emit-1 + sum so the block-accumulator merge is
-        # associative (reduce_stage.normalize_combine); the device pipeline
-        # below uses the normalized pair throughout.  The RAW map_fn is
-        # what the fused-kernel eligibility check identifies (the count
-        # wrapper emits the same 1s the kernel counts).
-        raw_map_fn = map_fn
-        map_fn, combine = normalize_combine(map_fn, combine)
-        self.map_fn = map_fn
-        tsize = cfg.resolved_table_size
-        mode = cfg.sort_mode
-
-        from locust_tpu.ops.hash_table import fold_into
-
-        # sort_mode="fused": the Pallas map->aggregate megakernel
-        # (ops/pallas/fused_fold.py) replaces the map stage + first
-        # aggregation at THIS boundary only — everywhere else the mode
-        # is "hasht" exactly (config.HASHT_FAMILY).  Eligibility is
-        # fully static, decided (and logged) once here, never inside
-        # traced code.
-        self._fused_kernel_on = False
-        self._fused_demoted = False
-        # Persistent streaming segment length (megakernel v2): how many
-        # staged blocks run_stream groups into ONE kernel launch with the
-        # table VMEM-resident across the whole segment.  1 = per-block
-        # (the v1 formulation); the clamp keeps the per-segment emit
-        # budget f32-exact and bounds off-TPU interpret cost
-        # (config.fused_stream_seg_blocks).
-        self._fused_stream_seg = 1
-        if mode == "fused":
-            from locust_tpu.config import fused_stream_seg_blocks
-            from locust_tpu.ops.pallas.fused_fold import (
-                fused_engine_eligible,
-            )
-
-            ok, why = fused_engine_eligible(cfg, raw_map_fn, self.combine)
-            self._fused_kernel_on = ok
-            self._fused_demoted = not ok
-            if not ok:
-                logger.info("sort_mode='fused': kernel not engaged — %s",
-                            why)
-            else:
-                self._fused_stream_seg = fused_stream_seg_blocks(
-                    cfg.emits_per_block,
-                    cfg.block_lines,
-                    jax.default_backend() == "tpu",
-                )
-
-        def fold_block(acc: KVBatch, lines: jax.Array):
-            """Map one block and merge its emits into the running table.
-
-            Sort modes: ONE sort of (table_size + emits_per_block) rows
-            does both the block's shuffle-grouping and the cross-block
-            merge.  The hasht family ("hasht" = scatter combine,
-            "hasht-mxu" = one-hot MXU combine, "fused" = the Pallas
-            megakernel below, else hasht): the sort-free fold with its
-            exactness ladder, rebuilt per fold (ops/hash_table.fold_into
-            — see there for why the incremental variant measured worse
-            and is not wired).  Either way the running distinct-key
-            count is measured BEFORE the capacity slice so a truncation
-            in any fold is observable.
-
-            Fused kernel path: the block pre-aggregates IN VMEM (the
-            [lines, emits, key_width] token tensor never touches HBM)
-            and the settlement folds (acc + kernel table + residual)
-            through the SAME aggregate_exact as "hasht" — the final
-            table is a pure function of the distinct-key set and the
-            per-key totals, so it is bit-identical to the hasht fold
-            (ops/pallas/fused_fold.py module docstring; pinned by
-            tests/test_fused_fold.py).  A residual-buffer overflow in
-            the kernel re-folds the block through the stock path via
-            lax.cond — exact either way, and the overflow counter is
-            the kernel's under both branches (identical tokenize
-            formulation).
-            """
-            if self._fused_kernel_on:
-                from locust_tpu.ops.pallas.fused_fold import (
-                    fused_block_preagg,
-                )
-
-                interpret = jax.default_backend() != "tpu"
-                ktab, kresid, overflow, bad = fused_block_preagg(
-                    lines, cfg, interpret=interpret
-                )
-
-                def fused_path(acc_in):
-                    return fold_into(
-                        acc_in, KVBatch.concat(ktab, kresid), tsize,
-                        combine, mode,
-                    )
-
-                def stock_path(acc_in):
-                    kv, _ = map_fn(lines, cfg)
-                    return fold_into(acc_in, kv, tsize, combine, mode)
-
-                merged, distinct = jax.lax.cond(
-                    bad, stock_path, fused_path, acc
-                )
-                return merged, overflow, distinct
-            return stock_fold(acc, lines)
-
-        def stock_fold(acc: KVBatch, lines: jax.Array):
-            """The kernel-free fold — fold_block's non-kernel tail, and
-            the breaker-failover executable: the CPU fallback must never
-            trace the Mosaic kernel (at failover trace time
-            jax.default_backend() is still the dead primary, so the
-            in-fold interpret switch cannot see the migration;
-            run_checkpointed dispatches THIS on the fallback device).
-            Bit-identical outputs to the kernel path by the settlement
-            argument, so mid-job migration changes nothing downstream.
-            """
-            kv, overflow = map_fn(lines, cfg)
-            merged, distinct = fold_into(acc, kv, tsize, combine, mode)
-            return merged, overflow, distinct
-
-        def fold_segment(acc: KVBatch, seg_lines: jax.Array):
-            """Persistent-kernel streaming fold (megakernel v2): ONE
-            kernel launch over ``[seg_blocks * block_lines, width]``
-            staged lines, table planes VMEM-resident across the whole
-            segment (fused_block_preagg already supports any
-            tile-multiple line count; its constant-index table BlockSpec
-            IS the persistence).  The acc->settle->acc HBM round-trip
-            and the table flush amortize by the segment length.
-
-            Bit-identity carries over from fold_block unchanged: the
-            settlement folds concat(acc, table, residual) through the
-            same aggregate_exact, and hasht's final table is a pure
-            function of the distinct-key set + per-key totals — which
-            are grouping-invariant (emit overflow is per-line, counts
-            are per-key sums).  A residual overflow re-folds the WHOLE
-            segment through the stock path (map over the segment lines
-            is exact at any length), so both cond branches stay exact.
-            """
-            from locust_tpu.ops.pallas.fused_fold import (
-                fused_block_preagg,
-            )
-
-            interpret = jax.default_backend() != "tpu"
-            ktab, kresid, overflow, bad = fused_block_preagg(
-                seg_lines, cfg, interpret=interpret
-            )
-
-            def fused_path(acc_in):
-                return fold_into(
-                    acc_in, KVBatch.concat(ktab, kresid), tsize,
-                    combine, mode,
-                )
-
-            def stock_path(acc_in):
-                kv, _ = map_fn(seg_lines, cfg)
-                return fold_into(acc_in, kv, tsize, combine, mode)
-
-            merged, distinct = jax.lax.cond(bad, stock_path, fused_path, acc)
-            return merged, overflow, distinct
-
-        def scan_blocks_into(acc0: KVBatch, blocks: jax.Array):
-            """Whole-corpus pipeline in ONE dispatch: fold blocks with lax.scan.
-
-            One device dispatch per corpus instead of per block — essential
-            when per-dispatch latency matters (many small blocks) and the XLA-
-            idiomatic way to loop without data-dependent Python control flow.
-            The init accumulator arrives as an ARGUMENT so the jit below
-            can donate it into the scan carry (cfg.donate_fold): even the
-            one-dispatch path allocates no second table.
-            """
-
-            def body(carry, blk):
-                acc, overflow_acc, max_distinct = carry
-                acc, overflow, distinct = fold_block(acc, blk)
-                return (
-                    acc,
-                    overflow_acc + overflow,
-                    jnp.maximum(max_distinct, distinct),
-                ), None
-
-            init = (acc0, jnp.int32(0), jnp.int32(0))
-            (acc, overflow, num), _ = jax.lax.scan(body, init, blocks)
-            return acc, overflow, num
-
-        # Donated fold state (cfg.donate_fold): the accumulator table —
-        # the largest live array — is donated into every per-block
-        # dispatch and into the scan init, so XLA aliases its buffers
-        # input->output (updated in place, no per-fold re-allocation).
-        # Callers therefore must treat the acc they passed as consumed;
-        # every loop here rebinds it, and snapshot marks copy on device
-        # first (_CheckpointPump.mark).
-        donate = (0,) if cfg.donate_fold else ()
-        self._fold_block = jax.jit(fold_block, donate_argnums=donate)
-        # Breaker-failover fold (run_checkpointed's on-CPU dispatch):
-        # identical to _fold_block unless the fused kernel is on — then
-        # it is the kernel-free stock fold (see stock_fold above).
-        # Traced lazily, so non-failover runs never pay its compile.
-        self._fold_block_fallback = (
-            jax.jit(stock_fold, donate_argnums=donate)
-            if self._fused_kernel_on
-            else self._fold_block
-        )
-        # Streaming-segment executable (megakernel v2): traced lazily on
-        # first run_stream use; None when the kernel is off or the clamp
-        # leaves segments at one block (then run_stream's per-block loop
-        # is already optimal).
-        self._fold_segment = (
-            jax.jit(fold_segment, donate_argnums=donate)
-            if self._fused_kernel_on and self._fused_stream_seg > 1
-            else None
-        )
-        self._scan_blocks_into = jax.jit(scan_blocks_into, donate_argnums=donate)
-        # The export/compile-check surface (__graft_entry__.entry, the
-        # TPU StableHLO lowering gates) keeps the one-argument signature.
-        self._scan_blocks = jax.jit(
-            lambda blocks: scan_blocks_into(
-                KVBatch.empty(tsize, cfg.key_lanes), blocks
-            )
-        )
-        # Batched job executable (the serve tier's coalesced dispatch,
-        # docs/SERVING.md): vmap the whole-corpus scan over a leading JOB
-        # axis, so N compatible small jobs fold in ONE device dispatch
-        # with per-job tables/counters out.  Each job slot gets its own
-        # fresh accumulator (no donation: slots are independent and the
-        # batch is rebuilt per dispatch); traced/compiled lazily on first
-        # use per [njobs, nblocks] shape — non-serve users never pay it.
-        self._scan_blocks_batch = jax.jit(
-            jax.vmap(
-                lambda blocks: scan_blocks_into(
-                    KVBatch.empty(tsize, cfg.key_lanes), blocks
-                )
-            )
-        )
-
-        # Split stages for the timed path only: map, process and reduce
-        # run once a block, the merge once a GROUP of blocks — the running
-        # table and all of the group's block tables through one sort and
-        # one segment combine, so the table is sorted again once a group
-        # and not once a block.  The capacity is the accumulator's own
-        # size and the fan-in the length of ``tables``, so the one jit
-        # re-traces per capacity timed_run grows to (_regrow) and per rung
-        # of the fan-in ladder (_timed_group_blocks).  ``distinct`` is the
-        # TRUE count of keys in table + group, whatever the capacity.
-        # ``acc`` is not donated: it is the way back when the merge passes
-        # the capacity.
-        def merge_tables(acc: KVBatch, tables: tuple[KVBatch, ...],
-                         max_distinct: jax.Array):
-            merged, distinct = segment_reduce_into(
-                sort_and_compact(KVBatch.concat(acc, *tables), mode), acc.size, combine
-            )
-            return merged, jnp.maximum(max_distinct, distinct)
-
-        self._map = jax.jit(lambda lines: map_fn(lines, cfg))
-        self._process = jax.jit(partial(sort_and_compact, mode=mode))
-        self._reduce = jax.jit(partial(segment_reduce, combine=combine))
-        self._merge = jax.jit(merge_tables)
-        self._table_size = tsize
+        # The programs belong to the configuration, not to this engine:
+        # the process builds them once a key (_programs_for).
+        programs, built = _programs_for(cfg, map_fn, combine)
+        obs.metric_inc("engine.programs_built", int(built))
+        obs.metric_inc("engine.programs_shared", int(not built))
+        self.map_fn = programs.map_fn
+        self._map = programs.map
+        self._process = programs.process
+        self._reduce = programs.reduce
+        self._merge = programs.merge
+        self._fold_block = programs.fold_block
+        # Breaker-failover fold (run_checkpointed's on-CPU dispatch).
+        self._fold_block_fallback = programs.fold_block_fallback
+        # Streaming-segment executable (megakernel v2); None when the
+        # kernel is off or segments are one block long.
+        self._fold_segment = programs.fold_segment
+        self._scan_blocks_into = programs.scan_blocks_into
+        self._scan_blocks = programs.scan_blocks
+        self._scan_blocks_batch = programs.scan_blocks_batch
+        self._fused_kernel_on = programs.fused_kernel_on
+        self._fused_demoted = programs.fused_demoted
+        self._fused_stream_seg = programs.fused_stream_seg
+        self._table_size = cfg.resolved_table_size
 
     # ---------------------------------------------------------------- ingest
 

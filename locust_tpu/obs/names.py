@@ -70,6 +70,8 @@ NAMES = {
     "job.workers": "gauge",         # cluster size of the running job
     "engine.compile_requests": "counter",  # programs asked of the persistent cache
     "engine.cache_hits": "counter",        # ... and found there (rest compiled)
+    "engine.programs_built": "counter",    # engines that built their configuration's programs (engine._programs_for)
+    "engine.programs_shared": "counter",   # ... that took the ones the process already held
     "engine.table_rows": "gauge",   # timed_run: the table's capacity at the job's end
     "engine.table_grows": "counter",  # timed_run: growth steps the job took
     "engine.merges": "counter",     # timed_run: merge programs launched (one a group + one a group redone)

@@ -8,6 +8,8 @@ Multi-device collectives are tested without TPU hardware via
 import os
 import sys
 
+import pytest
+
 # The tests run on the virtual 8-device CPU mesh whatever the ambient
 # platform is (on a machine with a chip, that chip is not the tests').
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -31,3 +33,14 @@ from locust_tpu.config import compile_cache_dir
 # are cheaper to recompile than to write and read back.
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
 compile_cache_dir(".jax_cache_cpu")
+
+
+@pytest.fixture(autouse=True)
+def _fresh_programs():
+    """An engine's programs belong to its configuration and the process
+    keeps them (engine._programs_for): no test runs programs traced under
+    another test's monkeypatched constants or tracer, and every test's
+    first engine of a configuration builds, as a fresh process's does."""
+    from locust_tpu import engine
+
+    engine.clear_programs()

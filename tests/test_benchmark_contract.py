@@ -19,6 +19,7 @@ import pytest
 
 import bench
 import chip_smoke
+from locust_tpu import engine
 from locust_tpu.config import EngineConfig, default_sort_mode
 from locust_tpu.core.kv import KVBatch
 from locust_tpu.engine import MapReduceEngine
@@ -38,14 +39,16 @@ JAX_EVENTS = {programs.TRACE, programs.LOWER, programs.COMPILE,
 HARNESS_ONLY = {"job_percentile", "memory_stats"}
 
 
-@pytest.fixture(scope="module")
-def program_names() -> set[str]:
-    """Module names of the four programs of the default path, as the
-    device trace's ``XLA Modules`` line will show them: lowered at toy
-    shapes under the mode the chip runs, never compiled."""
-    cfg = EngineConfig(block_lines=8, line_width=32, key_width=8,
-                       emits_per_line=4, sort_mode=default_sort_mode("tpu"))
-    eng = MapReduceEngine(cfg)
+def _toy_engine() -> MapReduceEngine:
+    """Toy shapes under the mode the chip runs."""
+    return MapReduceEngine(EngineConfig(
+        block_lines=8, line_width=32, key_width=8, emits_per_line=4,
+        sort_mode=default_sort_mode("tpu")))
+
+
+def _lowered_text(eng: MapReduceEngine) -> tuple[str, ...]:
+    """The four programs of the default path lowered, never compiled."""
+    cfg = eng.cfg
     lines = jax.ShapeDtypeStruct((cfg.block_lines, cfg.line_width), jnp.uint8)
     kv, _ = jax.eval_shape(eng._map, lines)
     batch = jax.eval_shape(eng._process, kv)
@@ -58,7 +61,23 @@ def program_names() -> set[str]:
         eng._reduce.lower(batch),
         eng._merge.lower(acc, (table, table), seen),
     )
-    return {re.match(r"module @(\S+)", low.as_text()).group(1) for low in lowered}
+    return tuple(low.as_text() for low in lowered)
+
+
+@pytest.fixture(scope="module")
+def program_names() -> dict[str, set[str]]:
+    """Module names of the four programs of the default path, as the
+    device trace's ``XLA Modules`` line will show them: of a
+    configuration's ``first`` engine, which builds them, and of a later
+    one, which takes the process's (``shared``, engine._programs_for) —
+    the same programs, text for text."""
+    engine.clear_programs()  # whatever ran before: the first engine builds
+    first, shared = _toy_engine(), _toy_engine()
+    assert shared._merge is first._merge
+    texts = {"first": _lowered_text(first), "shared": _lowered_text(shared)}
+    assert texts["shared"] == texts["first"]
+    return {which: {re.match(r"module @(\S+)", text).group(1) for text in found}
+            for which, found in texts.items()}
 
 
 @pytest.fixture(scope="module")
@@ -78,8 +97,19 @@ def _assert_patterns_match(patterns, names, what):
         )
 
 
-@pytest.mark.parametrize("path", METRIC_FILES, ids=os.path.basename)
-def test_layer_metric_reads_a_name_the_program_still_has(path, request):
+def _metric_cases():
+    """A case a metric file; one that finds PROGRAMS by name is two, held
+    to a configuration's first engine and to one that shares its programs."""
+    for path in METRIC_FILES:
+        with open(path) as f:
+            by_program = json.load(f)["reader"] in ("xla_module", "roofline")
+        for which in ("first", "shared") if by_program else (None,):
+            name = os.path.basename(path)
+            yield pytest.param(path, which, id=f"{name}-{which}" if which else name)
+
+
+@pytest.mark.parametrize("path, which", _metric_cases())
+def test_layer_metric_reads_a_name_the_program_still_has(path, which, request):
     with open(path) as f:
         spec = json.load(f)
     reader = spec["reader"]
@@ -91,10 +121,11 @@ def test_layer_metric_reads_a_name_the_program_still_has(path, request):
         prefix = spec.get("holds_all_work")
         assert prefix is None or any(s.startswith(prefix) for s in SPANS), prefix
     elif reader == "xla_module":
-        _assert_patterns_match(spec["patterns"], fixture("program_names"), "patterns")
+        _assert_patterns_match(spec["patterns"], fixture("program_names")[which], "patterns")
     elif reader == "roofline":
-        _assert_patterns_match([spec["unit"]], fixture("program_names"), "unit")
-        _assert_patterns_match(spec["programs"], fixture("program_names"), "programs")
+        names = fixture("program_names")[which]
+        _assert_patterns_match([spec["unit"]], names, "unit")
+        _assert_patterns_match(spec["programs"], names, "programs")
     elif reader == "jax_monitoring":
         events = set(spec["events"]) | set(spec.get("minus", []))
         assert events <= JAX_EVENTS, (

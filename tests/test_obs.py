@@ -601,10 +601,11 @@ def test_timed_run_names_staging_waits_and_finalize_per_group(
 
 def test_program_spans_in_a_fresh_engines_first_job_only():
     """What jax reports of its own pipeline lands in the timeline: a
-    fresh engine traces, lowers and loads its programs in its first job
-    (each span names its function), a second run on the same engine
-    does none of it.  The counters are the operator's copy of the
-    benchmark's compiles_in_window."""
+    configuration's first engine traces, lowers and loads its programs in
+    its first job (each span names its function), a second run on the
+    same engine does none of it, and neither does a second fresh engine
+    of the configuration: it takes the process's programs.  The counters
+    are the operator's copy of the benchmark's compiles_in_window."""
     t = obs.enable(process="programs")
     eng = MapReduceEngine(EngineConfig(**_SMALL))
     rows = eng.rows_from_lines([b"alpha beta alpha", b"beta gamma"] * 4)
@@ -627,7 +628,15 @@ def test_program_spans_in_a_fresh_engines_first_job_only():
     assert again and not [e for e in again
                           if e["name"].startswith("engine.program.")]
     assert counters["engine.merges"] == 1      # one group, one merge a job
+    assert (counters["engine.programs_built"], counters["engine.programs_shared"]) == (1, 0)
     assert obs.metrics_snapshot()["counters"] == dict(counters, **{"engine.merges": 2})
+    mark = len(_spans(t))
+    shared = MapReduceEngine(EngineConfig(**_SMALL))
+    assert shared.timed_run(rows).to_host_pairs() == eng.timed_run(rows).to_host_pairs()
+    assert not [e for e in _spans(t)[mark:]
+                if e["name"].startswith("engine.program.")]
+    assert obs.metrics_snapshot()["counters"] == dict(
+        counters, **{"engine.merges": 4, "engine.programs_shared": 1})
 
 
 def test_enable_disable_cycles_leave_no_monitoring_listener():
