@@ -398,8 +398,9 @@ def _run(args) -> int:
                 # The default path's table grows and never lands here;
                 # --stream / --no-timing hold a table of fixed size.
                 print("[locust] WARN: table capacity exceeded; tail keys "
-                      "dropped (only the default path, without --stream, "
-                      "--no-timing or --mesh, grows its table)",
+                      "dropped (--stream and --no-timing hold a table of "
+                      "fixed size; the default path grows its table, "
+                      "--mesh its shards)",
                       file=sys.stderr)
             with timer.span("output"), obs.span("cli.output"):
                 if args.stage == STAGE_MAP:
@@ -502,7 +503,8 @@ def _run_mesh(args, cfg, timer, prof, preloaded_rows=None,
         print(
             f"[locust] mesh: {dmr.n_dev} device(s), {dmr.lines_per_round} "
             f"lines/round, bin_capacity={dmr.bin_capacity}, "
-            f"shard_capacity={dmr.shard_capacity}",
+            f"shard_capacity={dmr.shard_capacity} (where the shards "
+            "start: they grow together with what they see)",
             file=sys.stderr,
         )
     n_dev = dmr.n_dev
@@ -544,7 +546,8 @@ def _run_mesh(args, cfg, timer, prof, preloaded_rows=None,
         run_ms = (_time.perf_counter() - t0) * 1e3
 
         # Per-shard report: one hash shard per shard_capacity rows (the
-        # hierarchical table has devs_per_slice shards, the flat one n_dev).
+        # hierarchical table has devs_per_slice shards, the flat one n_dev)
+        # — the capacity the run ENDED at: the flat mesh's shards grow.
         # Gather ONLY the valid mask through the multi-process-safe path —
         # a plain device_get of the sharded table touches non-addressable
         # devices on a pod, and the full-table gather would move
@@ -552,11 +555,17 @@ def _run_mesh(args, cfg, timer, prof, preloaded_rows=None,
         from locust_tpu.parallel.mesh import gather_host_array
 
         shard_live = gather_host_array(res.table.valid).reshape(
-            -1, dmr.shard_capacity
+            -1, res.shard_capacity
         ).sum(axis=1)
         for d in range(shard_live.shape[0]):
             print(
                 f"[locust] shard {d}: {int(shard_live[d])} keys",
+                file=sys.stderr,
+            )
+        if res.table_grows:
+            print(
+                f"[locust] shards grew {dmr.shard_capacity} -> "
+                f"{res.shard_capacity} rows in {res.table_grows} step(s)",
                 file=sys.stderr,
             )
         print(
@@ -567,10 +576,13 @@ def _run_mesh(args, cfg, timer, prof, preloaded_rows=None,
             file=sys.stderr,
         )
         if res.truncated:
+            # --mesh alone never lands here: its shards grow.  The
+            # hierarchical mesh (--slices) holds shards of fixed size.
             print(
                 "[locust] WARN: a shard's table capacity was exceeded; "
-                "tail keys dropped (only the single-device default "
-                "path grows its table)",
+                "tail keys dropped (--slices holds shards of fixed size; "
+                "--mesh without it grows them, as the default path "
+                "grows its table)",
                 file=sys.stderr,
             )
         with timer.span("output"), obs.span("cli.output"):

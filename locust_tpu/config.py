@@ -352,10 +352,13 @@ class EngineConfig:
     # resolved_table_size for the floor's rationale).  The default path
     # (engine.timed_run) STARTS here and grows its table when a group of
     # blocks counts more distinct keys than it holds, so it is exact at
-    # any vocabulary; every other path (run, run_fused, run_stream, the
-    # mesh's shards) holds this capacity for the whole job and reports
-    # truncation past it (RunResult.truncated; tests/test_scale.py pins
-    # that loud report at the default) — raise it explicitly there.
+    # any vocabulary, and so do the flat mesh's hash shards (their fair
+    # share of it is one floor of where they start,
+    # parallel/shuffle.DistributedMapReduce); every other path (run,
+    # run_fused, run_stream, the hierarchical mesh) holds this capacity
+    # for the whole job and reports truncation past it
+    # (RunResult.truncated; tests/test_scale.py pins that loud report at
+    # the default) — raise it explicitly there.
     table_size: int | None = None
 
     # Process-stage sort strategy (none of the speeds below is measured on

@@ -100,3 +100,30 @@ class KVBatch:
             order = np.argsort(keys.view(f"S{n_lanes * 4}").ravel(), kind="stable")
             keys, live_values = keys[order], live_values[order]
         return list(zip(bytes_ops.rows_to_strings(keys), live_values.tolist()))
+
+
+# A table that grows with what it sees — the default path's one table
+# (engine.timed_run) and the mesh's hash shards (parallel/shuffle.py) —
+# grows by powers of this factor, to the first capacity that holds the
+# count: a million-key job ends at 16 times its start, a handful of
+# capacities (and compiled programs) whatever the vocabulary.
+TABLE_GROWTH = 2
+
+
+def rows_to_hold(rows: int, distinct: int) -> int:
+    """The first capacity, ``TABLE_GROWTH``-fold steps up from ``rows``,
+    that holds ``distinct`` keys (``rows`` itself if it does).  Growing
+    AHEAD of work that, adding what the last stretch added, would pass
+    the table is the same rule asked of ``distinct + added``."""
+    while rows < distinct:
+        rows *= TABLE_GROWTH
+    return rows
+
+
+def grow_table(table: KVBatch, rows: int) -> KVBatch:
+    """``table`` with empty rows appended up to ``rows`` — every live row
+    kept where it was, so a fold that re-sorts or rebuilds (every fold of
+    this repo does) takes the grown table as it took the old one."""
+    return KVBatch.concat(
+        table, KVBatch.empty(rows - table.size, table.num_lanes)
+    )

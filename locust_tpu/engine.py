@@ -48,7 +48,7 @@ from locust_tpu import backend as backend_mod
 from locust_tpu import obs
 from locust_tpu.config import DEFAULT_CONFIG, EngineConfig
 from locust_tpu.core import bytes_ops
-from locust_tpu.core.kv import KVBatch
+from locust_tpu.core.kv import KVBatch, grow_table, rows_to_hold
 from locust_tpu.io.snapshot import AsyncCheckpointWriter, finalize_snapshot
 from locust_tpu.ops.map_stage import wordcount_map
 from locust_tpu.ops.process_stage import sort_and_compact
@@ -127,10 +127,9 @@ def merge_host_pairs(
 @partial(jax.jit, static_argnums=1)
 def _grow_table(table: KVBatch, rows: int) -> KVBatch:
     """``table`` with empty rows appended up to ``rows`` (not donated:
-    a growth step that falls short starts from ``table`` again)."""
-    return KVBatch.concat(
-        table, KVBatch.empty(rows - table.size, table.num_lanes)
-    )
+    a growth step that falls short starts from ``table`` again).  The
+    rule is the mesh shards' too (core/kv.grow_table)."""
+    return grow_table(table, rows)
 
 
 @dataclasses.dataclass
@@ -659,11 +658,6 @@ class MapReduceEngine:
     # a full group passes 2^6 (_timed_group_blocks) — so all job sizes
     # together run at most this many merge programs a capacity.
     MERGE_RUNGS = 7
-    # timed_run's table starts at cfg.resolved_table_size and grows by
-    # powers of this factor — to the first that holds the count — when a
-    # group's merge counts more distinct keys than it holds (_regrow): a
-    # million-key job ends at 16 times its start.
-    TABLE_GROWTH = 2
     # Configurations whose programs the process keeps (_programs_for),
     # least recently used out first: a daemon's handful of workloads and
     # shapes, a plan's stages.  An engine past it builds, as every engine
@@ -929,7 +923,7 @@ class MapReduceEngine:
                     # once more is grown before this one merges into it.
                     # (The first group's count says nothing: it holds every
                     # common key, so it is left out of ``added``.)
-                    ahead = self._rows_for(acc.size, distinct + added)
+                    ahead = rows_to_hold(acc.size, distinct + added)
                     if ahead > acc.size:
                         with obs.span("engine.table.grow", from_rows=acc.size,
                                       to_rows=ahead, distinct=distinct + added,
@@ -977,13 +971,13 @@ class MapReduceEngine:
         count before the group, ``tables`` the group's block tables (padded
         to the fan-in, ``blocks`` of them real), ``distinct`` what the
         group's merge counted: the true count of table + group, so ONE
-        step — ``TABLE_GROWTH``-fold as often as it takes to hold
+        step — ``core/kv.TABLE_GROWTH``-fold as often as it takes to hold
         ``distinct`` — and one merge give the exact table.  A handful of
         capacities whatever the vocabulary, each merge program compiled
         once and kept by the persistent cache.  Returns the table and its
         distinct count (on the device).
         """
-        to_rows = self._rows_for(start.size, distinct)
+        to_rows = rows_to_hold(start.size, distinct)
         with obs.span("engine.table.grow", from_rows=start.size, to_rows=to_rows,
                       distinct=distinct, blocks_redone=blocks):
             acc, max_distinct = self._merge(
@@ -992,13 +986,6 @@ class MapReduceEngine:
             with obs.span("engine.sync", what="merge"):
                 jax.block_until_ready(acc)  # locust: noqa[R003] the redone group's one wait: the stage's clock ends with its work
         return acc, max_distinct
-
-    def _rows_for(self, rows: int, distinct: int) -> int:
-        """The first capacity, ``TABLE_GROWTH``-fold steps up from ``rows``,
-        that holds ``distinct`` keys (``rows`` itself if it does)."""
-        while rows < distinct:
-            rows *= self.TABLE_GROWTH
-        return rows
 
     def run_lines(self, lines: Sequence[bytes]) -> RunResult:
         return self.run(self.rows_from_lines(lines))

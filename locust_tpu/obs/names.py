@@ -39,6 +39,10 @@ NAMES = {
     "engine.program.lower": "span", # ... lowered it to an MLIR module
     "engine.program.load": "span",  # ... compiled it or read it from the cache
     "stream.block": "span",         # run_stream: stage+dispatch of one block
+    "mesh.round": "span",           # mesh: one round staged + dispatched (arg lines)
+    "mesh.sync": "span",            # mesh: host blocked on the devices' stats (arg what: stats | regrow)
+    "mesh.table.grow": "span",      # mesh: every shard grown a step, the rounds since the last whole table folded again (args from_rows, to_rows, worst_shard, rounds_redone)
+    "mesh.gather": "span",          # mesh: table from its shards to sorted host pairs (args rows, shards)
     "ckpt.write": "span",           # async writer: serialize+publish one gen
     "serve.queue_wait": "span",     # serve: dispatcher waiting on the queue
     "serve.compile_or_hit": "span", # serve: warm-executable cache lookup/build
@@ -75,6 +79,10 @@ NAMES = {
     "engine.table_rows": "gauge",   # timed_run: the table's capacity at the job's end
     "engine.table_grows": "counter",  # timed_run: growth steps the job took
     "engine.merges": "counter",     # timed_run: merge programs launched (one a group + one a group redone)
+    "mesh.rounds": "counter",       # mesh: rounds dispatched (redone ones not counted again)
+    "mesh.table_grows": "counter",  # mesh: growth steps the job's shards took
+    "mesh.drain_rounds": "counter", # mesh: extra all-to-all rounds the backlog took
+    "mesh.shard_rows": "gauge",     # mesh: rows a shard holds at the job's end
     "stream.blocks": "counter",     # blocks folded by run_stream
     "stream.stall_ms": "histogram", # per-sync backpressure stall
     "ckpt.marks": "counter",        # snapshot generations marked

@@ -509,4 +509,5 @@ class HierarchicalMapReduce:
             truncated=truncated,
             fused_kernel="mesh" if self._fused_kernel_on else None,
             fused_demoted=self.fused_demoted,
+            shard_capacity=self.shard_capacity,
         )

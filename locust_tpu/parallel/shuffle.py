@@ -34,9 +34,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from locust_tpu import obs
 from locust_tpu.config import HASHT_FAMILY, EngineConfig
 from locust_tpu.core import packing
-from locust_tpu.core.kv import KVBatch
+from locust_tpu.core.kv import KVBatch, grow_table, rows_to_hold
 from locust_tpu.io.snapshot import AsyncCheckpointWriter, finalize_snapshot
 from locust_tpu.ops.map_stage import wordcount_map
 from locust_tpu.ops.hash_table import fold_into, reduce_into
@@ -768,6 +769,16 @@ class DistributedMapReduce:
     each device carries its hash shard of the result table across rounds
     (consistent hash partitioning makes the per-shard merge local — no
     cross-device traffic outside the one all-to-all per round).
+
+    The shards GROW with what they see, all together (``shard_capacity``
+    left at None): a shard's capacity is a compiled shape of one SPMD
+    program, so when the worst shard counted more distinct keys than a
+    shard holds, every shard grows to the capacity that holds them
+    (``core/kv.rows_to_hold``, the default path's rule) and the rounds
+    folded since the last table known to be whole are folded again from
+    it — the table is exact at any vocabulary (``_run_rounds``).  An
+    explicit ``shard_capacity=`` is a fixed bound, reported loudly
+    (``DistributedResult.truncated``) when passed.
     """
 
     def __init__(
@@ -814,8 +825,11 @@ class DistributedMapReduce:
         # is this device's fair share of cfg.resolved_table_size (+ skew),
         # so an explicitly raised table_size carries over to the mesh
         # engines instead of silently truncating at the emits-derived
-        # size (fuzz finding, r4).  Exceeding the capacity is reported
-        # via DistributedResult.truncated.
+        # size (fuzz finding, r4).  The default is where a run STARTS:
+        # its shards grow past it together (_run_rounds).  An explicit
+        # capacity is a fixed bound; exceeding it is reported via
+        # DistributedResult.truncated.
+        self.grows = shard_capacity is None
         self.shard_capacity = (
             shard_capacity
             if shard_capacity is not None
@@ -856,21 +870,8 @@ class DistributedMapReduce:
         self._fused_kernel_on, self.fused_demoted = _fused_mesh_gate(
             cfg, map_fn, combine, engine="flat"
         )
-        local_step = build_shuffle_step(
-            cfg,
-            norm_map_fn,
-            norm_combine,
-            n_bins=self.n_dev,
-            bin_capacity=self.bin_capacity,
-            shard_capacity=self.shard_capacity,
-            leftover_capacity=self.leftover_capacity,
-            max_drains=self.max_drain_rounds,
-            shuffle_axis=axis,
-            stat_axes=(axis,),
-            fused_preagg=self._fused_kernel_on,
-        )
-
         kv_spec = KVBatch(key_lanes=P(axis), values=P(axis), valid=P(axis))
+        self._kv_spec = kv_spec
         # Stats are reduced over the mesh's only axis, so they leave
         # shard_map REPLICATED (out_spec P()): every process can read them
         # without touching non-addressable shards.
@@ -894,25 +895,49 @@ class DistributedMapReduce:
         # jax's replication checking for this one mode; the hierarchical
         # engine's round step takes the same conditional, and this
         # engine's outputs are oracle-tested per mode.
-        self._step = jax.jit(
-            jax.shard_map(
-                local_step,
-                mesh=mesh,
-                in_specs=(P(axis), kv_spec, kv_spec),
-                out_specs=(kv_spec, kv_spec, P()),
-                # fused kernel engaged implies a TPU backend
-                # (fused_mesh_eligible), so the check is only ever
-                # dropped on TPU — the CPU engines keep check_vma=True
-                # and never trace a Pallas kernel in a mesh program.
-                check_vma=not (
-                    (
-                        cfg.sort_mode == "bitonic"
-                        and jax.default_backend() == "tpu"
-                    )
-                    or self._fused_kernel_on
-                ),
+        def build_step(shard_capacity: int):
+            """The step program at one shard capacity — the capacity is a
+            shape of the compiled fold, so a run that grows runs one such
+            program a capacity, each named ``jit_local_step``."""
+            local_step = build_shuffle_step(
+                cfg,
+                norm_map_fn,
+                norm_combine,
+                n_bins=self.n_dev,
+                bin_capacity=self.bin_capacity,
+                shard_capacity=shard_capacity,
+                leftover_capacity=self.leftover_capacity,
+                max_drains=self.max_drain_rounds,
+                shuffle_axis=axis,
+                stat_axes=(axis,),
+                fused_preagg=self._fused_kernel_on,
             )
-        )
+            return jax.jit(
+                jax.shard_map(
+                    local_step,
+                    mesh=mesh,
+                    in_specs=(P(axis), kv_spec, kv_spec),
+                    out_specs=(kv_spec, kv_spec, P()),
+                    # fused kernel engaged implies a TPU backend
+                    # (fused_mesh_eligible), so the check is only ever
+                    # dropped on TPU — the CPU engines keep check_vma=True
+                    # and never trace a Pallas kernel in a mesh program.
+                    check_vma=not (
+                        (
+                            cfg.sort_mode == "bitonic"
+                            and jax.default_backend() == "tpu"
+                        )
+                        or self._fused_kernel_on
+                    ),
+                )
+            )
+
+        self._build_step = build_step
+        # One step program a shard capacity — the one a run starts with
+        # (``_step``) and those its runs grew to — and one grow program a
+        # capacity grown TO, all kept for the engine's next run.
+        self._steps: dict[int, object] = {}
+        self._growers: dict[int, object] = {}
         # Across-round stats accumulation, jitted ONCE per engine (not per
         # run) and kept on device so run() never syncs per round.
         self._stats_merge = jax.jit(merge_stats_vectors)
@@ -926,6 +951,38 @@ class DistributedMapReduce:
     def empty_table(self) -> KVBatch:
         """Global (sharded) empty accumulator: one shard per device."""
         return KVBatch.empty(self.n_dev * self.shard_capacity, self.cfg.key_lanes)
+
+    def _step_at(self, shard_capacity: int):
+        """The step program whose shards hold ``shard_capacity`` rows."""
+        if shard_capacity not in self._steps:
+            self._steps[shard_capacity] = self._build_step(shard_capacity)
+        return self._steps[shard_capacity]
+
+    @property
+    def _step(self):
+        """The step program at the capacity a run starts with."""
+        return self._step_at(self.shard_capacity)
+
+    @_step.setter
+    def _step(self, step) -> None:
+        self._steps[self.shard_capacity] = step
+
+    def _grow_shards(self, table: KVBatch, shard_capacity: int) -> KVBatch:
+        """Every shard of ``table`` with empty rows up to ``shard_capacity``
+        (``core/kv.grow_table`` a shard; nothing donated: a growth step
+        that falls short starts from ``table`` again)."""
+        if shard_capacity not in self._growers:
+
+            def grow_shards(shard: KVBatch) -> KVBatch:
+                return grow_table(shard, shard_capacity)
+
+            self._growers[shard_capacity] = jax.jit(
+                jax.shard_map(
+                    grow_shards, mesh=self.mesh,
+                    in_specs=(self._kv_spec,), out_specs=self._kv_spec,
+                )
+            )
+        return self._growers[shard_capacity](table)
 
     def empty_leftover(self) -> KVBatch:
         """Global (sharded) empty shuffle-backlog buffer (0 rows in drop mode)."""
@@ -1079,6 +1136,24 @@ class DistributedMapReduce:
                 drains_used = int(extras["drains_used"])
                 truncated = bool(extras["truncated"])
 
+        # Rows a shard holds NOW.  A snapshot is taken of a settled table
+        # (the stats flush before it grows and redoes first), so it carries
+        # the capacity it was taken at in its shape, and a resume goes on
+        # at that capacity — never back at the one the run started with.
+        cap = acc.size // self.n_dev
+        # What a redo starts from (only shards that grow keep it): the
+        # table and backlog at the last sync that showed every key held,
+        # and the rounds dispatched since (their lines already on the
+        # devices; at most stats_sync_every).
+        whole = (acc, leftover) if self.grows else None
+        since: list = []
+        worst_before = 0  # the worst shard's count at the last sync
+        # The capacity the NEXT round wants (and the count it is for): a
+        # sync only notes it, the round that follows grows — the last
+        # sync of a run, and one ahead of a snapshot, grow nothing.
+        ahead = (cap, 0)
+        grows = rounds = 0
+
         def snapshot(next_round: int) -> None:
             ckpt.snapshot(
                 next_round,
@@ -1091,17 +1166,52 @@ class DistributedMapReduce:
                 truncated=np.bool_(truncated),
             )
 
+        def fetch(stats):
+            with obs.span("mesh.sync", what="stats"):
+                return jax.device_get(stats)  # locust: noqa[R003] the stats sync, once every stats_sync_every rounds: the loop's one wait
+
+        def settle(st):
+            """Stats of rounds that dropped no key: while the worst shard
+            counted more keys than a shard holds, grow every shard to the
+            capacity that holds them and fold the rounds since the last
+            whole table again, from that table.  A fold past its capacity
+            counts every key it saw but not those an earlier fold of the
+            stretch had dropped, so a step can fall short and is taken
+            again; the count only rises, so the loop ends.  The redone
+            rounds' wait FOLLOWS the grow span (no time is in both)."""
+            nonlocal acc, leftover, cap, grows
+            while int(st[4]) > cap:
+                worst = int(st[4])
+                to = rows_to_hold(cap, worst)
+                with obs.span("mesh.table.grow", from_rows=cap, to_rows=to,
+                              worst_shard=worst, rounds_redone=len(since)):
+                    acc = self._grow_shards(whole[0], to)
+                    leftover = whole[1]
+                    step, stats = self._step_at(to), None
+                    for lines in since:
+                        acc, leftover, one = step(lines, acc, leftover)
+                        stats = one if stats is None else self._stats_merge(stats, one)
+                with obs.span("mesh.sync", what="regrow"):
+                    st = jax.device_get(stats)  # locust: noqa[R003] the redone rounds' one wait
+                cap = to
+                grows += 1
+            return st
+
         # Device-side stats accumulator: rounds dispatch back-to-back and
         # the host folds the replicated stats vector in only at sync points.
         def on_sync(st) -> None:
             """Fold accumulated device stats into host counters; police
             the no-loss invariants (loudly, if a few rounds late)."""
             nonlocal emit_ovf, shuf_ovf, distinct, drains_used, truncated
+            nonlocal whole, worst_before, ahead
+            if self.grows:
+                st = settle(st)
             emit_ovf += int(st[0])
             shuf_ovf += int(st[1])
             distinct = int(st[2])
             backlog = int(st[3])
-            truncated |= int(st[4]) > self.shard_capacity
+            worst = int(st[4])
+            truncated |= worst > cap
             drains_used += int(st[5])
             if backlog > 0:
                 raise RuntimeError(
@@ -1119,25 +1229,52 @@ class DistributedMapReduce:
                     f"shuffle lost {shuf_ovf} entries despite retry mode; "
                     "map_fn emitted more than cfg.emits_per_block live rows"
                 )
+            if self.grows:
+                # A text adds fewer new keys as it goes on: shards that
+                # would not hold what the LAST stretch added once more are
+                # grown before the next one folds into them.  (The first
+                # stretch's count says nothing: it holds every common key.)
+                added = worst - worst_before if worst_before else 0
+                ahead = (rows_to_hold(cap, worst + added), worst + added)
+                worst_before = worst
+                whole = (acc, leftover)
+                since.clear()
 
-        round_stats = RoundStats(self._stats_merge, on_sync, stats_sync_every)
+        round_stats = RoundStats(
+            self._stats_merge, on_sync, stats_sync_every, fetch_fn=fetch
+        )
 
         def fold_round(chunk) -> None:
-            nonlocal acc, leftover
-            chunk = normalize_round_chunk(chunk, lpr, width)
-            sharded = (shard_fn or shard_rows)(chunk, self.mesh, self.axis)
-            acc, leftover, stats = self._step(sharded, acc, leftover)
+            nonlocal acc, leftover, cap, grows, rounds
+            if ahead[0] > cap:
+                with obs.span("mesh.table.grow", from_rows=cap, to_rows=ahead[0],
+                              worst_shard=ahead[1], rounds_redone=0):
+                    acc = self._grow_shards(acc, ahead[0])
+                cap = ahead[0]
+                grows += 1
+            with obs.span("mesh.round", lines=len(chunk)):
+                chunk = normalize_round_chunk(chunk, lpr, width)
+                sharded = (shard_fn or shard_rows)(chunk, self.mesh, self.axis)
+                acc, leftover, stats = self._step_at(cap)(sharded, acc, leftover)
+            if self.grows:
+                since.append(sharded)
+            rounds += 1
             round_stats.push(stats)
 
         drive_checkpointed_rounds(
             chunk_iter, fold_round, round_stats, ckpt, snapshot,
             checkpoint_every, start_round,
         )
+        obs.metric_inc("mesh.rounds", rounds)
+        obs.metric_inc("mesh.table_grows", grows)
+        obs.metric_inc("mesh.drain_rounds", drains_used)
+        obs.metric_set("mesh.shard_rows", cap)
         if truncated:
             logger.warning(
                 "a shard's distinct keys exceeded its table capacity (%d); "
-                "tail keys dropped — raise shard_capacity",
-                self.shard_capacity,
+                "tail keys dropped — raise shard_capacity, or leave it out: "
+                "shards of the default capacity grow with what they see",
+                cap,
             )
         return DistributedResult(
             table=acc,
@@ -1149,6 +1286,8 @@ class DistributedMapReduce:
             truncated=truncated,
             fused_kernel="mesh" if self._fused_kernel_on else None,
             fused_demoted=self.fused_demoted,
+            shard_capacity=cap,
+            table_grows=grows,
         )
 
 
@@ -1193,8 +1332,15 @@ class DistributedResult:
         truncated: bool = False,
         fused_kernel: str | None = None,
         fused_demoted: bool = False,
+        shard_capacity: int | None = None,
+        table_grows: int = 0,
     ):
         self.table = table
+        # Rows a hash shard holds (the table is shards x shard_capacity
+        # rows); for the flat engine the capacity the run ENDED at, after
+        # ``table_grows`` growth steps.  None: one shard, the whole table.
+        self.shard_capacity = shard_capacity or table.size
+        self.table_grows = table_grows
         self.emit_overflow = emit_overflow    # tokens beyond the per-line cap
         self.shuffle_overflow = shuffle_overflow  # entries LOST in the shuffle
         self.distinct = distinct
@@ -1220,4 +1366,8 @@ class DistributedResult:
         """
         from locust_tpu.engine import finalize_host_pairs
 
-        return finalize_host_pairs(_gather_batch_host(self.table), self.combine, sort)
+        with obs.span("mesh.gather", rows=self.table.size,
+                      shards=self.table.size // self.shard_capacity):
+            return finalize_host_pairs(
+                _gather_batch_host(self.table), self.combine, sort
+            )

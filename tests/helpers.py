@@ -5,6 +5,8 @@ delimiter set (locust_tpu.config.DELIMITERS) has exactly one mirror here.
 """
 
 import collections
+import ctypes
+import logging
 import re
 
 from locust_tpu.config import DELIMITERS
@@ -54,3 +56,36 @@ def serve_abandon(daemon):
         # that keeps heartbeating past its own "death".
         shipper.stop()
     daemon._sock.close()
+    # A dead process runs nothing more.  A dispatcher or stage thread that
+    # an injected delay holds in flight (30-60 s in the durability tests)
+    # would otherwise wake up in a LATER test file of the same worker and
+    # run its batch there: jit work, and root spans on a thread of its own
+    # in whatever tracer that test enabled.  Every thread the daemon owns
+    # ends where it next runs a line of Python: the executors' threads by
+    # SystemExit (no ``except Exception`` of a retry ladder absorbs it; a
+    # work item hands it to a future nobody reads), the dispatcher by an
+    # Exception its loop logs before it sees ``_shutdown`` and returns
+    # (a SystemExit would leave ``run`` and reach the thread excepthook).
+    executors = (
+        getattr(daemon, "_shard_executor", None),
+        getattr(daemon.pool, "_executor", None),
+    )
+    doomed = [(daemon._dispatcher, _Abandoned)]
+    for ex in executors:
+        doomed += [(t, SystemExit) for t in getattr(ex, "_threads", ())]
+    for t, exc in doomed:
+        if t.is_alive():
+            ctypes.pythonapi.PyThreadState_SetAsyncExc(
+                ctypes.c_ulong(t.ident), ctypes.py_object(exc)
+            )
+
+
+class _Abandoned(Exception):
+    """Raised in an abandoned daemon's dispatcher (``serve_abandon``)."""
+
+
+# The dispatcher's loop logs what ends it; that traceback would land in
+# the captured stderr of whichever test runs when the delay runs out.
+logging.getLogger("locust_tpu").addFilter(
+    lambda rec: not (rec.exc_info and rec.exc_info[0] is _Abandoned)
+)

@@ -46,8 +46,20 @@ def _toy_engine() -> MapReduceEngine:
         sort_mode=default_sort_mode("tpu")))
 
 
+def _toy_mesh_step():
+    """The mesh's step program at toy shapes, and the shapes it takes."""
+    from locust_tpu.parallel import DistributedMapReduce, make_mesh
+
+    cfg = _toy_engine().cfg
+    dmr = DistributedMapReduce(make_mesh(4), cfg)
+    lines = jax.ShapeDtypeStruct((dmr.lines_per_round, cfg.line_width), jnp.uint8)
+    return dmr._step, (lines, jax.eval_shape(dmr.empty_table),
+                       jax.eval_shape(dmr.empty_leftover))
+
+
 def _lowered_text(eng: MapReduceEngine) -> tuple[str, ...]:
-    """The four programs of the default path lowered, never compiled."""
+    """The programs the cells run lowered, never compiled: the default
+    path's four and ``--mesh``'s step."""
     cfg = eng.cfg
     lines = jax.ShapeDtypeStruct((cfg.block_lines, cfg.line_width), jnp.uint8)
     kv, _ = jax.eval_shape(eng._map, lines)
@@ -61,12 +73,15 @@ def _lowered_text(eng: MapReduceEngine) -> tuple[str, ...]:
         eng._reduce.lower(batch),
         eng._merge.lower(acc, (table, table), seen),
     )
+    step, shapes = _toy_mesh_step()
+    lowered += (step.lower(*shapes),)
     return tuple(low.as_text() for low in lowered)
 
 
 @pytest.fixture(scope="module")
 def program_names() -> dict[str, set[str]]:
-    """Module names of the four programs of the default path, as the
+    """Module names of the programs the cells run (the default path's
+    four and the mesh's step), as the
     device trace's ``XLA Modules`` line will show them: of a
     configuration's ``first`` engine, which builds them, and of a later
     one, which takes the process's (``shared``, engine._programs_for) —
@@ -82,18 +97,33 @@ def program_names() -> dict[str, set[str]]:
 
 @pytest.fixture(scope="module")
 def cli_stderr(tmp_path_factory) -> str:
-    """What ``cli.main`` prints beside the table for a ten-line text."""
+    """What ``cli.main`` prints beside the table for a ten-line text: on
+    the default path and with ``--mesh``."""
     path = tmp_path_factory.mktemp("contract") / "ten.txt"
     path.write_bytes(b"".join(b"line %d of ten, said twice\n" % i for i in range(10)))
-    table, err, _ = chip_smoke.run_cli([str(path)])
-    assert table.count(b"\n") == 15  # ten numbers and five words
-    return err
+    said = []
+    for flags in ([], ["--mesh"]):
+        table, err, _ = chip_smoke.run_cli([str(path), "--block-lines", "8", *flags])
+        assert table.count(b"\n") == 15  # ten numbers and five words
+        said.append(err)
+    return "\n".join(said)
+
+
+@pytest.fixture(scope="module")
+def mesh_op_names() -> set[str]:
+    """The HLO instructions of the mesh's step program, compiled for the
+    CPU at toy shapes: a device trace's ``XLA Ops`` line names an event by
+    its whole instruction (``%all_to_all.32 = u32[...] all-to-all(...)``
+    on a v5e), so a pattern is held to the opcodes the program has."""
+    step, shapes = _toy_mesh_step()
+    text = step.lower(*shapes).compile().as_text()
+    return {ln.strip() for ln in text.splitlines() if " = " in ln}
 
 
 def _assert_patterns_match(patterns, names, what):
     for pat in patterns:
         assert any(re.search(pat, n) for n in names), (
-            f"{what}: /{pat}/ matches no program of the default path {sorted(names)}"
+            f"{what}: /{pat}/ matches none of {[n[:80] for n in sorted(names)[:40]]}"
         )
 
 
@@ -122,6 +152,8 @@ def test_layer_metric_reads_a_name_the_program_still_has(path, which, request):
         assert prefix is None or any(s.startswith(prefix) for s in SPANS), prefix
     elif reader == "xla_module":
         _assert_patterns_match(spec["patterns"], fixture("program_names")[which], "patterns")
+    elif reader == "xla_op":
+        _assert_patterns_match(spec["patterns"], fixture("mesh_op_names"), "patterns")
     elif reader == "roofline":
         names = fixture("program_names")[which]
         _assert_patterns_match([spec["unit"]], names, "unit")

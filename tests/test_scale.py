@@ -4,7 +4,9 @@ Once nothing exercised >65,536 distinct keys (the
 default ``resolved_table_size``), where truncation semantics actually
 bite.  These tests build a synthetic corpus with a unique-heavy Zipf-ish
 vocabulary larger than 2^16 and push it through the fused single-device
-path and the mesh path.
+path and the mesh path held to an explicit ``shard_capacity`` — a fixed
+bound, reported loudly when passed.  (Left at its default the mesh's
+shards grow and stay exact: tests/test_mesh_growth.py.)
 """
 
 import numpy as np
@@ -69,7 +71,7 @@ def test_mesh_run_past_2_16_distinct_keys(corpus):
 
 
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 (virtual) devices")
-def test_mesh_default_shard_capacity_truncates_loudly(corpus):
+def test_mesh_explicit_shard_capacity_truncates_loudly(corpus):
     from locust_tpu.parallel import DistributedMapReduce, make_mesh
 
     mesh = make_mesh(8)

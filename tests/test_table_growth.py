@@ -5,9 +5,12 @@ when a group's merge counted more distinct keys than the table holds, it
 grows the table — in one step, geometric, to the capacity that holds them —
 and merges that group again from the table the group started with
 (``MapReduceEngine._regrow``; tests/test_group_merge.py has the merge).  Tolerance: none —
-every table here is byte-equal to the ``py_wordcount`` oracle.  The other
-paths (``run``, ``run_fused``, ``--stream``, ``--mesh``) keep a fixed
-table and their loud report (tests/test_scale.py).
+every table here is byte-equal to the ``py_wordcount`` oracle.  The flat
+mesh's hash shards grow by the same rule, all together
+(tests/test_mesh_growth.py); the other paths (``run``, ``run_fused``,
+``--stream``, the hierarchical mesh, a mesh given an explicit
+``shard_capacity``) keep a fixed table and their loud report
+(tests/test_scale.py).
 """
 
 import numpy as np
