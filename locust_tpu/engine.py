@@ -53,6 +53,7 @@ from locust_tpu.io.snapshot import AsyncCheckpointWriter, finalize_snapshot
 from locust_tpu.ops.map_stage import wordcount_map
 from locust_tpu.ops.process_stage import sort_and_compact
 from locust_tpu.ops.reduce_stage import (
+    combine_scatters,
     normalize_combine,
     segment_reduce,
     segment_reduce_into,
@@ -685,6 +686,9 @@ class MapReduceEngine:
         programs, built = _programs_for(cfg, map_fn, combine)
         obs.metric_inc("engine.programs_built", int(built))
         obs.metric_inc("engine.programs_shared", int(not built))
+        # Scatters over the emit stream that this configuration's segment
+        # combine issues (0 for sum/count: ops/reduce_stage.py).
+        obs.metric_set("engine.combine_scatters", combine_scatters(combine))
         self.map_fn = programs.map_fn
         self._map = programs.map
         self._process = programs.process

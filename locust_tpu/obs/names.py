@@ -76,6 +76,7 @@ NAMES = {
     "engine.cache_hits": "counter",        # ... and found there (rest compiled)
     "engine.programs_built": "counter",    # engines that built their configuration's programs (engine._programs_for)
     "engine.programs_shared": "counter",   # ... that took the ones the process already held
+    "engine.combine_scatters": "gauge",    # scatters over the input rows that the configuration's segment combine issues (reduce_stage.combine_scatters)
     "engine.table_rows": "gauge",   # timed_run: the table's capacity at the job's end
     "engine.table_grows": "counter",  # timed_run: growth steps the job took
     "engine.merges": "counter",     # timed_run: merge programs launched (one a group + one a group redone)

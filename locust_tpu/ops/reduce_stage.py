@@ -4,17 +4,21 @@ The reference reduces in three device phases (reference
 MapReduce/src/main.cu:161-238,447-465): ``kernFindUniqBool`` marks rows whose
 key differs from the left neighbor, ``thrust::partition`` compacts the
 boundary markers, and ``kernGetCount`` takes adjacent differences of boundary
-indices to recover per-key counts.  That construction is the hand-rolled form
-of a textbook vectorized identity (SURVEY.md §7.1):
+indices to recover per-key counts.  This module is that construction,
+generalised from counts to sums:
 
-    boundary_i  = valid_i & (i == 0 | key_i != key_{i-1})
-    segment_ids = cumsum(boundary) - 1
-    combined    = segment_combine(values, segment_ids)
+    boundary_i = valid_i & (i == 0 | key_i != key_{i-1})
+    before_i   = sum of the live values of rows 0 .. i-1      (int32, wraps)
+    start_j    = index of the j-th boundary row, n past the last one
+    combined_j = before[start_{j+1}] - before[start_j]
 
-which is how it is written here — one pass, no phase barriers, and it
-generalizes beyond counting: any monoid (sum/min/max) is a drop-in
-``jax.ops.segment_*``.  The reference's count-by-index-difference only works
-because every value is 1; ``segment_sum`` over the actual values subsumes it.
+The reference's difference of INDICES only counts because every value is 1;
+a difference of the running sum is exact for any int32 values (modulo 2^32,
+so bit for bit what ``jax.ops.segment_sum`` gives) and ``count`` is the same
+difference over ones.  Compacting the starts is one narrow ``lax.sort`` that
+carries ``before`` as its payload (``_at_segment_starts``): nothing scatters
+or gathers over the ``n`` input rows.  ``min``/``max`` are no differences of
+anything and keep a ``jax.ops.segment_*`` for their values.
 
 Input must be key-sorted with valid rows first (ops/process_stage.py), the
 same precondition the reference's reduce has — and which its distributed mode
@@ -66,6 +70,53 @@ def normalize_combine(map_fn, combine: str):
     return count_map, "sum"
 
 
+def combine_scatters(combine: str) -> int:
+    """Scatters over the INPUT rows that ``segment_reduce_into`` issues.
+
+    0 for ``sum``/``count`` (a running sum carried to the compacted segment
+    starts), 1 for ``min``/``max`` (their values keep ``jax.ops.segment_*``).
+    The engine records it once as the gauge ``engine.combine_scatters``.
+    """
+    if combine not in COMBINERS:
+        raise ValueError(f"combine must be one of {COMBINERS}, got {combine!r}")
+    return 0 if combine in ("sum", "count") else 1
+
+
+def _at_segment_starts(
+    boundary: jax.Array, out_size: int, carried: jax.Array | None = None, fill=0
+) -> tuple[jax.Array, jax.Array | None]:
+    """Row index of the first ``out_size + 1`` set flags, in order — ``n``
+    after the last — and ``carried`` read at those rows (``fill`` after the
+    last).
+
+    The compaction is ONE narrow sort: a flagged row's key is its index,
+    every other row's its index plus ``n``, so the head of the sorted keys is
+    the flagged indices in order and whatever follows is at least ``n``.
+    ``carried`` rides the sort as its second operand, so reading it at the
+    starts is no gather.  Slot ``j + 1`` is always one past segment ``j``'s
+    last row: the next segment's first row, or ``n``.
+    """
+    n = boundary.shape[0]
+    pos = jnp.arange(n, dtype=jnp.uint32)
+    operands = [jnp.where(boundary, pos, pos + jnp.uint32(n))]
+    fills = [n]
+    if carried is not None:
+        operands.append(jnp.where(boundary, carried, fill))
+        fills.append(fill)
+    # Every key is distinct, so stability would buy nothing: on a TPU it is
+    # a third operand through the sort (half again its time, twice its compile).
+    ordered = jax.lax.sort(operands, num_keys=1, is_stable=False)
+    heads = [x[: out_size + 1] for x in ordered]
+    short = out_size + 1 - n
+    if short > 0:
+        heads = [
+            jnp.concatenate([h, jnp.full((short,), f, h.dtype)])
+            for h, f in zip(heads, fills)
+        ]
+    start = jnp.minimum(heads[0], jnp.uint32(n)).astype(jnp.int32)
+    return start, (heads[1] if carried is not None else None)
+
+
 def segment_reduce_into(
     batch: KVBatch, out_size: int, combine: str = "sum"
 ) -> tuple[KVBatch, jax.Array]:
@@ -76,14 +127,17 @@ def segment_reduce_into(
     distinct-key count (may exceed ``out_size`` — the caller's truncation
     signal).
 
-    This is ``segment_reduce`` with the head-slice fused in: the key-row
-    gather and the value scatter both touch ``out_size`` rows instead of the
-    full batch — on TPU v5e, gathering/scattering [n, lanes] rows at the
-    full emit-stream size is ~60% of the whole reduce stage, and the engine
-    immediately slices to table capacity anyway (engine.py fold_block).
+    ``sum`` and ``count`` issue no scatter and no gather over the ``n`` input
+    rows: an int32 running sum of the live values rides the two-operand sort
+    that compacts the segment starts (``_at_segment_starts``), and a
+    segment's total is the running sum before the NEXT start minus the one
+    before its own — exact modulo 2^32, so a running sum that wraps still
+    gives every segment the ``segment_sum`` result bit for bit.  Invalid rows
+    add 0 to the running sum, wherever they lie.  ``min`` and ``max`` have no
+    such difference and keep ``jax.ops.segment_*`` for the values.  What is
+    left touches ``out_size`` rows: the key-row gather.
     """
-    if combine not in COMBINERS:
-        raise ValueError(f"combine must be one of {COMBINERS}, got {combine!r}")
+    scatters = combine_scatters(combine)
     lanes, values, valid = batch.key_lanes, batch.values, batch.valid
     n = lanes.shape[0]
 
@@ -91,32 +145,26 @@ def segment_reduce_into(
     neq = jnp.any(lanes != prev, axis=-1)
     first = jnp.arange(n) == 0
     boundary = valid & (first | neq)                        # [N]
-    seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1        # [N]
     num_segments = jnp.sum(boundary.astype(jnp.int32))
-    # Segments beyond out_size and invalid rows all fold into the dump slot.
-    ids = jnp.where(valid, jnp.minimum(seg, out_size), out_size)
 
-    if combine == "sum":
-        combined = jax.ops.segment_sum(values, ids, num_segments=out_size + 1)
-    elif combine == "count":
-        combined = jax.ops.segment_sum(
-            jnp.ones_like(values), ids, num_segments=out_size + 1
+    if not scatters:
+        ones = jnp.ones_like(values)
+        live = jnp.where(valid, ones if combine == "count" else values, 0)
+        running = jnp.cumsum(live)                          # through row i
+        start, before = _at_segment_starts(
+            boundary, out_size, running - live, fill=running[-1]
         )
-    elif combine == "min":
-        combined = jax.ops.segment_min(values, ids, num_segments=out_size + 1)
-    else:  # max
-        combined = jax.ops.segment_max(values, ids, num_segments=out_size + 1)
-    combined = combined[:out_size]
+        combined = before[1:] - before[:-1]
+    else:
+        start, _ = _at_segment_starts(boundary, out_size)
+        seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+        # Segments beyond out_size and invalid rows all fold into the dump slot.
+        ids = jnp.where(valid, jnp.minimum(seg, out_size), out_size)
+        scatter = jax.ops.segment_min if combine == "min" else jax.ops.segment_max
+        combined = scatter(values, ids, num_segments=out_size + 1)[:out_size]
 
-    # First row index of each kept segment (scatter-min, 1-wide), then a
-    # row gather of only out_size key rows.
-    start = jax.ops.segment_min(
-        jnp.arange(n, dtype=jnp.int32),
-        jnp.where(boundary, jnp.minimum(seg, out_size), out_size),
-        num_segments=out_size + 1,
-    )[:out_size]
     out_valid = jnp.arange(out_size, dtype=jnp.int32) < num_segments
-    safe_start = jnp.where(out_valid, start, 0)
+    safe_start = jnp.where(out_valid, start[:out_size], 0)
     out_lanes = lanes[safe_start] * out_valid[:, None].astype(lanes.dtype)
     return (
         KVBatch(
