@@ -144,10 +144,10 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else list(argv)
-    # Workload-ladder subcommands (PageRank / inverted index / TF-IDF,
-    # cli_apps.py).  Dispatch on the first argument so the reference's
-    # bare positional WordCount contract stays intact; a FILE literally
-    # named "pagerank" needs ./pagerank.
+    # Workload-ladder subcommands (PageRank / inverted index / TF-IDF /
+    # the record sort, cli_apps.py).  Dispatch on the first argument so
+    # the reference's bare positional WordCount contract stays intact; a
+    # FILE literally named "pagerank" or "sort" needs ./pagerank, ./sort.
     from locust_tpu.cli_apps import SUBCOMMANDS
     from locust_tpu import cli_apps
 

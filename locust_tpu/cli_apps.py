@@ -2,7 +2,8 @@
 
 The reference's entire capability is CLI-driven (reference
 MapReduce/src/main.cu:358-387, README.md:12-24); ours matched that for
-WordCount but left PageRank / inverted index / TF-IDF library-only.
+WordCount but left PageRank / inverted index / TF-IDF / the record sort
+library-only.
 Since the plan layer (docs/PLAN.md) these
 drivers no longer hand-wire stage chains: each one CONSTRUCTS the
 workload's canonical logical plan (locust_tpu/plan/builders.py) and runs
@@ -13,12 +14,17 @@ tests/test_plan.py).  These subcommands wire the existing apps:
   python -m locust_tpu pagerank <edges.txt> [--mesh] [--num-iters N]
   python -m locust_tpu index  <file> [--mesh] [--lines-per-doc K]
   python -m locust_tpu tfidf  <file> [--lines-per-doc K]
+  python -m locust_tpu sort   <in> <out> [--record-bytes 100] [--key-bytes 10]
 
 Edge-list format: one ``src dst`` pair of integer node ids per line;
 lines starting with ``#`` are comments (the web-Google / SNAP convention,
 BASELINE.json configs[3]).  For index/tfidf the doc id of line i is
 ``i // lines_per_doc`` — line-sharded documents, the same convention as
-the library tests.
+the library tests.  ``sort`` is TeraSort: IN holds fixed-width binary
+records (gensort's: 100 bytes, the first 10 the key), OUT gets every one
+of them ordered by key as unsigned bytes, equal keys in input order; a
+size that is no whole number of records, or an empty IN, is an error and
+exit status 2, and no OUT is written.
 
 ``--mesh`` selects the sharded engines (ShardedPageRank — rank state
 O(nodes/n_dev) per device — and DistributedInvertedIndex) over all
@@ -35,10 +41,11 @@ import numpy as np
 
 from locust_tpu import obs  # jax-free; zero-overhead unless --trace-out
 
-SUBCOMMANDS = ("pagerank", "index", "tfidf")
+SUBCOMMANDS = ("pagerank", "index", "tfidf", "sort")
 
 
-def _add_backend_flag(p: argparse.ArgumentParser) -> None:
+def _add_backend_flag(p: argparse.ArgumentParser,
+                      sort_mode: bool = True) -> None:
     p.add_argument(
         "--backend", choices=["auto", "cpu", "tpu"], default="auto",
         help="auto: whatever jax initializes; cpu: pin the CPU; tpu: "
@@ -49,13 +56,14 @@ def _add_backend_flag(p: argparse.ArgumentParser) -> None:
     # is traceable and tunable with zero new plumbing.
     from locust_tpu.config import SORT_MODES
 
-    p.add_argument(
-        "--sort-mode", choices=list(SORT_MODES), default=None,
-        help="Process-stage sort strategy (config.EngineConfig."
-             "sort_mode); default follows the measured per-backend "
-             "choice (config.default_sort_mode).  pagerank accepts it "
-             "for ladder parity only — its dense pipeline has no sort.",
-    )
+    if sort_mode:  # the record sort has ONE spelling (process_stage.order_by_lanes)
+        p.add_argument(
+            "--sort-mode", choices=list(SORT_MODES), default=None,
+            help="Process-stage sort strategy (config.EngineConfig."
+                 "sort_mode); default follows the measured per-backend "
+                 "choice (config.default_sort_mode).  pagerank accepts it "
+                 "for ladder parity only — its dense pipeline has no sort.",
+        )
     p.add_argument(
         "--trace-out", default=None, metavar="FILE",
         help="structured telemetry (locust_tpu.obs): record the run's "
@@ -78,6 +86,18 @@ def build_parser(cmd: str) -> argparse.ArgumentParser:
                             "(rank state sharded O(nodes/n_dev))")
         p.add_argument("--top", type=int, default=None,
                        help="print only the N highest-ranked nodes")
+    elif cmd == "sort":
+        from locust_tpu.plan.builders import KEY_BYTES, RECORD_BYTES
+
+        p.add_argument("input", metavar="IN",
+                       help="file of fixed-width binary records")
+        p.add_argument("output", metavar="OUT",
+                       help="file the sorted records are written to")
+        p.add_argument("--record-bytes", type=int, default=RECORD_BYTES,
+                       help="bytes a record (gensort: 100)")
+        p.add_argument("--key-bytes", type=int, default=KEY_BYTES,
+                       help="leading bytes of a record that are its key, "
+                            "compared as unsigned bytes (gensort: 10)")
     else:
         p.add_argument("filename", help="input text file")
         p.add_argument("--lines-per-doc", type=int, default=1,
@@ -92,7 +112,7 @@ def build_parser(cmd: str) -> argparse.ArgumentParser:
         p.add_argument("--line-width", type=int, default=128)
         p.add_argument("--key-width", type=int, default=32)
         p.add_argument("--emits-per-line", type=int, default=20)
-    _add_backend_flag(p)
+    _add_backend_flag(p, sort_mode=cmd != "sort")
     return p
 
 
@@ -214,6 +234,35 @@ def run_tfidf(args) -> int:
     return _print_rendered("tfidf", scores, args.limit)
 
 
+def run_sort(args, source) -> int:
+    from locust_tpu.config import EngineConfig
+    from locust_tpu.io import serde
+    from locust_tpu.plan import records_sort_plan
+    from locust_tpu.plan.compile import compile_plan
+
+    # Plan-compiled like the rest of the ladder: source/records ->
+    # sort/by_key -> sink/records onto engine.RecordSort.  The driver
+    # evaluates the source and the sink itself, as the WordCount CLI
+    # loads its rows and prints its table, so ingest, sort and output
+    # show as cli.load / cli.run / cli.output.
+    sort_plan = compile_plan(
+        records_sort_plan(args.record_bytes, args.key_bytes), EngineConfig()
+    )
+    with obs.span("cli.load"):
+        staged = sort_plan.load_records(source)
+        print(f"[locust] {staged.n_records} records of "
+              f"{args.record_bytes} bytes loaded", file=sys.stderr)
+    with obs.span("cli.run"):
+        ordered = sort_plan.run(staged, render=False).value
+    with obs.span("cli.output"):
+        written = serde.write_records(args.output, ordered.host_blocks())
+    print(f"[locust] sorted by the first {args.key_bytes} bytes: "
+          f"{written // args.record_bytes} records, {written} bytes written "
+          f"to {args.output}; {source.nbytes - written} bytes lost",
+          file=sys.stderr)
+    return 0 if written == source.nbytes else 1
+
+
 def main(cmd: str, argv) -> int:
     args = build_parser(cmd).parse_args(argv)
     # Pure argument validation BEFORE backend resolution: a trivially
@@ -227,7 +276,22 @@ def main(cmd: str, argv) -> int:
             file=sys.stderr,
         )
         return 2
-    if cmd != "pagerank" and args.lines_per_doc < 1:
+    source = None
+    if cmd == "sort":
+        if not 1 <= args.key_bytes <= args.record_bytes:
+            print(f"locust_tpu: error: --key-bytes {args.key_bytes} must lie "
+                  f"in 1..--record-bytes ({args.record_bytes})", file=sys.stderr)
+            return 2
+        from locust_tpu.io.loader import RecordSource
+
+        # The file's size is checked before the backend is taken: a cut
+        # or missing record is an error, never a shorter OUT.
+        try:
+            source = RecordSource.open(args.input, args.record_bytes)
+        except (OSError, ValueError) as e:
+            print(f"locust_tpu: error: {e}", file=sys.stderr)
+            return 2
+    elif cmd != "pagerank" and args.lines_per_doc < 1:
         print("locust_tpu: error: --lines-per-doc must be >= 1",
               file=sys.stderr)
         return 2
@@ -250,6 +314,8 @@ def main(cmd: str, argv) -> int:
             return run_pagerank(args)
         if cmd == "index":
             return run_index(args)
+        if cmd == "sort":
+            return run_sort(args, source)
         return run_tfidf(args)
     except (OSError, ValueError) as e:
         print(f"locust_tpu: error: {e}", file=sys.stderr)

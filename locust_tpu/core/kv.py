@@ -102,6 +102,57 @@ class KVBatch:
         return list(zip(bytes_ops.rows_to_strings(keys), live_values.tolist()))
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class RecordBatch:
+    """Fixed-width binary records, WHOLE: the value is the record, not an
+    int32 beside the key (TeraSort's 100-byte record is 10 key bytes and
+    90 of payload; every byte of input comes back out).
+
+    Attributes:
+      words: uint32 ``[N, W]`` — a record's bytes as the file holds them,
+        four to a word, little-endian (word j = bytes 4j..4j+3, byte 4j
+        lowest), zero-padded to a whole word: what ``ndarray.view`` gives
+        on the host with no pass over the data.
+    """
+
+    words: jax.Array
+
+    @property
+    def size(self) -> int:
+        return self.words.shape[0]
+
+    @staticmethod
+    def num_words(record_bytes: int) -> int:
+        return -(-record_bytes // 4)
+
+    @classmethod
+    def empty(cls, n: int, record_bytes: int) -> "RecordBatch":
+        return cls(jnp.zeros((n, cls.num_words(record_bytes)), jnp.uint32))
+
+    def key_lanes(self, key_bytes: int) -> tuple[jax.Array, ...]:
+        """The key cut from the records: its first ``key_bytes`` bytes as
+        big-endian uint32 lanes, most significant first, the last lane
+        zero-padded — tuple order on them IS unsigned byte order on the
+        key (core/packing.py), and a padding byte cannot reorder keys of
+        one width."""
+        lanes = []
+        for j in range(-(-key_bytes // 4)):
+            w = self.words[:, j]
+            lane = ((w << 24) | ((w & 0xFF00) << 8)
+                    | ((w >> 8) & 0xFF00) | (w >> 24))
+            keep = min(4, key_bytes - 4 * j)
+            if keep < 4:
+                lane = lane & jnp.uint32((0xFFFFFFFF << (8 * (4 - keep))) & 0xFFFFFFFF)
+            lanes.append(lane)
+        return tuple(lanes)
+
+    def take(self, rows: jax.Array) -> "RecordBatch":
+        """The records at ``rows``, in that order: the permutation of the
+        payload by a sorted index."""
+        return RecordBatch(self.words[rows])
+
+
 # A table that grows with what it sees — the default path's one table
 # (engine.timed_run) and the mesh's hash shards (parallel/shuffle.py) —
 # grows by powers of this factor, to the first capacity that holds the

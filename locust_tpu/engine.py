@@ -48,10 +48,10 @@ from locust_tpu import backend as backend_mod
 from locust_tpu import obs
 from locust_tpu.config import DEFAULT_CONFIG, EngineConfig
 from locust_tpu.core import bytes_ops
-from locust_tpu.core.kv import KVBatch, grow_table, rows_to_hold
+from locust_tpu.core.kv import KVBatch, RecordBatch, grow_table, rows_to_hold
 from locust_tpu.io.snapshot import AsyncCheckpointWriter, finalize_snapshot
 from locust_tpu.ops.map_stage import wordcount_map
-from locust_tpu.ops.process_stage import sort_and_compact
+from locust_tpu.ops.process_stage import order_by_lanes, sort_and_compact
 from locust_tpu.ops.reduce_stage import (
     combine_scatters,
     normalize_combine,
@@ -594,36 +594,94 @@ def _build_programs(cfg: EngineConfig, raw_map_fn: MapFn,
     )
 
 
+@dataclasses.dataclass(frozen=True)
+class _RecordPrograms:
+    """The record sort's jitted programs (``_build_record_programs``): one
+    record a (record width, key width) a process, like ``_Programs``."""
+
+    empty: Callable      # rows -> RecordBatch of zeros
+    place: Callable      # (records, a block's words, at) -> records (donated)
+    sort_keys: Callable  # (records, n, block_rows) -> int32 [blocks, block_rows]
+    permute: Callable    # (records, perm, block) -> that block's words, sorted
+
+
+def _build_record_programs(record_bytes: int, key_bytes: int) -> _RecordPrograms:
+    """Define and jit the record sort of ``record_bytes``-byte records by
+    their first ``key_bytes`` bytes.  No compaction and no combine: every
+    record in comes back out.  The records stay whole on the device; only
+    ``key_bytes`` of each and a row index go through ``lax.sort``
+    (``order_by_lanes``), and the payload is permuted by the sorted index
+    a block at a time.  A block crosses the host boundary as a flat array
+    of words, so neither transfer depends on the layout the compiler
+    gives a narrow ``[N, W]`` array."""
+    words = RecordBatch.num_words(record_bytes)
+
+    def empty_records(rows: int) -> RecordBatch:
+        return RecordBatch.empty(rows, record_bytes)
+
+    def place_records(records: RecordBatch, block: jax.Array,
+                      at: jax.Array) -> RecordBatch:
+        return RecordBatch(jax.lax.dynamic_update_slice(
+            records.words, block.reshape(-1, words), (at, 0)
+        ))
+
+    def sort_record_keys(records: RecordBatch, n: jax.Array,
+                         block_rows: int) -> jax.Array:
+        # Rows from n on are the last block's padding: a key of all ones
+        # and their larger row index put them behind every record.
+        row = jnp.arange(records.size, dtype=jnp.int32)
+        lanes = [
+            jnp.where(row < n, lane, jnp.uint32(0xFFFFFFFF))
+            for lane in records.key_lanes(key_bytes)
+        ]
+        _, perm = order_by_lanes(lanes)
+        return perm.reshape(-1, block_rows)
+
+    def permute_records(records: RecordBatch, perm: jax.Array,
+                        block: jax.Array) -> jax.Array:
+        return records.take(perm[block]).words.reshape(-1)
+
+    return _RecordPrograms(
+        empty=jax.jit(empty_records, static_argnames="rows"),
+        place=jax.jit(place_records, donate_argnums=0),
+        sort_keys=jax.jit(sort_record_keys, static_argnames="block_rows"),
+        permute=jax.jit(permute_records),
+    )
+
+
 # The process's programs, a record a key (_programs_for), least recently
 # used last out; guarded by the lock beside it.
-_PROGRAMS: "collections.OrderedDict[tuple, _Programs]" = collections.OrderedDict()
+_PROGRAMS: "collections.OrderedDict[tuple, object]" = collections.OrderedDict()
 _PROGRAMS_LOCK = threading.Lock()
 
 
-def _programs_for(cfg: EngineConfig, map_fn: MapFn,
-                  combine: str) -> tuple[_Programs, bool]:
-    """``(programs, built)`` for an engine of these arguments: the
-    process's one record of its key — everything ``_build_programs``
-    closes over or reads: the config, the map function object, the
-    user's combine and the backend — built here if the process holds none
-    (``built``).  Every later engine of the key takes the same jit
-    objects, so jax's in-memory cache answers its first call as it
-    answers an old engine's hundredth: nothing is traced, lowered or read
-    back.  At most ``MapReduceEngine.PROGRAM_KEYS`` keys are kept; an
+def _programs_for(key: tuple, build: Callable[[], object]):
+    """The programs of configuration ``key``: the process's one record of
+    it, made by ``build()`` if the process holds none (counted:
+    ``engine.programs_built`` / ``engine.programs_shared``).  ``key`` is
+    everything the builder closes over or reads — for the map/reduce
+    programs the config, the map function object and the user's combine
+    (``_build_programs``), for the record sort the record and key widths
+    (``_build_record_programs``) — and the backend joins it here.  Every
+    later engine of the key takes the same jit objects, so jax's in-memory
+    cache answers its first call as it answers an old engine's hundredth:
+    nothing is traced, lowered or read back.  At most ``MapReduceEngine.PROGRAM_KEYS`` keys are kept; an
     evicted record lives as long as the engines that hold it, and jax
     frees its executables with the last.  Built under the lock: two
     threads of one key get one record."""
-    key = (cfg, map_fn, combine, jax.default_backend())
+    key = (*key, jax.default_backend())
     with _PROGRAMS_LOCK:
         programs = _PROGRAMS.get(key)
         built = programs is None
         if built:
-            programs = _PROGRAMS[key] = _build_programs(cfg, map_fn, combine)
+            programs = _PROGRAMS[key] = build()
             while len(_PROGRAMS) > MapReduceEngine.PROGRAM_KEYS:
                 _PROGRAMS.popitem(last=False)
         else:
             _PROGRAMS.move_to_end(key)
-    return programs, built
+    obs.metric_inc("engine.programs_built", int(built))
+    obs.metric_inc("engine.programs_shared", int(not built))
+    return programs
 
 
 def clear_programs() -> None:
@@ -637,6 +695,132 @@ def clear_programs() -> None:
     traced then.  Engines already made keep their programs."""
     with _PROGRAMS_LOCK:
         _PROGRAMS.clear()
+
+
+@dataclasses.dataclass
+class StagedRecords:
+    """A job's records on the device (``RecordSort.load``): ``n_records``
+    real rows, then the last block's zero padding."""
+
+    records: RecordBatch
+    n_records: int
+    block_rows: int
+
+
+class SortedRecords:
+    """A sorted job whose payload is still on the device in input order
+    (``RecordSort.sort``): ``perm`` says which row comes where, and
+    ``host_blocks`` permutes and brings back a block at a time."""
+
+    def __init__(self, sorter: "RecordSort", staged: StagedRecords,
+                 perm: jax.Array):
+        self._sorter = sorter
+        self._staged = staged
+        self._perm = perm
+        self.n_records = staged.n_records
+
+    def host_blocks(self):
+        """The sorted records as host ``uint8`` arrays, in order, a block
+        each: at most ``RECORD_BLOCKS_IN_FLIGHT`` blocks are permuted and
+        on their way down while the caller writes the one before, and a
+        block's device copy is dropped once it is on the host."""
+        sorter, staged = self._sorter, self._staged
+        rb, words = sorter.record_bytes, RecordBatch.num_words(sorter.record_bytes)
+        blocks = self._perm.shape[0]
+        pending: collections.deque = collections.deque()
+        launched = 0
+        for b in range(blocks):
+            while launched < blocks and len(pending) < MapReduceEngine.RECORD_BLOCKS_IN_FLIGHT:
+                with obs.span("sort.permute", rows=staged.block_rows):
+                    flat = sorter.programs.permute(
+                        staged.records, self._perm, np.int32(launched)
+                    )
+                    flat.copy_to_host_async()
+                pending.append(flat)
+                launched += 1
+            flat = pending.popleft()
+            rows = min(staged.block_rows, self.n_records - b * staged.block_rows)
+            with obs.span("sort.d2h", bytes=rows * rb):
+                with obs.span("engine.sync", what="d2h"):
+                    host = np.asarray(flat)  # locust: noqa[R003] the block's one wait: the next blocks are already on their way
+            del flat
+            host = host.view(np.uint8)
+            if 4 * words == rb:
+                yield host[: rows * rb]
+            else:  # the word padding of an odd record width goes here
+                yield np.ascontiguousarray(
+                    host.reshape(-1, 4 * words)[:rows, :rb]
+                ).reshape(-1)
+
+
+class RecordSort:
+    """The record sort of one (record width, key width) on one device:
+    ``load`` the records, whole; ``sort`` their keys; read them back in
+    key order from ``SortedRecords.host_blocks``.  Made by
+    ``MapReduceEngine.record_sort``; holds the configuration's programs
+    (``_programs_for``: a process builds them once) and no data."""
+
+    def __init__(self, record_bytes: int, key_bytes: int):
+        if not 1 <= key_bytes <= record_bytes:
+            raise ValueError(
+                f"key_bytes {key_bytes} must lie in 1..record_bytes "
+                f"({record_bytes})"
+            )
+        self.record_bytes, self.key_bytes = record_bytes, key_bytes
+        self.programs = _programs_for(
+            ("records", record_bytes, key_bytes),
+            lambda: _build_record_programs(record_bytes, key_bytes),
+        )
+
+    def block_rows(self, n_records: int) -> int:
+        """Rows of one staged block: the largest power of two whose words
+        fit ``RECORD_BLOCK_BYTES``, and for a job under one block the
+        first power of two (at least 8) that holds it — a handful of
+        shapes over all small jobs, one a block count above that."""
+        words = RecordBatch.num_words(self.record_bytes)
+        full = 1 << ((MapReduceEngine.RECORD_BLOCK_BYTES // (4 * words)).bit_length() - 1)
+        rows = 8
+        while rows < min(n_records, full):
+            rows *= 2
+        return rows
+
+    def load(self, source) -> StagedRecords:
+        """``source`` (``io/loader.RecordSource``) onto the device: its
+        blocks handed up one after another — ``device_put`` returns at
+        once, so the next block's pages are found while this one's are on
+        their way — and placed into ONE resident ``RecordBatch``; returns
+        when the last is there."""
+        if source.record_bytes != self.record_bytes:
+            raise ValueError(
+                f"source holds {source.record_bytes}-byte records, this "
+                f"sort takes {self.record_bytes}"
+            )
+        rows = self.block_rows(source.n_records)
+        blocks = -(-source.n_records // rows)
+        records = self.programs.empty(rows=blocks * rows)
+        at = 0
+        for block in source.blocks(rows):
+            with obs.span("sort.h2d", bytes=block.nbytes):
+                records = self.programs.place(
+                    records, jax.device_put(block), np.int32(at)
+                )
+            at += rows
+        with obs.span("engine.sync", what="h2d"):
+            jax.block_until_ready(records)
+        obs.metric_inc("sort.records", source.n_records)
+        return StagedRecords(records, source.n_records, rows)
+
+    def sort(self, staged: StagedRecords) -> SortedRecords:
+        """Order the staged records' keys: (key lanes, row index) through
+        one ``lax.sort``; the payload does not move yet."""
+        with obs.span("sort.keys", rows=staged.records.size):
+            perm = self.programs.sort_keys(
+                staged.records, np.int32(staged.n_records),
+                block_rows=staged.block_rows,
+            )
+            with obs.span("engine.sync", what="keys"):
+                jax.block_until_ready(perm)
+        return SortedRecords(self, staged, perm)
 
 
 class MapReduceEngine:
@@ -664,6 +848,18 @@ class MapReduceEngine:
     # shapes, a plan's stages.  An engine past it builds, as every engine
     # did before the programs were shared.
     PROGRAM_KEYS = 8
+    # The record sort stages its transfers in blocks of about this many
+    # bytes (RecordSort.block_rows rounds down to a power of two of rows),
+    # so that H2D, the device, D2H and the file write can overlap; and it
+    # keeps this many sorted blocks permuted and on their way to the host
+    # ahead of the one being written.  The size is the HOST's: a block
+    # that comes down is a fresh numpy array, and one under glibc's
+    # largest mmap threshold (32 MB) is carved from memory the last one
+    # left, where a larger one is mapped anew and pays a page fault every
+    # 4 kB — on a v5e's host a job took 0.55 s at 16 MB, 0.68 at 32 MB and
+    # 1.89 s at 64 MB and 128 MB (PERF.md section 6, PR 35).
+    RECORD_BLOCK_BYTES = 16 << 20
+    RECORD_BLOCKS_IN_FLIGHT = 4
 
     def __init__(
         self,
@@ -683,9 +879,10 @@ class MapReduceEngine:
         self.combine = combine  # user-facing semantics (host finalize)
         # The programs belong to the configuration, not to this engine:
         # the process builds them once a key (_programs_for).
-        programs, built = _programs_for(cfg, map_fn, combine)
-        obs.metric_inc("engine.programs_built", int(built))
-        obs.metric_inc("engine.programs_shared", int(not built))
+        programs = _programs_for(
+            (cfg, map_fn, combine),
+            lambda: _build_programs(cfg, map_fn, combine),
+        )
         # Scatters over the emit stream that this configuration's segment
         # combine issues (0 for sum/count: ops/reduce_stage.py).
         obs.metric_set("engine.combine_scatters", combine_scatters(combine))
@@ -707,6 +904,11 @@ class MapReduceEngine:
         self._fused_demoted = programs.fused_demoted
         self._fused_stream_seg = programs.fused_stream_seg
         self._table_size = cfg.resolved_table_size
+
+    def record_sort(self, record_bytes: int, key_bytes: int) -> RecordSort:
+        """This engine's sort of fixed-width records by their leading key
+        bytes (no combiner: every record in comes back out)."""
+        return RecordSort(record_bytes, key_bytes)
 
     # ---------------------------------------------------------------- ingest
 

@@ -308,6 +308,77 @@ def load_rows(
     )
 
 
+class RecordSource:
+    """Fixed-length binary records — a file of them (``open``) or bytes in
+    memory — as the record sort takes them: no line splitting, no
+    tokenizer, every byte of every record.
+
+    A size that is no whole number of records, or no record at all, is a
+    ``ValueError`` HERE, before anything is read: a cut record is never
+    passed on in silence (what the line loader does to an over-long line
+    is ROADMAP A2's lesson).  ``blocks(rows)`` yields the records ``rows``
+    at a time as flat ``uint32`` arrays of ``rows x ceil(record_bytes / 4)``
+    words.  A file is MAPPED, not read: a whole block of a width that is a
+    multiple of four is a view of the page cache's own pages, so the
+    transfer to the device is the one pass over the bytes (on a v5e's host
+    a read into a fresh array first cost 0.5-1.0 s of a job's 0.1 s load,
+    PERF.md section 6, PR 35).  The last, short block and every block of
+    another width are copies: zero-padded to ``rows``, each record to
+    whole words.
+    """
+
+    def __init__(self, record_bytes: int, nbytes: int,
+                 path: str | None = None, data: bytes | None = None):
+        what = path or "input"
+        if record_bytes < 1:
+            raise ValueError(f"record_bytes must be >= 1, got {record_bytes}")
+        if nbytes == 0:
+            raise ValueError(f"{what}: no records (0 bytes)")
+        if nbytes % record_bytes:
+            raise ValueError(
+                f"{what}: {nbytes} bytes is no whole number of "
+                f"{record_bytes}-byte records ({nbytes % record_bytes} bytes "
+                "over); refusing to cut a record"
+            )
+        self.record_bytes = record_bytes
+        self.nbytes = nbytes
+        self.n_records = nbytes // record_bytes
+        self._path, self._data = path, data
+
+    @classmethod
+    def open(cls, path: str, record_bytes: int) -> "RecordSource":
+        import os
+
+        return cls(record_bytes, os.path.getsize(path), path=path)
+
+    @classmethod
+    def from_bytes(cls, data: bytes, record_bytes: int) -> "RecordSource":
+        return cls(record_bytes, len(data), data=data)
+
+    def blocks(self, rows: int):
+        from locust_tpu import obs
+
+        rb = self.record_bytes
+        words = -(-rb // 4)
+        raw = (np.memmap(self._path, np.uint8, "r") if self._path is not None
+               else np.frombuffer(self._data, np.uint8))
+        if raw.size != self.nbytes:
+            raise OSError(f"{self._path}: {raw.size} bytes now, {self.nbytes} "
+                          "when the sort began: the file changed under it")
+        for start in range(0, self.n_records, rows):
+            n = min(rows, self.n_records - start)
+            with obs.span("sort.read", bytes=n * rb):
+                chunk = raw[start * rb:(start + n) * rb]
+                if n == rows and 4 * words == rb:
+                    block = chunk.view(np.uint32)
+                else:
+                    block = np.zeros(rows * words, np.uint32)
+                    block.view(np.uint8).reshape(rows, 4 * words)[:n, :rb] = (
+                        chunk.reshape(n, rb)
+                    )
+            yield block
+
+
 class StreamingCorpus:
     """Iterate ``[<=block_lines, line_width]`` row blocks of a file in
     bounded memory.

@@ -51,6 +51,22 @@ _KVB_HEADER = struct.Struct("<4sBBHII")
 INTERMEDIATE_FORMATS = ("tsv", "bin")
 
 
+def write_records(path: str, blocks) -> int:
+    """Write sorted record blocks (``uint8`` arrays, in order) to ``path``
+    in one pass, each as it arrives; returns the bytes written.  The whole
+    data set goes OUT, where every other sink prints a table."""
+    from locust_tpu import obs
+
+    written = 0
+    with open(path, "wb") as f:
+        for block in blocks:
+            with obs.span("sort.write", bytes=block.nbytes):
+                f.write(memoryview(block))
+            written += block.nbytes
+    obs.metric_inc("sort.bytes_out", written)
+    return written
+
+
 def write_tsv(pairs: list[tuple[bytes, int]], path: str) -> None:
     """Write live (key, value) pairs as ``key\\tvalue`` lines."""
     with open(path, "wb") as f:

@@ -43,6 +43,12 @@ NAMES = {
     "mesh.sync": "span",            # mesh: host blocked on the devices' stats (arg what: stats | regrow)
     "mesh.table.grow": "span",      # mesh: every shard grown a step, the rounds since the last whole table folded again (args from_rows, to_rows, worst_shard, rounds_redone)
     "mesh.gather": "span",          # mesh: table from its shards to sorted host pairs (args rows, shards)
+    "sort.read": "span",            # record sort: a block of the mapped file found, or its copy where it must be padded (arg bytes)
+    "sort.h2d": "span",             # record sort: a staged block handed up and placed (arg bytes)
+    "sort.keys": "span",            # record sort: the key sort launched and waited for (arg rows)
+    "sort.permute": "span",         # record sort: a block's payload gather launched (arg rows)
+    "sort.d2h": "span",             # record sort: a sorted block brought down (arg bytes)
+    "sort.write": "span",           # record sort: a sorted block written to OUT (arg bytes)
     "ckpt.write": "span",           # async writer: serialize+publish one gen
     "serve.queue_wait": "span",     # serve: dispatcher waiting on the queue
     "serve.compile_or_hit": "span", # serve: warm-executable cache lookup/build
@@ -80,6 +86,8 @@ NAMES = {
     "engine.table_rows": "gauge",   # timed_run: the table's capacity at the job's end
     "engine.table_grows": "counter",  # timed_run: growth steps the job took
     "engine.merges": "counter",     # timed_run: merge programs launched (one a group + one a group redone)
+    "sort.records": "counter",      # record sort: records staged on the device
+    "sort.bytes_out": "counter",    # record sort: bytes written to OUT
     "mesh.rounds": "counter",       # mesh: rounds dispatched (redone ones not counted again)
     "mesh.table_grows": "counter",  # mesh: growth steps the job's shards took
     "mesh.drain_rounds": "counter", # mesh: extra all-to-all rounds the backlog took

@@ -97,21 +97,34 @@ def sort_and_compact(batch: KVBatch, mode: str = "hash") -> KVBatch:
     raise ValueError(f"unknown sort mode {mode!r}")
 
 
+def order_by_lanes(lanes) -> tuple[tuple[jax.Array, ...], jax.Array]:
+    """Order rows by uint32 key lanes (a sequence of ``[N]`` arrays, most
+    significant first) and carry the permutation: ``(sorted lanes,
+    perm)`` with ``perm[i]`` the input row that comes ``i``-th.
+
+    ONE ``lax.sort`` whose last key is the row index: equal keys keep
+    their input order and no two rows compare equal, so the result is
+    defined to the row and the sort may be the unstable one (on a v5e it
+    compiles in half the time and needs one operand less, PERF.md section
+    6, PR 32).  Whatever rides along — an int32 value, a 100-byte record
+    — is gathered by ``perm`` afterwards and never widens the sort.
+    """
+    lanes = tuple(lanes)
+    idx = jnp.arange(lanes[0].shape[0], dtype=jnp.int32)
+    out = jax.lax.sort((*lanes, idx), num_keys=len(lanes) + 1, is_stable=False)
+    return out[:-1], out[-1]
+
+
 def _lex_sort(batch: KVBatch) -> KVBatch:
     lanes = batch.key_lanes
-    n_lanes = lanes.shape[-1]
     invalid = (~batch.valid).astype(jnp.uint32)            # 0 = valid, first
-    operands = (
-        invalid,
-        *(lanes[:, i] for i in range(n_lanes)),
-        batch.values,
+    (sorted_invalid, *sorted_lanes), perm = order_by_lanes(
+        (invalid, *(lanes[:, i] for i in range(lanes.shape[-1])))
     )
-    out = jax.lax.sort(operands, num_keys=1 + n_lanes)
-    sorted_valid = out[0] == 0
-    sorted_lanes = jnp.stack(out[1 : 1 + n_lanes], axis=-1)
-    sorted_values = out[1 + n_lanes]
     return KVBatch(
-        key_lanes=sorted_lanes, values=sorted_values, valid=sorted_valid
+        key_lanes=jnp.stack(sorted_lanes, axis=-1),
+        values=batch.values[perm],
+        valid=sorted_invalid == 0,
     )
 
 

@@ -61,3 +61,19 @@ def pagerank_plan(num_iters: int = 20, damping: float = 0.85) -> Plan:
              num_iters=num_iters, damping=damping),
         node("out", "sink", "ranks", ("ranks",)),
     ))
+
+
+# gensort's record (sortbenchmark.org): 100 bytes, the first 10 the key.
+RECORD_BYTES, KEY_BYTES = 100, 10
+
+
+def records_sort_plan(record_bytes: int = RECORD_BYTES,
+                      key_bytes: int = KEY_BYTES) -> Plan:
+    """TeraSort: fixed-width binary records ordered by their leading key
+    bytes (unsigned, ties in input order) and every one of them written
+    back out — the shuffle WITHOUT a combiner (engine.RecordSort)."""
+    return Plan((
+        node("records", "source", "records", record_bytes=record_bytes),
+        node("order", "sort", "by_key", ("records",), key_bytes=key_bytes),
+        node("out", "sink", "records", ("order",)),
+    ))

@@ -20,6 +20,10 @@ already trusts —
     corpus — the ``build_tfidf`` stance);
   * ``iterate(pagerank)`` over an edge source lowers onto
     ``apps.pagerank`` (``ShardedPageRank`` under ``mesh=True``);
+  * ``sort(by_key)`` over a records source lowers onto the engine's
+    record sort (``engine.RecordSort``): the records whole on the device,
+    their key bytes and a row index through one ``lax.sort``, the payload
+    permuted by the sorted index — no combiner, every record kept;
   * ``join(inner)`` merges two terminal tables on key — a host fold
     over device-built tables, like every other table-level finalize;
   * ``sink`` renders the terminal value to the EXACT bytes the
@@ -93,6 +97,7 @@ class CompiledPlan:
         self.cfg = cfg
         self.mesh = mesh
         self._engine = None  # lazy MapReduceEngine (wordcount fold)
+        self._sorter = None  # lazy engine.RecordSort (sort stage)
         # The rewrite pass (plan/optimize.py) runs between validation
         # and lowering; ``self.plan`` stays the original so every
         # fingerprint-keyed identity (warm/result caches, WAL replay,
@@ -110,10 +115,11 @@ class CompiledPlan:
             self._stages: dict[str, tuple] = {}
             self._root = self._lower(self._sink.id)
         if cfg is None and any(
-            n.kind == "source" and n.op == "text" for n in plan.nodes
+            n.kind == "source" and n.op in ("text", "records")
+            for n in plan.nodes
         ):
             raise PlanError(
-                "a plan with a text source needs an EngineConfig"
+                "a plan with a text or records source needs an EngineConfig"
             )
         if mesh and self._needs_mesh_guard():
             raise PlanError(
@@ -183,6 +189,23 @@ class CompiledPlan:
                 f"node {n.id!r}: shuffle must feed a reduce node (the "
                 "engine's one-sort fold groups and combines together)"
             )
+        elif n.kind == "sort":
+            src_id = self._lower(n.inputs[0])
+            src = self._by_id[src_id]
+            if not (src.kind == "source" and src.op == "records"):
+                raise PlanError(  # pragma: no cover - typing owns this
+                    f"node {n.id!r}: sort(by_key) must consume a records source"
+                )
+            from locust_tpu.plan.builders import KEY_BYTES, RECORD_BYTES
+
+            record_bytes = src.param("record_bytes", RECORD_BYTES)
+            key_bytes = n.param("key_bytes", KEY_BYTES)
+            if key_bytes > record_bytes:
+                raise PlanError(
+                    f"node {n.id!r}: key_bytes {key_bytes} is more than the "
+                    f"source's record_bytes {record_bytes}"
+                )
+            stage = ("record_sort", src_id, record_bytes, key_bytes)
         elif n.kind == "join":
             left = self._lower(n.inputs[0])
             right = self._lower(n.inputs[1])
@@ -311,12 +334,48 @@ class CompiledPlan:
             return self.run(
                 (src, dst), max_nodes=SERVE_MAX_PAGERANK_NODES
             )
+        record_src = next(
+            (n for n in self.plan.nodes
+             if n.kind == "source" and n.op == "records"), None
+        )
+        if record_src is not None:
+            from locust_tpu.io.loader import RecordSource
+            from locust_tpu.plan.builders import RECORD_BYTES
+
+            try:
+                return self.run(RecordSource.from_bytes(
+                    corpus, record_src.param("record_bytes", RECORD_BYTES)
+                ))
+            except ValueError as e:  # no whole number of records
+                raise PlanError(str(e))
         if sub_cache is not None and corpus_sha is None:
             import hashlib
 
             corpus_sha = hashlib.sha256(corpus).hexdigest()
         return self.run(corpus.splitlines(), sub_cache=sub_cache,
                         corpus_sha=corpus_sha, corpus_bytes=corpus)
+
+    def _record_sorter(self):
+        """The sort stage's ``engine.RecordSort`` (one a compiled plan;
+        its programs are the process's, ``engine._programs_for``)."""
+        if self._sorter is None:
+            from locust_tpu.engine import MapReduceEngine
+
+            stage = next(
+                s for s in self._stages.values() if s[0] == "record_sort"
+            )
+            self._sorter = MapReduceEngine(self.cfg).record_sort(
+                stage[2], stage[3]
+            )
+        return self._sorter
+
+    def load_records(self, source):
+        """Evaluate a records plan's SOURCE now: ``source`` (an
+        ``io/loader.RecordSource``) read and staged on the device.  What
+        it returns is the ``data`` of a later ``run`` — for a driver that
+        times ingest apart from the sort (the CLI's ``cli.load`` /
+        ``cli.run``); ``run`` takes a ``RecordSource`` as well."""
+        return self._record_sorter().load(source)
 
     def _wordcount_engine(self):
         if self._engine is None:
@@ -395,6 +454,9 @@ class _RunCtx:
             out = self._eval_fold(sid, stage)
         elif kind == "score":
             out = self._eval_score(stage)
+        elif kind == "record_sort":
+            out = self.cp._record_sorter().sort(self.eval(stage[1]))
+            self._acct[sid] = (out.n_records, False, 0)
         elif kind == "join":
             out = self._eval_join(sid, stage)
         elif kind == "pagerank":
@@ -425,6 +487,12 @@ class _RunCtx:
         if n.op == "edges":
             src, dst = data
             return np.asarray(src), np.asarray(dst)
+        if n.op == "records":
+            from locust_tpu.engine import StagedRecords
+
+            if isinstance(data, StagedRecords):  # CompiledPlan.load_records
+                return data
+            return self.cp._record_sorter().load(data)
         from locust_tpu.core import bytes_ops
 
         cfg = self.cp.cfg
@@ -771,6 +839,9 @@ def iter_rendered(op: str, value):
     elif op == "ranks":
         for i in range(value.shape[0]):
             yield rank_row(i, value[i])
+    elif op == "records":  # the records themselves, a sorted block a row
+        for block in value.host_blocks():
+            yield memoryview(block)
     else:  # pragma: no cover - NODE_OPS closes the sink set
         raise PlanError(f"unknown sink op {op!r}")
 

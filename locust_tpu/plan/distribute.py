@@ -66,11 +66,14 @@ from .nodes import Plan
 
 # Node kinds that are distribution-exempt BY DESIGN (R014's two-sided
 # distributed-coverage check: every NODE_KINDS entry must either appear
-# in a ``.kind`` match below or be listed here with a reason).  Empty
-# today: every kind participates in at least one distributed shape —
-# source/map/shuffle/reduce as the fold spine, join as the hash-join
-# tree, iterate as the epoch sweep, sink as the terminal render.
-SOLO_ONLY: tuple = ()
+# in a ``.kind`` match below or be listed here with a reason).
+# source/map/shuffle/reduce participate as the fold spine, join as the
+# hash-join tree, iterate as the epoch sweep, sink as the terminal
+# render.  ``sort`` stays solo: the record sort keeps the whole data set
+# on ONE device, and its distributed form is not a stage program over
+# LKVB partitions but a range partition that moves every record
+# (apps/sample_sort.py's, PERF.md section 7 ``tera-skew.mesh4``).
+SOLO_ONLY: tuple = ("sort",)
 
 # Doc-id suffix budget for composite (word, doc) partition keys: the doc
 # id rides a uint32 key lane (apps/tfidf.py), so <= 10 decimal digits
@@ -240,6 +243,8 @@ def plan_shape(plan: Plan):
     except StopIteration:  # pragma: no cover - validation owns this
         return None, "no_sink"
     child = by_id[sink.inputs[0]]
+    if child.kind in SOLO_ONLY:
+        return None, "solo_only_kind"
 
     if child.kind == "iterate":
         if child.op != "pagerank":  # pragma: no cover - closed NODE_OPS

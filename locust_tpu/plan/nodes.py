@@ -48,10 +48,11 @@ PLAN_VERSION = 1
 # fall off the distributed surface (stale/unknown SOLO_ONLY entries are
 # findings too).
 NODE_KINDS = (
-    "source",   # ingest: corpus text or an edge list
+    "source",   # ingest: corpus text, an edge list or fixed-width records
     "map",      # per-record transform / emit (or a table-level rescore)
     "shuffle",  # group records by key (the Process-stage sort)
     "reduce",   # combine each group into one row
+    "sort",     # order records by key, every one kept (no combiner)
     "join",     # inner-join two tables on key
     "iterate",  # a fixed-point loop over a static structure
     "sink",     # render the terminal table to output bytes
@@ -59,13 +60,14 @@ NODE_KINDS = (
 
 # Operations per kind — the second closed tier under the kind registry.
 NODE_OPS = {
-    "source": ("text", "edges"),
+    "source": ("text", "edges", "records"),
     "map": ("tokenize_count", "tokenize_pairs", "tfidf_score"),
     "shuffle": ("by_key",),
     "reduce": ("sum", "collect_docs"),
+    "sort": ("by_key",),
     "join": ("inner",),
     "iterate": ("pagerank",),
-    "sink": ("table", "tfidf", "postings", "ranks"),
+    "sink": ("table", "tfidf", "postings", "ranks", "records"),
 }
 
 # Dataflow typing: (kind, op) -> [(input types, output type), ...].
@@ -75,6 +77,7 @@ NODE_OPS = {
 _SIGNATURES = {
     ("source", "text"): (((), "rows"),),
     ("source", "edges"): (((), "edges"),),
+    ("source", "records"): (((), "records"),),
     ("map", "tokenize_count"): ((("rows",), "emits"),),
     ("map", "tokenize_pairs"): ((("rows",), "pair_emits"),),
     ("map", "tfidf_score"): ((("pair_table",), "scores"),),
@@ -87,12 +90,14 @@ _SIGNATURES = {
         (("grouped_pairs",), "pair_table"),
     ),
     ("reduce", "collect_docs"): ((("grouped_pairs",), "postings"),),
+    ("sort", "by_key"): ((("records",), "sorted_records"),),
     ("join", "inner"): ((("table", "table"), "table"),),
     ("iterate", "pagerank"): ((("edges",), "ranks"),),
     ("sink", "table"): ((("table",), "output"),),
     ("sink", "tfidf"): ((("scores",), "output"),),
     ("sink", "postings"): ((("postings",), "output"),),
     ("sink", "ranks"): ((("ranks",), "output"),),
+    ("sink", "records"): ((("sorted_records",), "output"),),
 }
 
 # Per-(kind, op) parameter schema: name -> validator returning the
@@ -146,6 +151,8 @@ def _join_combine(v):
 _PARAM_SCHEMA = {
     ("source", "text"): {"lines_per_doc": _pos_int, "input": _input_name},
     ("source", "edges"): {"input": _input_name},
+    ("source", "records"): {"record_bytes": _pos_int, "input": _input_name},
+    ("sort", "by_key"): {"key_bytes": _pos_int},
     ("join", "inner"): {"combine": _join_combine},
     ("iterate", "pagerank"): {"num_iters": _iters, "damping": _damping},
 }
@@ -154,7 +161,7 @@ _ID_RE = re.compile(r"^[A-Za-z0-9_.-]{1,64}$")
 
 # Arity per kind (join is the one two-input node).
 _ARITY = {
-    "source": 0, "map": 1, "shuffle": 1, "reduce": 1, "join": 2,
+    "source": 0, "map": 1, "shuffle": 1, "reduce": 1, "sort": 1, "join": 2,
     "iterate": 1, "sink": 1,
 }
 

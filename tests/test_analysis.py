@@ -638,6 +638,8 @@ def test_r009_real_registry_mutation_fails_the_gate(tmp_path):
         "locust_tpu/obs/names.py",
         "locust_tpu/engine.py",
         "locust_tpu/io/snapshot.py",
+        "locust_tpu/io/loader.py",      # emits sort.read
+        "locust_tpu/io/serde.py",       # emits sort.write / sort.bytes_out
         "locust_tpu/utils/faultplan.py",
         "locust_tpu/distributor/master.py",
         "locust_tpu/distributor/worker.py",

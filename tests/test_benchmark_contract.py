@@ -21,7 +21,7 @@ import bench
 import chip_smoke
 from locust_tpu import engine
 from locust_tpu.config import EngineConfig, default_sort_mode
-from locust_tpu.core.kv import KVBatch
+from locust_tpu.core.kv import KVBatch, RecordBatch
 from locust_tpu.engine import MapReduceEngine
 from locust_tpu.obs import programs
 from locust_tpu.obs.names import METRIC_KINDS, NAMES
@@ -75,13 +75,31 @@ def _lowered_text(eng: MapReduceEngine) -> tuple[str, ...]:
     )
     step, shapes = _toy_mesh_step()
     lowered += (step.lower(*shapes),)
+    lowered += _lowered_record_sort(eng)
     return tuple(low.as_text() for low in lowered)
+
+
+def _lowered_record_sort(eng: MapReduceEngine) -> tuple:
+    """The ``sort`` command's programs at toy shapes: two blocks of eight
+    gensort-width records."""
+    sorter = eng.record_sort(100, 10)
+    records = jax.eval_shape(lambda: RecordBatch.empty(16, 100))
+    at = jax.ShapeDtypeStruct((), jnp.int32)
+    block = jax.ShapeDtypeStruct((8 * 25,), jnp.uint32)
+    perm = jax.ShapeDtypeStruct((2, 8), jnp.int32)
+    progs = sorter.programs
+    return (
+        progs.empty.lower(rows=16),
+        progs.place.lower(records, block, at),
+        progs.sort_keys.lower(records, at, block_rows=8),
+        progs.permute.lower(records, perm, at),
+    )
 
 
 @pytest.fixture(scope="module")
 def program_names() -> dict[str, set[str]]:
     """Module names of the programs the cells run (the default path's
-    four and the mesh's step), as the
+    four, the mesh's step and the record sort's four), as the
     device trace's ``XLA Modules`` line will show them: of a
     configuration's ``first`` engine, which builds them, and of a later
     one, which takes the process's (``shared``, engine._programs_for) —
@@ -132,7 +150,7 @@ def _metric_cases():
     to a configuration's first engine and to one that shares its programs."""
     for path in METRIC_FILES:
         with open(path) as f:
-            by_program = json.load(f)["reader"] in ("xla_module", "roofline")
+            by_program = json.load(f)["reader"] in ("xla_module", "roofline", "roofline_job")
         for which in ("first", "shared") if by_program else (None,):
             name = os.path.basename(path)
             yield pytest.param(path, which, id=f"{name}-{which}" if which else name)
@@ -154,6 +172,8 @@ def test_layer_metric_reads_a_name_the_program_still_has(path, which, request):
         _assert_patterns_match(spec["patterns"], fixture("program_names")[which], "patterns")
     elif reader == "xla_op":
         _assert_patterns_match(spec["patterns"], fixture("mesh_op_names"), "patterns")
+    elif reader == "roofline_job":
+        _assert_patterns_match(spec["programs"], fixture("program_names")[which], "programs")
     elif reader == "roofline":
         names = fixture("program_names")[which]
         _assert_patterns_match([spec["unit"]], names, "unit")

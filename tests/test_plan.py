@@ -26,6 +26,7 @@ from locust_tpu.plan import (
     index_plan,
     node,
     pagerank_plan,
+    records_sort_plan,
     tfidf_plan,
     wordcount_plan,
 )
@@ -53,14 +54,15 @@ def _rows():
 
 def test_registry_is_closed_and_typed():
     assert NODE_KINDS == (
-        "source", "map", "shuffle", "reduce", "join", "iterate", "sink",
+        "source", "map", "shuffle", "reduce", "sort", "join", "iterate",
+        "sink",
     )
     assert set(NODE_OPS) == set(NODE_KINDS)
 
 
 def test_builders_validate_and_roundtrip():
     for p in (wordcount_plan(), tfidf_plan(3), index_plan(2),
-              pagerank_plan(7, 0.9)):
+              pagerank_plan(7, 0.9), records_sort_plan(), records_sort_plan(64, 8)):
         p2 = from_json(p.canonical_json())
         assert p2 == p
         assert p2.fingerprint() == p.fingerprint()
@@ -114,6 +116,95 @@ def test_random_valid_plans_roundtrip_identical_fingerprint():
         assert q.to_doc() == p.to_doc()
         seen.add(p.fingerprint())
     assert len(seen) > 30  # params/ids actually vary the identity
+
+
+def test_canonical_plan_fingerprints_are_pinned():
+    """The serve caches and the WAL key on these: the new ``sort`` kind
+    moved no plan that was there, and the record sort's is a constant."""
+    assert wordcount_plan().fingerprint() == "f8785ffaf2ea"
+    assert records_sort_plan().fingerprint() == "9b8ff3d58901"
+    assert records_sort_plan(100, 10).fingerprint() == "9b8ff3d58901"
+    assert records_sort_plan(100, 2).fingerprint() == "4cbc4a7313cc"
+    assert EngineConfig().fingerprint() == "fe2d587b6cae"
+
+
+# --------------------------------------------------------- the sort kind
+
+
+def _gensort_like(n: int, seed: int = 0) -> bytes:
+    rng = np.random.default_rng(seed)
+    rows = rng.integers(0, 256, (n, 100), dtype=np.uint8)
+    rows[:, :9] &= 0x81  # ties down to the tenth byte
+    return rows.tobytes()
+
+
+def test_sort_plan_types_and_lowers_onto_the_record_sort():
+    p = records_sort_plan()
+    assert [n.kind for n in p.nodes] == ["source", "sort", "sink"]
+    assert p.node_types() == {
+        "records": "records", "order": "sorted_records", "out": "output",
+    }
+    cp = compile_plan(p, CFG)
+    assert cp._stages["order"] == ("record_sort", "records", 100, 10)
+
+
+@pytest.mark.parametrize("nodes,frag", [
+    ([node("t", "source", "text"), node("o", "sort", "by_key", ("t",)),
+      node("k", "sink", "records", ("o",))], "cannot consume"),
+    ([node("r", "source", "records"), node("k", "sink", "records", ("r",))],
+     "cannot consume"),
+    ([node("r", "source", "records"), node("o", "sort", "by_key", ("r",)),
+      node("k", "sink", "table", ("o",))], "cannot consume"),
+    ([node("r", "source", "records"),
+      node("o", "sort", "by_key", ("r",), key_bytes=0),
+      node("k", "sink", "records", ("o",))], "key_bytes"),
+    ([node("r", "source", "records"),
+      node("o", "sort", "by_value", ("r",)),
+      node("k", "sink", "records", ("o",))], "unknown op"),
+    ([node("r", "source", "records", width=100),
+      node("o", "sort", "by_key", ("r",)),
+      node("k", "sink", "records", ("o",))], "unknown param"),
+])
+def test_sort_plan_validation_is_loud(nodes, frag):
+    with pytest.raises(PlanError, match=frag):
+        Plan(tuple(nodes))
+
+
+def test_sort_plan_refuses_a_key_wider_than_the_record_at_compile():
+    with pytest.raises(PlanError, match="more than the source's record_bytes"):
+        compile_plan(records_sort_plan(8, 10), CFG)
+    with pytest.raises(PlanError, match="needs an EngineConfig"):
+        compile_plan(records_sort_plan())
+
+
+def test_sort_plan_runs_to_the_reference_bytes():
+    """``run`` on a RecordSource, ``run_corpus`` on raw bytes (the serve
+    tier's entry) and a pre-staged source (the CLI's) give the plain
+    reference's bytes; accounting says every record came out."""
+    from locust_tpu import records_reference
+    from locust_tpu.io.loader import RecordSource
+
+    data = _gensort_like(5000, seed=3)
+    want = records_reference.sorted_records(data)
+    cp = compile_plan(records_sort_plan(), CFG)
+    res = cp.run(RecordSource.from_bytes(data, 100))
+    assert res.output == want
+    assert (res.distinct, res.truncated, res.overflow_tokens) == (5000, False, 0)
+    assert cp.run_corpus(data).output == want
+    staged = cp.load_records(RecordSource.from_bytes(data, 100))
+    ordered = cp.run(staged, render=False)
+    assert ordered.output is None
+    assert b"".join(b.tobytes() for b in ordered.value.host_blocks()) == want
+    with pytest.raises(PlanError, match="no whole number"):
+        cp.run_corpus(data[:-1])
+
+
+def test_sort_plan_stays_on_the_solo_engine_and_says_why():
+    from locust_tpu.plan.distribute import SOLO_ONLY, plan_shape
+
+    assert "sort" in SOLO_ONLY
+    assert plan_shape(records_sort_plan()) == (None, "solo_only_kind")
+    assert plan_shape(wordcount_plan())[1] is None
 
 
 def test_fingerprint_is_content_addressed():
