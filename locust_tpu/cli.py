@@ -39,7 +39,14 @@ def build_parser() -> argparse.ArgumentParser:
         prog="mapreduce",
         description="TPU-native MapReduce (WordCount) with staged multi-node mode",
     )
-    p.add_argument("filename", help="input text file (stage 0/1); ignored for stage 2")
+    p.add_argument("filename",
+                   help="input text file (stage 0/1); ignored for stage 2.  "
+                        "The default path reads it inside the run, a group "
+                        "of blocks ahead of the device, and never holds it "
+                        "whole in host memory; --no-timing, "
+                        "--checkpoint-dir, --auto-caps and --mesh (each "
+                        "without --stream) load it whole first: their "
+                        "loops index the rows")
     p.add_argument("line_start", nargs="?", type=int, default=-1)
     p.add_argument("line_end", nargs="?", type=int, default=-1)
     p.add_argument("node_num", nargs="?", type=int, default=0)
@@ -64,7 +71,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "identical to the configured caps, smaller sorted "
                         "arrays).  With --stream the measuring pass re-reads "
                         "the file in bounded memory.  No effect for stage 2.")
-    p.add_argument("--no-timing", action="store_true")
+    p.add_argument("--no-timing", action="store_true",
+                   help="one dispatch over the whole corpus and no stage "
+                        "report (run_fused): the corpus is loaded whole "
+                        "and staged whole, and the table is of fixed size")
     p.add_argument("--limit", type=int, default=None,
                    help="print only the first N table rows")
     p.add_argument("--checkpoint-dir", default=None,
@@ -105,9 +115,12 @@ def build_parser() -> argparse.ArgumentParser:
                         "stays intra-slice (ICI), slices combine once at "
                         "the end (DCN)")
     p.add_argument("--stream", action="store_true",
-                   help="bounded-memory ingest: stream the corpus in "
-                        "blocks instead of materializing it (for corpora "
-                        "that do not fit RAM)")
+                   help="the one-program-a-block fold over the file read "
+                        "two blocks ahead, with no stage report and a table "
+                        "of fixed size; composes with --mesh, "
+                        "--checkpoint-dir and --auto-caps, which without "
+                        "it load the corpus whole (the default path holds "
+                        "no more of it than a group of blocks either)")
     p.add_argument("--backend", choices=["auto", "cpu", "tpu"], default="auto",
                    help="auto: whatever jax initializes (JAX_PLATFORMS is "
                         "honoured); cpu: pin the CPU; tpu: require a TPU, "
@@ -332,6 +345,7 @@ def _run(args) -> int:
         from locust_tpu.plan.compile import compile_plan
 
         wc_plan = compile_plan(wordcount_plan(), cfg)
+        lines_read: list[int] = []  # a block's, as the run reads it
         with prof:
             with timer.span("load"), obs.span("cli.load"):
                 if args.stream:
@@ -342,7 +356,19 @@ def _run(args) -> int:
                     )
                     if _stale_auto_caps(stream, auto_caps_fp):
                         return 1
+                elif (preloaded_rows is None and not args.no_timing
+                        and not args.checkpoint_dir):
+                    # The default path never holds the corpus: the file is
+                    # opened here and read inside the run, a group of
+                    # blocks ahead of the device (engine.timed_run).
+                    rows = _counted(lines_read, loader.StreamingCorpus(
+                        args.filename, cfg.line_width, cfg.block_lines,
+                        args.line_start, args.line_end,
+                    ))
                 else:
+                    # --no-timing (run_fused) and --checkpoint-dir
+                    # (run_checkpointed) index the rows, and --auto-caps
+                    # has loaded them to measure them: the array, whole.
                     rows = (
                         preloaded_rows
                         if preloaded_rows is not None
@@ -351,7 +377,7 @@ def _run(args) -> int:
                             args.line_start, args.line_end,
                         )
                     )
-                    print(f"[locust] {rows.shape[0]} lines loaded", file=sys.stderr)
+                    lines_read.append(rows.shape[0])
             with timer.span("run"), obs.span("cli.run"):
                 # Each run method syncs internally, so the span is accurate.
                 pairs = None
@@ -378,6 +404,8 @@ def _run(args) -> int:
                     )
                     res = pres.run_result
                     pairs = pres.value
+                    print(f"[locust] {sum(lines_read)} lines loaded",
+                          file=sys.stderr)
             if args.stream and res.stream is not None:
                 # Zero-stall executor accounting: backpressure stall +
                 # checkpoint mark/write stats (engine.run_stream).
@@ -446,6 +474,14 @@ def _run(args) -> int:
     if args.trace:
         print(timer.report(), file=sys.stderr)
     return 0
+
+
+def _counted(lines_read: list[int], blocks):
+    """``blocks`` as an iterator, each block's line count noted in
+    ``lines_read`` as it goes by (on whichever thread reads it)."""
+    for blk in blocks:
+        lines_read.append(blk.shape[0])
+        yield blk
 
 
 def _stale_auto_caps(stream, auto_caps_fp) -> bool:

@@ -915,20 +915,54 @@ class MapReduceEngine:
     def rows_from_lines(self, lines: Sequence[bytes]) -> np.ndarray:
         return bytes_ops.strings_to_rows(list(lines), self.cfg.line_width)
 
-    def _blocks(self, rows: np.ndarray):
-        """Yield fixed-shape [block_lines, line_width] blocks, zero-padded."""
+    def _blocks(self, rows):
+        """Yield fixed-shape [block_lines, line_width] blocks, zero-padded,
+        on the device.  ``rows`` is the corpus as one array, cut here, or
+        an iterable of its host blocks of at most ``block_lines`` rows."""
         bl = self.cfg.block_lines
-        n = rows.shape[0]
-        for i in range(0, max(n, 1), bl):
-            # The span closes BEFORE the yield: a generator suspended
-            # inside it would bill the consumer's work to staging.
-            with obs.span("engine.h2d", bytes=bl * rows.shape[1]):
-                blk = rows[i : i + bl]
+        host = rows
+        if isinstance(rows, np.ndarray):
+            host = (rows[i : i + bl] for i in range(0, max(rows.shape[0], 1), bl))
+        for blk in host:
+            # The span opens AFTER the pull (a wait for the reader is
+            # engine.ingest.wait's) and closes BEFORE the yield: a generator
+            # suspended inside it would bill the consumer's work to staging.
+            with obs.span("engine.h2d", bytes=bl * blk.shape[1]):
                 if blk.shape[0] < bl:
-                    pad = np.zeros((bl - blk.shape[0], rows.shape[1]), np.uint8)
+                    pad = np.zeros((bl - blk.shape[0], blk.shape[1]), np.uint8)
                     blk = np.concatenate([blk, pad]) if blk.size else pad
                 staged = jnp.asarray(blk)
             yield staged
+
+    def _read_ahead(self, blocks, group: int):
+        """Host blocks of an ITERABLE corpus for ``timed_run``, read a
+        group ahead of the device.
+
+        All but the last block of the first group are read here, inline:
+        a source that ends among them is a job of one group, and no thread
+        is started for it.  From the group's last block on a reader thread
+        runs up to ``group`` blocks ahead of the pulls
+        (``loader.prefetch_blocks``: ``timed_run`` pulls a group at once,
+        inside its merge stage, and nothing while the next group's map,
+        process and reduce stages run — a queue of a whole group lets the
+        file be read during all of them).  Same blocks, same order; a
+        reader's error is raised at the pull that reaches it; closing this
+        generator (``timed_run`` does, however it ends) closes the reader's
+        and stops its thread.  A source with no block at all gives one
+        empty block, as an array of no rows does.  The source must not
+        reuse a block's memory: up to ``group`` of them wait in the queue.
+        """
+        from locust_tpu.io.loader import prefetch_blocks, read_spans
+
+        source = read_spans(blocks)
+        inline = max(1, group - 1)
+        head = list(itertools.islice(source, inline))
+        if len(head) < inline:
+            yield from head or [np.zeros((0, self.cfg.line_width), np.uint8)]
+            return
+        yield from head
+        del head
+        yield from prefetch_blocks(source, depth=group)
 
     # ------------------------------------------------------------------- run
 
@@ -1014,6 +1048,15 @@ class MapReduceEngine:
         """
         return self.run_blocks(self.prepare_blocks(rows))
 
+    def _timed_full_group(self) -> int:
+        """Blocks of a FULL group of ``timed_run``: the configuration's
+        alone, whatever the input (``_timed_group_blocks`` has the rule)."""
+        cfg = self.cfg
+        kv_row = 4 * cfg.key_lanes + 4 + 1  # key lanes, int32 value, valid
+        per_block = (cfg.block_lines * cfg.line_width
+                     + 3 * cfg.emits_per_block * kv_row)
+        return max(1, self.TIMED_GROUP_BYTES // per_block)
+
     def _timed_group_blocks(self, nblocks: int) -> tuple[int, int]:
         """``(group, fan_in)`` of a job of ``nblocks`` blocks.
 
@@ -1034,11 +1077,7 @@ class MapReduceEngine:
         defaults a job that ends at 2^20 rows peaked at 0.4 GB on a v5e,
         under what the per-block merges and their kept copy held.
         """
-        cfg = self.cfg
-        kv_row = 4 * cfg.key_lanes + 4 + 1  # key lanes, int32 value, valid
-        per_block = (cfg.block_lines * cfg.line_width
-                     + 3 * cfg.emits_per_block * kv_row)
-        full = max(1, self.TIMED_GROUP_BYTES // per_block)
+        full = self._timed_full_group()
         if nblocks >= full:
             return full, full
         base = 2
@@ -1049,8 +1088,16 @@ class MapReduceEngine:
             fan_in *= base
         return max(1, nblocks), min(fan_in, full)
 
-    def timed_run(self, rows: np.ndarray) -> RunResult:
+    def timed_run(self, rows) -> RunResult:
         """Per-stage timing parity with the reference's report (main.cu:405-468).
+
+        ``rows`` is the corpus: a ``[lines, width]`` array, or an iterable
+        of its ``[<= block_lines, width]`` host blocks in order
+        (``io.loader.StreamingCorpus``), which is read a group AHEAD of the
+        device by a reader thread (``_read_ahead``) and never held whole.
+        Either way the job is the same blocks through the same programs:
+        the group and the merge's fan-in are those of the block count
+        (``_timed_group_blocks``), known once the first group is pulled.
 
         Stage-major over GROUPS of blocks (``_timed_group_blocks``): each
         stage's program is launched on every block of the group back to
@@ -1080,9 +1127,10 @@ class MapReduceEngine:
         overflows = []
         max_distinct = jnp.int32(0)
         times = StageTimes()
-        blocks = self._blocks(rows)
-        group, fan_in = self._timed_group_blocks(
-            -(-rows.shape[0] // self.cfg.block_lines)
+        full = self._timed_full_group()
+        blocks = self._blocks(
+            rows if isinstance(rows, np.ndarray)
+            else self._read_ahead(rows, full)
         )
         # obs spans shadow the t0..t4 boundaries exactly (each stage's one
         # sync is inside its span), so an exported timeline and the
@@ -1092,74 +1140,84 @@ class MapReduceEngine:
         # inside a stage span, and a stage's inputs are dropped once it has
         # launched, so a group holds two intermediates a block at a time.
         # The next group is staged while the device works off this one's
-        # merge: its engine.h2d spans lie inside engine.stage.merge.
-        staged = list(itertools.islice(blocks, group))
-        while staged:
-            n = len(staged)
-            t0 = time.perf_counter()
-            with obs.span("engine.stage.map", blocks=n):
-                mapped = [self._map(blk) for blk in staged]
-                del staged
-                with obs.span("engine.sync", what="map"):
-                    jax.block_until_ready(mapped)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
-            t1 = time.perf_counter()
-            overflows += [blk_overflow for _, blk_overflow in mapped]
-            with obs.span("engine.stage.process", blocks=n):
-                batches = [self._process(kv) for kv, _ in mapped]
-                del mapped
-                with obs.span("engine.sync", what="process"):
-                    jax.block_until_ready(batches)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
-            t2 = time.perf_counter()
-            with obs.span("engine.stage.reduce", blocks=n):
-                tables = [self._reduce(kv) for kv in batches]
-                del batches
-                with obs.span("engine.sync", what="reduce"):
-                    jax.block_until_ready(tables)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
-            t3 = time.perf_counter()
-            with obs.span("engine.stage.merge", blocks=n, tables=fan_in) as stage:
-                if n < fan_in:
-                    # A short group (a job's last, or a job under a full
-                    # group): padded to the one shape the job's merges have.
-                    tables += [
-                        KVBatch.empty(tables[0].size, self.cfg.key_lanes)
-                    ] * (fan_in - n)
-                if distinct:  # past the first group: acc is not empty
-                    # A text adds fewer new keys a group as it goes on: a
-                    # table that would not hold what the LAST group added
-                    # once more is grown before this one merges into it.
-                    # (The first group's count says nothing: it holds every
-                    # common key, so it is left out of ``added``.)
-                    ahead = rows_to_hold(acc.size, distinct + added)
-                    if ahead > acc.size:
-                        with obs.span("engine.table.grow", from_rows=acc.size,
-                                      to_rows=ahead, distinct=distinct + added,
-                                      blocks_redone=0):
-                            acc = _grow_table(acc, ahead)
+        # merge: its engine.h2d spans lie inside engine.stage.merge.  The
+        # first pull is a full group's worth: fewer blocks than that are the
+        # whole job, whose count then gives the group and the fan-in.
+        try:
+            staged = list(itertools.islice(blocks, full))
+            group, fan_in = self._timed_group_blocks(len(staged))
+            while staged:
+                n = len(staged)
+                t0 = time.perf_counter()
+                with obs.span("engine.stage.map", blocks=n):
+                    mapped = [self._map(blk) for blk in staged]
+                    del staged
+                    with obs.span("engine.sync", what="map"):
+                        jax.block_until_ready(mapped)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
+                t1 = time.perf_counter()
+                overflows += [blk_overflow for _, blk_overflow in mapped]
+                with obs.span("engine.stage.process", blocks=n):
+                    batches = [self._process(kv) for kv, _ in mapped]
+                    del mapped
+                    with obs.span("engine.sync", what="process"):
+                        jax.block_until_ready(batches)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
+                t2 = time.perf_counter()
+                with obs.span("engine.stage.reduce", blocks=n):
+                    tables = [self._reduce(kv) for kv in batches]
+                    del batches
+                    with obs.span("engine.sync", what="reduce"):
+                        jax.block_until_ready(tables)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
+                t3 = time.perf_counter()
+                with obs.span("engine.stage.merge",
+                              blocks=n, tables=fan_in) as stage:
+                    if n < fan_in:
+                        # A short group (a job's last, or a job under a full
+                        # group): padded to the one shape the job's merges have.
+                        tables += [
+                            KVBatch.empty(tables[0].size, self.cfg.key_lanes)
+                        ] * (fan_in - n)
+                    if distinct:  # past the first group: acc is not empty
+                        # A text adds fewer new keys a group as it goes on: a
+                        # table that would not hold what the LAST group added
+                        # once more is grown before this one merges into it.
+                        # (The first group's count says nothing: it holds every
+                        # common key, so it is left out of ``added``.)
+                        ahead = rows_to_hold(acc.size, distinct + added)
+                        if ahead > acc.size:
+                            with obs.span("engine.table.grow", from_rows=acc.size,
+                                          to_rows=ahead, distinct=distinct + added,
+                                          blocks_redone=0):
+                                acc = _grow_table(acc, ahead)
+                            grows += 1
+                    start, seen = acc, max_distinct
+                    acc, max_distinct = self._merge(start, tuple(tables), seen)
+                    staged = list(itertools.islice(blocks, group))
+                    with obs.span("engine.sync", what="merge"):
+                        jax.block_until_ready(acc)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
+                        # Computed by the merge just waited for: the read
+                        # is a scalar copy, no further wait on the device.
+                        now = int(max_distinct)
+                    redone = now > start.size
+                    if redone:
+                        # The merge dropped its tail, but counted every key:
+                        # the group is merged again from the table it started
+                        # with, grown to hold them.
+                        acc, max_distinct = self._regrow(start, seen, tables, n, now)
                         grows += 1
-                start, seen = acc, max_distinct
-                acc, max_distinct = self._merge(start, tuple(tables), seen)
-                staged = list(itertools.islice(blocks, group))
-                with obs.span("engine.sync", what="merge"):
-                    jax.block_until_ready(acc)  # locust: noqa[R003] stage-timing boundary (reference parity), once a stage a GROUP: the sync IS the measurement
-                    # Computed by the merge just waited for: the read
-                    # is a scalar copy, no further wait on the device.
-                    now = int(max_distinct)
-                redone = now > start.size
-                if redone:
-                    # The merge dropped its tail, but counted every key:
-                    # the group is merged again from the table it started
-                    # with, grown to hold them.
-                    acc, max_distinct = self._regrow(start, seen, tables, n, now)
-                    grows += 1
-                stage.set(merges=1 + redone)
-                merges += 1 + redone
-                added = now - distinct if distinct else 0
-                distinct = now
-                del tables, start
-            t4 = time.perf_counter()
-            times.map_ms += (t1 - t0) * 1e3
-            times.process_ms += (t2 - t1) * 1e3 + (t4 - t3) * 1e3
-            times.reduce_ms += (t3 - t2) * 1e3
+                    stage.set(merges=1 + redone)
+                    merges += 1 + redone
+                    added = now - distinct if distinct else 0
+                    distinct = now
+                    del tables, start
+                t4 = time.perf_counter()
+                times.map_ms += (t1 - t0) * 1e3
+                times.process_ms += (t2 - t1) * 1e3 + (t4 - t3) * 1e3
+                times.reduce_ms += (t3 - t2) * 1e3
+        finally:
+            # However the job ends, the source is closed: a reader
+            # thread stops, and its queued blocks are dropped.
+            blocks.close()
+
         # One read a JOB, of values the map syncs have already waited for:
         # the copies cost no device op, and the total stays exact.
         with obs.span("engine.sync", what="overflow"):

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from locust_tpu import obs
 from locust_tpu.core import bytes_ops
 
 
@@ -198,7 +199,15 @@ def prefetch_blocks(blocks, depth: int = 2):
     reader thread overlaps the next window's read+pad with the current
     fold's device time.  Semantically transparent: same items, same
     order, exceptions re-raised at the consuming ``next()``.  Memory grows
-    by at most ``depth`` staged blocks.
+    by at most ``depth`` staged blocks — 2 for a loop that pulls a block a
+    dispatch; ``timed_run``, which pulls a whole group at once and then
+    nothing while the group's stages run, asks for a group's worth.
+
+    Telemetry (the consumer's tracer, which the reader thread records
+    into as well: ``obs`` tracers are thread-local): a pull that found
+    the queue empty is an ``engine.ingest.wait`` span, and the blocks
+    handed over count into ``engine.ingest.blocks_ahead`` (read before
+    they were asked for) or ``engine.ingest.blocks_waited``.
 
     Abandoning the generator early (consumer raised mid-loop, e.g. a
     shuffle-overflow RuntimeError) stops the reader promptly: its puts
@@ -213,6 +222,7 @@ def prefetch_blocks(blocks, depth: int = 2):
     q: queue.Queue = queue.Queue(maxsize=max(1, depth))
     end = object()
     stop = threading.Event()
+    tracer = obs.current()
 
     def put_or_stop(item) -> bool:
         while not stop.is_set():
@@ -225,24 +235,33 @@ def prefetch_blocks(blocks, depth: int = 2):
 
     def reader():
         try:
-            for b in blocks:
-                if not put_or_stop(b):
-                    return
+            with obs.scoped(tracer):
+                for b in blocks:
+                    if not put_or_stop(b):
+                        return
             put_or_stop(end)
         except BaseException as e:  # noqa: BLE001 - relayed to consumer
             put_or_stop(_PrefetchError(e))
 
     t = threading.Thread(target=reader, daemon=True)
     t.start()
+    handed = [0, 0]  # blocks that were ahead of their pull, that were waited for
     try:
         while True:
-            item = q.get()
+            try:
+                item, waited = q.get_nowait(), False
+            except queue.Empty:
+                with obs.span("engine.ingest.wait"):
+                    item, waited = q.get(), True
             if item is end:
                 return
             if isinstance(item, _PrefetchError):
                 raise item.exc
+            handed[waited] += 1
             yield item
     finally:
+        obs.metric_inc("engine.ingest.blocks_ahead", handed[0])
+        obs.metric_inc("engine.ingest.blocks_waited", handed[1])
         stop.set()
         # Drain until the reader has exited: a single drain can race a
         # put that was already past the stop check, leaving one staged
@@ -267,6 +286,21 @@ def prefetch_blocks(blocks, depth: int = 2):
                 q.get_nowait()
         except queue.Empty:
             pass
+
+
+def read_spans(blocks):
+    """Iterate ``blocks`` with every pull from it — a block read, split
+    and padded, or the end of the file found — under an
+    ``engine.ingest.read`` span of the thread that pulls.  The span
+    closes before the block is handed on: a generator suspended inside it
+    would bill the consumer's work to the read."""
+    it, end = iter(blocks), object()
+    while True:
+        with obs.span("engine.ingest.read"):
+            blk = next(it, end)
+        if blk is end:
+            return
+        yield blk
 
 
 def count_lines(path: str) -> int:
@@ -356,8 +390,6 @@ class RecordSource:
         return cls(record_bytes, len(data), data=data)
 
     def blocks(self, rows: int):
-        from locust_tpu import obs
-
         rb = self.record_bytes
         words = -(-rb // 4)
         raw = (np.memmap(self._path, np.uint8, "r") if self._path is not None

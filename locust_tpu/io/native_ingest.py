@@ -224,12 +224,15 @@ def iter_blocks(
     line_end: int = -1,
 ):
     """Yield ``[<=block_lines, line_width]`` row blocks via the native
-    windowed scanner (bounded memory; see ingest.cpp ingest_load_window)."""
+    windowed scanner (bounded memory; see ingest.cpp ingest_load_window).
+    Every block is a fresh array: the default path keeps a group of them
+    queued ahead of the device (``engine.timed_run``)."""
     lib = _load()
     offset = ctypes.c_long(0)
     line_no = ctypes.c_long(0)
     while True:
-        out = np.zeros((block_lines, line_width), dtype=np.uint8)
+        # The scanner zero-fills ``out`` itself before it writes a line.
+        out = np.empty((block_lines, line_width), dtype=np.uint8)
         wrote = lib.ingest_load_window(
             str(path).encode(),
             ctypes.byref(offset),
@@ -244,4 +247,10 @@ def iter_blocks(
             raise OSError(f"native ingest failed to read {path!r}")
         if wrote == 0:
             return
-        yield out[:wrote] if wrote < block_lines else out
+        if wrote < block_lines:
+            # A window comes back short only at the end of the file or of
+            # the slice: the call that would find nothing left is spared
+            # (a third of a two-block job's reads).
+            yield out[:wrote]
+            return
+        yield out

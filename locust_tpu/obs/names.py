@@ -33,6 +33,8 @@ NAMES = {
     "engine.stage.merge": "span",   # timed_run: a group's block tables merged into the table at once (args blocks, tables, merges)
     "engine.table.grow": "span",    # timed_run: table grown + its group merged again
     "engine.h2d": "span",           # one block padded + staged host->device
+    "engine.ingest.read": "span",   # one pull from the corpus source: a block read, split and padded (timed_run's reader thread, or inline in the first group)
+    "engine.ingest.wait": "span",   # the consumer of a read-ahead queue found it empty and waited for the reader (loader.prefetch_blocks)
     "engine.sync": "span",          # host blocked on the device (arg what)
     "engine.finalize": "span",      # table D2H + decode + host sort
     "engine.program.trace": "span", # jax traced a program (obs/programs.py)
@@ -83,6 +85,8 @@ NAMES = {
     "engine.programs_built": "counter",    # engines that built their configuration's programs (engine._programs_for)
     "engine.programs_shared": "counter",   # ... that took the ones the process already held
     "engine.combine_scatters": "gauge",    # scatters over the input rows that the configuration's segment combine issues (reduce_stage.combine_scatters)
+    "engine.ingest.blocks_ahead": "counter",   # read-ahead queue: blocks that were there when pulled (loader.prefetch_blocks)
+    "engine.ingest.blocks_waited": "counter",  # ... that the consumer waited for; ahead / (ahead + waited) is the hit share
     "engine.table_rows": "gauge",   # timed_run: the table's capacity at the job's end
     "engine.table_grows": "counter",  # timed_run: growth steps the job took
     "engine.merges": "counter",     # timed_run: merge programs launched (one a group + one a group redone)

@@ -38,6 +38,7 @@ plans without a backend.
 
 from __future__ import annotations
 
+import collections.abc
 import dataclasses
 
 from locust_tpu import obs
@@ -247,7 +248,10 @@ class CompiledPlan:
         """Execute the compiled plan.
 
         ``data`` feeds the source node(s): a rows array / list of line
-        bytes for a text source, an ``(src, dst)`` edge-array pair for
+        bytes for a text source — or, for the ``timed`` wordcount fold
+        alone, an ITERATOR of its host row blocks
+        (``iter(loader.StreamingCorpus(...))``), read a group ahead of the
+        device and never held whole —, an ``(src, dst)`` edge-array pair for
         an edges source, or a ``{input_name: data}`` dict when sources
         name distinct inputs (``source`` param ``input``; default
         ``"corpus"``).  ``timed`` routes the wordcount fold through
@@ -493,6 +497,13 @@ class _RunCtx:
             if isinstance(data, StagedRecords):  # CompiledPlan.load_records
                 return data
             return self.cp._record_sorter().load(data)
+        if isinstance(data, collections.abc.Iterator):
+            # The corpus as an iterator of host row blocks
+            # (io.loader.StreamingCorpus): handed on as rows are, never
+            # held whole.  Only the timed wordcount fold reads it
+            # (engine.timed_run, a group ahead of the device); it has no
+            # doc ids, and no other fold can index it.
+            return data, None
         from locust_tpu.core import bytes_ops
 
         cfg = self.cp.cfg
@@ -655,6 +666,16 @@ class _RunCtx:
         src_node = self.cp._stages[stage[2]][1]
         rows, ids = self.eval(stage[2])
         cfg, mesh = self.cp.cfg, self.cp.mesh
+        if ids is None and not (  # the source handed a block iterator on
+            fold == "wordcount" and self.timed
+            and not mesh and not self.checkpoint_dir
+        ):
+            raise PlanError(
+                f"source {src_node.id!r}: an iterator of row blocks feeds "
+                "the timed wordcount fold only (run(..., timed=True), no "
+                "mesh, no checkpoints); every other fold indexes its rows "
+                "— pass the rows array"
+            )
         if fold == "wordcount":
             if mesh:
                 from locust_tpu.parallel.mesh import make_mesh

@@ -49,6 +49,67 @@ def test_cli_line_range_sharding(corpus_file, capsysbinary):
     assert got == dict(py_wordcount([CORPUS.splitlines()[0]], 8))
 
 
+@pytest.mark.parametrize("span", [(), ("0", "1"), ("37", "171"), ("100", "-1"),
+                                  ("300", "400")])
+def test_cli_default_path_reads_ahead_and_prints_what_the_loaded_rows_give(
+    tmp_path, capsysbinary, monkeypatch, span
+):
+    """The default path opens the file as a block iterator and never loads
+    it whole (``loader.load_rows`` is not called): 26 blocks of 8 lines in
+    groups of 4, so a reader thread runs ahead of the device — CRLF ends,
+    a line over the width, an empty line, no newline at the end.  Its
+    stdout is byte-equal to the table of the same lines LOADED and run as
+    one array (what the CLI did before), whole and under
+    ``line_start/line_end`` slices, one past the end among them; stderr
+    says how many lines were read."""
+    from locust_tpu.config import EngineConfig
+    from locust_tpu.engine import MapReduceEngine
+    from locust_tpu.io import loader
+
+    words = [b"w%03d" % (i * i % 311) for i in range(1800)]
+    lines = [b" ".join(words[i:i + 9]) for i in range(0, 1800, 9)]
+    lines += [b"", b"x" * 70 + b" tail", b"cr lf\r", b"last line, no newline"]
+    path = tmp_path / "in.txt"
+    path.write_bytes(b"\n".join(lines))
+    start, end = (int(span[0]), int(span[1])) if span else (-1, -1)
+    eng = MapReduceEngine(EngineConfig(block_lines=8, line_width=64, emits_per_line=8))
+    rows = loader.load_rows(str(path), 64, start, end)
+    want = b"".join(k + b"\t" + str(v).encode() + b"\n"
+                    for k, v in eng.timed_run(rows).to_host_pairs())
+    block_bytes = 8 * 64 + 3 * (8 * 8) * (32 + 4 + 1)
+    monkeypatch.setattr(MapReduceEngine, "TIMED_GROUP_BYTES", 4 * block_bytes)
+
+    def no_load(*a, **k):
+        raise AssertionError("the default path loaded the corpus whole")
+
+    monkeypatch.setattr(loader, "load_rows", no_load)
+    assert cli.main([str(path), *span] + _cfg_args()) == 0
+    got = capsysbinary.readouterr()
+    assert got.out == want and (want or rows.shape[0] == 0)
+    assert b"[locust] %d lines loaded\n" % rows.shape[0] in got.err
+
+
+@pytest.mark.parametrize("flags", [["--no-timing"], ["--auto-caps"], ["--checkpoint-dir"]])
+def test_cli_flags_whose_loops_index_the_rows_still_load_them(
+    corpus_file, tmp_path, capsysbinary, monkeypatch, flags
+):
+    """``run_fused``, ``run_checkpointed`` and the ``--auto-caps`` measure
+    take the corpus as one array: those flags keep ``load_rows``, and the
+    table is the default path's."""
+    from locust_tpu.io import loader
+
+    loads = []
+    real = loader.load_rows
+    monkeypatch.setattr(loader, "load_rows",
+                        lambda *a, **k: loads.append(a) or real(*a, **k))
+    if flags == ["--checkpoint-dir"]:
+        flags = flags + [str(tmp_path / "ckpt")]
+    assert cli.main([corpus_file] + flags + _cfg_args()) == 0
+    assert len(loads) == 1
+    got = _parse_table(capsysbinary.readouterr().out)
+    assert got == dict(py_wordcount(CORPUS.splitlines(), 8))
+
+
 def test_cli_staged_map_then_reduce(corpus_file, tmp_path, capsysbinary):
     """Two map nodes shard the file; the reduce node merges both TSVs —
     the reference's distributed flow (SURVEY.md §3.2-3.3) minus the bugs."""

@@ -10,6 +10,10 @@ tables a merge program takes comes from a short ladder, whatever the job's
 size (``MapReduceEngine._timed_group_blocks``).  Tolerance: none.
 """
 
+import threading
+import time
+
+import numpy as np
 import pytest
 
 from helpers import py_wordcount
@@ -187,3 +191,119 @@ def test_the_ladder_has_at_most_seven_rungs(cfg_kw, full):
         assert eng._timed_group_blocks(470) == (41, 41)      # wc100.batch
         assert eng._timed_group_blocks(302) == (41, 41)      # wczipf.batch
         assert all(f < 2 * max(n, 1) for n, (_, f) in pairs.items())
+
+
+# --------------------------------------- an iterator of blocks in place of rows
+
+
+def _host_blocks(rows, bl=16):
+    """``rows`` as ``io.loader.StreamingCorpus`` hands a file on: fresh
+    arrays of ``bl`` rows in order, the last one short."""
+    return (rows[i:i + bl].copy() for i in range(0, len(rows), bl))
+
+
+def _threads_settle(before: int) -> bool:
+    """Do the live threads come back to ``before`` within five seconds?"""
+    deadline = time.time() + 5
+    while threading.active_count() > before and time.time() < deadline:
+        time.sleep(0.02)
+    return threading.active_count() <= before
+
+
+@pytest.mark.parametrize("sort_mode", ["hash", CHIP_MODE])
+@pytest.mark.parametrize("n_lines", [
+    0,                     # no line at all: one empty block, as an array of none
+    16,                    # one block
+    16 * 2 + 5,            # a short last block, inside the first group
+    16 * (G - 1),          # the whole source read inline, one short of a group
+    16 * G,                # exactly one group: the reader finds only the end
+    16 * G + 1,            # a second group of one line
+    16 * (2 * G + 3) + 7,  # three groups, the table grown on the way
+])
+def test_an_iterator_of_blocks_is_the_job_its_rows_are(
+    group_of_four, n_lines, sort_mode
+):
+    """``timed_run(iterator of host blocks)`` against ``timed_run(rows)``
+    on the same lines, pair for pair: table, ``num_segments``,
+    ``overflow_tokens`` (every fourth line is two words over the cap),
+    ``truncated``, the table's capacity; the SAME merge programs asked for
+    in the same order (capacity, tables) and the same ``blocks`` /
+    ``tables`` / ``merges`` on every merge stage — the group and the
+    fan-in do not depend on how the corpus comes; and no reader thread
+    left behind."""
+    lines = valued_lines(7 * -(-max(n_lines, 8) * 5 // 8), rounds=2)[:n_lines]
+    lines = [ln + b" x y z" * (i % 4 == 0) for i, ln in enumerate(lines)]
+    shapes = dict(_SMALL, sort_mode=sort_mode)
+    got = {}
+    before = threading.active_count()
+    for feed in ("rows", "blocks"):
+        tracer = obs.enable(process=feed)
+        eng = MapReduceEngine(EngineConfig(table_size=128, **shapes), valued_map)
+        calls = _spy_on_merge(eng)
+        rows = (eng.rows_from_lines(lines) if lines
+                else np.zeros((0, _SMALL["line_width"]), np.uint8))
+        res = eng.timed_run(rows if feed == "rows" else _host_blocks(rows))
+        got[feed] = (
+            res.to_host_pairs(), res.num_segments, res.overflow_tokens,
+            res.truncated, res.table.size, calls,
+            [(m["blocks"], m["tables"], m["merges"]) for m in _stage_merges(tracer)],
+        )
+        obs.disable()
+    assert got["blocks"] == got["rows"]
+    pairs, distinct, overflow, truncated, _, calls, _ = got["rows"]
+    kept = [b" ".join(ln.split()[:8]) for ln in lines]  # the cap's eight emits
+    assert pairs == valued_oracle(kept, "sum") and distinct == len(pairs)
+    assert not truncated and (overflow > 0) == (n_lines > 0)
+    assert (got["rows"][4] > 128) == (n_lines > 16)     # grown past its start
+    nblocks = max(1, -(-n_lines // 16))
+    assert {tables for _, tables in calls} == {
+        MapReduceEngine(EngineConfig(**shapes))._timed_group_blocks(nblocks)[1]}
+    assert _threads_settle(before)
+
+
+def test_a_reader_that_raises_is_raised_by_the_job_and_leaves_no_thread(group_of_four):
+    """A source that fails in its third group, while the reader thread is
+    ahead of the device: the job raises THAT error (no table of half a
+    file), and the thread is gone."""
+    eng = MapReduceEngine(EngineConfig(table_size=128, **_SMALL))
+    rows = eng.rows_from_lines(distinct_lines(16 * 8 * (2 * G + 2)))
+
+    def failing():
+        yield from _host_blocks(rows)
+        raise OSError("disk on fire")
+
+    before = threading.active_count()
+    with pytest.raises(OSError, match="disk on fire"):
+        eng.timed_run(failing())
+    assert _threads_settle(before)
+
+
+def test_a_job_that_fails_stops_its_reader(group_of_four):
+    """The device side fails in the second group of a long source: the
+    reader thread stops and the source is closed, far short of its end."""
+    eng = MapReduceEngine(EngineConfig(table_size=4096, **_SMALL))
+    block = eng.rows_from_lines(distinct_lines(16 * 8))
+    state = {"yielded": 0, "closed": False}
+
+    def source():
+        try:
+            for _ in range(1000):
+                state["yielded"] += 1
+                yield block.copy()
+        finally:
+            state["closed"] = True
+
+    calls = _spy_on_merge(eng)
+    real = eng._merge
+
+    def merge(acc, tables, seen):
+        if len(calls) == 1:
+            raise RuntimeError("device lost")
+        return real(acc, tables, seen)
+
+    eng._merge = merge
+    before = threading.active_count()
+    with pytest.raises(RuntimeError, match="device lost"):
+        eng.timed_run(source())
+    assert _threads_settle(before)
+    assert state["closed"] and state["yielded"] <= 3 * G + 2

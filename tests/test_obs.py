@@ -654,6 +654,64 @@ def test_enable_disable_cycles_leave_no_monitoring_listener():
     assert _listeners() == before
 
 
+@pytest.mark.parametrize("scope", ["process", "request"])
+def test_timed_run_records_its_reader_thread_into_the_jobs_tracer(
+    monkeypatch, scope,
+):
+    """An iterator of 9 blocks under a budget of 3 a group.  The first two
+    are read inline, the other seven and the end of the source by the
+    reader thread — every pull an engine.ingest.read span, on two threads,
+    all in the tracer the JOB records into: the process's, or a
+    request-scoped one (``obs.scoped``: tracers are thread-local, so the
+    reader is scoped into its consumer's).  A pull that found the queue
+    empty is an engine.ingest.wait span on the consumer's thread, outside
+    engine.h2d, and the blocks handed over are counted ahead or waited."""
+    monkeypatch.setattr(MapReduceEngine, "TIMED_GROUP_BYTES",
+                        3 * _SMALL_BLOCK_BYTES)
+    eng = MapReduceEngine(EngineConfig(**_SMALL))
+    rows = eng.rows_from_lines([b"alpha beta alpha", b"beta gamma"] * 36)
+    assert rows.shape[0] == 9 * 8
+    process = obs.enable(process="timed")
+    request = obs_trace.Tracer(process="request")
+    t = process if scope == "process" else request
+
+    def blocks():
+        for i in range(0, 72, 8):
+            if i == 2 * 8:       # the reader's first: its consumer must wait
+                time.sleep(0.2)
+            yield rows[i:i + 8].copy()
+
+    with obs.scoped(t):
+        pairs = eng.timed_run(blocks()).to_host_pairs()
+    assert pairs == [(b"alpha", 72), (b"beta", 72), (b"gamma", 36)]
+    events = [e for e in t.to_chrome()["traceEvents"] if e.get("ph") == "X"]
+    reads = [e for e in events if e["name"] == "engine.ingest.read"]
+    assert len(reads) == 9 + 1               # every block, and the end found
+    main = next(e["tid"] for e in events if e["name"] == "engine.stage.map")
+    assert [e["tid"] == main for e in sorted(reads, key=lambda e: e["ts"])] == (
+        [True] * 2 + [False] * 8)
+    assert len({e["tid"] for e in reads}) == 2
+    assert all("parent" not in e["args"] for e in reads if e["tid"] != main)
+    waits = [e for e in events if e["name"] == "engine.ingest.wait"]
+    assert waits and all(e["tid"] == main for e in waits)
+    by_id = {e["args"]["id"]: e for e in events}
+    assert not any(by_id[e["args"]["parent"]]["name"] == "engine.h2d"
+                   for e in waits if "parent" in e["args"])
+    assert len([e for e in events if e["name"] == "engine.h2d"]) == 9
+    other = request if scope == "process" else process
+    assert not [e for e in other.to_chrome()["traceEvents"]
+                if e.get("name", "").startswith("engine.")]
+    counters = obs.metrics_snapshot()["counters"]
+    if scope == "process":
+        # Metrics are the process's: a request-scoped job counts nothing.
+        ahead = counters["engine.ingest.blocks_ahead"]
+        waited = counters["engine.ingest.blocks_waited"]
+        assert ahead + waited == 7 and 1 <= waited <= len(waits)
+    else:
+        assert "engine.ingest.blocks_ahead" not in counters
+    validate_trace(t.to_chrome())
+
+
 def test_timed_run_with_tracing_off_allocates_no_span_and_no_listener(
     monkeypatch,
 ):
@@ -666,8 +724,10 @@ def test_timed_run_with_tracing_off_allocates_no_span_and_no_listener(
     assert obs.current() is None
     eng = MapReduceEngine(EngineConfig(**_SMALL))
     assert obs.watch_programs() is None
-    res = eng.timed_run(eng.rows_from_lines([b"a b a"] * 20))
-    assert res.to_host_pairs() == [(b"a", 40), (b"b", 20)]
+    rows = eng.rows_from_lines([b"a b a"] * 20)
+    for corpus in (rows, iter([rows[:8], rows[8:16], rows[16:]])):
+        res = eng.timed_run(corpus)
+        assert res.to_host_pairs() == [(b"a", 40), (b"b", 20)]
     assert _listeners() == before
 
 

@@ -440,6 +440,42 @@ def test_wordcount_plan_byte_identical_single_device():
     assert t.run_result is not None and t.value == pres.value
 
 
+@pytest.mark.parametrize("plan_kw, run_kw", [
+    ({}, dict(timed=True)),                       # the one fold that reads it
+    ({}, {}),                                     # run_fused stages the rows whole
+    ({}, dict(timed=True, checkpoint_dir="ck")),  # run_checkpointed indexes them
+    (dict(mesh=True), dict(timed=True)),
+    ("tfidf", {}),                                # needs doc ids a line
+])
+def test_a_block_iterator_feeds_the_timed_wordcount_fold_and_no_other(
+    tmp_path, plan_kw, run_kw
+):
+    """The source node hands an ITERATOR of host row blocks on as it hands
+    rows on; ``timed_run`` reads it (same value, same accounting as from
+    the rows), and every fold that indexes its rows refuses it by name."""
+    from locust_tpu.plan import PlanError
+
+    rows = _rows()
+    blocks = iter([rows[i:i + CFG.block_lines]
+                   for i in range(0, rows.shape[0], CFG.block_lines)])
+    if "checkpoint_dir" in run_kw:
+        run_kw = dict(run_kw, checkpoint_dir=str(tmp_path / "ck"))
+    if plan_kw == "tfidf":
+        cp = compile_plan(tfidf_plan(3), CFG)
+    else:
+        cp = compile_plan(wordcount_plan(), CFG, **plan_kw)
+    if run_kw == dict(timed=True) and plan_kw == {}:
+        want = compile_plan(wordcount_plan(), CFG).run(rows, timed=True)
+        got = cp.run(blocks, **run_kw)
+        assert (got.value, got.output, got.distinct, got.truncated,
+                got.overflow_tokens) == (want.value, want.output, want.distinct,
+                                         want.truncated, want.overflow_tokens)
+        assert got.run_result is not None
+    else:
+        with pytest.raises(PlanError, match="iterator of row blocks"):
+            cp.run(blocks, **run_kw)
+
+
 def test_wordcount_plan_byte_identical_mesh():
     from locust_tpu.parallel.mesh import make_mesh
     from locust_tpu.parallel.shuffle import DistributedMapReduce

@@ -61,17 +61,23 @@ def _grow_spans(tracer):
             if e.get("ph") == "X" and e["name"] == "engine.table.grow"]
 
 
+@pytest.mark.parametrize("feed", ["rows", "blocks"])
 @pytest.mark.parametrize("times, n_tokens, min_steps", [(3, 1500, 2), (20, 16000, 5)])
-def test_timed_run_is_exact_past_its_starting_capacity(monkeypatch, times, n_tokens, min_steps):
+def test_timed_run_is_exact_past_its_starting_capacity(monkeypatch, times, n_tokens,
+                                                       min_steps, feed):
     """A Zipf text whose vocabulary passes the 128-row start 3x and 20x:
-    two and more doubling steps, several groups, the oracle's table."""
+    two and more doubling steps, several groups, the oracle's table —
+    from the rows as one array, and from an iterator of their blocks read
+    a group ahead by a thread (the CLI's default path)."""
     monkeypatch.setattr(MapReduceEngine, "TIMED_GROUP_BYTES", 5 * _BLOCK_BYTES)
     lines = zipf_lines(n_tokens, 1 << 16, seed=times)
     want = py_wordcount(lines, 8)
     assert len(want) > times * 128
     tracer = obs.enable(process="grow")
     eng = MapReduceEngine(EngineConfig(table_size=128, **_SMALL))
-    res = eng.timed_run(eng.rows_from_lines(lines))
+    rows = eng.rows_from_lines(lines)
+    res = eng.timed_run(rows if feed == "rows" else (
+        rows[i:i + 16].copy() for i in range(0, len(rows), 16)))
     assert not res.truncated and res.num_segments == len(want)
     assert _table(res.to_host_pairs()) == _oracle(lines)
     steps = _grow_spans(tracer)
