@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import argparse
 import sys
+import time
 
 import numpy as np
 
@@ -156,6 +157,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    entered = time.time()  # cli.setup starts here, before a tracer can
     argv = sys.argv[1:] if argv is None else list(argv)
     # Workload-ladder subcommands (PageRank / inverted index / TF-IDF /
     # the record sort, cli_apps.py).  Dispatch on the first argument so
@@ -165,8 +167,9 @@ def main(argv=None) -> int:
     from locust_tpu import cli_apps
 
     if argv and argv[0] in SUBCOMMANDS:
-        return cli_apps.main(argv[0], argv[1:])
+        return cli_apps.main(argv[0], argv[1:], entered)
     args = build_parser().parse_args(argv)
+    args.entered = entered
     if args.trace_out:
         obs.enable(process="cli")
     try:
@@ -279,6 +282,7 @@ def _run(args) -> int:
     if args.auto_caps and args.stage in (STAGE_SINGLE, STAGE_MAP):
         import dataclasses
 
+        _setup_over(args)
         with timer.span("load"), obs.span("cli.load"):
             if args.stream:
                 # Bounded-memory measuring pass: the file is read twice
@@ -347,6 +351,7 @@ def _run(args) -> int:
         wc_plan = compile_plan(wordcount_plan(), cfg)
         lines_read: list[int] = []  # a block's, as the run reads it
         with prof:
+            _setup_over(args)
             with timer.span("load"), obs.span("cli.load"):
                 if args.stream:
                     rows = None
@@ -449,6 +454,7 @@ def _run(args) -> int:
 
     # STAGE_REDUCE: merge intermediate TSVs from map nodes; always re-sort (Q6).
     with prof:
+        _setup_over(args)
         with timer.span("load"), obs.span("cli.load"):
             key_rows_list, values_list = [], []
             for path in inter:
@@ -474,6 +480,17 @@ def _run(args) -> int:
     if args.trace:
         print(timer.report(), file=sys.stderr)
     return 0
+
+
+def _setup_over(args) -> None:
+    """``cli.setup``, recorded by whichever ``cli.load`` comes first: from
+    ``main``'s entry to here — the parser, backend selection, the lazy
+    imports, ``EngineConfig``, the plan compiled or the mesh engine made.
+    Recorded once it is over (``obs.span_at``): the tracer exists only
+    from the parsed arguments on."""
+    if args.trace_out and args.entered is not None:
+        obs.span_at("cli.setup", args.entered, time.time())
+        args.entered = None
 
 
 def _counted(lines_read: list[int], blocks):
@@ -509,10 +526,6 @@ def _run_mesh(args, cfg, timer, prof, preloaded_rows=None,
     all-to-all shuffle (parallel/shuffle.py), so a multi-chip host uses
     every chip.
     """
-    import time as _time
-
-    import numpy as np
-
     import jax
 
     from locust_tpu.io import loader, serde
@@ -545,7 +558,8 @@ def _run_mesh(args, cfg, timer, prof, preloaded_rows=None,
         )
     n_dev = dmr.n_dev
     with prof:
-        t0 = _time.perf_counter()
+        _setup_over(args)
+        t0 = time.perf_counter()
         with timer.span("load"), obs.span("cli.load"):
             kw = {}
             if args.checkpoint_dir:
@@ -579,7 +593,7 @@ def _run_mesh(args, cfg, timer, prof, preloaded_rows=None,
                 else dmr.run(rows, **kw)
             )
             pairs = res.to_host_pairs()  # gathers + syncs
-        run_ms = (_time.perf_counter() - t0) * 1e3
+        run_ms = (time.perf_counter() - t0) * 1e3
 
         # Per-shard report: one hash shard per shard_capacity rows (the
         # hierarchical table has devs_per_slice shards, the flat one n_dev)
@@ -647,11 +661,14 @@ def _print_table(pairs: list[tuple[bytes, int]], limit=None) -> None:
         return
     # One write: a table of 650,000 rows written a row at a time took 7.7 s
     # to a file on the chip's host, most of a job.
-    sys.stdout.buffer.write(b"".join(
-        k + b"\t" + str(v).encode() + b"\n"
-        for k, v in pairs[: limit if limit is not None else len(pairs)]
-    ))
-    sys.stdout.flush()
+    shown = pairs[: limit if limit is not None else len(pairs)]
+    with obs.span("cli.output.render", rows=len(shown)):
+        table = b"".join(
+            k + b"\t" + str(v).encode() + b"\n" for k, v in shown
+        )
+    with obs.span("cli.output.write", bytes=len(table)):
+        sys.stdout.buffer.write(table)
+        sys.stdout.flush()
 
 
 if __name__ == "__main__":

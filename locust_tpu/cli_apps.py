@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import argparse
 import sys
+import time
 
 import numpy as np
 
@@ -248,6 +249,8 @@ def run_sort(args, source) -> int:
     sort_plan = compile_plan(
         records_sort_plan(args.record_bytes, args.key_bytes), EngineConfig()
     )
+    if args.trace_out:  # main's entry to the first cli.load, once it is over
+        obs.span_at("cli.setup", args.entered, time.time())
     with obs.span("cli.load"):
         staged = sort_plan.load_records(source)
         print(f"[locust] {staged.n_records} records of "
@@ -263,8 +266,11 @@ def run_sort(args, source) -> int:
     return 0 if written == source.nbytes else 1
 
 
-def main(cmd: str, argv) -> int:
+def main(cmd: str, argv, entered: float) -> int:
+    """``entered``: ``time.time()`` at ``cli.main``'s entry, where the
+    job's ``cli.setup`` span starts."""
     args = build_parser(cmd).parse_args(argv)
+    args.entered = entered
     # Pure argument validation BEFORE backend resolution: a trivially
     # invalid invocation must not pay a backend init (and take the
     # chip) before its error prints.

@@ -75,24 +75,29 @@ class KVBatch:
             valid=jnp.zeros((n,), dtype=bool),
         )
 
-    def to_host_pairs(self, sort: bool = False) -> list[tuple[bytes, int]]:
-        """Host-side: decode live entries to (key bytes, value) pairs,
-        in device order or (``sort``) ordered by key in numpy first —
-        byte order of the NUL-padded rows, which is the keys' own unless
-        a key holds a NUL inside (the caller's ``sorted`` then has the
-        last word, and is linear on a list that is already in order).
-
-        ONE device_get for the whole batch (a single round trip — on remote
-        TPU links per-array fetches each pay full latency), lane unpacking
-        in numpy (big-endian reinterpret), and a decode over whole arrays
-        that is O(live entries), not O(table capacity).
-        """
-        lanes, values, valid = jax.device_get(
+    def to_host(self) -> "KVBatch":
+        """The batch with numpy leaves: ONE device_get for all three (a
+        single round trip — on remote TPU links per-array fetches each
+        pay full latency).  Leaves that are on the host already (a table
+        gathered from a mesh's shards) come back as they are."""
+        return KVBatch(*jax.device_get(
             (self.key_lanes, self.values, self.valid)
-        )
-        valid = np.asarray(valid)
-        live_lanes = np.asarray(lanes)[valid]
-        live_values = np.asarray(values)[valid]
+        ))
+
+    def host_pairs(self, sort: bool = False) -> list[tuple[bytes, int]]:
+        """Decode the live entries of a batch that is ON THE HOST
+        (``to_host``) to (key bytes, value) pairs, in table order or
+        (``sort``) ordered by key in numpy first — byte order of the
+        NUL-padded rows, which is the keys' own unless a key holds a NUL
+        inside (the caller's ``sorted`` then has the last word, and is
+        linear on a list that is already in order).
+
+        Lane unpacking in numpy (big-endian reinterpret), and a decode
+        over whole arrays that is O(live entries), not O(table capacity).
+        """
+        valid = np.asarray(self.valid)
+        live_lanes = np.asarray(self.key_lanes)[valid]
+        live_values = np.asarray(self.values)[valid]
         # big-endian uint32 lanes -> the original NUL-padded key bytes
         n_live, n_lanes = live_lanes.shape
         keys = live_lanes.astype(">u4").view(np.uint8).reshape(n_live, n_lanes * 4)
@@ -100,6 +105,11 @@ class KVBatch:
             order = np.argsort(keys.view(f"S{n_lanes * 4}").ravel(), kind="stable")
             keys, live_values = keys[order], live_values[order]
         return list(zip(bytes_ops.rows_to_strings(keys), live_values.tolist()))
+
+    def to_host_pairs(self, sort: bool = False) -> list[tuple[bytes, int]]:
+        """Host-side: the fetch (``to_host``) and the decode
+        (``host_pairs``) in one call."""
+        return self.to_host().host_pairs(sort)
 
 
 @jax.tree_util.register_dataclass

@@ -75,22 +75,36 @@ _HOST_COMBINE = {
 
 
 def finalize_host_pairs(
-    table: KVBatch, combine: str = "sum", sort: bool = True
+    table: KVBatch, combine: str = "sum", sort: bool = True,
+    fetch=KVBatch.to_host,
 ) -> list[tuple[bytes, int]]:
     """Decode a device table to host (key, value) pairs, exactly.
 
     Re-merges duplicate key rows (possible only via a full 64-bit hash
     collision in sort_mode="hash") and restores lexicographic key order —
     the reference's sorted final print (main.cu:473).
+
+    The table's way to the host, in three spans that each name one piece
+    of host work: the copy (``fetch``: one ``device_get``, or the mesh's
+    gather of its shards), the numpy decode, the Python check and sort.
     """
-    pairs = table.to_host_pairs(sort=sort)
-    if len(dict(pairs)) != len(pairs):  # a duplicate row: merge by hand
-        op = _HOST_COMBINE[combine]
-        merged: dict[bytes, int] = {}
-        for k, v in pairs:
-            merged[k] = op(merged[k], v) if k in merged else v
-        pairs = list(merged.items())
-    return sorted(pairs) if sort else pairs
+    with obs.span("engine.finalize.d2h", rows=table.size) as sp:
+        host = fetch(table)
+        sp.set(bytes=host.key_lanes.nbytes + host.values.nbytes
+               + host.valid.nbytes)
+    with obs.span("engine.finalize.decode") as sp:
+        pairs = host.host_pairs(sort=sort)
+        sp.set(rows=len(pairs))
+    with obs.span("engine.finalize.order", rows=len(pairs)) as sp:
+        merged = len(dict(pairs)) != len(pairs)
+        sp.set(merged=int(merged))
+        if merged:  # a duplicate row: merge by hand
+            op = _HOST_COMBINE[combine]
+            by_key: dict[bytes, int] = {}
+            for k, v in pairs:
+                by_key[k] = op(by_key[k], v) if k in by_key else v
+            pairs = list(by_key.items())
+        return sorted(pairs) if sort else pairs
 
 
 def _wrap_i32(v: int) -> int:

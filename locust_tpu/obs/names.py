@@ -24,9 +24,12 @@ NAMES = {
     "master.map_rpc": "span",       # master: one shard map attempt RPC
     "master.fetch": "span",         # master: one intermediate transfer
     "worker.map": "span",           # worker: one map command (runner incl.)
+    "cli.setup": "span",            # CLI: main's entry to the first cli.load — parser, backend, imports, EngineConfig, compile_plan (recorded once over, obs.span_at)
     "cli.load": "span",             # CLI: corpus ingest
     "cli.run": "span",              # CLI: the engine run
     "cli.output": "span",           # CLI: table print / intermediate write
+    "cli.output.render": "span",    # CLI: the table's rows joined into one buffer (arg rows)
+    "cli.output.write": "span",     # CLI: that buffer written and flushed (arg bytes)
     "engine.stage.map": "span",     # timed_run Map stage (per GROUP of blocks, arg blocks)
     "engine.stage.process": "span", # timed_run Process stage (per group)
     "engine.stage.reduce": "span",  # timed_run Reduce stage (per group)
@@ -37,11 +40,15 @@ NAMES = {
     "engine.ingest.wait": "span",   # the consumer of a read-ahead queue found it empty and waited for the reader (loader.prefetch_blocks)
     "engine.sync": "span",          # host blocked on the device (arg what)
     "engine.finalize": "span",      # table D2H + decode + host sort
+    "engine.finalize.d2h": "span",  # ... the device-to-host copy alone; on the mesh the gather of the shards (args bytes, rows)
+    "engine.finalize.decode": "span",  # ... live rows masked, lanes to key bytes, numpy argsort, rows to pairs (arg rows, live)
+    "engine.finalize.order": "span",   # ... the duplicate-key check, the merge by hand where it fires, sorted (args rows, merged)
     "engine.program.trace": "span", # jax traced a program (obs/programs.py)
     "engine.program.lower": "span", # ... lowered it to an MLIR module
     "engine.program.load": "span",  # ... compiled it or read it from the cache
     "stream.block": "span",         # run_stream: stage+dispatch of one block
     "mesh.round": "span",           # mesh: one round staged + dispatched (arg lines)
+    "mesh.h2d": "span",             # mesh: a round's lines placed on the devices, inside its mesh.round (arg bytes)
     "mesh.sync": "span",            # mesh: host blocked on the devices' stats (arg what: stats | regrow)
     "mesh.table.grow": "span",      # mesh: every shard grown a step, the rounds since the last whole table folded again (args from_rows, to_rows, worst_shard, rounds_redone)
     "mesh.gather": "span",          # mesh: table from its shards to sorted host pairs (args rows, shards)

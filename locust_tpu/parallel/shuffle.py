@@ -795,6 +795,10 @@ class DistributedMapReduce:
     ):
         if on_overflow not in ("retry", "drop"):
             raise ValueError(f"on_overflow must be 'retry' or 'drop', got {on_overflow!r}")
+        # As MapReduceEngine does: the step programs' trace, lower and
+        # load become engine.program.* spans of the round or the growth
+        # step they happen in.
+        obs.watch_programs()
         self.mesh = mesh
         self.cfg = cfg
         self.axis = axis_name
@@ -1254,7 +1258,8 @@ class DistributedMapReduce:
                 grows += 1
             with obs.span("mesh.round", lines=len(chunk)):
                 chunk = normalize_round_chunk(chunk, lpr, width)
-                sharded = (shard_fn or shard_rows)(chunk, self.mesh, self.axis)
+                with obs.span("mesh.h2d", bytes=chunk.nbytes):
+                    sharded = (shard_fn or shard_rows)(chunk, self.mesh, self.axis)
                 acc, leftover, stats = self._step_at(cap)(sharded, acc, leftover)
             if self.grows:
                 since.append(sharded)
@@ -1369,5 +1374,5 @@ class DistributedResult:
         with obs.span("mesh.gather", rows=self.table.size,
                       shards=self.table.size // self.shard_capacity):
             return finalize_host_pairs(
-                _gather_batch_host(self.table), self.combine, sort
+                self.table, self.combine, sort, fetch=_gather_batch_host
             )

@@ -305,3 +305,86 @@ def test_a_snapshot_carries_its_capacity_and_a_resume_goes_on_at_it(tmp_path):
     assert all(s["from_rows"] >= held for s in steps)
     # Only the rounds after the snapshot were folded.
     assert obs.metrics_snapshot()["counters"]["mesh.rounds"] == n_rounds - at
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("n_dev", [4, 8])
+def test_a_traced_mesh_job_says_what_its_round_and_its_gather_are_made_of(n_dev, mode):
+    """A mesh engine watches jax's pipeline as the default path's does: its
+    step programs' trace, lower and load are engine.program.* spans inside
+    the mesh.round or mesh.table.grow that paid for them (so mesh.round's
+    self time is the round without the reload); every round holds one
+    mesh.h2d, the sharded device_put alone; mesh.gather resolves into the
+    three spans of the table's way to the host, as engine.finalize does."""
+    lines = zipf_lines(6_000, 1 << 14, seed=n_dev)
+    want = py_wordcount(lines, 8)
+    tracer = obs.enable(process="meshtail")
+    dmr = _engine(n_dev, mode)
+    res = dmr.run(_rows(dmr, lines), stats_sync_every=4)
+    pairs = res.to_host_pairs()
+    assert dict(pairs) == want and pairs == sorted(pairs)
+    spans = [e for e in tracer.to_chrome()["traceEvents"] if e.get("ph") == "X"]
+    by_id = {e["args"]["id"]: e for e in spans}
+
+    def kids(parent):
+        return sorted((e for e in spans
+                       if e["args"].get("parent") == parent["args"]["id"]),
+                      key=lambda e: e["ts"])
+
+    rounds = [e for e in spans if e["name"] == "mesh.round"]
+    assert len(rounds) == -(-len(lines) // dmr.lines_per_round)
+    width = dmr.cfg.line_width
+    for r in rounds:
+        staged = [e for e in kids(r) if e["name"] == "mesh.h2d"]
+        assert len(staged) == 1
+        assert staged[0]["args"]["bytes"] == dmr.lines_per_round * width
+        assert r["ts"] <= staged[0]["ts"]
+        assert staged[0]["ts"] + staged[0]["dur"] <= r["ts"] + r["dur"] + 1.0
+    assert len([e for e in spans if e["name"] == "mesh.h2d"]) == len(rounds)
+    programs = [e for e in spans if e["name"].startswith("engine.program.")]
+    steps = [e for e in programs if "local_step" in e["args"]["fun_name"]]
+    assert {e["name"].rsplit(".", 1)[1] for e in steps} == {"trace", "lower", "load"}
+    homes = {by_id[e["args"]["parent"]]["name"] for e in steps}
+    assert "mesh.round" in homes and homes <= {"mesh.round", "mesh.table.grow"}
+    traced = [e for e in steps if e["name"] == "engine.program.trace"
+              and by_id[e["args"]["parent"]]["name"] == "mesh.round"]
+    assert traced and traced[0]["args"]["parent"] == rounds[0]["args"]["id"]
+    # The round without its reload: what the program spans cover is not
+    # the round's own time.
+    own = tracer.self_times()[rounds[0]["args"]["id"]]
+    assert own < rounds[0]["dur"] - max(e["dur"] for e in kids(rounds[0])
+                                         if e["name"] == "engine.program.load")
+    [gather] = [e for e in spans if e["name"] == "mesh.gather"]
+    tail = kids(gather)
+    assert [e["name"] for e in tail] == [
+        "engine.finalize.d2h", "engine.finalize.decode", "engine.finalize.order"]
+    assert all(gather["ts"] <= e["ts"] and e["ts"] + e["dur"]
+               <= gather["ts"] + gather["dur"] + 1.0 for e in tail)
+    assert tail[0]["args"]["rows"] == res.table.size == gather["args"]["rows"]
+    assert tail[0]["args"]["bytes"] == res.table.size * (res.table.num_lanes * 4 + 5)
+    assert tail[1]["args"]["rows"] == len(want) == tail[2]["args"]["rows"]
+    # A second job on the engine re-makes no program: its rounds hold
+    # their staging alone.
+    mark = len(spans)
+    assert dict(dmr.run(_rows(dmr, lines), stats_sync_every=4).to_host_pairs()) == want
+    later = [e for e in tracer.to_chrome()["traceEvents"] if e.get("ph") == "X"][mark:]
+    assert not [e for e in later if e["name"].startswith("engine.program.")
+                and "local_step" in e["args"]["fun_name"]]
+    assert len([e for e in later if e["name"] == "mesh.h2d"]) == len(rounds)
+
+
+def test_a_mesh_engine_made_with_tracing_off_registers_no_listener():
+    import jax._src.monitoring as m
+
+    before = (len(m.get_event_time_span_listeners()), len(m.get_event_listeners()))
+    _engine(4, MODES[0])
+    assert (len(m.get_event_time_span_listeners()),
+            len(m.get_event_listeners())) == before
+    obs.enable(process="meshwatch")
+    _engine(4, MODES[0])
+    _engine(4, MODES[0])  # a second engine: the same pair
+    assert (len(m.get_event_time_span_listeners()),
+            len(m.get_event_listeners())) == (before[0] + 1, before[1] + 1)
+    obs.disable()
+    assert (len(m.get_event_time_span_listeners()),
+            len(m.get_event_listeners())) == before

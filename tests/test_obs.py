@@ -157,6 +157,15 @@ def test_disabled_path_is_noop_and_within_bench_noise():
     assert s is obs.span("engine.stage.map") is obs.span("cli.run")
     assert s is obs.span("engine.h2d", bytes=1) is obs.span("engine.finalize")
     assert s is obs.span("engine.sync", what="map")
+    # the job's tail, the mesh round's staging and the CLI's set-up (PR 37)
+    assert s is obs.span("engine.finalize.d2h", rows=1)
+    assert s is obs.span("engine.finalize.decode")
+    assert s is obs.span("engine.finalize.order", rows=1)
+    assert s is obs.span("cli.output.render", rows=1)
+    assert s is obs.span("cli.output.write", bytes=1)
+    assert s is obs.span("mesh.h2d", bytes=1)
+    assert s.set(bytes=1, merged=0) is None
+    assert obs.span_at("cli.setup", 0.0, 1.0) is None
     assert obs.event("stream.stall", ms=0.0) is None
     assert obs.metric_inc("stream.blocks") is None
     assert obs.span_at("engine.program.load", 0.0, 1.0) is None
@@ -731,6 +740,212 @@ def test_timed_run_with_tracing_off_allocates_no_span_and_no_listener(
     assert _listeners() == before
 
 
+# ---------------------------- the job's tail and the CLI's set-up (PR 37)
+
+
+TAIL = ("engine.finalize.d2h", "engine.finalize.decode", "engine.finalize.order")
+
+
+def _tail_children(spans, parent):
+    """The three spans of the table's way to the host under ``parent``,
+    held to: one each, in order, inside the parent, covering it."""
+    kids = sorted((e for e in spans
+                   if e["args"].get("parent") == parent["args"]["id"]),
+                  key=lambda e: e["ts"])
+    assert [e["name"] for e in kids] == list(TAIL)
+    assert all(_encloses(parent, e) for e in kids)
+    assert all(a["ts"] + a["dur"] <= b["ts"] + 1.0 for a, b in zip(kids, kids[1:]))
+    assert sum(e["dur"] for e in kids) >= 0.9 * parent["dur"], (kids, parent)
+    return kids
+
+
+def _many_words(n):
+    return [b" ".join(b"w%05d" % (7 * i + j) for j in range(4))
+            for i in range(n)]
+
+
+def test_the_tables_way_to_the_host_is_three_children_of_engine_finalize():
+    """engine.finalize (the one of ``to_host_pairs``; the count read of
+    ``_finish`` keeps its own, childless) resolves into the copy, the
+    numpy decode and the Python check + sort: one each, in that order,
+    none outlasting it, together at least nine tenths of it."""
+    eng = MapReduceEngine(EngineConfig(block_lines=256, line_width=32,
+                                       key_width=8, emits_per_line=4))
+    lines = _many_words(4096)
+    rows = eng.rows_from_lines(lines)
+    eng.timed_run(rows).to_host_pairs()  # programs built, caches warm
+    t = obs.enable(process="tail")
+    pairs = eng.timed_run(rows).to_host_pairs()
+    assert dict(pairs) == py_wordcount(lines) and pairs == sorted(pairs)
+    spans = _spans(t)
+    count_read, decode = [e for e in spans if e["name"] == "engine.finalize"]
+    assert not [e for e in spans
+                if e["args"].get("parent") == count_read["args"]["id"]]
+    d2h, dec, order = _tail_children(spans, decode)
+    table = eng.timed_run(rows).table
+    assert d2h["args"]["rows"] == table.size == decode["args"]["rows"]
+    assert d2h["args"]["bytes"] == table.size * (table.num_lanes * 4 + 4 + 1)
+    assert dec["args"]["rows"] == len(pairs) == order["args"]["rows"]
+    assert order["args"]["merged"] == 0
+    assert [e["name"] for e in spans if e["name"] in TAIL] == list(TAIL)
+    validate_trace(t.to_chrome())
+
+
+def _parent_to_host_pairs(batch, sort):
+    """``KVBatch.to_host_pairs`` as the parent of PR 37 had it, in one
+    piece: the reference for the cut into ``to_host`` + ``host_pairs``."""
+    import jax
+
+    from locust_tpu.core import bytes_ops
+
+    lanes, values, valid = jax.device_get(
+        (batch.key_lanes, batch.values, batch.valid))
+    valid = np.asarray(valid)
+    live_lanes = np.asarray(lanes)[valid]
+    live_values = np.asarray(values)[valid]
+    n_live, n_lanes = live_lanes.shape
+    keys = live_lanes.astype(">u4").view(np.uint8).reshape(n_live, n_lanes * 4)
+    if sort and n_live:
+        order = np.argsort(keys.view(f"S{n_lanes * 4}").ravel(), kind="stable")
+        keys, live_values = keys[order], live_values[order]
+    return list(zip(bytes_ops.rows_to_strings(keys), live_values.tolist()))
+
+
+def _parent_finalize(batch, combine, sort):
+    pairs = _parent_to_host_pairs(batch, sort)
+    if len(dict(pairs)) != len(pairs):
+        op = {"sum": lambda a, b: a + b, "count": lambda a, b: a + b,
+              "min": min, "max": max}[combine]
+        merged = {}
+        for k, v in pairs:
+            merged[k] = op(merged[k], v) if k in merged else v
+        pairs = list(merged.items())
+    return sorted(pairs) if sort else pairs
+
+
+_LAYOUTS = {
+    # keys (8 bytes wide), values, valid, whether the merge by hand fires
+    "plain": ([b"pear", b"apple", b"fig"], [3, 1, 2], [1, 1, 1], 0),
+    "a NUL inside a key": ([b"ab\0z", b"ab", b"a\0b", b"a"], [1, 2, 3, 4], [1, 1, 1, 1], 1),  # cut at the NUL, so two pairs of equal keys
+    "a forced duplicate row": ([b"dup", b"solo", b"dup", b"dup"], [5, 1, 7, -2], [1, 1, 1, 1], 1),
+    "dead rows between live ones": ([b"x", b"", b"y", b"x"], [1, 9, 2, 4], [1, 0, 1, 0], 0),
+    "nothing live": ([b"x", b"y"], [1, 2], [0, 0], 0),
+}
+
+
+@pytest.mark.parametrize("on_host", [False, True], ids=["device", "gathered"])
+@pytest.mark.parametrize("combine", ["sum", "min"])
+@pytest.mark.parametrize("sort", [True, False])
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+def test_finalize_equals_the_parents_in_one_piece(layout, sort, combine, on_host):
+    """The cut of ``KVBatch.to_host_pairs`` into fetch and decode, and of
+    ``finalize_host_pairs`` into three spans, changes no table: pair for
+    pair the parent's, traced and untraced, for a device table and for
+    one the mesh has gathered to numpy leaves already."""
+    import jax.numpy as jnp
+
+    from locust_tpu.core import bytes_ops
+    from locust_tpu.core.kv import KVBatch
+    from locust_tpu.engine import finalize_host_pairs
+
+    keys, values, valid, merged = _LAYOUTS[layout]
+    batch = KVBatch.from_bytes(
+        jnp.asarray(bytes_ops.strings_to_rows(keys, 8)),
+        jnp.asarray(np.asarray(values, np.int32)),
+        jnp.asarray(np.asarray(valid, bool)))
+    want = _parent_finalize(batch, combine, sort)
+    fetch = {}
+    if on_host:  # as DistributedResult.to_host_pairs hands its gather in
+        fetch = {"fetch": lambda table: KVBatch(
+            np.asarray(table.key_lanes), np.asarray(table.values),
+            np.asarray(table.valid))}
+    assert batch.to_host_pairs(sort) == _parent_to_host_pairs(batch, sort)
+    assert batch.to_host().host_pairs(sort) == _parent_to_host_pairs(batch, sort)
+    assert finalize_host_pairs(batch, combine, sort, **fetch) == want
+    t = obs.enable(process="cut")
+    assert finalize_host_pairs(batch, combine, sort, **fetch) == want
+    spans = _spans(t)
+    assert [e["name"] for e in spans] == list(TAIL)
+    live = sum(valid)
+    assert spans[1]["args"]["rows"] == live == spans[2]["args"]["rows"]
+    assert spans[2]["args"]["merged"] == merged
+
+
+@pytest.mark.parametrize("limit", [None, 0, 2, 99])
+def test_print_table_writes_the_parents_bytes_in_two_spans(limit, capsysbinary):
+    pairs = [(b"a\0b", 3), (b"apple", -1), (b"fig", 2147483647)]
+    want = b"".join(k + b"\t" + str(v).encode() + b"\n"
+                    for k, v in pairs[: limit if limit is not None else len(pairs)])
+    cli._print_table(pairs, limit)
+    assert capsysbinary.readouterr().out == want
+    t = obs.enable(process="print")
+    with obs.span("cli.output"):
+        cli._print_table(pairs, limit)
+    assert capsysbinary.readouterr().out == want
+    out, render, write = sorted(_spans(t), key=lambda e: e["ts"])
+    assert [render["name"], write["name"]] == ["cli.output.render", "cli.output.write"]
+    assert render["args"]["parent"] == write["args"]["parent"] == out["args"]["id"]
+    assert _encloses(out, render) and _encloses(out, write)
+    assert render["args"]["rows"] == want.count(b"\n")
+    assert write["args"]["bytes"] == len(want)
+
+
+@pytest.mark.parametrize("flags", [[], ["--mesh"], ["--stream"], ["--no-timing"]],
+                         ids=["default", "mesh", "stream", "no-timing"])
+def test_cli_setup_ends_where_the_first_load_starts(flags, tmp_path, capsysbinary):
+    """cli.setup runs from main's entry to the job's first cli.load and is
+    recorded once: the plan's compile lies inside it (the default path's;
+    the mesh makes its engine there instead), cli.load starts where it
+    ends, and cli.output holds the render and the write."""
+    corpus = tmp_path / "c.txt"
+    corpus.write_bytes(CORPUS * 4)
+    argv = [str(corpus), "--backend", "cpu", "--block-lines", "8",
+            "--line-width", "32", "--key-width", "8", "--emits-per-line", "4"]
+    assert cli.main(argv + flags) == 0
+    untraced = capsysbinary.readouterr().out
+    out = tmp_path / "t.trace.json"
+    t0 = time.time()
+    assert cli.main(argv + flags + ["--trace-out", str(out)]) == 0
+    assert capsysbinary.readouterr().out == untraced  # stdout byte-equal
+    doc = json.load(open(out))
+    validate_trace(doc)
+    spans = _spans(doc)
+    [setup] = [e for e in spans if e["name"] == "cli.setup"]
+    load = min((e for e in spans if e["name"] == "cli.load"), key=lambda e: e["ts"])
+    assert "parent" not in setup["args"]
+    assert t0 * 1e6 - 1e3 <= setup["ts"] and setup["dur"] > 0
+    assert setup["ts"] + setup["dur"] <= load["ts"] + 50.0
+    assert load["ts"] - (setup["ts"] + setup["dur"]) < 5e3
+    compiled = [e for e in spans if e["name"] == "plan.compile"]
+    assert len(compiled) == (0 if "--mesh" in flags else 1)
+    assert all(_encloses(setup, e, slack_us=50.0) for e in compiled)
+    [output] = [e for e in spans if e["name"] == "cli.output"]
+    kids = sorted((e for e in spans if e["name"].startswith("cli.output.")),
+                  key=lambda e: e["ts"])
+    assert [e["name"] for e in kids] == ["cli.output.render", "cli.output.write"]
+    assert all(e["args"]["parent"] == output["args"]["id"] for e in kids)
+    assert kids[1]["args"]["bytes"] == len(untraced)
+    assert kids[0]["args"]["rows"] == untraced.count(b"\n")
+    parents = {e["args"]["id"]: e["name"] for e in spans}
+    tails = [parents[e["args"]["parent"]] for e in spans if e["name"] in TAIL]
+    assert tails == ["mesh.gather" if "--mesh" in flags else "engine.finalize"] * 3
+
+
+def test_sort_command_records_cli_setup_before_its_load(tmp_path, capsys):
+    src, dst, out = tmp_path / "in", tmp_path / "out", tmp_path / "t.trace.json"
+    src.write_bytes(np.random.default_rng(5).integers(
+        0, 256, 64 * 100, dtype=np.uint8).tobytes())
+    assert cli.main(["sort", str(src), str(dst), "--backend", "cpu",
+                     "--trace-out", str(out)]) == 0
+    capsys.readouterr()
+    spans = _spans(json.load(open(out)))
+    [setup] = [e for e in spans if e["name"] == "cli.setup"]
+    [load] = [e for e in spans if e["name"] == "cli.load"]
+    assert setup["ts"] + setup["dur"] <= load["ts"] + 50.0
+    compiled = [e for e in spans if e["name"] == "plan.compile"]
+    assert compiled and all(_encloses(setup, e, slack_us=50.0) for e in compiled)
+
+
 def _host_annotations(xplane_path):
     from jax.profiler import ProfileData
 
@@ -806,7 +1021,8 @@ def test_cli_profile_dir_and_trace_out_share_one_xplane(tmp_path, capsys):
     assert all(isinstance(e["args"]["id"], int) for e in spans)
     roots = [e for e in spans if "parent" not in e["args"]]
     assert {e["name"] for e in roots} <= {
-        "cli.load", "cli.run", "cli.output", "plan.optimize", "plan.compile",
+        "cli.setup", "cli.load", "cli.run", "cli.output", "plan.optimize",
+        "plan.compile",
         "engine.program.trace", "engine.program.lower", "engine.program.load",
     }
     assert all(e["args"]["trace_id"] == doc["otherData"]["trace_id"]
