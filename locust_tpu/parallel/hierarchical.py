@@ -33,9 +33,11 @@ protocol cannot diverge between the two.
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import math
 import os
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -44,12 +46,16 @@ from jax.sharding import PartitionSpec as P
 
 from locust_tpu.config import EngineConfig
 from locust_tpu.core.kv import KVBatch
+from locust_tpu.engine import _programs_for
 from locust_tpu.ops.map_stage import wordcount_map
 from locust_tpu.ops.hash_table import reduce_into
 from locust_tpu.ops.reduce_stage import normalize_combine
 from locust_tpu.parallel.mesh import DATA_AXIS, SLICE_AXIS
 from locust_tpu.parallel.shuffle import (
     RoundStats,
+    _fused_mesh_gate,
+    _kv_spec,
+    _mesh_check_vma,
     _round_up,
     build_shuffle_step,
     drive_checkpointed_rounds,
@@ -59,6 +65,142 @@ from locust_tpu.parallel.shuffle import (
 )
 
 logger = logging.getLogger("locust_tpu")
+
+
+@dataclasses.dataclass(frozen=True)
+class _HierarchicalPrograms:
+    """The jitted programs of one hierarchical-mesh configuration
+    (``_build_hierarchical_programs``): the process's one record of it
+    (``engine._programs_for``)."""
+
+    step: Callable             # (lines, acc, leftover) -> (acc, leftover, stats)
+    combine: Callable          # acc -> (table replicated over slices, stats)
+    combine_dbg: Callable      # the same merge with the slice axis exposed
+    stats_merge: Callable      # merge_stats_vectors
+    replicate_stats: Callable  # slice-varying stats -> replicated
+
+
+def _build_hierarchical_programs(
+    map_fn,
+    combine: str,
+    cfg: EngineConfig,
+    mesh: jax.sharding.Mesh,
+    slice_axis: str,
+    data_axis: str,
+    bin_capacity: int,
+    shard_capacity: int,
+    leftover_capacity: int,
+    max_drains: int,
+    fused_preagg: bool,
+    check_vma: bool,
+) -> _HierarchicalPrograms:
+    """Define and jit the two-level engine's programs.  ``map_fn`` and
+    ``combine`` are the NORMALIZED pair; nothing here names an engine, so
+    engines share the record and a dead engine is no reference cycle."""
+    both = (slice_axis, data_axis)
+    local_step = build_shuffle_step(
+        cfg,
+        map_fn,
+        combine,
+        n_bins=int(mesh.shape[data_axis]),
+        bin_capacity=bin_capacity,
+        shard_capacity=shard_capacity,
+        leftover_capacity=leftover_capacity,
+        max_drains=max_drains,
+        shuffle_axis=data_axis,     # the ICI-only shuffle
+        stat_axes=(data_axis,),     # stats stay intra-slice per round
+        fused_preagg=fused_preagg,
+    )
+
+    def combine_step(acc: KVBatch):
+        """The ONE cross-slice (DCN) collective: gather shard-aligned
+        table copies over the slice axis, merge locally."""
+        lanes = jax.lax.all_gather(
+            acc.key_lanes, slice_axis, axis=0, tiled=True
+        )
+        values = jax.lax.all_gather(acc.values, slice_axis, axis=0, tiled=True)
+        valid = jax.lax.all_gather(acc.valid, slice_axis, axis=0, tiled=True)
+        gathered = KVBatch(key_lanes=lanes, values=values, valid=valid)
+        # reduce_into dispatches sort vs the "hasht" sort-free fold
+        # (no collectives inside; the all_gathers above already ran).
+        merged, distinct = reduce_into(
+            gathered, shard_capacity, combine, cfg.sort_mode
+        )
+        # Global distinct: shards are hash-disjoint within a slice
+        # column, identical across slices post-merge -> sum over data.
+        g_distinct = jax.lax.psum(distinct, data_axis)
+        worst = jax.lax.pmax(distinct, both)
+        return merged, jnp.stack([g_distinct, worst])
+
+    kv_spec_2d = _kv_spec(both)
+    kv_spec_data = _kv_spec(data_axis)
+    # Stats are reduced over the DATA axis only, so the vector is
+    # replicated within a slice but VARIES across slices — out_spec
+    # P(slice) gives the host a [n_slices * 6] stack to fold at sync
+    # time.  This keeps the round path free of cross-slice collectives.
+    step = jax.jit(
+        jax.shard_map(
+            local_step,
+            mesh=mesh,
+            in_specs=(P(both), kv_spec_2d, kv_spec_2d),
+            out_specs=(kv_spec_2d, kv_spec_2d, P(slice_axis)),
+            check_vma=check_vma,
+        )
+    )
+    # Output of the final combine is REPLICATED over the slice axis:
+    # every device in a column runs the identical deterministic merge
+    # of the identical all_gather result.  jax's varying-axes check
+    # cannot infer replication through all_gather statically, so it is
+    # disabled for THIS shard_map only (the claim is load-bearing and
+    # tested: tests assert the combined table equals the oracle).
+    combine_all = jax.jit(
+        jax.shard_map(
+            combine_step,
+            mesh=mesh,
+            in_specs=(kv_spec_2d,),
+            out_specs=(kv_spec_data, P()),
+            check_vma=False,
+        )
+    )
+    # Debug-mode self-policing of the replication claim behind
+    # check_vma=False above: the SAME combine
+    # body, but with out_specs that EXPOSE the slice axis instead of
+    # asserting replication over it, so the host can compare the
+    # per-slice tables byte-for-byte at finalize under
+    # LOCUST_DEBUG_CHECKS.  If a future combine edit lets
+    # slice-varying data leak into the merge, the comment's argument
+    # rots silently — this check fires loudly instead.
+    combine_dbg = jax.jit(
+        jax.shard_map(
+            combine_step,
+            mesh=mesh,
+            in_specs=(kv_spec_2d,),
+            out_specs=(kv_spec_2d, P(slice_axis)),
+            check_vma=False,
+        )
+    )
+    # Stats leave the step VARYING over the slice axis; on a
+    # multi-process pod a plain device_get of that stack would touch
+    # non-addressable devices.  This tiny replicating gather runs only
+    # at SYNC time (every stats_sync_every rounds), so it — not the
+    # round path — carries the cross-slice hop.
+    replicate_stats = jax.jit(
+        jax.shard_map(
+            lambda s: jax.lax.all_gather(s, slice_axis, axis=0, tiled=True),
+            mesh=mesh,
+            in_specs=(P(slice_axis),),
+            out_specs=P(),
+            check_vma=False,
+        )
+    )
+
+    return _HierarchicalPrograms(
+        step=step,
+        combine=combine_all,
+        combine_dbg=combine_dbg,
+        stats_merge=jax.jit(merge_stats_vectors),
+        replicate_stats=replicate_stats,
+    )
 
 
 class HierarchicalMapReduce:
@@ -123,133 +265,38 @@ class HierarchicalMapReduce:
             raise ValueError(f"shard_capacity must be >= 1, got {self.shard_capacity}")
         self.leftover_capacity = cfg.emits_per_block
         self.max_drain_rounds = 2 + -(-cfg.emits_per_block // self.bin_capacity)
-        both = (slice_axis, data_axis)
-
         norm_map_fn, norm_combine = normalize_combine(map_fn, combine)
         # sort_mode="fused" (megakernel v2): per-shard Pallas kernel when
         # eligible, explicit logged demotion (fused_demoted on results)
         # otherwise — same gate as the flat engine (shuffle.py).
-        from locust_tpu.parallel.shuffle import _fused_mesh_gate
-
         self._fused_kernel_on, self.fused_demoted = _fused_mesh_gate(
             cfg, map_fn, combine, engine="hierarchical"
         )
-        local_step = build_shuffle_step(
-            cfg,
-            norm_map_fn,
-            norm_combine,
-            n_bins=self.devs_per_slice,
-            bin_capacity=self.bin_capacity,
-            shard_capacity=self.shard_capacity,
-            leftover_capacity=self.leftover_capacity,
-            max_drains=self.max_drain_rounds,
-            shuffle_axis=data_axis,     # the ICI-only shuffle
-            stat_axes=(data_axis,),     # stats stay intra-slice per round
-            fused_preagg=self._fused_kernel_on,
+        # The five programs belong to the configuration, not to this
+        # engine (engine._programs_for, as the flat mesh's do): a process's
+        # second engine of an equal configuration takes the same jit
+        # objects and traces, lowers and reads back nothing.  The key is
+        # what _build_hierarchical_programs takes, the RAW (map_fn,
+        # combine) standing for the normalized pair they determine.  The
+        # attributes below are this engine's own: a test that assigns
+        # ``h._step`` changes no other engine.
+        config = (
+            cfg, mesh, slice_axis, data_axis, self.bin_capacity,
+            self.shard_capacity, self.leftover_capacity,
+            self.max_drain_rounds, self._fused_kernel_on,
+            _mesh_check_vma(cfg, self._fused_kernel_on),
         )
-
-        def combine_step(acc: KVBatch):
-            """The ONE cross-slice (DCN) collective: gather shard-aligned
-            table copies over the slice axis, merge locally."""
-            lanes = jax.lax.all_gather(
-                acc.key_lanes, slice_axis, axis=0, tiled=True
-            )
-            values = jax.lax.all_gather(acc.values, slice_axis, axis=0, tiled=True)
-            valid = jax.lax.all_gather(acc.valid, slice_axis, axis=0, tiled=True)
-            gathered = KVBatch(key_lanes=lanes, values=values, valid=valid)
-            # reduce_into dispatches sort vs the "hasht" sort-free fold
-            # (no collectives inside; the all_gathers above already ran).
-            merged, distinct = reduce_into(
-                gathered, self.shard_capacity, norm_combine, cfg.sort_mode
-            )
-            # Global distinct: shards are hash-disjoint within a slice
-            # column, identical across slices post-merge -> sum over data.
-            g_distinct = jax.lax.psum(distinct, data_axis)
-            worst = jax.lax.pmax(distinct, both)
-            return merged, jnp.stack([g_distinct, worst])
-
-        kv_spec_2d = KVBatch(
-            key_lanes=P(both), values=P(both), valid=P(both)
+        programs: _HierarchicalPrograms = _programs_for(
+            ("hierarchical", map_fn, combine, *config),
+            lambda: _build_hierarchical_programs(
+                norm_map_fn, norm_combine, *config
+            ),
         )
-        kv_spec_data = KVBatch(
-            key_lanes=P(data_axis), values=P(data_axis), valid=P(data_axis)
-        )
-        # Stats are reduced over the DATA axis only, so the vector is
-        # replicated within a slice but VARIES across slices — out_spec
-        # P(slice) gives the host a [n_slices * 6] stack to fold at sync
-        # time.  This keeps the round path free of cross-slice collectives.
-        # check_vma off for sort_mode="bitonic" ON TPU, like the flat
-        # engine (shuffle.py ctor, incl. the rationale for the TPU-only
-        # condition: the off-TPU interpret kernel inside a mesh program
-        # segfaults XLA's CPU compiler): jax's vma machinery cannot
-        # trace the Pallas kernel, and with the check on, the round step
-        # would silently measure the stock-sort fallback instead of the
-        # hand-written kernel.
-        self._step = jax.jit(
-            jax.shard_map(
-                local_step,
-                mesh=mesh,
-                in_specs=(P(both), kv_spec_2d, kv_spec_2d),
-                out_specs=(kv_spec_2d, kv_spec_2d, P(slice_axis)),
-                # fused kernel engaged implies TPU (fused_mesh_eligible),
-                # so like the flat engine the check is only dropped on
-                # TPU — CPU mesh programs never trace a Pallas kernel.
-                check_vma=not (
-                    (
-                        cfg.sort_mode == "bitonic"
-                        and jax.default_backend() == "tpu"
-                    )
-                    or self._fused_kernel_on
-                ),
-            )
-        )
-        # Output of the final combine is REPLICATED over the slice axis:
-        # every device in a column runs the identical deterministic merge
-        # of the identical all_gather result.  jax's varying-axes check
-        # cannot infer replication through all_gather statically, so it is
-        # disabled for THIS shard_map only (the claim is load-bearing and
-        # tested: tests assert the combined table equals the oracle).
-        self._combine = jax.jit(
-            jax.shard_map(
-                combine_step,
-                mesh=mesh,
-                in_specs=(kv_spec_2d,),
-                out_specs=(kv_spec_data, P()),
-                check_vma=False,
-            )
-        )
-        # Debug-mode self-policing of the replication claim behind
-        # check_vma=False above: the SAME combine
-        # body, but with out_specs that EXPOSE the slice axis instead of
-        # asserting replication over it, so the host can compare the
-        # per-slice tables byte-for-byte at finalize under
-        # LOCUST_DEBUG_CHECKS.  If a future combine edit lets
-        # slice-varying data leak into the merge, the comment's argument
-        # rots silently — this check fires loudly instead.
-        self._combine_dbg = jax.jit(
-            jax.shard_map(
-                combine_step,
-                mesh=mesh,
-                in_specs=(kv_spec_2d,),
-                out_specs=(kv_spec_2d, P(slice_axis)),
-                check_vma=False,
-            )
-        )
-        self._stats_merge = jax.jit(merge_stats_vectors)
-        # Stats leave the step VARYING over the slice axis; on a
-        # multi-process pod a plain device_get of that stack would touch
-        # non-addressable devices.  This tiny replicating gather runs only
-        # at SYNC time (every stats_sync_every rounds), so it — not the
-        # round path — carries the cross-slice hop.
-        self._replicate_stats = jax.jit(
-            jax.shard_map(
-                lambda s: jax.lax.all_gather(s, slice_axis, axis=0, tiled=True),
-                mesh=mesh,
-                in_specs=(P(slice_axis),),
-                out_specs=P(),
-                check_vma=False,
-            )
-        )
+        self._step = programs.step
+        self._combine = programs.combine
+        self._combine_dbg = programs.combine_dbg
+        self._stats_merge = programs.stats_merge
+        self._replicate_stats = programs.replicate_stats
 
     def _fetch_stats(self, stats):
         return jax.device_get(self._replicate_stats(stats))

@@ -26,8 +26,12 @@ fully determined by the hash function and key order.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import logging
 import math
+import threading
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +42,7 @@ from locust_tpu import obs
 from locust_tpu.config import HASHT_FAMILY, EngineConfig
 from locust_tpu.core import packing
 from locust_tpu.core.kv import KVBatch, grow_table, rows_to_hold
+from locust_tpu.engine import _programs_for
 from locust_tpu.io.snapshot import AsyncCheckpointWriter, finalize_snapshot
 from locust_tpu.ops.map_stage import wordcount_map
 from locust_tpu.ops.hash_table import fold_into, reduce_into
@@ -762,6 +767,147 @@ def _fused_mesh_gate(
     return ok, not ok
 
 
+def _mesh_check_vma(cfg: EngineConfig, fused_kernel_on: bool) -> bool:
+    """``check_vma`` of a mesh engine's step program (both engines).
+
+    Disabled for sort_mode="bitonic" ON TPU so the hand-written Pallas
+    kernel actually RUNS on mesh engines.  Under check_vma=True the
+    kernel cannot trace — jax's vma machinery breaks inside the pallas
+    interpret re-trace (verified this jax version: "Primitive lt requires
+    varying manual axes to match") — and process_stage._bitonic_sort
+    would silently serve the stock lax.sort formulation instead.  With
+    the check off, vma types are absent, the kernel traces, and mesh
+    bitonic is oracle-exact.  TPU-only because the off-TPU INTERPRET
+    kernel inside a full mesh program has twice segfaulted XLA's CPU
+    compiler (thread stack overflow in libjax_common.so, kernel log
+    2026-07-31) nondeterministically — on CPU the engines keep
+    check_vma=True, so _bitonic_sort takes its loud stock-formulation
+    fallback there; the kernel's shard_map traceability itself is pinned
+    by a direct small test (tests/test_distributed.py).  The cost on TPU
+    is losing jax's replication checking for this one mode; the engines'
+    outputs are oracle-tested per mode.  The fused kernel engaged implies
+    a TPU backend (fused_mesh_eligible), so the check is only ever
+    dropped on TPU — the CPU engines keep check_vma=True and never trace
+    a Pallas kernel in a mesh program.
+    """
+    return not (
+        (cfg.sort_mode == "bitonic" and jax.default_backend() == "tpu")
+        or fused_kernel_on
+    )
+
+
+def _kv_spec(axis) -> KVBatch:
+    """A KVBatch whose every leaf is sharded over ``axis``."""
+    return KVBatch(key_lanes=P(axis), values=P(axis), valid=P(axis))
+
+
+def _build_mesh_step(
+    map_fn,
+    combine: str,
+    cfg: EngineConfig,
+    mesh: jax.sharding.Mesh,
+    axis: str,
+    bin_capacity: int,
+    leftover_capacity: int,
+    max_drains: int,
+    fused_preagg: bool,
+    check_vma: bool,
+    shard_capacity: int,
+):
+    """The flat mesh's step program at one shard capacity — the capacity
+    is a shape of the compiled fold, so a run that grows runs one such
+    program a capacity, each named ``jit_local_step``.  ``map_fn`` and
+    ``combine`` are the NORMALIZED pair; nothing here names an engine
+    (``_MeshPrograms``)."""
+    local_step = build_shuffle_step(
+        cfg,
+        map_fn,
+        combine,
+        n_bins=mesh.shape[axis],
+        bin_capacity=bin_capacity,
+        shard_capacity=shard_capacity,
+        leftover_capacity=leftover_capacity,
+        max_drains=max_drains,
+        shuffle_axis=axis,
+        stat_axes=(axis,),
+        fused_preagg=fused_preagg,
+    )
+    kv_spec = _kv_spec(axis)
+    # Stats are reduced over the mesh's only axis, so they leave
+    # shard_map REPLICATED (out_spec P()): every process can read them
+    # without touching non-addressable shards.
+    return jax.jit(
+        jax.shard_map(
+            local_step,
+            mesh=mesh,
+            in_specs=(P(axis), kv_spec, kv_spec),
+            out_specs=(kv_spec, kv_spec, P()),
+            check_vma=check_vma,
+        )
+    )
+
+
+def _build_shard_grower(mesh: jax.sharding.Mesh, axis: str, shard_capacity: int):
+    """The program that gives every shard of a table empty rows up to
+    ``shard_capacity`` (``core/kv.grow_table`` a shard)."""
+
+    def grow_shards(shard: KVBatch) -> KVBatch:
+        return grow_table(shard, shard_capacity)
+
+    kv_spec = _kv_spec(axis)
+    return jax.jit(
+        jax.shard_map(
+            grow_shards, mesh=mesh, in_specs=(kv_spec,), out_specs=kv_spec,
+        )
+    )
+
+
+class _ByCapacity:
+    """A program a shard capacity, made by ``build(capacity)`` the first
+    time one is asked for, under a lock: two engines of one record that
+    ask at once get one program."""
+
+    def __init__(self, build):
+        self._build = build
+        self._lock = threading.Lock()
+        self._made: dict[int, object] = {}
+
+    def __call__(self, shard_capacity: int):
+        with self._lock:
+            if shard_capacity not in self._made:
+                self._made[shard_capacity] = self._build(shard_capacity)
+            return self._made[shard_capacity]
+
+
+@dataclasses.dataclass(frozen=True)
+class _MeshPrograms:
+    """The jitted programs of ONE flat-mesh configuration, the process's
+    record of it (``engine._programs_for``).  The shard capacity is no
+    part of the configuration's key: a job that grows finds its second
+    step program in the SAME record, so it spends one key and an
+    eviction never splits a configuration's programs."""
+
+    step: _ByCapacity      # capacity -> jit_local_step at that capacity
+    grower: _ByCapacity    # capacity -> every shard grown TO that capacity
+    stats_merge: Callable  # merge_stats_vectors, on device across rounds
+
+
+def _build_mesh_programs(map_fn, combine: str, *config) -> _MeshPrograms:
+    """The record of one flat-mesh configuration: ``config`` is
+    ``_build_mesh_step``'s arguments between the NORMALIZED (map_fn,
+    combine) and the capacity.  Nothing here names an engine, so the
+    record outlives every engine that took it and a dead engine is no
+    reference cycle; nothing is traced here either."""
+    _cfg, mesh, axis = config[:3]
+    return _MeshPrograms(
+        step=_ByCapacity(
+            functools.partial(_build_mesh_step, map_fn, combine, *config)
+        ),
+        grower=_ByCapacity(functools.partial(_build_shard_grower, mesh, axis)),
+        stats_merge=jax.jit(merge_stats_vectors),
+    )
+
+
 class DistributedMapReduce:
     """Mesh-parallel MapReduce: shard_map(local pipeline + all-to-all).
 
@@ -874,77 +1020,27 @@ class DistributedMapReduce:
         self._fused_kernel_on, self.fused_demoted = _fused_mesh_gate(
             cfg, map_fn, combine, engine="flat"
         )
-        kv_spec = KVBatch(key_lanes=P(axis), values=P(axis), valid=P(axis))
-        self._kv_spec = kv_spec
-        # Stats are reduced over the mesh's only axis, so they leave
-        # shard_map REPLICATED (out_spec P()): every process can read them
-        # without touching non-addressable shards.
-        #
-        # check_vma: disabled for sort_mode="bitonic" ON TPU so the
-        # hand-written Pallas kernel actually RUNS on mesh engines.
-        # Under check_vma=True the kernel cannot
-        # trace — jax's vma machinery breaks inside the pallas interpret
-        # re-trace (verified this jax version: "Primitive lt requires
-        # varying manual axes to match") — and process_stage._bitonic_sort
-        # would silently serve the stock lax.sort formulation instead.
-        # With the check off, vma types are absent, the kernel traces,
-        # and mesh bitonic is oracle-exact.  TPU-only because the
-        # off-TPU INTERPRET kernel inside a full mesh program has twice
-        # segfaulted XLA's CPU compiler (thread stack overflow in
-        # libjax_common.so, kernel log 2026-07-31) nondeterministically
-        # — on CPU the engines keep check_vma=True, so _bitonic_sort
-        # takes its loud stock-formulation fallback there; the kernel's
-        # shard_map traceability itself is pinned by a direct small
-        # test (tests/test_distributed.py).  The cost on TPU is losing
-        # jax's replication checking for this one mode; the hierarchical
-        # engine's round step takes the same conditional, and this
-        # engine's outputs are oracle-tested per mode.
-        def build_step(shard_capacity: int):
-            """The step program at one shard capacity — the capacity is a
-            shape of the compiled fold, so a run that grows runs one such
-            program a capacity, each named ``jit_local_step``."""
-            local_step = build_shuffle_step(
-                cfg,
-                norm_map_fn,
-                norm_combine,
-                n_bins=self.n_dev,
-                bin_capacity=self.bin_capacity,
-                shard_capacity=shard_capacity,
-                leftover_capacity=self.leftover_capacity,
-                max_drains=self.max_drain_rounds,
-                shuffle_axis=axis,
-                stat_axes=(axis,),
-                fused_preagg=self._fused_kernel_on,
-            )
-            return jax.jit(
-                jax.shard_map(
-                    local_step,
-                    mesh=mesh,
-                    in_specs=(P(axis), kv_spec, kv_spec),
-                    out_specs=(kv_spec, kv_spec, P()),
-                    # fused kernel engaged implies a TPU backend
-                    # (fused_mesh_eligible), so the check is only ever
-                    # dropped on TPU — the CPU engines keep check_vma=True
-                    # and never trace a Pallas kernel in a mesh program.
-                    check_vma=not (
-                        (
-                            cfg.sort_mode == "bitonic"
-                            and jax.default_backend() == "tpu"
-                        )
-                        or self._fused_kernel_on
-                    ),
-                )
-            )
-
-        self._build_step = build_step
-        # One step program a shard capacity — the one a run starts with
-        # (``_step``) and those its runs grew to — and one grow program a
-        # capacity grown TO, all kept for the engine's next run.
-        self._steps: dict[int, object] = {}
-        self._growers: dict[int, object] = {}
-        # Across-round stats accumulation, jitted ONCE per engine (not per
-        # run) and kept on device so run() never syncs per round.
-        self._stats_merge = jax.jit(merge_stats_vectors)
+        check_vma = _mesh_check_vma(cfg, self._fused_kernel_on)
+        # The programs belong to the configuration, not to this engine
+        # (engine._programs_for, as MapReduceEngine's do): a process's
+        # second engine of an equal configuration — the CLI makes one a
+        # job — takes the SAME jit objects, so its first round traces,
+        # lowers and reads back nothing.  The key is everything the step
+        # closes over or reads while traced; the RAW (map_fn, combine)
+        # stand for the normalized pair they determine ("count" makes its
+        # wrapper anew each time); the shard capacity is no part of it
+        # (_MeshPrograms).  The builder names no engine.
+        config = (cfg, mesh, axis, self.bin_capacity, self.leftover_capacity,
+                  self.max_drain_rounds, self._fused_kernel_on, check_vma)
+        self._programs: _MeshPrograms = _programs_for(
+            ("mesh", map_fn, combine, *config),
+            lambda: _build_mesh_programs(norm_map_fn, norm_combine, *config),
+        )
+        self._stats_merge = self._programs.stats_merge
+        # Step programs put on THIS engine by hand (``dmr._step = f``: a
+        # test's dying or counting step), by shard capacity; _step_at asks
+        # here first, and nothing here reaches the process's record.
+        self._own_steps: dict[int, object] = {}
 
     # ------------------------------------------------------------------ api
 
@@ -957,10 +1053,10 @@ class DistributedMapReduce:
         return KVBatch.empty(self.n_dev * self.shard_capacity, self.cfg.key_lanes)
 
     def _step_at(self, shard_capacity: int):
-        """The step program whose shards hold ``shard_capacity`` rows."""
-        if shard_capacity not in self._steps:
-            self._steps[shard_capacity] = self._build_step(shard_capacity)
-        return self._steps[shard_capacity]
+        """The step program whose shards hold ``shard_capacity`` rows: the
+        one put on this engine by hand, else the configuration's."""
+        own = self._own_steps.get(shard_capacity)
+        return own if own is not None else self._programs.step(shard_capacity)
 
     @property
     def _step(self):
@@ -969,24 +1065,13 @@ class DistributedMapReduce:
 
     @_step.setter
     def _step(self, step) -> None:
-        self._steps[self.shard_capacity] = step
+        self._own_steps[self.shard_capacity] = step
 
     def _grow_shards(self, table: KVBatch, shard_capacity: int) -> KVBatch:
         """Every shard of ``table`` with empty rows up to ``shard_capacity``
         (``core/kv.grow_table`` a shard; nothing donated: a growth step
         that falls short starts from ``table`` again)."""
-        if shard_capacity not in self._growers:
-
-            def grow_shards(shard: KVBatch) -> KVBatch:
-                return grow_table(shard, shard_capacity)
-
-            self._growers[shard_capacity] = jax.jit(
-                jax.shard_map(
-                    grow_shards, mesh=self.mesh,
-                    in_specs=(self._kv_spec,), out_specs=self._kv_spec,
-                )
-            )
-        return self._growers[shard_capacity](table)
+        return self._programs.grower(shard_capacity)(table)
 
     def empty_leftover(self) -> KVBatch:
         """Global (sharded) empty shuffle-backlog buffer (0 rows in drop mode)."""

@@ -327,7 +327,8 @@ def test_a_traced_mesh_job_says_what_its_round_and_its_gather_are_made_of(n_dev,
     by_id = {e["args"]["id"]: e for e in spans}
 
     def kids(parent):
-        return sorted((e for e in spans
+        every = [e for e in tracer.to_chrome()["traceEvents"] if e.get("ph") == "X"]
+        return sorted((e for e in every
                        if e["args"].get("parent") == parent["args"]["id"]),
                       key=lambda e: e["ts"])
 
@@ -363,14 +364,20 @@ def test_a_traced_mesh_job_says_what_its_round_and_its_gather_are_made_of(n_dev,
     assert tail[0]["args"]["rows"] == res.table.size == gather["args"]["rows"]
     assert tail[0]["args"]["bytes"] == res.table.size * (res.table.num_lanes * 4 + 5)
     assert tail[1]["args"]["rows"] == len(want) == tail[2]["args"]["rows"]
-    # A second job on the engine re-makes no program: its rounds hold
-    # their staging alone.
-    mark = len(spans)
-    assert dict(dmr.run(_rows(dmr, lines), stats_sync_every=4).to_host_pairs()) == want
-    later = [e for e in tracer.to_chrome()["traceEvents"] if e.get("ph") == "X"][mark:]
-    assert not [e for e in later if e["name"].startswith("engine.program.")
-                and "local_step" in e["args"]["fun_name"]]
-    assert len([e for e in later if e["name"] == "mesh.h2d"]) == len(rounds)
+    # Only a configuration's FIRST job holds them: a second job re-makes
+    # no program — on this engine or on a new one of the configuration,
+    # which takes the process's (engine._programs_for) — and its rounds
+    # hold their staging alone.
+    for again in (dmr, _engine(n_dev, mode)):
+        mark = len([e for e in tracer.to_chrome()["traceEvents"] if e.get("ph") == "X"])
+        res2 = again.run(_rows(again, lines), stats_sync_every=4)
+        assert res2.table_grows == res.table_grows
+        assert dict(res2.to_host_pairs()) == want
+        later = [e for e in tracer.to_chrome()["traceEvents"] if e.get("ph") == "X"][mark:]
+        assert not [e for e in later if e["name"].startswith("engine.program.")]
+        mine = [e for e in later if e["name"] == "mesh.round"]
+        assert len(mine) == len(rounds)
+        assert all([k["name"] for k in kids(r)] == ["mesh.h2d"] for r in mine)
 
 
 def test_a_mesh_engine_made_with_tracing_off_registers_no_listener():
