@@ -10,16 +10,25 @@ classic sample-sort recipe on TPU collectives:
   1. SAMPLE   every device takes a strided sample of its local keys; one
               ``all_gather`` shares all samples; every device sorts the
               (small) sample set identically and picks n_dev-1 splitters.
-  2. PARTITION bucket = #splitters <= key (vectorized lexicographic compare
-              on packed lanes, core/packing.lanes_geq_table); scatter into
+  2. PARTITION bucket = #splitters <= key (lexicographic compare over the
+              lanes, parallel/record_sort.range_bucket); scatter into
               equal-capacity bins; one ``all_to_all`` — the range shuffle.
   3. LOCAL SORT each device lex-sorts what it received (full-lane
               ``lax.sort``: exact byte order, ops/process_stage "lex" mode).
 
 Device d then holds range-shard d, internally sorted, and every key on
 device d precedes every key on device d+1 — a globally sorted sequence.
-Skewed inputs (duplicate-heavy keys) can overflow a range bin; overflow is
-counted and psum'd like the hash shuffle's (SURVEY.md §7.3.3).
+
+This is the LIBRARY sort of (key, ``int32``) pairs, with fixed bins: a
+skewed input (duplicate-heavy keys) can overflow a range bin, the rows
+that did not fit are counted (``SortResult.overflow``, psum'd like the
+hash shuffle's, SURVEY.md §7.3.3) and ``sort_strings`` rebuilds the sort
+with doubled bins until none is.  The CLI's ``sort IN OUT --mesh`` is NOT
+this class: it carries whole records, cuts a duplicated key by input
+position and redoes an overflowing exchange on the resident data
+(parallel/record_sort.py) — it never drops a row.  Both take their
+splitters and buckets from that module's ``range_splitters`` /
+``range_bucket``: one range partition.
 """
 
 from __future__ import annotations
@@ -32,10 +41,11 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from locust_tpu.config import EngineConfig
-from locust_tpu.core import bytes_ops, packing
+from locust_tpu.core import bytes_ops
 from locust_tpu.core.kv import KVBatch
 from locust_tpu.ops.process_stage import sort_and_compact
 from locust_tpu.parallel.mesh import DATA_AXIS, shard_rows
+from locust_tpu.parallel.record_sort import range_bucket, range_splitters
 from locust_tpu.parallel.shuffle import partition_to_bins
 
 
@@ -93,25 +103,16 @@ class DistributedSort:
             sample_ok = valid[take]                          # [s]
             all_samples = jax.lax.all_gather(sample, axis)   # [n_dev, s, L]
             all_ok = jax.lax.all_gather(sample_ok, axis)     # [n_dev, s]
-            flat = all_samples.reshape(-1, n_lanes)
-            flat_inv = (~all_ok.reshape(-1)).astype(jnp.uint32)
-            # Sort samples with invalid LAST, then place the n_dev-1
-            # splitters at quantiles of the VALID prefix only.
-            ops = (flat_inv, *(flat[:, i] for i in range(n_lanes)))
-            s_out = jax.lax.sort(ops, num_keys=1 + n_lanes)
-            sorted_lanes = jnp.stack(s_out[1:], axis=-1)     # [n_dev*s, L]
-            n_valid_samples = jnp.sum(all_ok.astype(jnp.int32))
-            j = jnp.arange(n_dev - 1, dtype=jnp.int32) + 1
-            idx = jnp.clip(
-                j * n_valid_samples // n_dev, 0, sorted_lanes.shape[0] - 1
-            )
-            splitters = sorted_lanes[idx]                    # [n_dev-1, L]
+            # Samples sorted with the invalid LAST, the n_dev-1 splitters
+            # at quantiles of the VALID prefix only.
+            splitters = range_splitters(
+                all_samples.reshape(-1, n_lanes), all_ok.reshape(-1), n_dev
+            )                                                # [n_dev-1, L]
 
             # 2. PARTITION + all_to_all (range shuffle).
-            bucket = jnp.sum(
-                packing.lanes_geq_table(lanes, splitters).astype(jnp.int32),
-                axis=-1,
-            ).astype(jnp.uint32)                             # [N] in [0, n_dev)
+            bucket = range_bucket(
+                [lanes[:, i] for i in range(n_lanes)], splitters
+            )                                                # [N] in [0, n_dev)
             send_lanes, send_vals, send_valid, overflow, _ = partition_to_bins(
                 kv, n_dev, self.bin_capacity, bucket=bucket
             )
@@ -177,16 +178,19 @@ class SortResult:
     def to_host_sorted(self) -> list[tuple[bytes, int]]:
         """Concatenate per-device sorted valid prefixes -> global order.
 
-        Warns loudly if rows were dropped (overflowed range bins): the
-        result is then NOT a permutation of the input — re-sort with a
-        higher skew_factor (sort_strings does this automatically).
+        Warns loudly if rows did not fit their range bins (``overflow``):
+        the result is then NOT a permutation of the input and must not be
+        used as one — re-sort with a higher skew_factor (``sort_strings``
+        does, and raises rather than return a short list).  Only this
+        library class has that outcome; the CLI's mesh sort redoes the
+        exchange instead (parallel/record_sort.py).
         """
         if self.overflow:
             import logging
 
             logging.getLogger("locust_tpu").warning(
-                "sample sort dropped %d rows (range-bin overflow); "
-                "output is truncated — raise skew_factor",
+                "sample sort: %d rows did not fit their range bins; the "
+                "output is NOT the whole input — raise skew_factor",
                 self.overflow,
             )
         if jax.process_count() > 1:  # exercised by tests/test_multiprocess.py

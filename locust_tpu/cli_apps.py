@@ -14,7 +14,7 @@ tests/test_plan.py).  These subcommands wire the existing apps:
   python -m locust_tpu pagerank <edges.txt> [--mesh] [--num-iters N]
   python -m locust_tpu index  <file> [--mesh] [--lines-per-doc K]
   python -m locust_tpu tfidf  <file> [--lines-per-doc K]
-  python -m locust_tpu sort   <in> <out> [--record-bytes 100] [--key-bytes 10]
+  python -m locust_tpu sort   <in> <out> [--mesh] [--record-bytes 100] [--key-bytes 10]
 
 Edge-list format: one ``src dst`` pair of integer node ids per line;
 lines starting with ``#`` are comments (the web-Google / SNAP convention,
@@ -24,10 +24,15 @@ the library tests.  ``sort`` is TeraSort: IN holds fixed-width binary
 records (gensort's: 100 bytes, the first 10 the key), OUT gets every one
 of them ordered by key as unsigned bytes, equal keys in input order; a
 size that is no whole number of records, or an empty IN, is an error and
-exit status 2, and no OUT is written.
+exit status 2, and no OUT is written.  An OUT that is already there is
+written over in place and cut to size at the end (``serde.write_records``).
 
 ``--mesh`` selects the sharded engines (ShardedPageRank — rank state
-O(nodes/n_dev) per device — and DistributedInvertedIndex) over all
+O(nodes/n_dev) per device —, DistributedInvertedIndex, and for ``sort``
+the mesh record sort: IN's blocks dealt round the devices, every record
+through one all-to-all to the device that owns its key range, OUT
+written shard after shard, the same bytes, one ``shard d: n records``
+line a device on stderr) over all
 visible devices; without it the single-device variants run.  Backend
 resolution (probe/fallback) is shared with the WordCount path.
 """
@@ -35,6 +40,7 @@ resolution (probe/fallback) is shared with the WordCount path.
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import time
 
@@ -93,12 +99,19 @@ def build_parser(cmd: str) -> argparse.ArgumentParser:
         p.add_argument("input", metavar="IN",
                        help="file of fixed-width binary records")
         p.add_argument("output", metavar="OUT",
-                       help="file the sorted records are written to")
+                       help="file the sorted records are written to (one that is there "
+                            "is written over in place)")
         p.add_argument("--record-bytes", type=int, default=RECORD_BYTES,
                        help="bytes a record (gensort: 100)")
         p.add_argument("--key-bytes", type=int, default=KEY_BYTES,
                        help="leading bytes of a record that are its key, "
                             "compared as unsigned bytes (gensort: 10)")
+        p.add_argument("--mesh", action="store_true",
+                       help="sort across all visible devices: the file's "
+                            "blocks dealt round them, every record through "
+                            "one all-to-all to the device that owns its key "
+                            "range (sampled splitters), the shards written "
+                            "in turn — the same bytes in OUT")
     else:
         p.add_argument("filename", help="input text file")
         p.add_argument("--lines-per-doc", type=int, default=1,
@@ -247,7 +260,8 @@ def run_sort(args, source) -> int:
     # loads its rows and prints its table, so ingest, sort and output
     # show as cli.load / cli.run / cli.output.
     sort_plan = compile_plan(
-        records_sort_plan(args.record_bytes, args.key_bytes), EngineConfig()
+        records_sort_plan(args.record_bytes, args.key_bytes), EngineConfig(),
+        mesh=args.mesh,
     )
     if args.trace_out:  # main's entry to the first cli.load, once it is over
         obs.span_at("cli.setup", args.entered, time.time())
@@ -256,9 +270,25 @@ def run_sort(args, source) -> int:
         print(f"[locust] {staged.n_records} records of "
               f"{args.record_bytes} bytes loaded", file=sys.stderr)
     with obs.span("cli.run"):
-        ordered = sort_plan.run(staged, render=False).value
+        from locust_tpu.parallel.record_sort import BinOverflow
+
+        try:
+            ordered = sort_plan.run(staged, render=False).value
+        except BinOverflow:
+            # Not every record found a place (the mesh's retry budget):
+            # an OUT left by an earlier job must not pass for this one's.
+            if os.path.exists(args.output):
+                try:
+                    os.unlink(args.output)
+                except OSError:  # no name to remove (/proc/self/fd/N): emptied
+                    os.truncate(args.output, 0)
+            raise
     with obs.span("cli.output"):
         written = serde.write_records(args.output, ordered.host_blocks())
+    for d, rows in enumerate(getattr(ordered, "shard_rows", ())):
+        # The range partition, as the WordCount mesh reports its hash
+        # shards: shard d's keys all precede shard d + 1's.
+        print(f"[locust] shard {d}: {rows} records", file=sys.stderr)
     print(f"[locust] sorted by the first {args.key_bytes} bytes: "
           f"{written // args.record_bytes} records, {written} bytes written "
           f"to {args.output}; {source.nbytes - written} bytes lost",

@@ -75,18 +75,6 @@ def lanes_less(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.where(any_diff, a_at < b_at, False)
 
 
-def lanes_geq_table(keys: jax.Array, splitters: jax.Array) -> jax.Array:
-    """Pairwise lexicographic ``keys[n] >= splitters[s]`` -> bool ``[N, S]``.
-
-    Vectorized comparator for range partitioning (sample sort).  S (number
-    of splitters, ~mesh size) is small, so the [N, S, L] broadcast is cheap.
-    """
-    any_diff, a_at, b_at = _first_diff_lanes(
-        keys[:, None, :], splitters[None, :, :]
-    )
-    return jnp.where(any_diff, a_at > b_at, True)           # equal => >=
-
-
 def _fmix32(h: jax.Array) -> jax.Array:
     """murmur3 finalizer: a full-avalanche bijection on uint32."""
     h ^= h >> 16

@@ -721,6 +721,22 @@ class StagedRecords:
     block_rows: int
 
 
+def fetch_record_block(flat: jax.Array, rows: int, record_bytes: int) -> np.ndarray:
+    """A permuted block (a flat array of words on its way down) as the
+    host ``uint8`` bytes of its first ``rows`` records: the block's one
+    wait, and where the word padding of an odd record width goes."""
+    words = RecordBatch.num_words(record_bytes)
+    with obs.span("sort.d2h", bytes=rows * record_bytes):
+        with obs.span("engine.sync", what="d2h"):
+            host = np.asarray(flat)  # locust: noqa[R003] the block's one wait: the next blocks are already on their way
+    host = host.view(np.uint8)
+    if 4 * words == record_bytes:
+        return host[: rows * record_bytes]
+    return np.ascontiguousarray(
+        host.reshape(-1, 4 * words)[:rows, :record_bytes]
+    ).reshape(-1)
+
+
 class SortedRecords:
     """A sorted job whose payload is still on the device in input order
     (``RecordSort.sort``): ``perm`` says which row comes where, and
@@ -739,7 +755,7 @@ class SortedRecords:
         on their way down while the caller writes the one before, and a
         block's device copy is dropped once it is on the host."""
         sorter, staged = self._sorter, self._staged
-        rb, words = sorter.record_bytes, RecordBatch.num_words(sorter.record_bytes)
+        rb = sorter.record_bytes
         blocks = self._perm.shape[0]
         pending: collections.deque = collections.deque()
         launched = 0
@@ -752,19 +768,23 @@ class SortedRecords:
                     flat.copy_to_host_async()
                 pending.append(flat)
                 launched += 1
-            flat = pending.popleft()
             rows = min(staged.block_rows, self.n_records - b * staged.block_rows)
-            with obs.span("sort.d2h", bytes=rows * rb):
-                with obs.span("engine.sync", what="d2h"):
-                    host = np.asarray(flat)  # locust: noqa[R003] the block's one wait: the next blocks are already on their way
-            del flat
-            host = host.view(np.uint8)
-            if 4 * words == rb:
-                yield host[: rows * rb]
-            else:  # the word padding of an odd record width goes here
-                yield np.ascontiguousarray(
-                    host.reshape(-1, 4 * words)[:rows, :rb]
-                ).reshape(-1)
+            yield fetch_record_block(pending.popleft(), rows, rb)
+
+
+def record_block_rows(record_bytes: int, n_records: int) -> int:
+    """Rows of one staged block of a device that takes ``n_records``: the
+    largest power of two whose words fit ``RECORD_BLOCK_BYTES``, and for a
+    job under one block the first power of two (at least 8) that holds it
+    — a handful of shapes over all small jobs, one a block count above
+    that.  The mesh sort (parallel/record_sort.py) asks it of a device's
+    share of the records."""
+    words = RecordBatch.num_words(record_bytes)
+    full = 1 << ((MapReduceEngine.RECORD_BLOCK_BYTES // (4 * words)).bit_length() - 1)
+    rows = 8
+    while rows < min(n_records, full):
+        rows *= 2
+    return rows
 
 
 class RecordSort:
@@ -787,16 +807,7 @@ class RecordSort:
         )
 
     def block_rows(self, n_records: int) -> int:
-        """Rows of one staged block: the largest power of two whose words
-        fit ``RECORD_BLOCK_BYTES``, and for a job under one block the
-        first power of two (at least 8) that holds it — a handful of
-        shapes over all small jobs, one a block count above that."""
-        words = RecordBatch.num_words(self.record_bytes)
-        full = 1 << ((MapReduceEngine.RECORD_BLOCK_BYTES // (4 * words)).bit_length() - 1)
-        rows = 8
-        while rows < min(n_records, full):
-            rows *= 2
-        return rows
+        return record_block_rows(self.record_bytes, n_records)
 
     def load(self, source) -> StagedRecords:
         """``source`` (``io/loader.RecordSource``) onto the device: its

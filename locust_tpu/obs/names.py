@@ -53,11 +53,15 @@ NAMES = {
     "mesh.table.grow": "span",      # mesh: every shard grown a step, the rounds since the last whole table folded again (args from_rows, to_rows, worst_shard, rounds_redone)
     "mesh.gather": "span",          # mesh: table from its shards to sorted host pairs (args rows, shards)
     "sort.read": "span",            # record sort: a block of the mapped file found, or its copy where it must be padded (arg bytes)
-    "sort.h2d": "span",             # record sort: a staged block handed up and placed (arg bytes)
+    "sort.h2d": "span",             # record sort: a staged block handed up and placed (arg bytes; under --mesh also device)
     "sort.keys": "span",            # record sort: the key sort launched and waited for (arg rows)
-    "sort.permute": "span",         # record sort: a block's payload gather launched (arg rows)
+    "sort.permute": "span",         # record sort: a block's payload gather launched (arg rows; under --mesh also device)
     "sort.d2h": "span",             # record sort: a sorted block brought down (arg bytes)
     "sort.write": "span",           # record sort: a sorted block written to OUT (arg bytes)
+    "sort.mesh.split": "span",      # mesh record sort: sample, gather, splitters and their one sync (args samples, splitters)
+    "sort.mesh.exchange": "span",   # mesh record sort: bucket, bin, all-to-all, the bin counts read back (args bin_rows, attempt, worst_bin)
+    "sort.mesh.retry": "span",      # mesh record sort: parent of an exchange redone with larger bins (args from_bin_rows, to_bin_rows, worst_bin)
+    "sort.mesh.shard_sort": "span", # mesh record sort: the shards' key sorts launched and waited for (arg rows of the largest shard)
     "ckpt.write": "span",           # async writer: serialize+publish one gen
     "serve.queue_wait": "span",     # serve: dispatcher waiting on the queue
     "serve.compile_or_hit": "span", # serve: warm-executable cache lookup/build
@@ -99,6 +103,11 @@ NAMES = {
     "engine.merges": "counter",     # timed_run: merge programs launched (one a group + one a group redone)
     "sort.records": "counter",      # record sort: records staged on the device
     "sort.bytes_out": "counter",    # record sort: bytes written to OUT
+    "sort.mesh.retries": "counter",          # mesh record sort: exchanges redone because a bin overflowed
+    "sort.mesh.bin_rows": "gauge",           # mesh record sort: rows a (source, destination) bin held in the exchange that stood
+    "sort.mesh.shard_rows_max": "gauge",     # mesh record sort: records of the largest shard
+    "sort.mesh.shard_rows_min": "gauge",     # ... and of the smallest
+    "sort.mesh.bytes_exchanged": "counter",  # mesh record sort: record bytes that left their device in the all-to-all
     "mesh.rounds": "counter",       # mesh: rounds dispatched (redone ones not counted again)
     "mesh.table_grows": "counter",  # mesh: growth steps the job's shards took
     "mesh.drain_rounds": "counter", # mesh: extra all-to-all rounds the backlog took

@@ -431,6 +431,56 @@ class RoundStats:
         self._on_sync(st)
 
 
+def _sort_by_bin(bucket: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Rows grouped by destination bin: ``(sb, sidx)``, the sorted bucket
+    (int32) and the row that comes there — ONE single-key ``lax.sort``
+    that carries only a row index, so a bin's rows keep their order.
+    Whatever rides along is gathered by ``sidx`` afterwards — an
+    ``int32`` value (``partition_to_bins``) or a whole record
+    (``partition_words_to_bins``) — and never widens the sort."""
+    idx = jnp.arange(bucket.shape[0], dtype=jnp.int32)
+    sb_u, sidx = jax.lax.sort((bucket, idx), num_keys=1)
+    return sb_u.astype(jnp.int32), sidx
+
+
+def partition_words_to_bins(
+    words: jax.Array,
+    bucket: jax.Array,
+    n_bins: int,
+    bin_capacity: int,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``partition_to_bins``' grouping with a WIDE payload: whole records
+    (``words``, uint32 ``[N, W]`` — W = 25 for gensort's 100 bytes) into
+    ``[n_bins, capacity, W]`` bins, with the row each slot was filled
+    from (int32 ``[n_bins, capacity]``, -1 = a dead slot) and the rows
+    each bin was SENT (int32 ``[n_bins]``).
+
+    ``bucket`` is uint32 ``[N]`` in ``[0, n_bins]``, ``n_bins`` marking a
+    row that goes nowhere (padding).  The same ONE single-key sort groups
+    the rows by bin and keeps a bin's rows in ROW order; a bin is then a
+    contiguous run of the sorted row indices — found by a binary search,
+    cut out by a slice — and its records are read straight from where
+    they lie: one gather of whole records, where the KV partition gathers
+    its narrow rows and then scatters them.  A dead slot holds an
+    arbitrary record.  Nothing is dropped in silence: where a count
+    passes ``bin_capacity`` the caller redoes the partition with bins
+    that hold (parallel/record_sort.py)."""
+    sb, sidx = _sort_by_bin(bucket)
+    starts = jnp.searchsorted(
+        sb, jnp.arange(n_bins + 1, dtype=jnp.int32), side="left"
+    ).astype(jnp.int32)
+    counts = starts[1:] - starts[:-1]
+    # Padded so that a bin's slice never runs off the end (a slice that
+    # would is shifted back by dynamic_slice, not cut).
+    run = jnp.pad(sidx, (0, bin_capacity))
+    row = jnp.stack([
+        jax.lax.dynamic_slice(run, (starts[b],), (bin_capacity,))
+        for b in range(n_bins)
+    ])                                                         # [B, C]
+    live = jnp.arange(bin_capacity, dtype=jnp.int32)[None, :] < counts[:, None]
+    return words[row], jnp.where(live, row, -1), counts
+
+
 def partition_to_bins(
     batch: KVBatch,
     n_bins: int,
@@ -442,7 +492,9 @@ def partition_to_bins(
 
     ``bucket`` overrides the destination-bin assignment (uint32 ``[N]`` in
     ``[0, n_bins)``) — used by range partitioners (apps/sample_sort.py);
-    default is the hash partition.
+    default is the hash partition.  The value is ONE ``int32`` a row; a
+    wide payload (whole records) takes the same grouping through
+    ``partition_words_to_bins``.
 
     Live entries that do not fit their bin land in a compacted LEFTOVER
     buffer of ``leftover_capacity`` rows instead of being dropped — the
@@ -465,9 +517,7 @@ def partition_to_bins(
     # Group by bin: single-key sort carrying only a row index, then gather.
     # Within-bin order is arbitrary — the post-shuffle merge re-sorts by key
     # (local_step), so no multi-key sort is needed here.
-    idx = jnp.arange(n, dtype=jnp.int32)
-    sb_u, sidx = jax.lax.sort((bucket, idx), num_keys=1)
-    sb = sb_u.astype(jnp.int32)
+    sb, sidx = _sort_by_bin(bucket)
     slanes = lanes[sidx]
     svals = values[sidx]
     svalid = sb < n_bins

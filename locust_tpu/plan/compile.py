@@ -360,18 +360,51 @@ class CompiledPlan:
                         corpus_sha=corpus_sha, corpus_bytes=corpus)
 
     def _record_sorter(self):
-        """The sort stage's ``engine.RecordSort`` (one a compiled plan;
-        its programs are the process's, ``engine._programs_for``)."""
+        """The sort stage's sorter, one a compiled plan (its programs are
+        the process's, ``engine._programs_for``): ``engine.RecordSort`` on
+        one device, under ``mesh`` ``parallel.record_sort.MeshRecordSort``
+        over every visible device — same ``load`` / ``sort`` /
+        ``host_blocks``, same bytes out."""
         if self._sorter is None:
-            from locust_tpu.engine import MapReduceEngine
-
             stage = next(
                 s for s in self._stages.values() if s[0] == "record_sort"
             )
-            self._sorter = MapReduceEngine(self.cfg).record_sort(
-                stage[2], stage[3]
-            )
+            if self.mesh:
+                from locust_tpu.parallel.mesh import make_mesh
+                from locust_tpu.parallel.record_sort import MeshRecordSort
+
+                self._sorter = MeshRecordSort(make_mesh(), stage[2], stage[3])
+            else:
+                from locust_tpu.engine import MapReduceEngine
+
+                self._sorter = MapReduceEngine(self.cfg).record_sort(
+                    stage[2], stage[3]
+                )
         return self._sorter
+
+    def explain(self) -> str:
+        """What each stage of the lowered plan runs on, a line a stage in
+        the order they were lowered: ``<node>: <stage> -> <lowering>``."""
+        import jax
+
+        lines = []
+        for nid, stage in self._stages.items():
+            kind = stage[0]
+            if kind == "record_sort":
+                where = (
+                    f"parallel.record_sort.MeshRecordSort over "
+                    f"{len(jax.devices())} devices (range partition by "
+                    "sampled splitters, one all-to-all, a sorted shard a device)"
+                    if self.mesh else
+                    "engine.RecordSort on one device (the whole data set resident)"
+                )
+                lines.append(f"{nid}: record_sort({stage[2]}-byte records, "
+                             f"{stage[3]}-byte key) -> {where}")
+            else:
+                what = ", ".join(str(x) for x in stage[1:] if isinstance(x, (str, int)))
+                lines.append(f"{nid}: {kind}({what})"
+                             + (" [mesh]" if self.mesh and kind == "fold" else ""))
+        return "\n".join(lines)
 
     def load_records(self, source):
         """Evaluate a records plan's SOURCE now: ``source`` (an

@@ -69,10 +69,12 @@ from .nodes import Plan
 # in a ``.kind`` match below or be listed here with a reason).
 # source/map/shuffle/reduce participate as the fold spine, join as the
 # hash-join tree, iterate as the epoch sweep, sink as the terminal
-# render.  ``sort`` stays solo: the record sort keeps the whole data set
-# on ONE device, and its distributed form is not a stage program over
-# LKVB partitions but a range partition that moves every record
-# (apps/sample_sort.py's, PERF.md section 7 ``tera-skew.mesh4``).
+# render.  ``sort`` stays solo HERE: this distributor moves keyed LKVB
+# partitions of (key, int32) pairs between workers and has no record
+# partitions.  The sort's distributed form exists, on a mesh: a range
+# partition that moves every record whole through one all-to-all
+# (parallel/record_sort.py, ``compile_plan(..., mesh=True)``, the CLI's
+# ``sort IN OUT --mesh``) — one process's chips, not this pool's workers.
 SOLO_ONLY: tuple = ("sort",)
 
 # Doc-id suffix budget for composite (word, doc) partition keys: the doc

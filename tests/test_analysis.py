@@ -654,6 +654,7 @@ def test_r009_real_registry_mutation_fails_the_gate(tmp_path):
         "locust_tpu/plan/optimize.py",  # emits plan.optimize/plan.rewrites
         "locust_tpu/plan/distribute.py",  # emits plan.partition_bytes
         "locust_tpu/parallel/shuffle.py",  # emits the mesh.* spans/metrics
+        "locust_tpu/parallel/record_sort.py",  # emits the sort.mesh.* spans/metrics
         "locust_tpu/ops/pallas/fused_fold.py",  # kernel: must stay name-free
     ):
         dst = tmp_path / rel

@@ -76,6 +76,7 @@ def _lowered_text(eng: MapReduceEngine) -> tuple[str, ...]:
     step, shapes = _toy_mesh_step()
     lowered += (step.lower(*shapes),)
     lowered += _lowered_record_sort(eng)
+    lowered += _toy_mesh_record_sort()[1]
     return tuple(low.as_text() for low in lowered)
 
 
@@ -96,10 +97,43 @@ def _lowered_record_sort(eng: MapReduceEngine) -> tuple:
     )
 
 
+def _toy_mesh_record_sort():
+    """The mesh record sort at toy shapes — four devices, two blocks of
+    eight gensort-width records a device, bins of eight — and its
+    programs lowered."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from locust_tpu.parallel import make_mesh
+    from locust_tpu.parallel.record_sort import MeshRecordSort
+
+    sorter = MeshRecordSort(make_mesh(4), 100, 10)
+    on, everywhere = (NamedSharding(sorter.mesh, spec) for spec in (P("data"), P()))
+
+    def shape(dims, dtype, sharding=on):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    words, n = shape((4 * 16, 25), jnp.uint32), shape((), jnp.int32, everywhere)
+    progs = sorter.programs
+    partition = progs.partition.lower(
+        words, shape((3, 4), jnp.uint32, everywhere), n, block_rows=8, bin_rows=8)
+    return partition, (
+        progs.empty.lower(rows=16),
+        progs.place.lower(words, shape((4 * 8 * 25,), jnp.uint32), n),
+        progs.split.lower(words, n, block_rows=8, samples=8),
+        partition,
+        progs.sort_shard.lower(shape((4 * 32, 25), jnp.uint32),
+                               shape((4 * 32,), jnp.int32), block_rows=8),
+        progs.permute.lower(jax.ShapeDtypeStruct((32, 25), jnp.uint32),
+                            jax.ShapeDtypeStruct((4, 8), jnp.int32),
+                            jax.ShapeDtypeStruct((), jnp.int32)),
+    )
+
+
 @pytest.fixture(scope="module")
 def program_names() -> dict[str, set[str]]:
     """Module names of the programs the cells run (the default path's
-    four, the mesh's step and the record sort's four), as the
+    four, the mesh's step, the record sort's four and the mesh record
+    sort's six), as the
     device trace's ``XLA Modules`` line will show them: of a
     configuration's ``first`` engine, which builds them, and of a later
     one, which takes the process's (``shared``, engine._programs_for) —
@@ -124,7 +158,24 @@ def cli_stderr(tmp_path_factory) -> str:
         table, err, _ = chip_smoke.run_cli([str(path), "--block-lines", "8", *flags])
         assert table.count(b"\n") == 15  # ten numbers and five words
         said.append(err)
+    said.append(_mesh_sort_stderr(path.parent))
     return "\n".join(said)
+
+
+def _mesh_sort_stderr(tmp) -> str:
+    """What ``cli.main`` says of a ``sort IN OUT --mesh`` job of 64 records."""
+    import contextlib
+    import io
+
+    from locust_tpu.cli import main as cli_main
+
+    src = tmp / "records.bin"
+    src.write_bytes(bytes(range(256)) * 25)
+    err = io.StringIO()
+    with contextlib.redirect_stderr(err):
+        assert cli_main(["sort", str(src), str(tmp / "sorted.bin"), "--mesh",
+                         "--backend", "cpu"]) == 0
+    return err.getvalue()
 
 
 @pytest.fixture(scope="module")
@@ -135,6 +186,14 @@ def mesh_op_names() -> set[str]:
     on a v5e), so a pattern is held to the opcodes the program has."""
     step, shapes = _toy_mesh_step()
     text = step.lower(*shapes).compile().as_text()
+    return {ln.strip() for ln in text.splitlines() if " = " in ln}
+
+
+@pytest.fixture(scope="module")
+def mesh_record_op_names() -> set[str]:
+    """The same of the mesh record sort's partition program, where its
+    all-to-alls are."""
+    text = _toy_mesh_record_sort()[0].compile().as_text()
     return {ln.strip() for ln in text.splitlines() if " = " in ln}
 
 
@@ -150,7 +209,9 @@ def _metric_cases():
     to a configuration's first engine and to one that shares its programs."""
     for path in METRIC_FILES:
         with open(path) as f:
-            by_program = json.load(f)["reader"] in ("xla_module", "roofline", "roofline_job")
+            spec = json.load(f)
+        by_program = spec["reader"] in ("xla_module", "roofline", "roofline_job") or (
+            spec["reader"] == "roofline_device_job" and "programs" in spec)
         for which in ("first", "shared") if by_program else (None,):
             name = os.path.basename(path)
             yield pytest.param(path, which, id=f"{name}-{which}" if which else name)
@@ -164,14 +225,23 @@ def test_layer_metric_reads_a_name_the_program_still_has(path, which, request):
     # Built on first use, once a module: a case that reads a span name
     # lowers no program and runs no job.
     fixture = request.getfixturevalue
-    if reader in ("obs_span", "span_count", "device_in_span"):
+    if reader in ("obs_span", "span_count", "span_count_of", "device_in_span"):
         assert spec["span"] in SPANS, f"{spec['span']!r} is not a registered span"
+        assert spec.get("within", spec["span"]) in SPANS, spec["within"]
         prefix = spec.get("holds_all_work")
         assert prefix is None or any(s.startswith(prefix) for s in SPANS), prefix
     elif reader == "xla_module":
         _assert_patterns_match(spec["patterns"], fixture("program_names")[which], "patterns")
     elif reader == "xla_op":
-        _assert_patterns_match(spec["patterns"], fixture("mesh_op_names"), "patterns")
+        # rec_* files read the mesh record sort's exchange, the others
+        # the WordCount mesh's step.
+        ops = "mesh_record_op_names" if os.path.basename(path).startswith("rec_") else "mesh_op_names"
+        _assert_patterns_match(spec["patterns"], fixture(ops), "patterns")
+    elif reader == "roofline_device_job":
+        if "programs" in spec:
+            _assert_patterns_match(spec["programs"], fixture("program_names")[which], "programs")
+        else:
+            _assert_patterns_match(spec["ops"], fixture("mesh_record_op_names"), "ops")
     elif reader == "roofline_job":
         _assert_patterns_match(spec["programs"], fixture("program_names")[which], "programs")
     elif reader == "roofline":
