@@ -385,7 +385,6 @@ def _run(args) -> int:
                     lines_read.append(rows.shape[0])
             with timer.span("run"), obs.span("cli.run"):
                 # Each run method syncs internally, so the span is accurate.
-                pairs = None
                 if args.stream:
                     kw = {}
                     if args.checkpoint_dir:
@@ -400,17 +399,20 @@ def _run(args) -> int:
                         rows,
                         timed=not args.no_timing,
                         render=False,
-                        # The staged map node only dumps the raw table
-                        # (dump_intermediate): skip the host finalize
-                        # its output path would discard.
-                        finalize=args.stage != STAGE_MAP,
+                        # No pairs: the table on stdout is rendered from
+                        # rows (below), and the staged map node dumps the
+                        # raw table (dump_intermediate).
+                        finalize=False,
                         checkpoint_dir=args.checkpoint_dir or None,
                         every=args.checkpoint_every,
                     )
                     res = pres.run_result
-                    pairs = pres.value
                     print(f"[locust] {sum(lines_read)} lines loaded",
                           file=sys.stderr)
+                if args.stage != STAGE_MAP:
+                    # The table's way to the host belongs to the run
+                    # (engine.finalize), as on the mesh.
+                    table = res.to_host_rows()
             if args.stream and res.stream is not None:
                 # Zero-stall executor accounting: backpressure stall +
                 # checkpoint mark/write stats (engine.run_stream).
@@ -442,12 +444,7 @@ def _run(args) -> int:
                     print(f"[locust] node {args.node_num}: intermediate written to {out}",
                           file=sys.stderr)
                 else:
-                    # The plan run already host-finalized the table
-                    # (PlanResult.value); the stream path decodes here.
-                    _print_table(
-                        pairs if pairs is not None else res.to_host_pairs(),
-                        args.limit,
-                    )
+                    _print_table(table, args.limit)
         if args.trace:
             print(timer.report(), file=sys.stderr)
         return 0
@@ -468,15 +465,15 @@ def _run(args) -> int:
         batch = KVBatch.from_bytes(
             jnp.asarray(keys), jnp.asarray(values), jnp.ones(keys.shape[0], bool)
         )
-        from locust_tpu.engine import finalize_host_pairs
+        from locust_tpu.engine import finalize_host_rows
         from locust_tpu.ops import segment_reduce, sort_and_compact
 
         eng = MapReduceEngine(cfg)  # stage 2 only: the normalized combine
         with timer.span("run"), obs.span("cli.run"):
             table = segment_reduce(sort_and_compact(batch, cfg.sort_mode), eng.combine)
-            pairs = finalize_host_pairs(table, eng.combine)  # device sync
+            rows = finalize_host_rows(table, eng.combine)  # device sync
         with timer.span("output"), obs.span("cli.output"):
-            _print_table(pairs, args.limit)
+            _print_table(rows, args.limit)
     if args.trace:
         print(timer.report(), file=sys.stderr)
     return 0
@@ -592,7 +589,10 @@ def _run_mesh(args, cfg, timer, prof, preloaded_rows=None,
                 if args.stream
                 else dmr.run(rows, **kw)
             )
-            pairs = res.to_host_pairs()  # gathers + syncs
+            # gathers + syncs: pairs for the staged map node's file, rows
+            # for the table on stdout
+            table = (res.to_host_pairs() if args.stage == STAGE_MAP
+                     else res.to_host_rows())
         run_ms = (time.perf_counter() - t0) * 1e3
 
         # Per-shard report: one hash shard per shard_capacity rows (the
@@ -638,36 +638,48 @@ def _run_mesh(args, cfg, timer, prof, preloaded_rows=None,
         with timer.span("output"), obs.span("cli.output"):
             if args.stage == STAGE_MAP:
                 out = inter[0]
-                serde.write_intermediate(pairs, out, args.inter_format)
+                serde.write_intermediate(table, out, args.inter_format)
                 print(
                     f"[locust] node {args.node_num}: intermediate written "
                     f"to {out}",
                     file=sys.stderr,
                 )
             else:
-                _print_table(pairs, args.limit)
+                _print_table(table, args.limit)
     return 0
 
 
-def _print_table(pairs: list[tuple[bytes, int]], limit=None) -> None:
+def _print_table(table, limit=None) -> None:
     """Final ``key<TAB>count`` table on stdout (analog of printKeyIntValues,
-    main.cu:126-134 — we print two columns, not its internal three).  On a
-    multi-process pod every process holds the gathered table
-    (to_host_pairs allgathers); only process 0 prints so the pod's
-    combined stdout is one table, not N interleaved copies."""
+    main.cu:126-134 — we print two columns, not its internal three), its
+    first ``limit`` rows.  ``table`` is what ``to_host_rows`` /
+    ``finalize_host_rows`` gave the default path, ``--stream``, ``--mesh``
+    and the reduce stage: key-ordered ``HostRows``, rendered in numpy
+    (``bytes_ops.render_rows``), or — where the data cannot be printed
+    from arrays — the sorted pair list, joined a row at a time.  On a
+    multi-process pod every process holds the gathered table (the gather
+    is an allgather); only process 0 prints so the pod's combined stdout
+    is one table, not N interleaved copies."""
     import jax
+
+    from locust_tpu.core import bytes_ops
+    from locust_tpu.core.kv import HostRows
 
     if jax.process_count() > 1 and jax.process_index() != 0:
         return
     # One write: a table of 650,000 rows written a row at a time took 7.7 s
     # to a file on the chip's host, most of a job.
-    shown = pairs[: limit if limit is not None else len(pairs)]
-    with obs.span("cli.output.render", rows=len(shown)):
-        table = b"".join(
-            k + b"\t" + str(v).encode() + b"\n" for k, v in shown
-        )
-    with obs.span("cli.output.write", bytes=len(table)):
-        sys.stdout.buffer.write(table)
+    shown = table[:limit]
+    fast = isinstance(shown, HostRows)
+    with obs.span("cli.output.render", rows=len(shown), fast=int(fast)):
+        if fast:
+            out = bytes_ops.render_rows(shown.keys, shown.values)
+        else:
+            out = b"".join(
+                k + b"\t" + str(v).encode() + b"\n" for k, v in shown
+            )
+    with obs.span("cli.output.write", bytes=len(out)):
+        sys.stdout.buffer.write(out)
         sys.stdout.flush()
 
 

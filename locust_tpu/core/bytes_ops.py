@@ -144,6 +144,72 @@ def rows_to_strings(rows: np.ndarray) -> list[bytes]:
     return out
 
 
+def render_rows(keys: np.ndarray, values: np.ndarray) -> bytes:
+    """Host-side: ordered table rows -> the ``key<TAB>count<LF>`` bytes the
+    CLI prints, in numpy, with no Python object a row.
+
+    ONE ``uint8 [n, width + digits + 2]`` matrix holds a row's NUL-padded
+    key, TAB, its decimal digits right-aligned behind NULs, and LF; every
+    NUL is then dropped by one boolean take.  The digits by repeated
+    division by ten over the rows that still have a quotient (a WordCount
+    table's counts are mostly one digit, so the second pass sees a few
+    rows in a hundred).  Byte-equal to ``b"".join(k + b"\t" +
+    str(v).encode() + b"\n")`` over ``rows_to_strings(keys)`` where
+    ``render_blocker`` finds nothing in the way; the caller asks it first.
+
+    Args:
+      keys: uint8 ``[n, width]`` NUL-padded key rows, in print order.
+      values: int32 ``[n]``, none negative.
+    """
+    n, width = keys.shape
+    if n == 0:
+        return b""
+    digits = len(str(int(values.max())))
+    out = np.zeros((n, width + digits + 2), dtype=np.uint8)
+    out[:, :width] = keys
+    out[:, width] = ord("\t")
+    out[:, -1] = ord("\n")
+    ten, zero = np.uint32(10), np.uint32(ord("0"))
+    v = values.astype(np.uint32)
+    q = v // ten
+    out[:, -2] = v - q * ten + zero
+    live = np.flatnonzero(q)
+    v, col = q[live], -3
+    while live.size:
+        q = v // ten
+        out[live, col] = v - q * ten + zero
+        more = q != 0
+        live, v, col = live[more], q[more], col - 1
+    flat = out.ravel()
+    return flat[flat != 0].tobytes()
+
+
+def render_blocker(keys: np.ndarray, values: np.ndarray) -> str | None:
+    """What stands between ordered rows and ``render_rows``, or None.
+
+    ``keys`` are in byte order of the NUL-padded rows and that is the
+    order and the spelling of the printed keys unless one of three things
+    holds, each checked over whole arrays on the fixed-width view:
+    ``"nul"`` — a key with a NUL inside it (``rows_to_strings`` cuts it
+    there, which can make two keys equal and move a row); ``"duplicate"``
+    — two neighbouring rows of one key (a 64-bit hash collision's second
+    row, to be merged); ``"negative"`` — a value below zero (``min`` /
+    ``max`` combines, int32 wraparound), whose sign the digits lack."""
+    n, width = keys.shape
+    if n == 0:
+        return None
+    fixed = np.ascontiguousarray(keys).view(f"S{width}").ravel()
+    # A row's length to its last non-NUL byte is at least its count of
+    # non-NUL bytes: the sums are equal only where every row's are.
+    if int(np.char.str_len(fixed).sum()) != np.count_nonzero(keys):
+        return "nul"
+    if (fixed[1:] == fixed[:-1]).any():
+        return "duplicate"
+    if values.min() < 0:
+        return "negative"
+    return None
+
+
 def strings_to_rows(strings: list[bytes], width: int) -> np.ndarray:
     """Host-side: byte strings -> NUL-padded uint8 rows, truncated to width."""
     out = np.zeros((len(strings), width), dtype=np.uint8)

@@ -84,16 +84,15 @@ class KVBatch:
             (self.key_lanes, self.values, self.valid)
         ))
 
-    def host_pairs(self, sort: bool = False) -> list[tuple[bytes, int]]:
-        """Decode the live entries of a batch that is ON THE HOST
-        (``to_host``) to (key bytes, value) pairs, in table order or
-        (``sort``) ordered by key in numpy first — byte order of the
-        NUL-padded rows, which is the keys' own unless a key holds a NUL
-        inside (the caller's ``sorted`` then has the last word, and is
-        linear on a list that is already in order).
+    def host_rows(self, sort: bool = False) -> "HostRows":
+        """The live entries of a batch that is ON THE HOST (``to_host``)
+        as two arrays, in table order or (``sort``) ordered by key in
+        numpy — byte order of the NUL-padded rows, which is the keys' own
+        unless a key holds a NUL inside.
 
-        Lane unpacking in numpy (big-endian reinterpret), and a decode
-        over whole arrays that is O(live entries), not O(table capacity).
+        Lane unpacking in numpy (big-endian reinterpret) over whole
+        arrays: O(live entries), not O(table capacity), and no Python
+        object a row.
         """
         valid = np.asarray(self.valid)
         live_lanes = np.asarray(self.key_lanes)[valid]
@@ -104,12 +103,45 @@ class KVBatch:
         if sort and n_live:
             order = np.argsort(keys.view(f"S{n_lanes * 4}").ravel(), kind="stable")
             keys, live_values = keys[order], live_values[order]
-        return list(zip(bytes_ops.rows_to_strings(keys), live_values.tolist()))
+        return HostRows(keys, live_values)
+
+    def host_pairs(self, sort: bool = False) -> list[tuple[bytes, int]]:
+        """``host_rows`` decoded to (key bytes, value) pairs — a ``bytes``,
+        an ``int`` and a tuple a row — for the callers that merge dicts or
+        write LKVB (serve, the distributor's workers, the plan evaluator's
+        ``value``, ``apps/``, the staged map's dump).  Where a key holds a
+        NUL inside, the caller's ``sorted`` has the last word on the order
+        (linear on a list that is already in order).  The CLI's printed
+        table takes ``host_rows`` and makes no pairs (engine
+        ``finalize_host_rows``)."""
+        return self.host_rows(sort).pairs()
 
     def to_host_pairs(self, sort: bool = False) -> list[tuple[bytes, int]]:
         """Host-side: the fetch (``to_host``) and the decode
         (``host_pairs``) in one call."""
         return self.to_host().host_pairs(sort)
+
+
+@dataclasses.dataclass
+class HostRows:
+    """A table's live rows on the host, as arrays (``KVBatch.host_rows``).
+
+    Attributes:
+      keys: uint8 ``[n, key_width]`` — NUL-padded key bytes.
+      values: int32 ``[n]``.
+    """
+
+    keys: np.ndarray
+    values: np.ndarray
+
+    def __len__(self) -> int:
+        return self.keys.shape[0]
+
+    def __getitem__(self, rows: slice) -> "HostRows":
+        return HostRows(self.keys[rows], self.values[rows])
+
+    def pairs(self) -> list[tuple[bytes, int]]:
+        return list(zip(bytes_ops.rows_to_strings(self.keys), self.values.tolist()))
 
 
 @jax.tree_util.register_dataclass

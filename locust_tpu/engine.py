@@ -48,7 +48,7 @@ from locust_tpu import backend as backend_mod
 from locust_tpu import obs
 from locust_tpu.config import DEFAULT_CONFIG, EngineConfig
 from locust_tpu.core import bytes_ops
-from locust_tpu.core.kv import KVBatch, RecordBatch, grow_table, rows_to_hold
+from locust_tpu.core.kv import HostRows, KVBatch, RecordBatch, grow_table, rows_to_hold
 from locust_tpu.io.snapshot import AsyncCheckpointWriter, finalize_snapshot
 from locust_tpu.ops.map_stage import wordcount_map
 from locust_tpu.ops.process_stage import order_by_lanes, sort_and_compact
@@ -74,6 +74,28 @@ _HOST_COMBINE = {
 }
 
 
+def _fetch_host(table: KVBatch, fetch) -> KVBatch:
+    with obs.span("engine.finalize.d2h", rows=table.size) as sp:
+        host = fetch(table)
+        sp.set(bytes=host.key_lanes.nbytes + host.values.nbytes
+               + host.valid.nbytes)
+    return host
+
+
+def _exact_pairs(pairs, combine: str, sort: bool, sp) -> list[tuple[bytes, int]]:
+    """Decoded pairs with duplicate keys merged and (``sort``) in key
+    order: the Python half of a finalize, inside its ``order`` span."""
+    merged = len(dict(pairs)) != len(pairs)
+    sp.set(merged=int(merged))
+    if merged:  # a duplicate row: merge by hand
+        op = _HOST_COMBINE[combine]
+        by_key: dict[bytes, int] = {}
+        for k, v in pairs:
+            by_key[k] = op(by_key[k], v) if k in by_key else v
+        pairs = list(by_key.items())
+    return sorted(pairs) if sort else pairs
+
+
 def finalize_host_pairs(
     table: KVBatch, combine: str = "sum", sort: bool = True,
     fetch=KVBatch.to_host,
@@ -86,25 +108,46 @@ def finalize_host_pairs(
 
     The table's way to the host, in three spans that each name one piece
     of host work: the copy (``fetch``: one ``device_get``, or the mesh's
-    gather of its shards), the numpy decode, the Python check and sort.
+    gather of its shards), the decode to pairs, the Python check and sort.
+    For the callers that need pairs; a table that is only printed takes
+    ``finalize_host_rows``.
     """
-    with obs.span("engine.finalize.d2h", rows=table.size) as sp:
-        host = fetch(table)
-        sp.set(bytes=host.key_lanes.nbytes + host.values.nbytes
-               + host.valid.nbytes)
+    host = _fetch_host(table, fetch)
     with obs.span("engine.finalize.decode") as sp:
         pairs = host.host_pairs(sort=sort)
         sp.set(rows=len(pairs))
     with obs.span("engine.finalize.order", rows=len(pairs)) as sp:
-        merged = len(dict(pairs)) != len(pairs)
-        sp.set(merged=int(merged))
-        if merged:  # a duplicate row: merge by hand
-            op = _HOST_COMBINE[combine]
-            by_key: dict[bytes, int] = {}
-            for k, v in pairs:
-                by_key[k] = op(by_key[k], v) if k in by_key else v
-            pairs = list(by_key.items())
-        return sorted(pairs) if sort else pairs
+        return _exact_pairs(pairs, combine, sort, sp)
+
+
+def finalize_host_rows(
+    table: KVBatch, combine: str = "sum", fetch=KVBatch.to_host,
+) -> HostRows | list[tuple[bytes, int]]:
+    """A device table as key-ordered host ROWS — two arrays, no Python
+    object a row — for the one consumer that only prints them
+    (``cli._print_table`` through ``bytes_ops.render_rows``).
+
+    The same three spans as ``finalize_host_pairs``: the copy, the numpy
+    decode (``KVBatch.host_rows``), and in ``order`` what makes the rows
+    exact without Python (``bytes_ops.render_blocker``: no key with a NUL
+    inside, no two rows of one key, no negative value; ``fast=1``).  Where
+    the data holds one of the three, the rows go the pairs' way — decoded,
+    merged by hand, ``sorted`` — and the sorted PAIRS come back
+    (``fast=0`` and the ``reason`` on the span, one log line).
+    """
+    host = _fetch_host(table, fetch)
+    with obs.span("engine.finalize.decode") as sp:
+        rows = host.host_rows(sort=True)
+        sp.set(rows=len(rows))
+    with obs.span("engine.finalize.order", rows=len(rows)) as sp:
+        reason = bytes_ops.render_blocker(rows.keys, rows.values)
+        if reason is None:
+            sp.set(fast=1, merged=0)
+            return rows
+        sp.set(fast=0, reason=reason)
+        logger.info("table finalized a pair at a time, not as arrays "
+                    "(%s in its rows)", reason)
+        return _exact_pairs(rows.pairs(), combine, True, sp)
 
 
 def _wrap_i32(v: int) -> int:
@@ -193,6 +236,15 @@ class RunResult:
         """
         with obs.span("engine.finalize", rows=self.table.size):
             return finalize_host_pairs(self.table, self.combine, sort)
+
+    def to_host_rows(self) -> HostRows | list[tuple[bytes, int]]:
+        """The table as key-ordered rows for printing
+        (``finalize_host_rows``), under the same ``engine.finalize``:
+        what the CLI's table on stdout asks for.  Everyone who merges or
+        serializes pairs (serve, the distributor, plans, ``apps/``,
+        ``dump_intermediate``) calls ``to_host_pairs``."""
+        with obs.span("engine.finalize", rows=self.table.size):
+            return finalize_host_rows(self.table, self.combine)
 
     def dump_intermediate(self, path: str, fmt: str = "tsv") -> None:
         """Stage-1 output plumbing: the combined local table as an

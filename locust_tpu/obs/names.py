@@ -28,7 +28,7 @@ NAMES = {
     "cli.load": "span",             # CLI: corpus ingest
     "cli.run": "span",              # CLI: the engine run
     "cli.output": "span",           # CLI: table print / intermediate write
-    "cli.output.render": "span",    # CLI: the table's rows joined into one buffer (arg rows)
+    "cli.output.render": "span",    # CLI: the table's rows made into one buffer — in numpy from ordered rows (fast=1, bytes_ops.render_rows), or joined a row at a time from pairs on the fall-back (fast=0) (args rows, fast)
     "cli.output.write": "span",     # CLI: that buffer written and flushed (arg bytes)
     "engine.stage.map": "span",     # timed_run Map stage (per GROUP of blocks, arg blocks)
     "engine.stage.process": "span", # timed_run Process stage (per group)
@@ -39,10 +39,10 @@ NAMES = {
     "engine.ingest.read": "span",   # one pull from the corpus source: a block read, split and padded (timed_run's reader thread, or inline in the first group)
     "engine.ingest.wait": "span",   # the consumer of a read-ahead queue found it empty and waited for the reader (loader.prefetch_blocks)
     "engine.sync": "span",          # host blocked on the device (arg what)
-    "engine.finalize": "span",      # table D2H + decode + host sort
+    "engine.finalize": "span",      # table D2H + decode + the checks or the host sort
     "engine.finalize.d2h": "span",  # ... the device-to-host copy alone; on the mesh the gather of the shards (args bytes, rows)
-    "engine.finalize.decode": "span",  # ... live rows masked, lanes to key bytes, numpy argsort, rows to pairs (arg rows, live)
-    "engine.finalize.order": "span",   # ... the duplicate-key check, the merge by hand where it fires, sorted (args rows, merged)
+    "engine.finalize.decode": "span",  # ... live rows masked, lanes to key bytes, numpy argsort, both arrays taken in that order: ends at ordered ROWS (finalize_host_rows, the CLI's table) or goes on to pairs (finalize_host_pairs) (arg rows, live)
+    "engine.finalize.order": "span",   # ... for rows three checks over whole arrays (no NUL inside a key, no key twice, no negative value: fast=1), the pairs' way only where one fails (fast=0, reason nul | duplicate | negative); for pairs the duplicate-key check, the merge by hand where it fires, sorted (args rows, merged, fast, reason)
     "engine.program.trace": "span", # jax traced a program (obs/programs.py)
     "engine.program.lower": "span", # ... lowered it to an MLIR module
     "engine.program.load": "span",  # ... compiled it or read it from the cache
@@ -51,7 +51,7 @@ NAMES = {
     "mesh.h2d": "span",             # mesh: a round's lines placed on the devices, inside its mesh.round (arg bytes)
     "mesh.sync": "span",            # mesh: host blocked on the devices' stats (arg what: stats | regrow)
     "mesh.table.grow": "span",      # mesh: every shard grown a step, the rounds since the last whole table folded again (args from_rows, to_rows, worst_shard, rounds_redone)
-    "mesh.gather": "span",          # mesh: table from its shards to sorted host pairs (args rows, shards)
+    "mesh.gather": "span",          # mesh: table from its shards to key-ordered host rows (to_host_rows) or sorted pairs (to_host_pairs) (args rows, shards)
     "sort.read": "span",            # record sort: a block of the mapped file found, or its copy where it must be padded (arg bytes)
     "sort.h2d": "span",             # record sort: a staged block handed up and placed (arg bytes; under --mesh also device)
     "sort.keys": "span",            # record sort: the key sort launched and waited for (arg rows)

@@ -1511,3 +1511,17 @@ class DistributedResult:
             return finalize_host_pairs(
                 self.table, self.combine, sort, fetch=_gather_batch_host
             )
+
+    def to_host_rows(self):
+        """Gather all shards to key-ordered ROWS for printing
+        (``engine.finalize_host_rows``: arrays, or the sorted pairs where
+        the data cannot be printed from arrays), under the same
+        ``mesh.gather`` — the CLI's table; ``to_host_pairs`` for everyone
+        who needs pairs."""
+        from locust_tpu.engine import finalize_host_rows
+
+        with obs.span("mesh.gather", rows=self.table.size,
+                      shards=self.table.size // self.shard_capacity):
+            return finalize_host_rows(
+                self.table, self.combine, fetch=_gather_batch_host
+            )

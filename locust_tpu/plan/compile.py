@@ -261,10 +261,11 @@ class CompiledPlan:
         output-bytes rendering (CLI drivers print from ``value``);
         ``finalize=False`` additionally skips the wordcount fold's
         host-pairs decode (``value`` comes back None, ``run_result``
-        carries the device table) — for callers like the CLI's staged
-        map node that only dump the raw table, where the full decode
-        would be paid and discarded.  Only a plan whose sink consumes
-        the wordcount fold directly may skip it.
+        carries the device table) — for the CLI, which prints its table
+        from ordered rows (``RunResult.to_host_rows``) and whose staged
+        map node only dumps the raw table: the pair list would be paid
+        and discarded.  Only a plan whose sink consumes the wordcount
+        fold directly may skip it.
         """
         stage = self._stages[self._stages[self._root][2]]
         if not finalize and not (

@@ -364,6 +364,17 @@ def test_a_traced_mesh_job_says_what_its_round_and_its_gather_are_made_of(n_dev,
     assert tail[0]["args"]["rows"] == res.table.size == gather["args"]["rows"]
     assert tail[0]["args"]["bytes"] == res.table.size * (res.table.num_lanes * 4 + 5)
     assert tail[1]["args"]["rows"] == len(want) == tail[2]["args"]["rows"]
+    # The CLI's table asks for ROWS: another mesh.gather over the same
+    # three children, the order span saying the arrays are what is printed.
+    rows = res.to_host_rows()
+    assert rows.pairs() == pairs and rows.keys.shape == (len(want), 8)
+    gather2 = [e for e in tracer.to_chrome()["traceEvents"]
+               if e.get("ph") == "X" and e["name"] == "mesh.gather"][1]
+    tail2 = kids(gather2)
+    assert [e["name"] for e in tail2] == [e["name"] for e in tail]
+    assert [e["args"]["rows"] for e in tail2] == [e["args"]["rows"] for e in tail]
+    assert tail2[2]["args"]["fast"] == 1 and "fast" not in tail[2]["args"]
+    assert tail2[2]["args"]["merged"] == tail[2]["args"]["merged"] == 0
     # Only a configuration's FIRST job holds them: a second job re-makes
     # no program — on this engine or on a new one of the configuration,
     # which takes the process's (engine._programs_for) — and its rounds

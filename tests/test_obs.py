@@ -764,18 +764,22 @@ def _many_words(n):
             for i in range(n)]
 
 
-def test_the_tables_way_to_the_host_is_three_children_of_engine_finalize():
-    """engine.finalize (the one of ``to_host_pairs``; the count read of
-    ``_finish`` keeps its own, childless) resolves into the copy, the
-    numpy decode and the Python check + sort: one each, in that order,
-    none outlasting it, together at least nine tenths of it."""
+@pytest.mark.parametrize("method", ["to_host_pairs", "to_host_rows"])
+def test_the_tables_way_to_the_host_is_three_children_of_engine_finalize(method):
+    """engine.finalize (the one of ``to_host_pairs`` or ``to_host_rows``;
+    the count read of ``_finish`` keeps its own, childless) resolves into
+    the copy, the numpy decode and the check (+ sort, for pairs): one
+    each, in that order, none outlasting it, together at least nine
+    tenths of it.  Rows say ``fast`` on the last; pairs do not."""
     eng = MapReduceEngine(EngineConfig(block_lines=256, line_width=32,
                                        key_width=8, emits_per_line=4))
     lines = _many_words(4096)
     rows = eng.rows_from_lines(lines)
     eng.timed_run(rows).to_host_pairs()  # programs built, caches warm
     t = obs.enable(process="tail")
-    pairs = eng.timed_run(rows).to_host_pairs()
+    pairs = getattr(eng.timed_run(rows), method)()
+    if method == "to_host_rows":
+        pairs = pairs.pairs()
     assert dict(pairs) == py_wordcount(lines) and pairs == sorted(pairs)
     spans = _spans(t)
     count_read, decode = [e for e in spans if e["name"] == "engine.finalize"]
@@ -787,6 +791,7 @@ def test_the_tables_way_to_the_host_is_three_children_of_engine_finalize():
     assert d2h["args"]["bytes"] == table.size * (table.num_lanes * 4 + 4 + 1)
     assert dec["args"]["rows"] == len(pairs) == order["args"]["rows"]
     assert order["args"]["merged"] == 0
+    assert order["args"].get("fast") == (1 if method == "to_host_rows" else None)
     assert [e["name"] for e in spans if e["name"] in TAIL] == list(TAIL)
     validate_trace(t.to_chrome())
 
@@ -929,6 +934,14 @@ def test_cli_setup_ends_where_the_first_load_starts(flags, tmp_path, capsysbinar
     parents = {e["args"]["id"]: e["name"] for e in spans}
     tails = [parents[e["args"]["parent"]] for e in spans if e["name"] in TAIL]
     assert tails == ["mesh.gather" if "--mesh" in flags else "engine.finalize"] * 3
+    # Once a job each, the table printed from rows: fast on the check and
+    # on the render, and the rows asked for inside cli.run.
+    assert sorted(e["name"] for e in spans if e["name"] in TAIL) == sorted(TAIL)
+    [order] = [e for e in spans if e["name"] == "engine.finalize.order"]
+    assert order["args"]["fast"] == kids[0]["args"]["fast"] == 1
+    assert "reason" not in order["args"]
+    [run] = [e for e in spans if e["name"] == "cli.run"]
+    assert _encloses(run, order) and run["ts"] + run["dur"] <= output["ts"] + 1.0
 
 
 def test_sort_command_records_cli_setup_before_its_load(tmp_path, capsys):
