@@ -18,9 +18,17 @@ tests/test_plan.py).  These subcommands wire the existing apps:
 
 Edge-list format: one ``src dst`` pair of integer node ids per line;
 lines starting with ``#`` are comments (the web-Google / SNAP convention,
-BASELINE.json configs[3]).  For index/tfidf the doc id of line i is
-``i // lines_per_doc`` — line-sharded documents, the same convention as
-the library tests.  ``sort`` is TeraSort: IN holds fixed-width binary
+BASELINE.json configs[3]).  A clean file — comments at its head, then
+``src<TAB or SPACE>dst<LF>`` and nothing else — is parsed in numpy; any
+other goes through the line loop, which names the line at fault.  Ids
+are int32: a larger one, or one past ``--num-nodes``, is an error and
+never a wrapped index.  ``pagerank`` prints one ``id<TAB>rank`` line for
+every node 0 .. N-1 in id order (``--top K``: the K highest, by rank),
+the rank with NINE significant digits as ``d.dddddddde-XX`` — what a
+float32 needs to come back bit for bit; eight decimals held two or three
+digits of a rank of 1e-6 (``plan.compile.rank_row``).  For index/tfidf
+the doc id of line i is ``i // lines_per_doc`` — line-sharded documents,
+the same convention as the library tests.  ``sort`` is TeraSort: IN holds fixed-width binary
 records (gensort's: 100 bytes, the first 10 the key), OUT gets every one
 of them ordered by key as unsigned bytes, equal keys in input order; a
 size that is no whole number of records, or an empty IN, is an error and
@@ -83,7 +91,9 @@ def _add_backend_flag(p: argparse.ArgumentParser,
 def build_parser(cmd: str) -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(prog=f"locust_tpu {cmd}")
     if cmd == "pagerank":
-        p.add_argument("edges", help="edge list: 'src dst' per line, # comments")
+        p.add_argument("edges", help="edge list: 'src dst' per line, # comments; "
+                       "prints 'id<TAB>rank' for every node, the rank with "
+                       "nine significant digits (d.dddddddde-XX)")
         p.add_argument("--num-iters", type=int, default=20)
         p.add_argument("--damping", type=float, default=0.85)
         p.add_argument("--num-nodes", type=int, default=None,
@@ -140,51 +150,63 @@ def load_edges(path: str) -> tuple[np.ndarray, np.ndarray]:
     from locust_tpu.plan import PlanError
     from locust_tpu.plan.compile import edges_from_bytes
 
-    with open(path, "rb") as f:
-        data = f.read()
+    with obs.span("pagerank.read") as sp:
+        with open(path, "rb") as f:
+            data = f.read()
+        sp.set(bytes=len(data))
     try:
         return edges_from_bytes(data)
     except PlanError as e:
         raise ValueError(f"{path}: {e}")
 
 
-
-
 def run_pagerank(args) -> int:
-    src, dst = load_edges(args.edges)
-    n = (
-        args.num_nodes
-        if args.num_nodes is not None
-        else int(max(src.max(), dst.max())) + 1
-    )
-    if max(int(src.max()), int(dst.max())) >= n:
-        print(
-            f"locust_tpu: error: --num-nodes {n} but max node id is "
-            f"{max(int(src.max()), int(dst.max()))}",
-            file=sys.stderr,
-        )
-        return 1
     from locust_tpu.plan import pagerank_plan
-    from locust_tpu.plan.compile import compile_plan
+    from locust_tpu.plan.compile import (
+        MAX_NODE_ID, compile_plan, rank_row, render_ranks,
+    )
 
     # The driver constructs the canonical plan and lets the compiler
     # pick the lowering (apps.pagerank single-device vs ShardedPageRank
     # under --mesh) — same value, byte-identical output (docs/PLAN.md).
-    ranks = compile_plan(
+    rank_plan = compile_plan(
         pagerank_plan(num_iters=args.num_iters, damping=args.damping),
         mesh=args.mesh,
-    ).run((src, dst), num_nodes=n, render=False).value
-    from locust_tpu.plan.compile import rank_row
-
-    order = (
-        np.argsort(-ranks, kind="stable")[: args.top]
-        if args.top is not None
-        else np.arange(n)
     )
-    out = sys.stdout.buffer
-    for node in order:
-        out.write(rank_row(int(node), ranks[node]))
-    out.flush()
+    if args.trace_out:  # main's entry to the first cli.load, once it is over
+        obs.span_at("cli.setup", args.entered, time.time())
+    with obs.span("cli.load"):
+        src, dst = load_edges(args.edges)
+        top = int(max(src.max(), dst.max()))
+        n = args.num_nodes if args.num_nodes is not None else top + 1
+        # Both bounds before anything is put on the device, where an
+        # index past the end is clamped or dropped and never an error.
+        if top >= n or n > MAX_NODE_ID + 1:
+            print(
+                f"locust_tpu: error: --num-nodes {n} but max node id is "
+                f"{top}: every id must lie under --num-nodes, and "
+                f"--num-nodes under {MAX_NODE_ID + 1} (ids are int32)",
+                file=sys.stderr,
+            )
+            return 1
+        print(f"[locust] {src.shape[0]} edges loaded, {n} nodes",
+              file=sys.stderr)
+    with obs.span("cli.run"):
+        ranks = rank_plan.run(
+            (src, dst), num_nodes=n, render=False
+        ).value
+    with obs.span("cli.output"):
+        whole = args.top is None
+        with obs.span("cli.output.render", fast=int(whole)) as sp:
+            if whole:
+                out = render_ranks(ranks)
+            else:
+                order = np.argsort(-ranks, kind="stable")[: args.top]
+                out = b"".join(rank_row(int(i), ranks[i]) for i in order)
+            sp.set(rows=out.count(b"\n"))
+        with obs.span("cli.output.write", bytes=len(out)):
+            sys.stdout.buffer.write(out)
+            sys.stdout.buffer.flush()
     return 0
 
 

@@ -184,6 +184,85 @@ def render_rows(keys: np.ndarray, values: np.ndarray) -> bytes:
     return flat[flat != 0].tobytes()
 
 
+def _write_digits(out: np.ndarray, cols, v: np.ndarray) -> None:
+    """``v``'s decimal digits (uint32 ``[n]``), least significant first,
+    into the columns ``cols`` of every row of ``out``."""
+    ten, zero = np.uint32(10), np.uint32(ord("0"))
+    for col in cols:
+        q = v // ten
+        out[:, col] = v - q * ten + zero
+        v = q
+
+
+_RANK_CHARS = 14  # a rank as plan.compile.rank_row spells it: d.dddddddde-XX
+# 10**k as the nearest double for every two-digit decimal exponent e
+# (k = 8 - e; a float32's e lies in -45 .. 38).
+_POW10_BIAS = 100
+_POW10 = np.array(
+    [1.0 / float(10 ** -k) if k < 0 else float(10 ** k)
+     for k in range(-_POW10_BIAS, 111)]
+)
+
+
+def render_rank_rows(ranks: np.ndarray) -> bytes | None:
+    """Host-side: a rank vector -> the ``id<TAB>rank<LF>`` bytes of every
+    node in id order, in numpy, with no Python object a row; byte-equal
+    to ``plan.compile.rank_row`` a row.  None where the vector holds what
+    the fixed-width layout cannot spell (a negative, inf, nan, a
+    three-digit exponent): the caller then joins ``rank_row`` a row.
+
+    A rank is scaled to a nine-digit integer in float64 (exact for a
+    float32 up to 4e-16 relative), rounded, and its digits written by
+    division.  Where the scaled value lies within 1e-5 of a half — a few
+    rows in a million — the double cannot say which way the exact decimal
+    rounds, and that row's fourteen characters come from Python's own
+    formatting.
+    """
+    x = np.asarray(ranks, dtype=np.float64)
+    n = x.shape[0]
+    if n == 0:
+        return b""
+    if not np.isfinite(x).all() or x.min() < 0:
+        return None
+    live = x > 0
+    e = np.zeros(n, dtype=np.int64)
+    e[live] = np.floor(np.log10(x[live]))
+    if np.abs(e).max() > 98:
+        return None
+    scaled = x * _POW10[8 - e + _POW10_BIAS]
+    for low, step in ((scaled < 1e8) & live, -1), (scaled >= 1e9, 1):
+        e[low] += step  # log10's floor, off by one beside a power of ten
+        scaled[low] = x[low] * _POW10[8 - e[low] + _POW10_BIAS]
+    whole = np.floor(scaled)
+    unsure = np.flatnonzero(np.abs(scaled - whole - 0.5) < 1e-5)
+    mant = np.rint(scaled).astype(np.uint32)
+    carried = mant == 1_000_000_000  # 9.999999996 prints as 1.00000000e+01
+    mant[carried] = 100_000_000
+    e[carried] += 1
+
+    id_digits = len(str(n - 1))
+    out = np.empty((n, id_digits + _RANK_CHARS + 2), dtype=np.uint8)
+    _write_digits(out, range(id_digits - 1, -1, -1), np.arange(n, dtype=np.uint32))
+    for col in range(id_digits - 1):  # ids are 0 .. n-1: the short ones lead
+        out[:10 ** (id_digits - 1 - col), col] = 0  # NUL, dropped below
+    out[:, id_digits] = ord("\t")
+    out[:, -1] = ord("\n")
+    rank = out[:, id_digits + 1:-1]
+    # d.dddddddd: column 1 is the point
+    _write_digits(rank, (9, 8, 7, 6, 5, 4, 3, 2, 0), mant)
+    ten, zero = np.uint32(10), np.uint32(ord("0"))
+    rank[:, 1] = ord(".")
+    rank[:, 10] = ord("e")
+    rank[:, 11] = np.where(e < 0, ord("-"), ord("+"))
+    mag = np.abs(e).astype(np.uint32)
+    rank[:, 12] = mag // ten + zero
+    rank[:, 13] = mag % ten + zero
+    for i in unsure:
+        rank[i] = np.frombuffer(format(x[i], ".8e").encode(), np.uint8)
+    flat = out.ravel()
+    return flat[flat != 0].tobytes()
+
+
 def render_blocker(keys: np.ndarray, values: np.ndarray) -> str | None:
     """What stands between ordered rows and ``render_rows``, or None.
 

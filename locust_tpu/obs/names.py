@@ -58,6 +58,11 @@ NAMES = {
     "sort.permute": "span",         # record sort: a block's payload gather launched (arg rows; under --mesh also device)
     "sort.d2h": "span",             # record sort: a sorted block brought down (arg bytes)
     "sort.write": "span",           # record sort: a sorted block written to OUT (arg bytes)
+    "pagerank.read": "span",        # pagerank CLI: the edge list read whole from its file (arg bytes)
+    "pagerank.parse": "span",       # the ONE edge parser (plan.compile.edges_from_bytes, CLI and daemon): fast=1 a clean file read in numpy, 0 the line loop (args bytes, edges, fast)
+    "pagerank.h2d": "span",         # pagerank: src and dst put on the device and waited for (arg bytes)
+    "pagerank.iterate": "span",     # pagerank: the iterate program dispatched and waited for (its child engine.sync what=iterate); under --mesh ShardedPageRank's whole run (args nodes, edges, iters)
+    "pagerank.d2h": "span",         # pagerank: the rank vector brought down (arg bytes)
     "sort.mesh.split": "span",      # mesh record sort: sample, gather, splitters and their one sync (args samples, splitters)
     "sort.mesh.exchange": "span",   # mesh record sort: bucket, bin, all-to-all, the bin counts read back (args bin_rows, attempt, worst_bin)
     "sort.mesh.retry": "span",      # mesh record sort: parent of an exchange redone with larger bins (args from_bin_rows, to_bin_rows, worst_bin)
@@ -101,6 +106,9 @@ NAMES = {
     "engine.table_rows": "gauge",   # timed_run: the table's capacity at the job's end
     "engine.table_grows": "counter",  # timed_run: growth steps the job took
     "engine.merges": "counter",     # timed_run: merge programs launched (one a group + one a group redone)
+    "pagerank.edges": "counter",    # pagerank: edges ranked over
+    "pagerank.nodes": "counter",    # pagerank: dense node slots (largest id + 1, or --num-nodes)
+    "pagerank.iterations": "counter",  # pagerank: rounds run (benchmarks' closed_loop_cli_edges holds a traced job to the configuration's count by it)
     "sort.records": "counter",      # record sort: records staged on the device
     "sort.bytes_out": "counter",    # record sort: bytes written to OUT
     "sort.mesh.retries": "counter",          # mesh record sort: exchanges redone because a bin overflowed

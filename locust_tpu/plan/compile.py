@@ -827,20 +827,37 @@ class _RunCtx:
                 f"endpoint's cap ({self.max_nodes}); renumber the "
                 "graph or run it through the CLI"
             )
+        edges = int(src.shape[0])
+        obs.metric_inc("pagerank.edges", edges)
+        obs.metric_inc("pagerank.nodes", n)
+        obs.metric_inc("pagerank.iterations", num_iters)
+        sizes = dict(nodes=n, edges=edges, iters=num_iters)
         if self.cp.mesh:
             from locust_tpu.apps.pagerank import ShardedPageRank
             from locust_tpu.parallel.mesh import make_mesh
 
-            ranks = ShardedPageRank(make_mesh(), n, damping=damping).run(
-                src, dst, num_iters=num_iters
-            )
+            with obs.span("pagerank.iterate", **sizes):
+                ranks = ShardedPageRank(
+                    make_mesh(), n, damping=damping
+                ).run(src, dst, num_iters=num_iters)
         else:
+            import jax
+
             from locust_tpu.apps.pagerank import pagerank
 
-            ranks = np.asarray(pagerank(
-                np.asarray(src, np.int32), np.asarray(dst, np.int32),
-                num_nodes=n, num_iters=num_iters, damping=damping,
-            ))
+            with obs.span("pagerank.h2d", bytes=8 * edges):
+                on_device = jax.block_until_ready(jax.device_put(
+                    (np.asarray(src, np.int32), np.asarray(dst, np.int32))
+                ))
+            with obs.span("pagerank.iterate", **sizes):
+                ranks = pagerank(
+                    *on_device, num_nodes=n, num_iters=num_iters,
+                    damping=damping,
+                )
+                with obs.span("engine.sync", what="iterate"):
+                    ranks.block_until_ready()
+            with obs.span("pagerank.d2h", bytes=4 * n):
+                ranks = np.asarray(ranks)
         self._acct[sid] = (n, False, 0)
         return ranks
 
@@ -868,8 +885,12 @@ def _fold_value_bytes(fold: str, value) -> int:
 def rank_row(node: int, rank: float) -> bytes:
     """ONE spelling of a pagerank output row — the ``ranks`` sink and
     the driver's ``--top`` path (which reorders rows) both use it, so
-    the formats cannot drift apart."""
-    return f"{node}\t{rank:.8f}\n".encode()
+    the formats cannot drift apart.  Nine significant digits,
+    ``d.dddddddde-XX``: what a float32 needs to come back bit for bit
+    (eight decimals held two or three digits of a rank of 1e-6).
+    ``bytes_ops.render_rank_rows`` prints a whole vector's rows from
+    arrays, byte-equal to this a row (tests/test_pagerank_cli.py)."""
+    return f"{node}\t{float(rank):.8e}\n".encode()
 
 
 def iter_rendered(op: str, value):
@@ -904,15 +925,83 @@ def iter_rendered(op: str, value):
 def _render(op: str, value) -> bytes:
     """Sink rendering: byte-for-byte the hand-wired drivers' stdout —
     the byte-identity contract serve plan results ride."""
+    if op == "ranks":
+        return render_ranks(value)
     return b"".join(iter_rendered(op, value))
 
 
+def render_ranks(ranks) -> bytes:
+    """Every node's ``rank_row`` in id order as one buffer: rendered from
+    arrays (``bytes_ops.render_rank_rows``), a row at a time only for a
+    vector that holds what its fixed-width layout cannot spell."""
+    from locust_tpu.core import bytes_ops
+
+    out = bytes_ops.render_rank_rows(ranks)
+    if out is None:
+        out = b"".join(iter_rendered("ranks", ranks))
+    return out
+
+
+# The largest node id: ids are int32 on the device, where an index past
+# the end is clamped or dropped and never an error.
+MAX_NODE_ID = (1 << 31) - 1
+
+
 def edges_from_bytes(corpus: bytes):
-    """SNAP-style ``src dst`` edge list from raw bytes.  The ONE parser
-    (comment/2-field/int/negative-id rules): ``cli_apps.load_edges``
-    delegates here, so a pagerank plan submitted to the daemon parses
-    its corpus exactly like the CLI parses a file — by construction,
-    not by parallel maintenance."""
+    """SNAP-style ``src dst`` edge list from raw bytes, as two int32
+    arrays.  The ONE parser (comment/2-field/int/negative-id rules):
+    ``cli_apps.load_edges`` delegates here, so a pagerank plan submitted
+    to the daemon parses its corpus exactly like the CLI parses a file —
+    by construction, not by parallel maintenance.  A clean file (``#``
+    lines at its head, then ``src<TAB or SPACE>dst<LF>`` and nothing
+    else) is read without a Python object an edge; anything else goes
+    through the line loop, which words the errors."""
+    with obs.span("pagerank.parse", bytes=len(corpus)) as sp:
+        parsed = _edges_clean(corpus)
+        fast = parsed is not None
+        src, dst = parsed if fast else _edges_by_line(corpus)
+        _check_top_id(max(int(src.max()), int(dst.max())))
+        sp.set(edges=int(src.shape[0]), fast=int(fast))
+        return src.astype("int32"), dst.astype("int32")
+
+
+def _check_top_id(top: int) -> None:
+    if top > MAX_NODE_ID:
+        raise PlanError(
+            f"edge list has a node id past int32 ({top} > {MAX_NODE_ID})"
+        )
+
+
+def _edges_clean(corpus: bytes):
+    """The edges of a CLEAN file as two int64 arrays, or None.  Clean:
+    ``#`` lines at the head only; then digits, each pair of numbers one
+    TAB or SPACE apart and each line ended by one LF (the last may lack
+    it).  Every byte that is no digit is looked at — ``translate`` keeps
+    them, and they must alternate separator, LF — so a sign, a third
+    field, a blank line, a CR or a later comment sends the file to the
+    line loop; ``numpy.fromstring`` then reads numbers it cannot misread
+    (a number past int64 comes back as int64's largest, past int32)."""
+    import numpy as np
+
+    head = 0
+    while corpus.startswith(b"#", head):
+        head = corpus.find(b"\n", head) + 1
+        if head == 0:
+            return None  # comments only
+    body = corpus[head:] if head else corpus
+    if not body[:1].isdigit():
+        return None
+    seps = np.frombuffer(body.translate(None, b"0123456789"), np.uint8)
+    within, ends = seps[0::2], seps[1::2]
+    if not (((within == 9) | (within == 32)).all() and (ends == 10).all()):
+        return None
+    values = np.fromstring(body, dtype=np.int64, sep=" ")
+    if values.size % 2 or values.size != seps.size + body[-1:].isdigit():
+        return None  # a separator that stands alone: a lone field, a blank
+    return values[0::2], values[1::2]
+
+
+def _edges_by_line(corpus: bytes):
     import numpy as np
 
     src, dst = [], []
@@ -935,8 +1024,7 @@ def edges_from_bytes(corpus: bytes):
             )
     if not src:
         raise PlanError("edge list has no edges")
-    s = np.asarray(src, np.int64)
-    d = np.asarray(dst, np.int64)
-    if s.min() < 0 or d.min() < 0:
+    if min(min(src), min(dst)) < 0:
         raise PlanError("edge list has a negative node id")
-    return s, d
+    _check_top_id(max(max(src), max(dst)))  # Python ints: past int64 too
+    return np.asarray(src, np.int64), np.asarray(dst, np.int64)

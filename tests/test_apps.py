@@ -4,6 +4,7 @@ import numpy as np
 import jax
 import pytest
 
+from locust_tpu import pagerank_reference
 from locust_tpu.config import EngineConfig
 from locust_tpu.apps import build_inverted_index, pagerank
 from locust_tpu.apps.pagerank import DistributedPageRank
@@ -13,15 +14,8 @@ from helpers import strtok_tokens
 
 
 def np_pagerank(src, dst, n, iters=20, d=0.85):
-    deg = np.bincount(src, minlength=n).astype(np.float64)
-    ranks = np.full(n, 1.0 / n)
-    for _ in range(iters):
-        contrib = np.zeros(n)
-        w = ranks[src] / deg[src]
-        np.add.at(contrib, dst, w)
-        dangling = ranks[deg == 0].sum()
-        ranks = (1 - d) / n + d * (contrib + dangling / n)
-    return ranks
+    """The repo's ONE float64 oracle (``locust_tpu/pagerank_reference.py``)."""
+    return pagerank_reference.pagerank(src, dst, n, num_iters=iters, damping=d)
 
 
 EDGES = np.array(

@@ -77,7 +77,17 @@ def _lowered_text(eng: MapReduceEngine) -> tuple[str, ...]:
     lowered += (step.lower(*shapes),)
     lowered += _lowered_record_sort(eng)
     lowered += _toy_mesh_record_sort()[1]
+    lowered += (_lowered_pagerank(),)
     return tuple(low.as_text() for low in lowered)
+
+
+def _lowered_pagerank():
+    """The ``pagerank`` command's one program at toy shapes, damping
+    traced as the plan passes it."""
+    from locust_tpu.apps.pagerank import pagerank
+
+    edges = jax.ShapeDtypeStruct((16,), jnp.int32)
+    return pagerank.lower(edges, edges, num_nodes=8, num_iters=3, damping=0.85)
 
 
 def _lowered_record_sort(eng: MapReduceEngine) -> tuple:
@@ -132,8 +142,8 @@ def _toy_mesh_record_sort():
 @pytest.fixture(scope="module")
 def program_names() -> dict[str, set[str]]:
     """Module names of the programs the cells run (the default path's
-    four, the mesh's step, the record sort's four and the mesh record
-    sort's six), as the
+    four, the mesh's step, the record sort's four, the mesh record
+    sort's six and pagerank's one), as the
     device trace's ``XLA Modules`` line will show them: of a
     configuration's ``first`` engine, which builds them, and of a later
     one, which takes the process's (``shared``, engine._programs_for) —
@@ -210,7 +220,8 @@ def _metric_cases():
     for path in METRIC_FILES:
         with open(path) as f:
             spec = json.load(f)
-        by_program = spec["reader"] in ("xla_module", "roofline", "roofline_job") or (
+        by_program = spec["reader"] in (
+            "xla_module", "roofline", "roofline_job", "roofline_pagerank_job") or (
             spec["reader"] == "roofline_device_job" and "programs" in spec)
         for which in ("first", "shared") if by_program else (None,):
             name = os.path.basename(path)
@@ -242,7 +253,7 @@ def test_layer_metric_reads_a_name_the_program_still_has(path, which, request):
             _assert_patterns_match(spec["programs"], fixture("program_names")[which], "programs")
         else:
             _assert_patterns_match(spec["ops"], fixture("mesh_record_op_names"), "ops")
-    elif reader == "roofline_job":
+    elif reader in ("roofline_job", "roofline_pagerank_job"):
         _assert_patterns_match(spec["programs"], fixture("program_names")[which], "programs")
     elif reader == "roofline":
         names = fixture("program_names")[which]

@@ -199,6 +199,23 @@ def test_tokenize_block_einsum_branch_compiles(one_chip):
     assert "convolution" in compiled.as_text() or "dot" in compiled.as_text()
 
 
+def test_pagerank_iterate_compiles_at_the_cells_shape(one_chip):
+    """``pagerank5M.batch``'s one program at its own shape — web-Google's
+    5,105,039 edges over 916,428 ids, 20 rounds, damping traced as the
+    plan passes it (3.4 s on a described v5e, PR 41): a change that breaks
+    the shape, or blows its memory past a chip's, shows on the CPU."""
+    from locust_tpu.apps.pagerank import pagerank
+
+    edges = jax.ShapeDtypeStruct((5_105_039,), jnp.int32, sharding=one_chip)
+    compiled = pagerank.lower(
+        edges, edges, num_nodes=916_428, num_iters=20, damping=0.85
+    ).compile()
+    text = compiled.as_text()
+    assert "while" in text and "scatter" in text  # one scan, the scatter-add inside
+    stats = compiled.memory_analysis()
+    assert stats.argument_size_in_bytes + stats.temp_size_in_bytes < 1 << 30
+
+
 def test_check_kernels_match_chip_smoke():
     """Every kernel chip_smoke.py runs on the chip has a compile case
     above, by name."""

@@ -1,0 +1,291 @@
+"""``python -m locust_tpu pagerank`` held to the plain reference (PR 41).
+
+At CPU size — a seeded R-MAT graph of some 10^4 nodes and 10^5 edges from
+the benchmark's own generator, with hubs, dangling nodes and ids no edge
+names: the CLI's printed ranks against ``locust_tpu/pagerank_reference.py``
+(float64, no jax) within the benchmark cell's tolerance, the fast edge
+parser against the line loop on clean files and on every malformed shape,
+the numpy rank renderer against ``rank_row`` a row, and the spans and
+counters a ``--trace-out`` file of a pagerank job holds.
+"""
+
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+from locust_tpu import cli, pagerank_reference
+from locust_tpu.core import bytes_ops
+from locust_tpu.plan import PlanError
+from locust_tpu.plan import compile as plan_compile
+from locust_tpu.plan.compile import edges_from_bytes, rank_row, render_ranks
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+
+import rmat_edges  # noqa: E402
+
+with open(os.path.join(REPO, "benchmarks", "configs", "pagerank-rmat-5M.json")) as _f:
+    TOLERANCE = json.load(_f)["tolerance"]
+
+
+@pytest.fixture(scope="module")
+def graph(tmp_path_factory):
+    """(path, src, dst, N) of a 100,000-edge R-MAT edge list."""
+    path = str(tmp_path_factory.mktemp("rmat") / "edges.txt")
+    rmat_edges.build(path, 100_000, 2147483659)
+    src, dst = rmat_edges.load(path)
+    return path, src, dst, int(max(src.max(), dst.max())) + 1
+
+
+def test_the_graph_has_hubs_dangling_nodes_and_unnamed_ids(graph):
+    _, src, dst, n = graph
+    out_degree = np.bincount(src, minlength=n)
+    in_degree = np.bincount(dst, minlength=n)
+    assert src.size == 100_000 and n > 10_000
+    assert len({(s, d) for s, d in zip(src.tolist(), dst.tolist())}) == src.size
+    assert (src != dst).all()
+    assert in_degree.max() > 200 and out_degree.max() > 200          # hubs
+    assert ((out_degree == 0) & (in_degree > 0)).sum() > 1000          # dangling
+    assert ((out_degree == 0) & (in_degree == 0)).sum() > 100          # unnamed ids
+    assert out_degree[n - 1] + in_degree[n - 1] > 0                    # N is fixed by a named id
+
+
+def test_cli_ranks_match_the_float64_reference(graph, capsysbinary):
+    path, src, dst, n = graph
+    assert cli.main(["pagerank", path, "--backend", "cpu"]) == 0
+    table = capsysbinary.readouterr().out
+    assert table.count(b"\n") == n
+    ids, ranks = pagerank_reference.parse_ranks(table)
+    assert np.array_equal(ids, np.arange(n))
+    want = pagerank_reference.pagerank(src, dst, n)
+    assert (np.abs(ranks - want) / want).max() <= TOLERANCE["rank_rel"]
+    assert abs(ranks.sum() - 1.0) <= TOLERANCE["sum_abs"]
+    # Nine significant digits carry the float32 itself.
+    from locust_tpu.apps.pagerank import pagerank
+
+    device = np.asarray(pagerank(src.astype(np.int32), dst.astype(np.int32), num_nodes=n,
+                                 num_iters=20, damping=0.85))  # traced, as the plan passes it
+    assert np.array_equal(ranks.astype(np.float32), device)
+
+
+def test_the_reference_agrees_with_the_benchmarks_own_copy(graph):
+    _, src, dst, n = graph
+    ours = pagerank_reference.pagerank(src, dst, n, num_iters=7, damping=0.9)
+    np.testing.assert_allclose(ours, rmat_edges.oracle((src, dst), 7, 0.9), rtol=1e-13)
+    assert abs(ours.sum() - 1.0) < 1e-12
+
+
+@pytest.mark.parametrize("rounds, rounding", [(10, None), (20, "bfloat16")],
+                         ids=["ten-rounds", "bfloat16"])
+def test_the_tolerance_tells_a_short_or_a_coarse_result_from_a_sound_one(
+        graph, rounds, rounding):
+    import ml_dtypes
+
+    _, src, dst, n = graph
+    want = pagerank_reference.pagerank(src, dst, n)
+    got = pagerank_reference.pagerank(src, dst, n, num_iters=rounds)
+    if rounding:
+        got = got.astype(ml_dtypes.bfloat16).astype(np.float64)
+    assert (np.abs(got - want) / want).max() > 5 * TOLERANCE["rank_rel"]
+
+
+# ------------------------------------------------------------ the parser
+
+CLEAN = {
+    "tabs": b"0\t1\n1\t2\n2\t0\n",
+    "spaces": b"0 1\n1 2\n2 0\n",
+    "mixed-separators": b"0 1\n1\t2\n2 0\n",
+    "no-last-newline": b"0\t1\n1\t2\n2\t0",
+    "snap-header": b"# Directed graph\n# Nodes: 3 Edges: 3\n# FromNodeId\tToNodeId\n0\t1\n1\t2\n2\t0\n",
+    "one-edge": b"7 3",
+    "long-ids": b"2147483647\t0\n12\t2147483646\n",
+}
+
+
+@pytest.mark.parametrize("name", sorted(CLEAN))
+def test_a_clean_file_is_read_in_numpy_and_equals_the_line_loop(name):
+    data = CLEAN[name]
+    fast = plan_compile._edges_clean(data)
+    assert fast is not None
+    slow = plan_compile._edges_by_line(data)
+    assert np.array_equal(fast[0], slow[0]) and np.array_equal(fast[1], slow[1])
+    src, dst = edges_from_bytes(data)
+    assert src.dtype == dst.dtype == np.int32
+    assert np.array_equal(src, slow[0]) and np.array_equal(dst, slow[1])
+
+
+# name -> (bytes, the edges the line loop reads, or the error it words)
+NOT_CLEAN = {
+    "comment-after-data": (b"0 1\n# later\n1 0\n", ([0, 1], [1, 0])),
+    "blank-line": (b"0 1\n\n1 0\n", ([0, 1], [1, 0])),
+    "crlf": (b"0 1\r\n1 0\r\n", ([0, 1], [1, 0])),
+    "leading-space": (b" 0 1\n1 0\n", ([0, 1], [1, 0])),
+    "trailing-space": (b"0 1 \n1 0\n", ([0, 1], [1, 0])),
+    "two-tabs": (b"0\t\t1\n1 0\n", ([0, 1], [1, 0])),
+    "indented-comment": (b"  # c\n0 1\n", ([0], [1])),
+    "plus-sign": (b"0 +1\n", ([0], [1])),
+    "underscore": (b"1_0 1\n", ([10], [1])),
+    "third-field": (b"0 1\n0 1 2\n", "edge list line 2: expected 'src dst', got b'0 1 2'"),
+    "lone-field": (b"0 1\n5\n", "edge list line 2: expected 'src dst', got b'5'"),
+    "lone-field-pair": (b"0\n1\n", "edge list line 1: expected 'src dst', got b'0'"),
+    "field-split-over-lines": (b"0 1\n2\n3 4 5\n", "edge list line 2: expected 'src dst', got b'2'"),
+    "non-integer": (b"# c\n0 1\n1 x\n", "edge list line 3: non-integer node id b'1 x'"),
+    "decimal-point": (b"0 1.5\n", "edge list line 1: non-integer node id b'0 1.5'"),
+    "negative": (b"0 1\n-1 0\n", "edge list has a negative node id"),
+    "empty": (b"", "edge list has no edges"),
+    "comments-only": (b"# a\n# b", "edge list has no edges"),
+    "past-int32": (b"0 2147483648\n", "edge list has a node id past int32 (2147483648 > 2147483647)"),
+    "past-int64-by-line": (b"0 1\n\n99999999999999999999 0\n",
+                           "edge list has a node id past int32 (99999999999999999999 > 2147483647)"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NOT_CLEAN))
+def test_any_other_file_goes_through_the_line_loop_with_its_messages(name):
+    data, want = NOT_CLEAN[name]
+    if name != "past-int32":  # clean, and a loud error all the same
+        assert plan_compile._edges_clean(data) is None
+    if isinstance(want, str):
+        with pytest.raises(PlanError) as err:
+            edges_from_bytes(data)
+        assert str(err.value) == want
+    else:
+        src, dst = edges_from_bytes(data)
+        assert src.tolist() == want[0] and dst.tolist() == want[1]
+
+
+def test_a_clean_number_past_int64_is_a_loud_error_not_a_wrapped_index():
+    with pytest.raises(PlanError, match="past int32"):
+        edges_from_bytes(b"0\t1\n99999999999999999999999\t0\n")
+
+
+def test_the_generated_file_takes_the_fast_path(graph):
+    path, src, dst, _ = graph
+    with open(path, "rb") as f:
+        fast = plan_compile._edges_clean(f.read())
+    assert fast is not None
+    assert np.array_equal(fast[0], src) and np.array_equal(fast[1], dst)
+
+
+# ------------------------------------------------------------ the renderer
+
+def _vectors():
+    rng = np.random.default_rng(41)
+    bits = rng.integers(1, 0x7F7FFFFF, 50_000, dtype=np.uint32)
+    return {
+        "random-bits": bits.view(np.float32),
+        "ranks-like": (rng.random(30_000) ** 6 / 9e5).astype(np.float32),
+        "powers-of-two": np.float32(2.0) ** -np.arange(0, 149, dtype=np.float32),
+        "powers-of-ten": np.array([float(f"1e{e}") for e in range(-44, 39)], np.float32),
+        "ties-and-carries": np.array([2.0 ** -13, 9.9999999e-7, 0.99999999, 1.0, 0.0,
+                                      1e-45, 3.4e38, 1.220703125e-4, 9.9999999949e-5]),
+        "uniform": np.full(1001, 1 / 875713, np.float32),
+        "one": np.array([0.25], np.float32),
+        "none": np.zeros(0, np.float32),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_vectors()))
+def test_rank_rows_rendered_in_numpy_equal_rank_row_a_row(name):
+    ranks = _vectors()[name]
+    want = b"".join(rank_row(i, r) for i, r in enumerate(ranks))
+    assert bytes_ops.render_rank_rows(ranks) == want
+    assert render_ranks(ranks) == want
+
+
+@pytest.mark.parametrize("bad", [-1e-6, float("nan"), float("inf"), 1e-120],
+                         ids=["negative", "nan", "inf", "three-digit-exponent"])
+def test_what_the_fixed_layout_cannot_spell_is_joined_a_row(bad):
+    ranks = np.array([0.5, bad, 0.25])
+    assert bytes_ops.render_rank_rows(ranks) is None
+    assert render_ranks(ranks) == b"".join(rank_row(i, r) for i, r in enumerate(ranks))
+
+
+def test_a_rank_row_carries_nine_significant_digits():
+    assert rank_row(3, np.float32(1 / 875713)) == b"3\t1.14192665e-06\n"
+    assert np.float32(float(rank_row(0, np.float32(1 / 875713)).split()[1])) == np.float32(1 / 875713)
+
+
+# ------------------------------------------------------------ the CLI's checks
+
+def test_num_nodes_is_checked_against_both_bounds_before_the_device(tmp_path, capsys):
+    edges = tmp_path / "e.txt"
+    edges.write_bytes(b"0 1\n1 5\n")
+    assert cli.main(["pagerank", str(edges), "--num-nodes", "5", "--backend", "cpu"]) == 1
+    err = capsys.readouterr().err
+    assert "--num-nodes 5 but max node id is 5" in err and "int32" in err
+    assert cli.main(["pagerank", str(edges), "--num-nodes", str(1 << 32),
+                     "--backend", "cpu"]) == 1
+    assert "--num-nodes under 2147483648" in capsys.readouterr().err
+    edges.write_bytes(b"0 1\n1 2147483648\n")
+    assert cli.main(["pagerank", str(edges), "--backend", "cpu"]) == 1
+    assert f"{edges}: edge list has a node id past int32" in capsys.readouterr().err
+    edges.write_bytes(b"0 1\n1 2\n")
+    assert cli.main(["pagerank", str(edges), "--num-nodes", "6", "--backend", "cpu"]) == 0
+    out, err = capsys.readouterr()
+    assert out.count("\n") == 6 and "2 edges loaded, 6 nodes" in err
+
+
+def test_top_prints_the_highest_ranks_in_the_one_spelling(graph, capsysbinary):
+    path, src, dst, n = graph
+    assert cli.main(["pagerank", path, "--top", "5", "--backend", "cpu"]) == 0
+    ids, ranks = pagerank_reference.parse_ranks(capsysbinary.readouterr().out)
+    want = pagerank_reference.pagerank(src, dst, n)
+    assert ids.tolist() == np.argsort(-want, kind="stable")[:5].tolist()
+    assert (np.diff(ranks) <= 0).all()
+
+
+# ------------------------------------------------------------ spans and counters
+
+SPANS_ONCE = ("cli.setup", "cli.load", "pagerank.read", "pagerank.parse", "cli.run",
+              "pagerank.h2d", "pagerank.iterate", "pagerank.d2h", "cli.output",
+              "cli.output.render", "cli.output.write")
+
+
+def test_a_traced_pagerank_job_records_every_span_once_and_four_counters(
+        graph, tmp_path, capsysbinary):
+    path, src, _, n = graph
+    trace = tmp_path / "pr.trace.json"
+    assert cli.main(["pagerank", path, "--backend", "cpu", "--trace-out", str(trace)]) == 0
+    capsysbinary.readouterr()
+    doc = json.loads(trace.read_text())
+    spans = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+    names = [e["name"] for e in spans]
+    for name in SPANS_ONCE:
+        assert names.count(name) == 1, (name, names)
+    by = {e["name"]: e for e in spans}
+    ids = {e["args"]["id"]: e["name"] for e in spans}
+
+    def parent(name):
+        return ids.get(by[name]["args"].get("parent"))
+
+    assert parent("pagerank.read") == parent("pagerank.parse") == "cli.load"
+    assert parent("pagerank.h2d") == parent("pagerank.iterate") == "plan.run"
+    assert parent("plan.run") == "cli.run" and parent("engine.sync") == "pagerank.iterate"
+    assert parent("cli.output.render") == parent("cli.output.write") == "cli.output"
+    assert by["engine.sync"]["args"]["what"] == "iterate"
+    parse = by["pagerank.parse"]["args"]
+    assert (parse["edges"], parse["fast"]) == (src.size, 1)
+    assert parse["bytes"] == by["pagerank.read"]["args"]["bytes"] == os.path.getsize(path)
+    iterate = by["pagerank.iterate"]["args"]
+    assert (iterate["nodes"], iterate["edges"], iterate["iters"]) == (n, src.size, 20)
+    assert by["cli.output.render"]["args"]["rows"] == n
+    assert by["cli.setup"]["ts"] + by["cli.setup"]["dur"] <= by["cli.load"]["ts"] + 1e3
+    counters = doc["otherData"]["metrics"]["counters"]
+    assert {k: v for k, v in counters.items() if k.startswith("pagerank.")} == {
+        "pagerank.edges": src.size, "pagerank.nodes": n, "pagerank.iterations": 20}
+
+
+def test_an_untraced_pagerank_job_opens_no_span(graph, capsysbinary, monkeypatch):
+    from locust_tpu import obs
+    from locust_tpu.obs import trace as obs_trace
+
+    opened = []
+    monkeypatch.setattr(obs_trace._Span, "__init__",
+                        lambda self, *a, **k: opened.append(a))
+    assert cli.main(["pagerank", graph[0], "--backend", "cpu"]) == 0
+    capsysbinary.readouterr()
+    assert not opened and obs.current() is None

@@ -540,7 +540,7 @@ def test_pagerank_plan_byte_identical_single_and_mesh():
     )
     assert np.array_equal(pres.value, ranks)
     assert pres.output == b"".join(
-        f"{i}\t{ranks[i]:.8f}\n".encode() for i in range(n)
+        f"{i}\t{ranks[i]:.8e}\n".encode() for i in range(n)
     )
     mranks = ShardedPageRank(make_mesh(), n, damping=0.85).run(
         src, dst, num_iters=8
