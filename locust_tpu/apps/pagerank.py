@@ -7,7 +7,11 @@ and reduce by key with sum; then apply damping.
 
 TPU-native formulation: node ids ARE the keys, so the shuffle degenerates to
 a dense ``segment_sum`` into a ``[num_nodes]`` vector — no byte keys, no
-sort.  Iterations run under ``lax.scan`` (static trip count) or a
+sort.  What is a function of the NODE is computed over the nodes: a round
+multiplies ``ranks * inv_deg`` once (the emit every out-edge of a node
+carries) and the edges take it in ONE gather — a gathered word costs a v5e
+some 7 ns, the product over the nodes nothing beside it (PERF.md §6, PR 42).
+Iterations run under ``lax.scan`` (static trip count) or a
 ``while_loop`` on the L1 residual.  Distributed: edges shard across the
 mesh, each device computes a partial dense contribution vector, and the
 "shuffle" is a single ``psum`` — the degenerate all-to-all for dense integer
@@ -28,9 +32,15 @@ from locust_tpu.parallel.mesh import DATA_AXIS
 
 
 def _contributions(src, dst, ranks, inv_deg, num_nodes):
-    """Dense map+reduce of one iteration: sum_d rank[s]/deg[s]."""
-    contrib = ranks[src] * inv_deg[src]
-    return jax.ops.segment_sum(contrib, dst, num_segments=num_nodes)
+    """Dense map+reduce of one iteration: sum_d rank[s]/deg[s].
+
+    The share is multiplied over the NODES and the edges gather it once:
+    the same two float32 operands an edge as ``ranks[src] * inv_deg[src]``,
+    so the same bits (tests/test_pagerank_cli.py holds both), from one load
+    an edge.
+    """
+    share = ranks * inv_deg
+    return jax.ops.segment_sum(share[src], dst, num_segments=num_nodes)
 
 
 @functools.partial(jax.jit, static_argnames=("num_nodes", "num_iters"))
@@ -89,7 +99,9 @@ def pagerank_step(
     num_nodes: int,
 ) -> jax.Array:
     """ONE ``pagerank`` iteration as a standalone jit, bit-identical to
-    the scan body above.  ``damping`` is a TRACED f32 operand on
+    the scan body above (both go through ``_contributions``: one gather
+    an edge, of the share multiplied over the nodes).  ``damping`` is a
+    TRACED f32 operand on
     purpose: the fused kernel traces it too, so ``(1-damping)/n``
     computes in f32 on device — marking it static would constant-fold
     that expression in python float64 and change the low bits (pinned
@@ -128,7 +140,7 @@ class DistributedPageRank:
 
         def step(src, dst, mask, ranks, inv_deg, dangling_vec):
             # Local partial: masked edges contribute 0.
-            w = ranks[src] * inv_deg[src] * mask
+            w = (ranks * inv_deg)[src] * mask
             partial = jax.ops.segment_sum(w, dst, num_segments=num)
             contrib = jax.lax.psum(partial, axis_name)          # the combine
             local_dangling = jnp.sum(jnp.where(dangling_vec, ranks, 0.0))
@@ -187,7 +199,7 @@ class ShardedPageRank:
     the graph is static, so the entire routing plan (slot ids, receive
     maps) is computed ONCE on the host and the device step is just
 
-      gather local ranks -> segment_sum into send slots ->
+      gather local shares (ONE gather) -> segment_sum into send slots ->
       lax.all_to_all -> segment_sum into the local rank block -> damp,
 
     with the dangling-mass correction as a scalar psum.  Because slots
@@ -308,7 +320,7 @@ class ShardedPageRank:
             ranks_l, inv_deg_l = ranks_l[0], inv_deg_l[0]
             dangling_l, valid_l = dangling_l[0], valid_l[0]
 
-            w = ranks_l[src_l] * inv_deg_l[src_l] * mask
+            w = (ranks_l * inv_deg_l)[src_l] * mask
             send = jax.ops.segment_sum(
                 w, send_seg, num_segments=n_dev * cap + 1
             )[: n_dev * cap].reshape(n_dev, cap)

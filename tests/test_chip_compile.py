@@ -23,6 +23,7 @@ Left out, with the measured reason (described v5e, no chip, PR 22): one
 whole-program compiles are a scratch script's job, not a test's.
 """
 
+import contextlib
 import functools
 
 import jax
@@ -54,17 +55,25 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(autouse=True)
-def cache_off():
+@contextlib.contextmanager
+def _persistent_cache_off():
     """A described-chip executable is written to the persistent cache but
     cannot be read back without a chip (the next compile would warn)."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", True)
-    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def cache_off():
+    with _persistent_cache_off():
+        yield
 
 
 def _block(one_chip, block_lines):
@@ -199,21 +208,46 @@ def test_tokenize_block_einsum_branch_compiles(one_chip):
     assert "convolution" in compiled.as_text() or "dot" in compiled.as_text()
 
 
-def test_pagerank_iterate_compiles_at_the_cells_shape(one_chip):
+PAGERANK_EDGES = 5_105_039
+
+
+@pytest.fixture(scope="module")
+def pagerank_text_and_stats(one_chip):
     """``pagerank5M.batch``'s one program at its own shape — web-Google's
     5,105,039 edges over 916,428 ids, 20 rounds, damping traced as the
-    plan passes it (3.4 s on a described v5e, PR 41): a change that breaks
-    the shape, or blows its memory past a chip's, shows on the CPU."""
+    plan passes it (3.4 s on a described v5e, PR 41), compiled once for
+    the cases below."""
     from locust_tpu.apps.pagerank import pagerank
 
-    edges = jax.ShapeDtypeStruct((5_105_039,), jnp.int32, sharding=one_chip)
-    compiled = pagerank.lower(
-        edges, edges, num_nodes=916_428, num_iters=20, damping=0.85
-    ).compile()
-    text = compiled.as_text()
+    edges = jax.ShapeDtypeStruct((PAGERANK_EDGES,), jnp.int32, sharding=one_chip)
+    with _persistent_cache_off():  # a module's fixture runs before a test's
+        compiled = pagerank.lower(
+            edges, edges, num_nodes=916_428, num_iters=20, damping=0.85
+        ).compile()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+def test_pagerank_iterate_compiles_at_the_cells_shape(pagerank_text_and_stats):
+    """A change that breaks the shape, or blows its memory past a chip's,
+    shows on the CPU."""
+    text, stats = pagerank_text_and_stats
     assert "while" in text and "scatter" in text  # one scan, the scatter-add inside
-    stats = compiled.memory_analysis()
     assert stats.argument_size_in_bytes + stats.temp_size_in_bytes < 1 << 30
+
+
+def test_pagerank_round_is_one_gather_fusion_on_the_chip(pagerank_text_and_stats):
+    """The chip's compiler keeps the node-sized product out of the gather:
+    the whole program holds ONE fusion that yields a float an edge (the
+    gather of ``ranks * inv_deg``; the parent's two were 58 + 38 ms a
+    round where one is 39, PERF.md §6, PR 42), and it stands in the loop."""
+    import re
+
+    text, _ = pagerank_text_and_stats
+    fusions = re.findall(
+        rf"^\s*(?:ROOT )?(%\S+) = f32\[{PAGERANK_EDGES}\]\S* fusion\(.*$", text, re.M
+    )
+    assert len(fusions) == 1, fusions
+    assert fusions[0] + " = " not in text[text.index("\nENTRY "):]   # in the scan's body
 
 
 def test_check_kernels_match_chip_smoke():

@@ -13,10 +13,12 @@ import json
 import os
 import sys
 
+import jax
 import numpy as np
 import pytest
 
 from locust_tpu import cli, pagerank_reference
+from locust_tpu.apps.pagerank import _contributions, pagerank, pagerank_prep, pagerank_step
 from locust_tpu.core import bytes_ops
 from locust_tpu.plan import PlanError
 from locust_tpu.plan import compile as plan_compile
@@ -28,7 +30,8 @@ sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 import rmat_edges  # noqa: E402
 
 with open(os.path.join(REPO, "benchmarks", "configs", "pagerank-rmat-5M.json")) as _f:
-    TOLERANCE = json.load(_f)["tolerance"]
+    CONFIG = json.load(_f)
+TOLERANCE = CONFIG["tolerance"]
 
 
 @pytest.fixture(scope="module")
@@ -289,3 +292,108 @@ def test_an_untraced_pagerank_job_opens_no_span(graph, capsysbinary, monkeypatch
     assert cli.main(["pagerank", graph[0], "--backend", "cpu"]) == 0
     capsysbinary.readouterr()
     assert not opened and obs.current() is None
+
+
+# ------------------------------------------------ the round gathers once (PR 42)
+
+def _hub_graph(n, edges, seed):
+    """A seeded multigraph over ``n`` slots: sources and destinations drawn
+    with replacement from a steep distribution over the first 70% of the
+    ids (hubs, repeated edges), the next 20% named only as destinations
+    (dangling), the last 10% named by no edge."""
+    rng = np.random.default_rng(seed)
+    body, named = n * 7 // 10, n * 9 // 10
+    src = (body * rng.random(edges) ** 3).astype(np.int32)
+    dst = (named * rng.random(edges) ** 2).astype(np.int32)
+    return src, dst, n
+
+
+def _probe_graph(tmp_path):
+    probe = {k: v for k, v in CONFIG["probe"].items() if k != "why"}
+    path = str(tmp_path / "probe.txt")
+    rmat_edges.build_probe(path, 2147483659, **probe)
+    src, dst = rmat_edges.load(path)
+    assert (probe["ids"], src.size) == (8192, 22_304)         # the shape the cell's set-up ranks
+    return src.astype(np.int32), dst.astype(np.int32), probe["ids"]
+
+
+GRAPHS = {
+    "seven-nodes": lambda tmp_path: _hub_graph(7, 40, 1),
+    "hubs-3001": lambda tmp_path: _hub_graph(3001, 50_000, 2),
+    "probe-8192": _probe_graph,
+}
+
+
+@pytest.fixture(params=sorted(GRAPHS))
+def small_graph(request, tmp_path):
+    src, dst, n = GRAPHS[request.param](tmp_path)
+    out_degree, in_degree = np.bincount(src, minlength=n), np.bincount(dst, minlength=n)
+    assert ((out_degree == 0) & (in_degree > 0)).any()       # dangling
+    assert ((out_degree == 0) & (in_degree == 0)).any()      # slots no edge names
+    assert in_degree.max() > 1.5 * in_degree.mean()          # a hub
+    return src, dst, n
+
+
+def test_one_gather_of_the_share_is_the_two_gather_round_bit_for_bit(small_graph):
+    src, dst, n = small_graph
+    if n != CONFIG["probe"]["ids"]:    # the probe's edges are distinct, the others' repeat
+        assert len(set(zip(src.tolist(), dst.tolist()))) < src.size
+    inv_deg, _ = pagerank_prep(src, num_nodes=n)
+
+    @jax.jit
+    def two_gathers(src, dst, ranks, inv_deg):
+        return jax.ops.segment_sum(ranks[src] * inv_deg[src], dst, num_segments=n)
+
+    one_gather = jax.jit(_contributions, static_argnums=4)
+    rng = np.random.default_rng(n)
+    for ranks in (np.full(n, 1.0 / n, np.float32),
+                  (rng.random(n) ** 4 / n).astype(np.float32)):
+        got = np.asarray(one_gather(src, dst, ranks, inv_deg, n))
+        want = np.asarray(two_gathers(src, dst, ranks, inv_deg))
+        assert got.dtype == np.float32 and got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("rounds", [1, 4])
+def test_the_scan_is_as_many_steps_bit_for_bit(small_graph, rounds):
+    src, dst, n = small_graph
+    damping = np.float32(0.85)
+    inv_deg, dangling = pagerank_prep(src, num_nodes=n)
+    ranks = np.full(n, 1.0 / n, np.float32)
+    for _ in range(rounds):
+        ranks = pagerank_step(src, dst, ranks, inv_deg, dangling, damping, num_nodes=n)
+    whole = pagerank(src, dst, num_nodes=n, num_iters=rounds, damping=damping)
+    assert np.asarray(whole).tobytes() == np.asarray(ranks).tobytes()
+
+
+def _edge_sized_gathers(jaxpr, edges, in_loop=False):
+    """(inside a loop's body, outside any) counts of the ``gather``
+    equations of ``jaxpr`` whose result is ``[edges]``, sub-programs
+    included."""
+    inside = outside = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather" and eqn.outvars[0].aval.shape == (edges,):
+            inside, outside = inside + in_loop, outside + (not in_loop)
+        loop = in_loop or eqn.primitive.name in ("scan", "while")
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    a, b = _edge_sized_gathers(sub, edges, loop)
+                    inside, outside = inside + a, outside + b
+    return inside, outside
+
+
+def test_a_round_of_the_program_holds_one_edge_sized_gather():
+    """The guard against the second gather coming back: what is a function
+    of the node is computed over the nodes, and the edges gather ONE word."""
+    src, dst, n = _hub_graph(3001, 50_000, 2)
+    program = jax.make_jaxpr(
+        lambda s, d, damping: pagerank(s, d, num_nodes=n, num_iters=3, damping=damping)
+    )(src, dst, np.float32(0.85))
+    inside, outside = _edge_sized_gathers(program.jaxpr, src.size)
+    assert inside == 1 and outside <= 1
+    node = np.zeros(n, np.float32)
+    step = jax.make_jaxpr(
+        lambda s, d, r, i, g, damping: pagerank_step(s, d, r, i, g, damping, num_nodes=n)
+    )(src, dst, node, node, node > 0, np.float32(0.85))
+    assert _edge_sized_gathers(step.jaxpr, src.size) == (0, 1)
