@@ -111,6 +111,17 @@ def _load() -> ctypes.CDLL:
                 ctypes.c_long,
                 ctypes.c_long,
             ]
+            lib.ingest_parse_edges.restype = ctypes.c_long
+            lib.ingest_parse_edges.argtypes = [
+                ctypes.c_char_p,
+                ctypes.c_long,
+                ctypes.POINTER(ctypes.c_int),
+                ctypes.POINTER(ctypes.c_int),
+                ctypes.c_long,
+                ctypes.POINTER(ctypes.c_longlong),
+            ]
+            lib.ingest_count_lf.restype = ctypes.c_long
+            lib.ingest_count_lf.argtypes = [ctypes.c_char_p, ctypes.c_long]
             _lib = lib
     return _lib
 
@@ -214,6 +225,34 @@ def read_tsv(path: str, key_width: int) -> tuple[np.ndarray, np.ndarray]:
         if wrote < n:  # file shrank between passes
             keys, values = keys[:wrote], values[:wrote]
     return keys, values
+
+
+def parse_edges(corpus: bytes):
+    """A CLEAN SNAP-style edge list -> ``(src, dst, top)``: two int32
+    arrays and the largest id as a Python int, in one native walk of the
+    bytes; ``None`` for "not clean" (``ingest_parse_edges`` has the
+    grammar: ``plan.compile._edges_clean``'s, and numbers of at most 18
+    digits).  ``top`` may lie past int32 — the arrays then hold narrowed
+    values and the caller must refuse them (``plan.compile._check_top_id``).
+    The outputs are sized by the LF count: pages a parse never writes
+    are never touched."""
+    lib = _load()
+    cap = lib.ingest_count_lf(corpus, len(corpus)) + 1
+    src = np.empty(cap, np.int32)
+    dst = np.empty(cap, np.int32)
+    top = ctypes.c_longlong(0)
+    int_p = ctypes.POINTER(ctypes.c_int)
+    n = lib.ingest_parse_edges(
+        corpus,
+        len(corpus),
+        src.ctypes.data_as(int_p),
+        dst.ctypes.data_as(int_p),
+        cap,
+        ctypes.byref(top),
+    )
+    if n < 0:
+        return None
+    return src[:n], dst[:n], int(top.value)
 
 
 def iter_blocks(

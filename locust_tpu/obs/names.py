@@ -59,7 +59,7 @@ NAMES = {
     "sort.d2h": "span",             # record sort: a sorted block brought down (arg bytes)
     "sort.write": "span",           # record sort: a sorted block written to OUT (arg bytes)
     "pagerank.read": "span",        # pagerank CLI: the edge list read whole from its file (arg bytes)
-    "pagerank.parse": "span",       # the ONE edge parser (plan.compile.edges_from_bytes, CLI and daemon): fast=1 a clean file read in numpy, 0 the line loop (args bytes, edges, fast)
+    "pagerank.parse": "span",       # the ONE edge parser (plan.compile.edges_from_bytes, CLI, daemon and workers): fast=1 a clean file read with no Python object an edge, 0 the line loop; native=1 read by the ONE native pass (native_ingest.parse_edges), 0 by numpy or the line loop (args bytes, edges, fast, native)
     "pagerank.h2d": "span",         # pagerank: src and dst put on the device and waited for (arg bytes)
     "pagerank.iterate": "span",     # pagerank: the iterate program dispatched and waited for (its child engine.sync what=iterate); under --mesh ShardedPageRank's whole run (args nodes, edges, iters)
     "pagerank.d2h": "span",         # pagerank: the rank vector brought down (arg bytes)
@@ -109,6 +109,7 @@ NAMES = {
     "pagerank.edges": "counter",    # pagerank: edges ranked over
     "pagerank.nodes": "counter",    # pagerank: dense node slots (largest id + 1, or --num-nodes)
     "pagerank.iterations": "counter",  # pagerank: rounds run (benchmarks' closed_loop_cli_edges holds a traced job to the configuration's count by it)
+    "pagerank.parse.native": "counter",  # pagerank: edge lists parsed by the native pass (0 where the library did not load or the file was not clean)
     "sort.records": "counter",      # record sort: records staged on the device
     "sort.bytes_out": "counter",    # record sort: bytes written to OUT
     "sort.mesh.retries": "counter",          # mesh record sort: exchanges redone because a bin overflowed

@@ -954,15 +954,31 @@ def edges_from_bytes(corpus: bytes):
     to the daemon parses its corpus exactly like the CLI parses a file —
     by construction, not by parallel maintenance.  A clean file (``#``
     lines at its head, then ``src<TAB or SPACE>dst<LF>`` and nothing
-    else) is read without a Python object an edge; anything else goes
-    through the line loop, which words the errors."""
+    else) is read without a Python object an edge: by ONE native pass
+    (``native_ingest.parse_edges``) where the library loads, by numpy
+    (``_edges_clean``) where it does not.  Anything else goes through
+    the line loop, which words the errors."""
+    from locust_tpu.io import native_ingest
+
     with obs.span("pagerank.parse", bytes=len(corpus)) as sp:
-        parsed = _edges_clean(corpus)
+        try:
+            parsed = native_ingest.parse_edges(corpus)
+            native = parsed is not None
+        except OSError:  # no toolchain: numpy reads the clean file
+            parsed = _edges_clean(corpus)
+            native = False
         fast = parsed is not None
-        src, dst = parsed if fast else _edges_by_line(corpus)
-        _check_top_id(max(int(src.max()), int(dst.max())))
-        sp.set(edges=int(src.shape[0]), fast=int(fast))
-        return src.astype("int32"), dst.astype("int32")
+        if native:
+            src, dst, top = parsed
+        else:
+            src, dst = parsed if fast else _edges_by_line(corpus)
+            top = max(int(src.max()), int(dst.max()))
+        _check_top_id(top)
+        sp.set(edges=int(src.shape[0]), fast=int(fast), native=int(native))
+        obs.metric_inc("pagerank.parse.native", int(native))
+        # int32 already from the native pass: no copy there.
+        return (src.astype("int32", copy=False),
+                dst.astype("int32", copy=False))
 
 
 def _check_top_id(top: int) -> None:

@@ -425,4 +425,73 @@ long ingest_read_tsv(const char* path, unsigned char* out_keys,
   return rows;
 }
 
+// A CLEAN SNAP-style edge list -> int32 src[] / dst[] in one walk: the
+// native spelling of plan/compile.py:_edges_clean, which stays as the
+// fallback where no toolchain is and as the oracle the tests hold this
+// to (tests/test_pagerank_cli.py).  Clean is EXACTLY what that accepts:
+// '#' lines at the head only; then a line an edge — one or more digits,
+// ONE TAB or SPACE, one or more digits, ONE LF (the last line may lack
+// it).  A sign, a third field, a blank line, a CR, a '#' after the
+// first edge, comments only, an empty body: -1, "not clean", and the
+// caller's line loop words the error.  A number of more than 18 digits
+// is not clean either, so no arithmetic here can wrap.  A value past
+// int32 is NOT judged here: it is narrowed into its slot as written and
+// *out_top carries the largest id in 64 bits, which the caller checks
+// before it trusts an array (_check_top_id's PlanError).  ``cap`` is
+// the room in src / dst (the file's LF count + 1 always holds it).
+// Returns the edges written (>= 1), or -1.
+long ingest_parse_edges(const unsigned char* data, long n, int* src,
+                        int* dst, long cap, long long* out_top) {
+  const unsigned char* p = data;
+  const unsigned char* const end = data + n;
+  while (p < end && *p == '#') {
+    const void* lf = std::memchr(p, '\n', static_cast<size_t>(end - p));
+    if (!lf) return -1;  // comments only
+    p = static_cast<const unsigned char*>(lf) + 1;
+  }
+  long rows = 0;
+  long long top = 0;
+  // One field: 1..18 digits at p, advanced past them; -1 otherwise.
+  auto field = [&]() -> long long {
+    const unsigned char* const start = p;
+    long long v = 0;
+    unsigned d;
+    while (p < end && (d = static_cast<unsigned>(*p) - '0') < 10u) {
+      v = v * 10 + d;
+      ++p;
+    }
+    return (p == start || p - start > 18) ? -1 : v;
+  };
+  while (p < end) {
+    const long long a = field();
+    if (a < 0 || p == end || (*p != '\t' && *p != ' ')) return -1;
+    ++p;
+    const long long b = field();
+    if (b < 0 || rows == cap) return -1;
+    if (p < end && *p++ != '\n') return -1;
+    src[rows] = static_cast<int>(a);
+    dst[rows] = static_cast<int>(b);
+    ++rows;
+    if (a > top) top = a;
+    if (b > top) top = b;
+  }
+  if (rows == 0) return -1;  // an empty body
+  *out_top = top;
+  return rows;
+}
+
+// '\n' bytes in data[0, n): what sizes ingest_parse_edges' outputs.
+// Summed 255 bytes at a time into ONE byte, which the compiler keeps in
+// byte lanes (a fourth of the time of a long summed a byte at a time).
+long ingest_count_lf(const unsigned char* data, long n) {
+  long lf = 0;
+  for (long i = 0; i < n;) {
+    const long stop = n - i < 255 ? n : i + 255;
+    unsigned char part = 0;
+    for (; i < stop; ++i) part += data[i] == '\n';
+    lf += part;
+  }
+  return lf;
+}
+
 }  // extern "C"

@@ -89,3 +89,15 @@ class _Abandoned(Exception):
 logging.getLogger("locust_tpu").addFilter(
     lambda rec: not (rec.exc_info and rec.exc_info[0] is _Abandoned)
 )
+
+
+def native_ingest_missing(monkeypatch):
+    """Make ``native/ingest.cpp``'s library fail to load, as where no
+    toolchain is: every ``native_ingest`` entry point raises ``OSError``
+    and its callers take their Python paths."""
+    from locust_tpu.io import native_ingest
+
+    def no_toolchain():
+        raise OSError("native ingest build failed: no g++")
+
+    monkeypatch.setattr(native_ingest, "_load", no_toolchain)

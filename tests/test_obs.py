@@ -18,7 +18,7 @@ import time
 import numpy as np
 import pytest
 
-from helpers import py_wordcount
+from helpers import native_ingest_missing, py_wordcount
 
 from locust_tpu import cli, obs
 from locust_tpu.config import EngineConfig
@@ -1061,6 +1061,31 @@ def test_engine_config_trace_knob_enables_process_tracer():
         e["name"] == "engine.stage.process"
         for e in tracer.to_chrome()["traceEvents"]
     )
+
+
+# ------------------------------------- the edge parser says who read (PR 43)
+
+
+@pytest.mark.parametrize("library, data, fast, native", [
+    (True, b"# h\n0\t1\n1 2\n", 1, 1),
+    (False, b"# h\n0\t1\n1 2\n", 1, 0),
+    (True, b"0 1\n\n1 2\n", 0, 0),
+    (False, b"0 1\n\n1 2\n", 0, 0),
+], ids=["native", "numpy", "line-loop", "line-loop-library-missing"])
+def test_pagerank_parse_names_its_reader_in_an_arg_and_a_counter(
+        library, data, fast, native, monkeypatch):
+    from locust_tpu.plan.compile import edges_from_bytes
+
+    if not library:
+        native_ingest_missing(monkeypatch)
+    tracer = obs.enable(process="parse")
+    src, dst = edges_from_bytes(data)
+    assert (src.tolist(), dst.tolist()) == ([0, 1], [1, 2])
+    (span,) = [e for e in tracer.to_chrome()["traceEvents"]
+               if e.get("ph") == "X" and e["name"] == "pagerank.parse"]
+    got = span["args"]
+    assert (got["bytes"], got["edges"], got["fast"], got["native"]) == (len(data), 2, fast, native)
+    assert obs.metrics_snapshot()["counters"]["pagerank.parse.native"] == native
 
 
 # ------------------------------------------------------------ bench summary

@@ -5,6 +5,8 @@ the benchmark's own generator, with hubs, dangling nodes and ids no edge
 names: the CLI's printed ranks against ``locust_tpu/pagerank_reference.py``
 (float64, no jax) within the benchmark cell's tolerance, the fast edge
 parser against the line loop on clean files and on every malformed shape,
+the native pass (PR 43) against numpy's ``_edges_clean`` on all of those and
+the ONE parser with the library loaded against it with the library missing,
 the numpy rank renderer against ``rank_row`` a row, and the spans and
 counters a ``--trace-out`` file of a pagerank job holds.
 """
@@ -17,9 +19,12 @@ import jax
 import numpy as np
 import pytest
 
+from helpers import native_ingest_missing
+
 from locust_tpu import cli, pagerank_reference
 from locust_tpu.apps.pagerank import _contributions, pagerank, pagerank_prep, pagerank_step
 from locust_tpu.core import bytes_ops
+from locust_tpu.io import native_ingest
 from locust_tpu.plan import PlanError
 from locust_tpu.plan import compile as plan_compile
 from locust_tpu.plan.compile import edges_from_bytes, rank_row, render_ranks
@@ -173,6 +178,142 @@ def test_the_generated_file_takes_the_fast_path(graph):
     assert np.array_equal(fast[0], src) and np.array_equal(fast[1], dst)
 
 
+# ------------------------------------------------ the native pass (PR 43)
+
+# Every case above, and the native pass's own: what it must read as numpy
+# reads it, and what both must leave to the line loop.
+PARSER_CASES = {
+    **CLEAN,
+    **{name: data for name, (data, _) in NOT_CLEAN.items()},
+    "mixed-separators-no-last-newline": b"3\t4\n5 6\n7\t8",
+    "leading-zeros": b"007\t01\n0\t0000\n00 10\n",
+    "top-of-int32": b"0\t2147483647\n2147483647 1\n",
+    "int32-plus-one-as-src": b"2147483648\t0\n",
+    "digits-18": b"0\t1\n999999999999999999\t2\n",
+    "header-then-no-last-newline": b"# h\n1 2\n3 4",
+    "comment-without-newline-after-data": b"0 1\n# c",
+    "comment-line-alone-with-newline": b"#\n",
+    "newline-only": b"\n",
+    "digits-only": b"12",
+    "separator-ends-the-file": b"0 1\n5\t",
+    "lone-field-last-line": b"0 1\n5",
+    "starts-with-separator": b"\t0 1\n",
+    "starts-with-newline": b"\n0 1\n",
+    "two-newlines-at-the-end": b"0 1\n\n",
+    "cr-alone": b"0 1\r1 0\n",
+    "minus-zero": b"0 -0\n",
+    "nul-byte": b"0 1\n1\x000\n",
+    "vertical-tab": b"0\x0b1\n",
+    "form-feed": b"0\x0c1\n",
+    "utf8-digits": "0 \u0661\n".encode(),
+    "hex": b"0 0x10\n",
+    "exponent": b"0 1e3\n",
+}
+# More than 18 digits: numpy reads the number (past int64 as int64's largest),
+# the native pass hands the file to the line loop, whose Python ints read it.
+OVER_18_DIGITS = {
+    "digits-19": b"0\t1\n1000000000000000000\t2\n",
+    "digits-19-past-int64": b"0\t1\n9999999999999999999\t2\n",
+    "digits-23": b"0\t1\n99999999999999999999999\t0\n",
+    "zeros-19": b"0000000000000000001\t2\n",
+}
+PARSER_CASES.update(OVER_18_DIGITS)
+
+
+def _assert_reads_as_numpy(got, want):
+    """The native pass's ``(src, dst, top)`` against ``_edges_clean``'s int64
+    pair: int32 arrays, a value past int32 narrowed as numpy narrows it, and
+    the top id in full, which is what says so."""
+    src, dst, top = got
+    assert src.dtype == dst.dtype == np.int32
+    assert src.tobytes() == want[0].astype(np.int32).tobytes()
+    assert dst.tobytes() == want[1].astype(np.int32).tobytes()
+    assert top == max(int(want[0].max()), int(want[1].max()))
+
+
+@pytest.mark.parametrize("name", sorted(PARSER_CASES))
+def test_the_native_pass_reads_what_numpy_reads_and_refuses_what_it_refuses(name):
+    data = PARSER_CASES[name]
+    want = plan_compile._edges_clean(data)
+    got = native_ingest.parse_edges(data)
+    if name in OVER_18_DIGITS:
+        assert want is not None and got is None
+    elif want is None:
+        assert got is None
+    else:
+        _assert_reads_as_numpy(got, want)
+
+
+def test_the_native_pass_agrees_with_numpy_on_seeded_damage_to_a_clean_file():
+    """A clean file with a few bytes swapped for the grammar's own and its
+    neighbours': whichever way each falls, both readers say the same."""
+    rng = np.random.default_rng(43)
+    alphabet = np.frombuffer(b"0123456789\t \n\n\t#-+\r9", np.uint8)
+    clean = not_clean = 0
+    for _ in range(1500):
+        edges = rng.integers(0, 3000, (int(rng.integers(1, 12)), 2))
+        data = np.frombuffer(
+            b"# head\n" * int(rng.integers(0, 2))
+            + b"".join(b"%d%s%d\n" % (a, b"\t "[i % 2:i % 2 + 1], b)
+                       for i, (a, b) in enumerate(edges.tolist())), np.uint8).copy()
+        hits = rng.integers(0, data.size, int(rng.integers(0, 3)))
+        data[hits] = rng.choice(alphabet, hits.size)
+        data = data[:data.size - int(rng.integers(0, 2))].tobytes()
+        want = plan_compile._edges_clean(data)
+        got = native_ingest.parse_edges(data)
+        if want is None:
+            assert got is None, data
+            not_clean += 1
+        else:
+            assert got is not None, data
+            _assert_reads_as_numpy(got, want)
+            clean += 1
+    assert clean > 300 and not_clean > 300
+
+
+def _parsed_or_refused(data):
+    try:
+        src, dst = edges_from_bytes(data)
+    except PlanError as e:
+        return str(e)
+    assert src.dtype == dst.dtype == np.int32
+    return src.tolist(), dst.tolist()
+
+
+@pytest.mark.parametrize("name", sorted(PARSER_CASES))
+def test_the_one_parser_is_the_same_with_the_library_and_without(name, monkeypatch):
+    data = PARSER_CASES[name]
+    loaded = _parsed_or_refused(data)
+    native_ingest_missing(monkeypatch)
+    missing = _parsed_or_refused(data)
+    if name in ("digits-19-past-int64", "digits-23"):
+        # The one wording that differs: numpy names int64's largest, the line loop the number.
+        digits = data.split()[2].decode()
+        assert loaded == f"edge list has a node id past int32 ({digits} > 2147483647)"
+        assert missing == "edge list has a node id past int32 (9223372036854775807 > 2147483647)"
+    else:
+        assert loaded == missing
+
+
+def test_the_generated_file_takes_the_native_pass(graph):
+    path, src, dst, n = graph
+    with open(path, "rb") as f:
+        got = native_ingest.parse_edges(f.read())
+    assert np.array_equal(got[0], src) and np.array_equal(got[1], dst)
+    assert got[2] == n - 1 and got[0].dtype == got[1].dtype == np.int32
+
+
+def test_the_cli_prints_the_same_bytes_with_the_library_and_without(
+        graph, capsysbinary, monkeypatch):
+    assert cli.main(["pagerank", graph[0], "--backend", "cpu"]) == 0
+    loaded = capsysbinary.readouterr()
+    native_ingest_missing(monkeypatch)
+    assert cli.main(["pagerank", graph[0], "--backend", "cpu"]) == 0
+    missing = capsysbinary.readouterr()
+    assert loaded.out == missing.out and loaded.out.count(b"\n") == graph[3]
+    assert loaded.err == missing.err
+
+
 # ------------------------------------------------------------ the renderer
 
 def _vectors():
@@ -248,9 +389,12 @@ SPANS_ONCE = ("cli.setup", "cli.load", "pagerank.read", "pagerank.parse", "cli.r
               "cli.output.render", "cli.output.write")
 
 
-def test_a_traced_pagerank_job_records_every_span_once_and_four_counters(
-        graph, tmp_path, capsysbinary):
+@pytest.mark.parametrize("native", [1, 0], ids=["native", "library-missing"])
+def test_a_traced_pagerank_job_records_every_span_once_and_its_counters(
+        graph, tmp_path, capsysbinary, monkeypatch, native):
     path, src, _, n = graph
+    if not native:
+        native_ingest_missing(monkeypatch)
     trace = tmp_path / "pr.trace.json"
     assert cli.main(["pagerank", path, "--backend", "cpu", "--trace-out", str(trace)]) == 0
     capsysbinary.readouterr()
@@ -271,7 +415,7 @@ def test_a_traced_pagerank_job_records_every_span_once_and_four_counters(
     assert parent("cli.output.render") == parent("cli.output.write") == "cli.output"
     assert by["engine.sync"]["args"]["what"] == "iterate"
     parse = by["pagerank.parse"]["args"]
-    assert (parse["edges"], parse["fast"]) == (src.size, 1)
+    assert (parse["edges"], parse["fast"], parse["native"]) == (src.size, 1, native)
     assert parse["bytes"] == by["pagerank.read"]["args"]["bytes"] == os.path.getsize(path)
     iterate = by["pagerank.iterate"]["args"]
     assert (iterate["nodes"], iterate["edges"], iterate["iters"]) == (n, src.size, 20)
@@ -279,7 +423,8 @@ def test_a_traced_pagerank_job_records_every_span_once_and_four_counters(
     assert by["cli.setup"]["ts"] + by["cli.setup"]["dur"] <= by["cli.load"]["ts"] + 1e3
     counters = doc["otherData"]["metrics"]["counters"]
     assert {k: v for k, v in counters.items() if k.startswith("pagerank.")} == {
-        "pagerank.edges": src.size, "pagerank.nodes": n, "pagerank.iterations": 20}
+        "pagerank.edges": src.size, "pagerank.nodes": n, "pagerank.iterations": 20,
+        "pagerank.parse.native": native}
 
 
 def test_an_untraced_pagerank_job_opens_no_span(graph, capsysbinary, monkeypatch):
