@@ -50,7 +50,7 @@ from locust_tpu.config import (  # noqa: E402 - jax-free
 # (tests/test_chip_compile.py compiles each for a described v5e).  A
 # literal: a kernel the compiler refuses is REMOVED here with its message
 # recorded in ROADMAP.md, never skipped at run time.
-CHIP_KERNELS = ("tokenize_block_pallas", "fused_block_preagg", "bitonic_sort")
+CHIP_KERNELS = ("tokenize_block_pallas", "fused_block_preagg")
 
 SAMPLE_CORPUS = os.path.join(HERE, "data", "sample_corpus.txt")
 # The one table of chips the repo keeps, keyed by device_kind (read only).
@@ -58,7 +58,6 @@ PEAKS = os.path.join(HERE, "benchmarks", "peaks.json")
 CORPUS_BYTES = 32 << 20          # ROADMAP's wc-sample-32MB shape
 FUSED_CLI_BYTES = 4 << 20        # the `--sort-mode fused` CLI run's prefix
 KERNEL_BLOCK_LINES = 32768       # one real block, the kernels phase
-BITONIC_N = 1 << 17
 # The chip check allows a cold run 1200 s, nearly all of it compilation
 # (measured cold on a v5e, PR 22: 871 s for the whole default run, the
 # last 128 s of it the `--sort-mode fused` CLI run).  Every phase always
@@ -300,37 +299,9 @@ def kernel_fused(rows, cfg) -> None:
            jnp.where(want.valid, want.values, 0))
 
 
-def kernel_bitonic(rows, cfg) -> None:
-    """2^17 distinct uint32 keys cut from the block's own bytes (high bits
-    = text, low bits = position, so ties cannot reorder), payload = the
-    raw words: sorted key and payload must equal jax.lax.sort's."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from locust_tpu.ops.pallas.sort import bitonic_sort
-
-    words = np.ascontiguousarray(rows).view(np.uint32).reshape(-1)[:BITONIC_N]
-    key = jnp.asarray(
-        (words & np.uint32(0xFFFE0000)) | np.arange(BITONIC_N, dtype=np.uint32)
-    )
-    pay = jnp.asarray(words)
-    skey, (spay,) = jax.jit(
-        functools.partial(bitonic_sort, interpret=False)
-    )(key, (pay,))
-    rkey, rpay = jax.jit(
-        functools.partial(jax.lax.sort, num_keys=1)
-    )((key, pay))
-    _equal("bitonic key", skey, rkey)
-    _equal("bitonic payload", spay, rpay)
-
-
 _KERNEL_CHECKS = {
     "tokenize_block_pallas": kernel_tokenize,
     "fused_block_preagg": kernel_fused,
-    "bitonic_sort": kernel_bitonic,
 }
 
 

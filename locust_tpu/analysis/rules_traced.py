@@ -4,8 +4,8 @@ R002 (traced-purity, interprocedural): functions handed to ``jax.jit`` /
 ``shard_map`` / ``pallas_call`` (as calls or
 decorators) run under tracing: side effects execute ONCE at trace time
 and then silently never again — or, for Pallas interpret mode on CPU,
-can crash the XLA compiler outright (the bitonic-under-mesh segfault
-guard, CLAUDE.md).  Flags ``print``, ``time.*``, ``random.*``/
+can crash the XLA compiler outright (an interpret kernel inside a CPU
+mesh program, CLAUDE.md).  Flags ``print``, ``time.*``, ``random.*``/
 ``np.random.*``, ``open``/socket I/O, and global/nonlocal writes in the
 traced function AND in every callee the summaries call graph can
 attribute, across modules — a traced body outsourcing its side effect to

@@ -278,7 +278,7 @@ def _traced_fn_exprs(nodes: list):
 def _donate_positions(expr: ast.AST) -> tuple[int, ...]:
     """Int argument positions a ``donate_argnums=`` expression can take:
     every int constant anywhere in it (covers literal tuples and the
-    ``(0,) if cfg.donate_fold else ()`` conditional idiom)."""
+    ``(0,) if flag else ()`` conditional idiom)."""
     pos = set()
     for n in ast.walk(expr):
         if isinstance(n, ast.Constant) and type(n.value) is int:

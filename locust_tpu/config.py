@@ -26,10 +26,7 @@ DELIMITERS: bytes = b" ,.-;:'()\"\t"
 # The single source of truth for Process-stage sort strategies:
 # EngineConfig validation, the CLI --sort-mode choices, and
 # ops.process_stage.sort_and_compact dispatch all key off this.
-SORT_MODES = (
-    "hash", "hashp", "hashp2", "hashp1", "hash1", "radix", "bitonic", "lex",
-    "hasht", "hasht-mxu", "fused",
-)
+SORT_MODES = ("hash", "hashp2", "hashp1", "lex", "hasht", "hasht-mxu", "fused")
 
 # The sort-FREE fold family (ops/hash_table.py): identical probe/exactness
 # ladder, differing only in how the value-combine scatter is spelled —
@@ -193,8 +190,8 @@ FUSED_RESID_PAD: int = 8
 # NEVER inside a full CPU mesh program, CLAUDE.md); the interpreter
 # re-traces the kernel body per grid step, so production block sizes cost
 # minutes of XLA CPU compile.  Blocks with more lines than this take the
-# hasht-identical stock path off-TPU with a one-time notice — the same
-# stance as BITONIC_INTERPRET_MAX.  On TPU the Mosaic kernel always runs.
+# hasht-identical stock path off-TPU with a one-time notice.  On TPU the
+# Mosaic kernel always runs.
 FUSED_INTERPRET_MAX_LINES: int = 8192
 
 
@@ -260,68 +257,6 @@ def fused_table_layout(slots: int | None = None) -> tuple[int, int]:
     return max(FUSED_SUBLANE, t_hi), t_lo
 
 
-# --- bitonic Pallas sort (ops/pallas/sort.py) ---
-
-# Tile of the sort, in rows of 128 lanes.  Bigger tiles trade fewer HBM
-# round-trips for larger VMEM residency and longer unrolled kernels;
-# where the knee is has not been measured on this machine.  A power of
-# two (the network's strides) of at least the int32 sublane tile.
-BITONIC_TILE_ROWS: int = 256
-assert BITONIC_TILE_ROWS >= 8 and _pow2(BITONIC_TILE_ROWS)
-
-# Cap on compare-exchange substages statically unrolled into ONE Pallas
-# launch.  Unlimited fusion (the round-4 first cut) produced a ~120-substage
-# kernel whose Mosaic compile crashed the compiler service of an earlier
-# v5e set-up (2026-07-31); capping trades extra HBM round-trips for a
-# compilable kernel.  0 = unlimited.  The DEFAULT stays capped (32: ~4
-# launches for the 120-substage first stage block): the capped schedule
-# is the one the local chip's compiler has accepted
-# (tests/test_chip_compile.py, chip_smoke.py); whether unlimited fusion
-# compiles there, and which is faster, is an open perf question.
-BITONIC_MAX_FUSED: int = 32
-
-
-def _pack_local_stages(specs, max_fused):
-    """Split/merge tile-local stage specs ``(s, t_hi, t_lo)`` into launches
-    of at most ``max_fused`` substages each (greedy, order-preserving;
-    stages split mid-run when needed)."""
-    launches, cur, cnt = [], [], 0
-    for s, t_hi, t_lo in specs:
-        t = t_hi
-        while t >= t_lo:
-            if cnt == max_fused:
-                launches.append(tuple(cur))
-                cur, cnt = [], 0
-            take = min(max_fused - cnt, t - t_lo + 1)
-            cur.append((s, t, t - take + 1))
-            cnt += take
-            t -= take
-    if cur:
-        launches.append(tuple(cur))
-    return launches
-
-
-def bitonic_schedule(kbits: int, m: int, max_fused: int | None = None):
-    """HBM-pass schedule of the Pallas bitonic sort for ``n = 2^kbits``
-    elements with tile ``2^m``: a list of ``("local", ((s, t_hi, t_lo), ...))``
-    fused-kernel launches and ``("cross", s, t)`` single XLA passes, in
-    execution order.  The ONE place the launch structure is decided
-    (ops/pallas/sort.py executes it)."""
-    mf = BITONIC_MAX_FUSED if max_fused is None else max_fused
-    if mf <= 0:
-        mf = 1 << 30
-    sched = []
-    local1 = [(s, s, 1) for s in range(1, min(kbits, m) + 1)]
-    for ch in _pack_local_stages(local1, mf):
-        sched.append(("local", ch))
-    for s in range(m + 1, kbits + 1):
-        for t in range(s, m, -1):
-            sched.append(("cross", s, t))
-        for ch in _pack_local_stages([(s, m, 1)], mf):
-            sched.append(("local", ch))
-    return sched
-
-
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
     """Static shape/capacity configuration of one MapReduce pipeline.
@@ -366,17 +301,12 @@ class EngineConfig:
     # default — see default_sort_mode).  "hash": sort by a 64-bit key hash
     # — 3 sort operands + one index payload + gather; equal keys still
     # group adjacently (exact-key segment boundaries downstream), device
-    # order is hash order (host output re-sorts).  "hashp": same 3 hash
-    # keys but the row rides as sort PAYLOAD operands instead of a
-    # post-sort gather.  "hashp2": payload carriage with only 2 key
-    # operands (validity folded into a 31-bit primary hash, h2 tiebreak).
-    # "hash1": ONE 32-bit sort operand (31 hash bits + validity bit) +
-    # gather; collisions only duplicate a table row, re-merged downstream
-    # (process_stage._folded_key).  "radix": same folded key sorted by
-    # O(n) LSD radix passes instead of the comparison network
-    # (ops/radix_sort.py).  "bitonic": hand-written Pallas bitonic network
-    # (ops/pallas/sort.py) over the folded key with payload carriage —
-    # tile-local compare passes fused in VMEM; interpret mode off-TPU.
+    # order is hash order (host output re-sorts).  "hashp2": the row rides
+    # as sort PAYLOAD operands instead of a post-sort gather, with only 2
+    # key operands (validity folded into a 31-bit primary hash, h2
+    # tiebreak).  "hashp1": payload carriage behind ONE 32-bit sort
+    # operand (31 hash bits + validity bit); collisions only duplicate a
+    # table row, re-merged downstream (process_stage._folded_key).
     # "lex": sort full big-endian key lanes — exact lexicographic device
     # order, the reference's KIVComparator semantics (KeyValue.h:20-33).
     # "hasht": the fold-level SORT-FREE hash-table aggregation
@@ -395,11 +325,6 @@ class EngineConfig:
     # mode degrades to "hasht" exactly.
     sort_mode: str = "hash"
 
-    # Overflow behavior for > emits_per_line tokens: the reference prints
-    # "WARN: Exceeded emit limit" and drops (main.cu:141-144). We drop
-    # silently on device and surface a host-side overflow count.
-    warn_on_overflow: bool = True
-
     # Use Pallas kernels for the map/reduce hot loops where available;
     # otherwise pure-jnp/XLA lowering.
     use_pallas: bool = False
@@ -413,15 +338,7 @@ class EngineConfig:
     # resolves per backend at trace time: einsum on TPU, gather elsewhere.
     map_impl: str = "auto"
 
-    # --- zero-stall streaming executor knobs (docs/DESIGN.md) ---------
-    # Donate the fold accumulator into each per-block dispatch
-    # (jax.jit donate_argnums): XLA aliases the hash-table buffers
-    # input->output so the largest live array is updated in place
-    # instead of re-allocated per fold.  Applies to the per-block fold
-    # AND the one-dispatch lax.scan path; escape hatch for callers that
-    # hold references to a pre-fold accumulator.
-    donate_fold: bool = True
-
+    # --- zero-stall streaming executor knob (docs/DESIGN.md) ----------
     # Move checkpoint snapshots to a bounded background writer
     # (io/snapshot.py): the fold loop only marks a generation (an
     # on-device table copy, async) and the writer thread does the
@@ -429,13 +346,6 @@ class EngineConfig:
     # path, latest-wins when the loop laps it.  False restores the
     # synchronous in-loop save (identical on-disk format either way).
     async_checkpoint: bool = True
-
-    # Reuse a ring of STREAM_DISPATCH_DEPTH+1 pre-allocated host staging
-    # buffers for run_stream's per-block pad+transfer instead of a fresh
-    # numpy allocation per block — allocation-free steady state, and the
-    # ring size is exactly what the bounded-inflight backpressure
-    # guarantees is no longer referenced by an in-flight fold.
-    stream_staging_ring: bool = True
 
     # Structured telemetry opt-in (locust_tpu.obs, docs/OBSERVABILITY.md):
     # True enables the process tracer at engine construction, so API

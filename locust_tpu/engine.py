@@ -279,12 +279,12 @@ class _StagingRing:
         ]
         self._next = 0
 
-    def stage(self, chunk, block_lines: int, width: int) -> np.ndarray:
+    def stage(self, chunk) -> np.ndarray:
         from locust_tpu.parallel.shuffle import normalize_round_chunk
 
         buf = self._bufs[self._next]
         self._next = (self._next + 1) % len(self._bufs)
-        return normalize_round_chunk(chunk, block_lines, width, out=buf)
+        return normalize_round_chunk(chunk, *buf.shape, out=buf)
 
 
 class _CheckpointPump:
@@ -554,8 +554,8 @@ def _build_programs(cfg: EngineConfig, raw_map_fn: MapFn,
         when per-dispatch latency matters (many small blocks) and the XLA-
         idiomatic way to loop without data-dependent Python control flow.
         The init accumulator arrives as an ARGUMENT so the jit below
-        can donate it into the scan carry (cfg.donate_fold): even the
-        one-dispatch path allocates no second table.
+        can donate it into the scan carry: even the one-dispatch path
+        allocates no second table.
         """
 
         def body(carry, blk):
@@ -571,24 +571,23 @@ def _build_programs(cfg: EngineConfig, raw_map_fn: MapFn,
         (acc, overflow, num), _ = jax.lax.scan(body, init, blocks)
         return acc, overflow, num
 
-    # Donated fold state (cfg.donate_fold): the accumulator table —
-    # the largest live array — is donated into every per-block
-    # dispatch and into the scan init, so XLA aliases its buffers
-    # input->output (updated in place, no per-fold re-allocation).
+    # Donated fold state: the accumulator table — the largest live
+    # array — is donated into every per-block dispatch and into the
+    # scan init, so XLA aliases its buffers input->output (updated in
+    # place, no per-fold re-allocation).
     # Callers therefore must treat the acc they passed as consumed;
     # every loop here rebinds it, and snapshot marks copy on device
     # first (_CheckpointPump.mark).
     # The two donating jits are bound here under the names the engine
     # calls them by: the analyzer's R010 knows a donating callable by the
     # name its jax.jit(..., donate_argnums=) is bound to.
-    donate = (0,) if cfg.donate_fold else ()
-    _fold_block = jax.jit(fold_block, donate_argnums=donate)
+    _fold_block = jax.jit(fold_block, donate_argnums=(0,))
     # Breaker-failover fold (run_checkpointed's on-CPU dispatch):
     # identical to _fold_block unless the fused kernel is on — then
     # it is the kernel-free stock fold (see stock_fold above).
     # Traced lazily, so non-failover runs never pay its compile.
     fold_block_fallback = (
-        jax.jit(stock_fold, donate_argnums=donate)
+        jax.jit(stock_fold, donate_argnums=(0,))
         if fused_kernel_on
         else _fold_block
     )
@@ -597,11 +596,11 @@ def _build_programs(cfg: EngineConfig, raw_map_fn: MapFn,
     # leaves segments at one block (then run_stream's per-block loop
     # is already optimal).
     jit_fold_segment = (
-        jax.jit(fold_segment, donate_argnums=donate)
+        jax.jit(fold_segment, donate_argnums=(0,))
         if fused_kernel_on and fused_stream_seg > 1
         else None
     )
-    _scan_blocks_into = jax.jit(scan_blocks_into, donate_argnums=donate)
+    _scan_blocks_into = jax.jit(scan_blocks_into, donate_argnums=(0,))
     # The export/compile-check surface (__graft_entry__.entry, the
     # TPU StableHLO lowering gates) keeps the one-argument signature.
     jit_scan_blocks = jax.jit(
@@ -1361,7 +1360,6 @@ class MapReduceEngine:
         backpressure.  Stall accounting lands in ``RunResult.stream``.
         """
         from locust_tpu.io.loader import prefetch_blocks
-        from locust_tpu.parallel.shuffle import normalize_round_chunk
         blocks = prefetch_blocks(blocks)  # overlap host reads with folds
         bl, w = self.cfg.block_lines, self.cfg.line_width
         acc = KVBatch.empty(self._table_size, self.cfg.key_lanes)
@@ -1396,11 +1394,7 @@ class MapReduceEngine:
                 blocks, acc, overflow, max_distinct, start_block, pump,
                 every,
             )
-        ring = (
-            _StagingRing(self.STREAM_DISPATCH_DEPTH + 1, bl, w)
-            if self.cfg.stream_staging_ring
-            else None
-        )
+        ring = _StagingRing(self.STREAM_DISPATCH_DEPTH + 1, bl, w)
 
         stall_ms = 0.0
         flush_ms = 0.0
@@ -1429,15 +1423,9 @@ class MapReduceEngine:
                 # Span covers staging + dispatch, NOT device completion
                 # (folds are async; completion shows up as the later
                 # stream.stall events) — docs/OBSERVABILITY.md.
-                with obs.span("stream.block", i=i,
-                              staging="ring" if ring is not None else "alloc"):
-                    blk = (
-                        ring.stage(blk, bl, w)
-                        if ring is not None
-                        else normalize_round_chunk(blk, bl, w)
-                    )
+                with obs.span("stream.block", i=i):
                     acc, blk_overflow, distinct = self._fold_block(
-                        acc, jnp.asarray(blk)
+                        acc, jnp.asarray(ring.stage(blk))
                     )
                 overflow = overflow + blk_overflow
                 max_distinct = jnp.maximum(max_distinct, distinct)
@@ -1469,8 +1457,6 @@ class MapReduceEngine:
         obs.metric_inc("stream.blocks", max(0, i + 1 - start_block))
         stream = {
             "blocks": max(0, i + 1 - start_block),
-            "staging_ring": ring is not None,
-            "donate_fold": self.cfg.donate_fold,
             "backpressure_stall_ms": round(stall_ms, 3),
             "total_ms": round(total_ms, 3),
         }
@@ -1490,11 +1476,11 @@ class MapReduceEngine:
         """run_stream's persistent-kernel tail (megakernel v2).
 
         Blocks stage into ``[seg_blocks * block_lines, width]`` segment
-        buffers (a ring sized like _StagingRing when
-        cfg.stream_staging_ring) and each FULL segment folds in ONE
-        ``_fold_segment`` dispatch — the kernel table stays VMEM-resident
-        across the whole segment, so the per-block acc->settle->acc HBM
-        round-trip and table flush amortize by ``seg_blocks``.  The
+        buffers (a ring sized like _StagingRing) and each FULL segment
+        folds in ONE ``_fold_segment`` dispatch — the kernel table stays
+        VMEM-resident across the whole segment, so the per-block
+        acc->settle->acc HBM round-trip and table flush amortize by
+        ``seg_blocks``.  The
         trailing partial segment zero-pads its unfilled blocks (zero
         lines tokenize to nothing, the _blocks padding contract), so one
         executable serves every segment.  Checkpoint marks land at
@@ -1512,33 +1498,23 @@ class MapReduceEngine:
 
         bl, w = self.cfg.block_lines, self.cfg.line_width
         seg = self._fused_stream_seg
-        n_slots = self.STREAM_DISPATCH_DEPTH + 1
-        bufs = (
-            [np.zeros((seg * bl, w), np.uint8) for _ in range(n_slots)]
-            if self.cfg.stream_staging_ring
-            else None
+        bufs = itertools.cycle(
+            np.zeros((seg * bl, w), np.uint8)
+            for _ in range(self.STREAM_DISPATCH_DEPTH + 1)
         )
         state = {
             "acc": acc, "overflow": overflow,
-            "max_distinct": max_distinct, "slot": 0, "segments": 0,
+            "max_distinct": max_distinct, "segments": 0,
             "stall_ms": 0.0, "last_mark": start_block,
         }
         flush_ms = 0.0
         inflight: _collections.deque = _collections.deque()
         t0 = time.perf_counter()
 
-        def next_buf() -> np.ndarray:
-            if bufs is None:
-                return np.zeros((seg * bl, w), np.uint8)
-            buf = bufs[state["slot"]]
-            state["slot"] = (state["slot"] + 1) % n_slots
-            return buf
-
         def dispatch(buf: np.ndarray, n_filled: int, seg_end: int) -> None:
-            if n_filled < seg and bufs is not None:
+            if n_filled < seg:
                 buf[n_filled * bl:, :] = 0  # ring reuse: clear stale tail
             with obs.span("stream.block", i=seg_end - 1,
-                          staging="ring" if bufs is not None else "alloc",
                           seg_blocks=n_filled):
                 acc2, blk_overflow, distinct = self._fold_segment(
                     state["acc"], jnp.asarray(buf)
@@ -1571,7 +1547,7 @@ class MapReduceEngine:
                 if i < start_block:  # resume: re-read, don't re-fold
                     continue
                 if fill == 0:
-                    cur = next_buf()
+                    cur = next(bufs)
                 normalize_round_chunk(
                     blk, bl, w, out=cur[fill * bl:(fill + 1) * bl]
                 )
@@ -1594,8 +1570,6 @@ class MapReduceEngine:
         obs.metric_inc("stream.blocks", max(0, i + 1 - start_block))
         stream = {
             "blocks": max(0, i + 1 - start_block),
-            "staging_ring": bufs is not None,
-            "donate_fold": self.cfg.donate_fold,
             "backpressure_stall_ms": round(state["stall_ms"], 3),
             "total_ms": round(total_ms, 3),
             "fused": {
@@ -1891,7 +1865,7 @@ class MapReduceEngine:
                 num,
                 acc.size,
             )
-        if overflow and self.cfg.warn_on_overflow:
+        if overflow:
             # Reference: "WARN: Exceeded emit limit" printf (main.cu:141-144).
             logger.warning(
                 "WARN: Exceeded emit limit — %d tokens beyond %d-per-line cap dropped",

@@ -397,8 +397,7 @@ def fused_engine_eligible(cfg: EngineConfig, map_fn, combine: str):
       planes, exact only below the float24 integer ceiling;
     * off-TPU, blocks above FUSED_INTERPRET_MAX_LINES stay on the stock
       path — the interpreter re-traces the kernel body per grid step and
-      production block sizes cost minutes of XLA CPU compile (see
-      BITONIC_INTERPRET_MAX for the precedent).
+      production block sizes cost minutes of XLA CPU compile.
     """
     from locust_tpu.config import FUSED_INTERPRET_MAX_LINES
     from locust_tpu.ops.map_stage import wordcount_map

@@ -7,12 +7,10 @@ emit slots to the tail (reference MapReduce/src/main.cu:411) then
 (reference README.md:72-80) and is the headline perf target (BASELINE.json).
 
 TPU-native formulations, selected by ``EngineConfig.sort_mode`` (also
-"hashp"/"hashp2"/"hashp1" = payload-carry at 3/2/1 hash key operands,
-"hash1" = one folded 32-bit key + gather, "radix" = LSD counting sort,
-"bitonic" = the hand-written Pallas VMEM-tiled network
-(ops/pallas/sort.py), and "hasht" = the fold-level SORT-FREE hash-table
-aggregation (ops/hash_table.py; this module serves its grouping-interface
-consumers via the hashp1 formulation); see the variant functions below):
+"hashp2"/"hashp1" = payload-carry at 2/1 hash key operands, and "hasht" =
+the fold-level SORT-FREE hash-table aggregation (ops/hash_table.py; this
+module serves its grouping-interface consumers via the hashp1
+formulation); see the variant functions below):
 
 * **"lex"** — ONE multi-operand ``jax.lax.sort`` whose most-significant key
   is the inverted validity bit and whose remaining keys are the big-endian
@@ -35,32 +33,12 @@ consumers via the hashp1 formulation); see the variant functions below):
 
 from __future__ import annotations
 
-import logging
-
 import jax
 import jax.numpy as jnp
 
 from locust_tpu.config import HASHT_FAMILY
 from locust_tpu.core import packing
 from locust_tpu.core.kv import KVBatch
-
-logger = logging.getLogger("locust_tpu")
-_warned_bitonic_fallback = False
-_warned_bitonic_interpret = False
-
-def _vma_of(x) -> frozenset:
-    """The array's varying-manual-axes set (empty outside shard_map)."""
-    return getattr(jax.typeof(x), "vma", None) or frozenset()
-
-
-# Largest padded element count the INTERPRET-mode bitonic kernel (the
-# off-TPU test vehicle) is allowed to trace: the interpreter re-traces
-# every fused VMEM launch into one XLA program, and at production shapes
-# under a mesh program that blows up the CPU compiler (observed: SIGSEGV
-# inside XLA at full-hamlet mesh-merge shapes, 8 shards x 2^18 rows x 10
-# operands).  Above the cap, off-TPU callers get the stock formulation
-# with a loud one-time notice; on TPU the real Mosaic kernel always runs.
-BITONIC_INTERPRET_MAX: int = 1 << 16
 
 
 def sort_and_compact(batch: KVBatch, mode: str = "hash") -> KVBatch:
@@ -71,8 +49,6 @@ def sort_and_compact(batch: KVBatch, mode: str = "hash") -> KVBatch:
     """
     if mode == "hash":
         return _hash_sort(batch)
-    if mode == "hashp":
-        return _hashp_sort(batch)
     if mode == "hashp2":
         return _hashp2_sort(batch)
     if mode == "hashp1":
@@ -86,12 +62,6 @@ def sort_and_compact(batch: KVBatch, mode: str = "hash") -> KVBatch:
         # CLI) get the stock formulation with the same key-grouping
         # guarantees.
         return _hashp1_sort(batch)
-    if mode == "hash1":
-        return _hash1_sort(batch)
-    if mode == "radix":
-        return _radix_sort(batch)
-    if mode == "bitonic":
-        return _bitonic_sort(batch)
     if mode == "lex":
         return _lex_sort(batch)
     raise ValueError(f"unknown sort mode {mode!r}")
@@ -140,43 +110,21 @@ def _hash_sort(batch: KVBatch) -> KVBatch:
     )
 
 
-def _hashp_sort(batch: KVBatch) -> KVBatch:
-    """Hash keys, rows ride as sort PAYLOADS — no post-sort gather.
-
-    Same 3 sort keys as "hash" but the key lanes and values travel through
-    ``lax.sort`` as payload operands instead of being gathered by a sorted
-    index afterwards: sequential passes over 9 more operands in place of
-    a random-access gather (not measured on this machine).
-    Collision/correctness story identical to "hash".
-    """
-    lanes, values, valid = batch.key_lanes, batch.values, batch.valid
-    n_lanes = lanes.shape[-1]
-    invalid = (~valid).astype(jnp.uint32)                  # 0 = valid, first
-    h1, h2 = packing.hash_pair(lanes)
-    out = jax.lax.sort(
-        (invalid, h1, h2, *(lanes[:, i] for i in range(n_lanes)), values),
-        num_keys=3,
-    )
-    return KVBatch(
-        key_lanes=jnp.stack(out[3 : 3 + n_lanes], axis=-1),
-        values=out[3 + n_lanes],
-        valid=out[0] == 0,
-    )
-
-
 def _hashp2_sort(batch: KVBatch) -> KVBatch:
     """2 sort keys + payload-carry: validity folded into the primary hash.
 
-    Like "hashp" but the invalid flag rides in the top bit of a 31-bit
-    primary hash (``_folded_key``) with the full h2 as tiebreaker — one
-    fewer key operand per sort pass.  Valid rows keep ``h1 >> 1`` (top bit
-    0, < 0x80000000), invalid rows get 0xFFFFFFFF, so ascending order is
+    The key lanes and values travel through ``lax.sort`` as payload
+    operands (no post-sort gather, unlike "hash"), and the invalid flag
+    rides in the top bit of a 31-bit primary hash with the full h2 as
+    tiebreaker — one key operand fewer than "hash".  Valid rows keep
+    ``h1 >> 1`` (top bit 0, < 0x80000000), invalid rows get 0xFFFFFFFF,
+    so ascending order is
     still valid-first and validity is reconstructed from the sorted key.
     Grouping tiebreak is 31+32 hash bits; as everywhere, the segment
     reduce compares full key lanes at boundaries so collisions only
     duplicate a table row (re-merged downstream).  The TPU default and
     the mode every benchmark cell runs (PERF.md section 5:
-    ``sort_dev_ms.tput``); not measured against "hashp" on this machine.
+    ``sort_dev_ms.tput``).
     """
     lanes, values, valid = batch.key_lanes, batch.values, batch.valid
     n_lanes = lanes.shape[-1]
@@ -200,10 +148,9 @@ def _hashp1_sort(batch: KVBatch) -> KVBatch:
     31-bit key (``_folded_key``: validity in the top bit) with NO h2
     tiebreaker, rows riding as payloads — 6 uint32 operands per pass vs
     hashp2's 7, i.e. ~14% less HBM traffic through the stage the whole
-    pipeline is bottlenecked on.  Collision story is exactly "hash1"'s
-    (same 31-bit grouping key, already shipped): ~C(n,2)/2^31 colliding
-    pairs interleave within a hash run, the segment reduce's full-lane
-    boundary compare splits them into duplicate table rows, and the next
+    pipeline is bottlenecked on.  Collision story is ``_folded_key``'s:
+    ~C(n,2)/2^31 colliding pairs interleave within a hash run, the
+    segment reduce's full-lane boundary compare splits them into duplicate table rows, and the next
     fold or the host finalize re-merges those — never a wrong count.
     Not measured on the current machine.
     """
@@ -234,111 +181,3 @@ def _folded_key(batch: KVBatch) -> jax.Array:
     """
     h1, _ = packing.hash_pair(batch.key_lanes)
     return jnp.where(batch.valid, h1 >> 1, jnp.uint32(0xFFFFFFFF))
-
-
-def _hash1_sort(batch: KVBatch) -> KVBatch:
-    lanes, values, valid = batch.key_lanes, batch.values, batch.valid
-    idx = jnp.arange(lanes.shape[0], dtype=jnp.int32)
-    _, sidx = jax.lax.sort((_folded_key(batch), idx), num_keys=1)
-    return KVBatch(
-        key_lanes=lanes[sidx], values=values[sidx], valid=valid[sidx]
-    )
-
-
-def _radix_sort(batch: KVBatch) -> KVBatch:
-    """LSD radix passes over the folded key (ops/radix_sort.py) — the O(n)
-    alternative to lax.sort's comparison network for the Process stage."""
-    from locust_tpu.ops.radix_sort import radix_argsort
-
-    lanes, values, valid = batch.key_lanes, batch.values, batch.valid
-    sidx = radix_argsort(_folded_key(batch))
-    return KVBatch(
-        key_lanes=lanes[sidx], values=values[sidx], valid=valid[sidx]
-    )
-
-
-def _bitonic_sort(batch: KVBatch) -> KVBatch:
-    """Hand-written Pallas bitonic network over the folded key, row as
-    payload (ops/pallas/sort.py): "hash1"'s single 31-bit-hash+validity
-    operand with "hashp"'s payload carriage, but the tile-local compare
-    passes run in VMEM instead of streaming HBM.  Interpret mode engages
-    automatically off-TPU (slow; CI uses small shapes) and is CAPPED at
-    BITONIC_INTERPRET_MAX padded elements — beyond it, off-TPU callers
-    get the stock formulation with a one-time notice (the interpreter's
-    re-trace crashes the CPU XLA compiler at production mesh shapes);
-    on TPU the Mosaic kernel always runs.
-
-    Inside a ``shard_map(check_vma=True)`` manual trace the kernel
-    cannot trace: jax's vma machinery breaks inside the pallas
-    interpret re-trace (a mixed-vma ``lt``; a ``pvary`` re-attach fails
-    again in the physical-type re-trace).  BOTH mesh engines therefore
-    pass ``check_vma=False`` on their round step when this mode is
-    configured (shuffle.py and hierarchical.py engine ctors;
-    hierarchical's sync/combine shard_maps are check_vma=False for
-    their own all_gather-replication reason), which removes vma types
-    entirely and the kernel RUNS — pinned by
-    tests/test_distributed.py::test_mesh_engines_run_bitonic_kernel.
-    This fallback remains only for third-party shard_map sites that
-    keep check_vma=True: there the mode serves the semantically
-    IDENTICAL stock formulation — same single folded-key operand, same
-    payload carriage via ``lax.sort`` — with a loud one-time warning so
-    no A/B can silently time the fallback believing it measured the
-    kernel."""
-    lanes, values, valid = batch.key_lanes, batch.values, batch.valid
-    n_lanes = lanes.shape[-1]
-    folded = _folded_key(batch)
-    vma = frozenset().union(
-        *(_vma_of(x) for x in (folded, lanes, values))
-    )
-    if vma:
-        # Loud once: evidence recorded as sort_mode="bitonic" on a mesh
-        # engine measured THIS stock formulation, not the Pallas kernel —
-        # a silent substitution would let a future A/B conclude the
-        # kernel gives no mesh speedup when it never ran.
-        global _warned_bitonic_fallback  # locust: noqa[R002] deliberate warn-once AT TRACE TIME: the substitution notice must fire exactly when tracing picks the stock fallback
-        if not _warned_bitonic_fallback:
-            _warned_bitonic_fallback = True
-            logger.warning(
-                "sort_mode='bitonic' inside shard_map(check_vma=True): "
-                "jax's vma machinery cannot trace the Pallas kernel "
-                "(mixed-vma compare in the pallas interpret re-trace); "
-                "using the equivalent stock lax.sort formulation — these "
-                "timings do NOT measure the hand-written kernel.  On TPU "
-                "the built-in mesh engines avoid this by passing "
-                "check_vma=False for this mode (off-TPU they keep the "
-                "check: the interpret kernel inside a mesh program can "
-                "crash XLA's CPU compiler)"
-            )
-        # The stock formulation of the same sort IS mode "hashp1" —
-        # delegate so "semantically identical" stays true by construction.
-        return _hashp1_sort(batch)
-    from locust_tpu.ops.pallas.sort import bitonic_sort
-
-    interpret = jax.default_backend() != "tpu"
-    n_pad = max(1 << 10, 1 << max(batch.size - 1, 1).bit_length())
-    if interpret and n_pad > BITONIC_INTERPRET_MAX:
-        # Interpret mode is the off-TPU TEST vehicle; at production
-        # shapes its re-trace of every fused launch crashes the CPU
-        # XLA compiler (SIGSEGV at mesh-merge shapes).  Off-TPU big
-        # sorts take the stock formulation — loudly, so no CPU timing
-        # can be mistaken for a kernel measurement.
-        global _warned_bitonic_interpret  # locust: noqa[R002] deliberate warn-once AT TRACE TIME: the interpret-skip notice must fire exactly when tracing takes this branch
-        if not _warned_bitonic_interpret:
-            _warned_bitonic_interpret = True
-            logger.warning(
-                "sort_mode='bitonic' off-TPU at %d rows (> %d): interpret-"
-                "mode kernel skipped; using the equivalent stock lax.sort "
-                "formulation",
-                batch.size, BITONIC_INTERPRET_MAX,
-            )
-        return _hashp1_sort(batch)
-    key, pays = bitonic_sort(
-        folded,
-        tuple(lanes[:, i] for i in range(n_lanes)) + (values,),
-        interpret=interpret,
-    )
-    return KVBatch(
-        key_lanes=jnp.stack(pays[:n_lanes], axis=-1),
-        values=pays[n_lanes],
-        valid=key < jnp.uint32(0x80000000),
-    )

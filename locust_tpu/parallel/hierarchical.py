@@ -55,7 +55,6 @@ from locust_tpu.parallel.shuffle import (
     RoundStats,
     _fused_mesh_gate,
     _kv_spec,
-    _mesh_check_vma,
     _round_up,
     build_shuffle_step,
     drive_checkpointed_rounds,
@@ -92,7 +91,6 @@ def _build_hierarchical_programs(
     leftover_capacity: int,
     max_drains: int,
     fused_preagg: bool,
-    check_vma: bool,
 ) -> _HierarchicalPrograms:
     """Define and jit the two-level engine's programs.  ``map_fn`` and
     ``combine`` are the NORMALIZED pair; nothing here names an engine, so
@@ -144,7 +142,8 @@ def _build_hierarchical_programs(
             mesh=mesh,
             in_specs=(P(both), kv_spec_2d, kv_spec_2d),
             out_specs=(kv_spec_2d, kv_spec_2d, P(slice_axis)),
-            check_vma=check_vma,
+            # Off only with the Pallas kernel engaged (shuffle.py's rule).
+            check_vma=not fused_preagg,
         )
     )
     # Output of the final combine is REPLICATED over the slice axis:
@@ -284,7 +283,6 @@ class HierarchicalMapReduce:
             cfg, mesh, slice_axis, data_axis, self.bin_capacity,
             self.shard_capacity, self.leftover_capacity,
             self.max_drain_rounds, self._fused_kernel_on,
-            _mesh_check_vma(cfg, self._fused_kernel_on),
         )
         programs: _HierarchicalPrograms = _programs_for(
             ("hierarchical", map_fn, combine, *config),

@@ -601,9 +601,8 @@ def build_shuffle_step(
     caller gates this on :func:`fused_mesh_eligible` (TPU-only: the
     interpret kernel never runs inside a CPU mesh program — the check_vma
     segfault class, CLAUDE.md) and must disable check_vma on the wrapping
-    shard_map (the bitonic precedent: jax's vma machinery breaks inside
-    the Pallas re-trace).  The kernel output pads up to the local
-    combiner's capacity contract (output size == raw emit count) and a
+    shard_map (jax's vma machinery breaks inside the Pallas re-trace).
+    The kernel output pads up to the local combiner's capacity contract (output size == raw emit count) and a
     residual overflow re-folds the shard's block through the stock path
     via lax.cond — bit-identity to "hasht" carries over shard-by-shard
     (the settlement argument, ops/pallas/fused_fold.py docstring).
@@ -817,35 +816,6 @@ def _fused_mesh_gate(
     return ok, not ok
 
 
-def _mesh_check_vma(cfg: EngineConfig, fused_kernel_on: bool) -> bool:
-    """``check_vma`` of a mesh engine's step program (both engines).
-
-    Disabled for sort_mode="bitonic" ON TPU so the hand-written Pallas
-    kernel actually RUNS on mesh engines.  Under check_vma=True the
-    kernel cannot trace — jax's vma machinery breaks inside the pallas
-    interpret re-trace (verified this jax version: "Primitive lt requires
-    varying manual axes to match") — and process_stage._bitonic_sort
-    would silently serve the stock lax.sort formulation instead.  With
-    the check off, vma types are absent, the kernel traces, and mesh
-    bitonic is oracle-exact.  TPU-only because the off-TPU INTERPRET
-    kernel inside a full mesh program has twice segfaulted XLA's CPU
-    compiler (thread stack overflow in libjax_common.so, kernel log
-    2026-07-31) nondeterministically — on CPU the engines keep
-    check_vma=True, so _bitonic_sort takes its loud stock-formulation
-    fallback there; the kernel's shard_map traceability itself is pinned
-    by a direct small test (tests/test_distributed.py).  The cost on TPU
-    is losing jax's replication checking for this one mode; the engines'
-    outputs are oracle-tested per mode.  The fused kernel engaged implies
-    a TPU backend (fused_mesh_eligible), so the check is only ever
-    dropped on TPU — the CPU engines keep check_vma=True and never trace
-    a Pallas kernel in a mesh program.
-    """
-    return not (
-        (cfg.sort_mode == "bitonic" and jax.default_backend() == "tpu")
-        or fused_kernel_on
-    )
-
-
 def _kv_spec(axis) -> KVBatch:
     """A KVBatch whose every leaf is sharded over ``axis``."""
     return KVBatch(key_lanes=P(axis), values=P(axis), valid=P(axis))
@@ -861,7 +831,6 @@ def _build_mesh_step(
     leftover_capacity: int,
     max_drains: int,
     fused_preagg: bool,
-    check_vma: bool,
     shard_capacity: int,
 ):
     """The flat mesh's step program at one shard capacity — the capacity
@@ -892,7 +861,9 @@ def _build_mesh_step(
             mesh=mesh,
             in_specs=(P(axis), kv_spec, kv_spec),
             out_specs=(kv_spec, kv_spec, P()),
-            check_vma=check_vma,
+            # Off only with the Pallas kernel engaged (TPU-only,
+            # fused_mesh_eligible): it cannot trace under the check.
+            check_vma=not fused_preagg,
         )
     )
 
@@ -1070,7 +1041,6 @@ class DistributedMapReduce:
         self._fused_kernel_on, self.fused_demoted = _fused_mesh_gate(
             cfg, map_fn, combine, engine="flat"
         )
-        check_vma = _mesh_check_vma(cfg, self._fused_kernel_on)
         # The programs belong to the configuration, not to this engine
         # (engine._programs_for, as MapReduceEngine's do): a process's
         # second engine of an equal configuration — the CLI makes one a
@@ -1081,7 +1051,7 @@ class DistributedMapReduce:
         # wrapper anew each time); the shard capacity is no part of it
         # (_MeshPrograms).  The builder names no engine.
         config = (cfg, mesh, axis, self.bin_capacity, self.leftover_capacity,
-                  self.max_drain_rounds, self._fused_kernel_on, check_vma)
+                  self.max_drain_rounds, self._fused_kernel_on)
         self._programs: _MeshPrograms = _programs_for(
             ("mesh", map_fn, combine, *config),
             lambda: _build_mesh_programs(norm_map_fn, norm_combine, *config),

@@ -166,7 +166,7 @@ def test_r002_fires_on_global_write_in_shard_map_body(tmp_path):
 
 
 def test_r002_fires_under_functools_partial_jit_decorator(tmp_path):
-    # The dominant decorator idiom in this repo (radix_sort, tokenize,
+    # The dominant decorator idiom in this repo (tokenize,
     # pagerank): the tracer name lives in the partial's ARGUMENTS.
     _write(tmp_path, "mod.py", """
         import functools

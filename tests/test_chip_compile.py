@@ -183,17 +183,6 @@ def test_fused_kernel_asks_for_the_vmem_the_published_widths_need():
     assert params.vmem_limit_bytes >= 32 << 20
 
 
-def test_bitonic_sort_compiles(one_chip):
-    from locust_tpu.ops.pallas.sort import bitonic_sort
-
-    n = 1 << 17
-    arr = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=one_chip)
-    compiled = jax.jit(
-        functools.partial(bitonic_sort, interpret=False)
-    ).lower(arr, (arr,)).compile()
-    assert _has_kernel(compiled)
-
-
 def test_tokenize_block_einsum_branch_compiles(one_chip):
     """The MXU formulation of the map stage — the branch a TPU takes
     (``map_impl="auto"`` asks jax.default_backend(), which is the CPU

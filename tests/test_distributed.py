@@ -383,92 +383,6 @@ class TestRoundStats:
             RoundStats(merge_stats_vectors, lambda s: None, every=0)
 
 
-def test_bitonic_kernel_traces_under_shard_map():
-    """The shard_map traceability the TPU mesh engines rely on (they
-    pass check_vma=False for sort_mode="bitonic" so the kernel RUNS):
-    a direct small interpret-mode kernel call
-    under shard_map(check_vma=False) must trace, run per-shard, and
-    sort exactly.  (The full-mesh-program interpret combination is
-    deliberately NOT exercised: it has twice segfaulted XLA's CPU
-    compiler — thread stack overflow — which is why the engines take
-    the kernel path on TPU only.)"""
-    import numpy as np
-
-    from jax.sharding import Mesh, PartitionSpec as P
-    from locust_tpu.ops.pallas.sort import bitonic_sort
-
-    mesh = Mesh(np.array(jax.devices()[:8]).reshape(8), ("d",))
-
-    def body(k, v):
-        sk, (sv,) = bitonic_sort(k, (v,), interpret=True)
-        return sk, sv
-
-    k = (jnp.arange(8 * 2048, dtype=jnp.uint32)
-         * jnp.uint32(2654435761)) % jnp.uint32(977)
-    v = jnp.arange(8 * 2048, dtype=jnp.uint32)
-    f = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(P("d"), P("d")),
-        out_specs=(P("d"), P("d")), check_vma=False,
-    ))
-    sk, sv = f(k, v)
-    for s in range(8):
-        shard = np.asarray(sk)[s * 2048:(s + 1) * 2048]
-        src = np.asarray(k)[s * 2048:(s + 1) * 2048]
-        assert (shard[:-1] <= shard[1:]).all()
-        assert sorted(shard.tolist()) == sorted(src.tolist())
-
-
-def test_mesh_bitonic_cpu_falls_back_loudly_and_exact():
-    """Off-TPU, mesh engines keep check_vma=True for bitonic, so the
-    mode takes process_stage's LOUD stock-formulation fallback (the
-    interpret kernel inside a full mesh program segfaults the CPU XLA
-    compiler — kernel-log evidence, round 5) and stays oracle-exact.
-    On TPU the same engines flip check_vma off and run the Mosaic
-    kernel."""
-    import locust_tpu.ops.process_stage as ps
-
-    from locust_tpu.parallel.hierarchical import HierarchicalMapReduce
-    from locust_tpu.parallel.mesh import make_mesh, make_mesh_2d
-
-    lines = [b"to be or not to be", b"that is the question", b"the the"] * 8
-    cfg = small_cfg(sort_mode="bitonic")
-    rows = bytes_ops.strings_to_rows(lines, cfg.line_width)
-    want = dict(py_wordcount(lines, cfg.emits_per_line))
-    ps._warned_bitonic_fallback = False
-    res = DistributedMapReduce(make_mesh(8), cfg).run(rows)
-    assert dict(res.to_host_pairs()) == want
-    assert ps._warned_bitonic_fallback, (
-        "CPU mesh bitonic should take (and announce) the stock fallback"
-    )
-    res = HierarchicalMapReduce(make_mesh_2d(2, 4), cfg).run(rows)
-    assert dict(res.to_host_pairs()) == want
-
-
-def test_single_device_bitonic_interpret_cap():
-    """Single-device OFF-TPU bitonic above the interpret-size cap must
-    complete via the loud stock fallback (uncapped interpret re-traces
-    of production-shape kernels are the segfault class round 5 hit) and
-    stay oracle-exact."""
-    import os
-
-    import locust_tpu.ops.process_stage as ps
-    from locust_tpu.engine import MapReduceEngine
-
-    path = "/root/reference/hamlet.txt"
-    if not os.path.exists(path):
-        pytest.skip("reference corpus not mounted")
-    lines = open(path, "rb").read().splitlines()
-    # Default caps: the fold sorts table + emits = 65,536 + 81,920 rows
-    # -> padded 2^18, over the 2^16 interpret cap.
-    cfg = EngineConfig(sort_mode="bitonic")
-    ps._warned_bitonic_interpret = False
-    res = MapReduceEngine(cfg).run_lines(lines)
-    assert dict(res.to_host_pairs()) == dict(
-        py_wordcount(lines, cfg.emits_per_line)
-    )
-    assert ps._warned_bitonic_interpret
-
-
 def test_shard_capacity_honors_table_size():
     """An explicitly raised cfg.table_size must carry over to the mesh
     engines' default shard capacity: with tiny blocks the emits-derived

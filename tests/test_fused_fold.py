@@ -495,8 +495,7 @@ def test_fused_engine_scan_lowers_for_tpu():
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 (virtual) devices")
 def test_fused_kernel_traces_under_shard_map():
     """The shard_map traceability a future TPU mesh integration relies
-    on (the bitonic precedent): a direct small
-    interpret-mode kernel call under shard_map(check_vma=False) must
+    on: a direct small interpret-mode kernel call under shard_map(check_vma=False) must
     trace, run per-shard, and pre-aggregate exactly.  (The
     full-mesh-program interpret combination is deliberately NOT
     exercised: it is the CPU-compiler segfault class.)"""
@@ -631,25 +630,6 @@ def test_stream_fused_multi_segment_identical_to_hasht():
     assert f.stream["blocks"] % fs["seg_blocks"] != 0  # partial trailing seg
     assert fs["segments"] == -(-f.stream["blocks"] // fs["seg_blocks"])
     assert h.fused_kernel is None and not h.fused_demoted
-
-
-def test_stream_fused_without_staging_ring_identical():
-    """cfg.stream_staging_ring=False takes the fresh-buffer path through
-    the same segment dispatch — identical tables either way."""
-    lines = corpus_lines(150)
-    a_eng = MapReduceEngine(_stream_cfg())
-    b_eng = MapReduceEngine(_stream_cfg(stream_staging_ring=False))
-    bl = a_eng.cfg.block_lines
-
-    def blocks(eng):
-        rows = eng.rows_from_lines(lines)
-        for i in range(0, rows.shape[0], bl):
-            yield rows[i:i + bl]
-
-    a = a_eng.run_stream(blocks(a_eng))
-    b = b_eng.run_stream(blocks(b_eng))
-    _assert_tables_identical(a.table, b.table, "ring vs alloc staging")
-    assert a.stream["staging_ring"] and not b.stream["staging_ring"]
 
 
 def test_stream_fused_crash_resume_byte_identical(tmp_path):

@@ -333,9 +333,9 @@ def test_debug_checks_accept_hasht_tables(monkeypatch):
 
 def test_hasht_scan_lowers_for_tpu():
     """The full-corpus hasht fold (scatters + nested lax.cond inside
-    lax.scan) must lower to TPU StableHLO off-hardware — the same
-    pre-hardware gate the bitonic kernel gets, so a lowering regression
-    is caught before it costs chip time."""
+    lax.scan) must lower to TPU StableHLO off-hardware — the
+    pre-hardware gate, so a lowering regression is caught before it
+    costs chip time."""
     import jax
     # 0.4.x has the module but not the lazy ``jax.export`` attribute.
     from jax import export as jax_export

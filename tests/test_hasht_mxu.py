@@ -300,7 +300,7 @@ def test_debug_checks_accept_hasht_mxu_tables(monkeypatch):
 def test_hasht_mxu_scan_lowers_for_tpu():
     """The fused fold (one-hot contractions + scatters + nested lax.cond
     inside lax.scan) must lower to TPU StableHLO off-hardware — the same
-    pre-hardware gate hasht and the bitonic kernel get, so a lowering
+    pre-hardware gate hasht gets, so a lowering
     regression is caught before it costs chip time."""
     from jax import export as jax_export
 

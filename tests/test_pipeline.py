@@ -350,8 +350,7 @@ def test_engine_checkpoint_fingerprint_mismatch_starts_fresh(tmp_path):
 
 @pytest.mark.parametrize("mode", list(SORT_MODES))
 def test_engine_oracle_exact_across_sort_modes(mode):
-    """Every Process-stage sort strategy must produce the identical table
-    (hash1/radix are the optimized-sort attempts)."""
+    """Every Process-stage sort strategy must produce the identical table."""
     from locust_tpu.config import EngineConfig
     from locust_tpu.engine import MapReduceEngine
 
@@ -367,7 +366,64 @@ def test_engine_oracle_exact_across_sort_modes(mode):
     assert got == sorted(py_wordcount(lines, 12).items())
 
 
-@pytest.mark.parametrize("mode", ["hash1", "radix", "bitonic"])
+REMOVED_SORT_MODES = ["hashp", "hash1", "radix", "bitonic"]  # PR 44
+
+
+@pytest.mark.parametrize("name", REMOVED_SORT_MODES)
+def test_removed_sort_modes_are_refused(name):
+    """A removed mode's name is refused as any unknown mode is: no alias,
+    no shim — the ValueError names the modes there are."""
+    with pytest.raises(ValueError) as e:
+        EngineConfig(sort_mode=name)
+    assert repr(name) in str(e.value)
+    assert all(repr(m) in str(e.value) for m in SORT_MODES)
+    assert len(SORT_MODES) == 7 and name not in SORT_MODES
+
+
+def test_removed_sort_mode_is_refused_by_the_cli(tmp_path):
+    """``python -m locust_tpu FILE --sort-mode radix``: argparse's own
+    exit 2, naming the choices, before anything runs."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    f = tmp_path / "in.txt"
+    f.write_bytes(b"to be or not to be\n")
+    r = subprocess.run(
+        [sys.executable, "-m", "locust_tpu", str(f), "--sort-mode", "radix"],
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": repo},
+        capture_output=True, text=True, timeout=120,
+    )
+    assert r.returncode == 2 and r.stdout == ""
+    assert "invalid choice: 'radix'" in r.stderr
+    assert "choose from " + ", ".join(SORT_MODES) in r.stderr
+
+
+def test_cli_choices_are_sort_modes():
+    """The two CLIs offer exactly ``SORT_MODES``, and every backend's
+    default is one of them."""
+    from locust_tpu import cli, cli_apps
+    from locust_tpu.config import default_sort_mode
+
+    def choices(parser):
+        (action,) = [
+            a for a in parser._actions if "--sort-mode" in a.option_strings
+        ]
+        return tuple(action.choices)
+
+    assert choices(cli.build_parser()) == SORT_MODES
+    for cmd in cli_apps.SUBCOMMANDS:
+        parser = cli_apps.build_parser(cmd)
+        if cmd == "sort":  # the record sort has one spelling, and no flag
+            assert "--sort-mode" not in parser._option_string_actions
+        else:
+            assert choices(parser) == SORT_MODES
+    for backend in ("tpu", "cpu", "gpu"):
+        assert default_sort_mode(backend) in SORT_MODES
+
+
+@pytest.mark.parametrize("mode", ["hashp1", "hashp2", "hash"])
 def test_single_key_sort_modes_group_equal_keys(mode):
     from locust_tpu.core import bytes_ops
     from locust_tpu.core.kv import KVBatch

@@ -125,7 +125,9 @@ def test_canonical_plan_fingerprints_are_pinned():
     assert records_sort_plan().fingerprint() == "9b8ff3d58901"
     assert records_sort_plan(100, 10).fingerprint() == "9b8ff3d58901"
     assert records_sort_plan(100, 2).fingerprint() == "4cbc4a7313cc"
-    assert EngineConfig().fingerprint() == "fe2d587b6cae"
+    # PR 44: three one-valued fields left ``repr(cfg)``, so every
+    # configuration's fingerprint moved once (CHANGES.md).
+    assert EngineConfig().fingerprint() == "49eee6bb9e67"
 
 
 # --------------------------------------------------------- the sort kind
@@ -662,7 +664,7 @@ def test_ladder_cli_accepts_sort_mode_and_trace_out(tmp_path):
         [sys.executable, "-m", "locust_tpu", "tfidf", str(corpus),
          "--backend", "cpu", "--lines-per-doc", "2",
          "--block-lines", "8", "--line-width", "64", "--key-width", "16",
-         "--emits-per-line", "8", "--sort-mode", "hash1",
+         "--emits-per-line", "8", "--sort-mode", "hashp1",
          "--trace-out", str(trace)],
         env=env, capture_output=True, timeout=240,
     )

@@ -381,5 +381,6 @@ def test_engine_records_the_combines_scatters(combine, scatters):
         obs.disable()
 
 
-def test_config_fingerprint_is_the_parents():
-    assert EngineConfig().fingerprint() == "fe2d587b6cae"
+def test_config_fingerprint_is_pinned():
+    """No field came or went unnoticed (PR 44 took three: CHANGES.md)."""
+    assert EngineConfig().fingerprint() == "49eee6bb9e67"

@@ -308,6 +308,7 @@ def test_parse_spec_rejects_with_structured_codes():
         ({"corpus_b64": "!!!"}, "bad_spec"),           # bad base64
         ({"config": {"bogus_knob": 1}, **good}, "bad_spec"),
         ({"config": {"sort_mode": "nope"}, **good}, "bad_spec"),
+        ({"config": {"sort_mode": "radix"}, **good}, "bad_spec"),  # removed, PR 44
         ({"weight": -1, **good}, "bad_spec"),
     ]:
         with pytest.raises(ValueError) as e:

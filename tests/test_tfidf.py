@@ -33,7 +33,7 @@ LINES = [
 DOCS = np.array([0, 0, 1, 1, 2], dtype=np.int32)
 
 
-@pytest.mark.parametrize("mode", ["hash", "hashp2", "bitonic", "lex", "hasht"])
+@pytest.mark.parametrize("mode", ["hash", "hashp2", "hashp1", "lex", "hasht"])
 def test_term_doc_counts_oracle_exact(mode):
     cfg = EngineConfig(block_lines=2, line_width=64, emits_per_line=8,
                        sort_mode=mode)
