@@ -28,7 +28,21 @@ the rank with NINE significant digits as ``d.dddddddde-XX`` — what a
 float32 needs to come back bit for bit; eight decimals held two or three
 digits of a rank of 1e-6 (``plan.compile.rank_row``).  For index/tfidf
 the doc id of line i is ``i // lines_per_doc`` — line-sharded documents,
-the same convention as the library tests.  ``sort`` is TeraSort: IN holds fixed-width binary
+the same convention as the library tests.  ``index`` prints one
+``word<TAB>d1,d2,...<LF>`` line a word, the words in byte order, a word's
+documents distinct and ascending — PUMA's Inverted-Index.  On one device
+it is a COLLECT (``apps.inverted_index.build_index``, PR 45): every
+block's distinct (word, doc) pairs appended to a pair store resident on
+the device that grows with the job (no capacity to guess, no size one
+device holds an error), the store ordered ONCE, the index brought back as
+arrays (words, offsets, postings) and printed from them in numpy
+(``bytes_ops.render_postings``; ``--limit N``: the first N words).  What
+the fixed widths drop or cut is said on stderr in the WordCount CLI's own
+words — ``[locust] index: words= pairs= docs= emit_overflow=
+key_overflow= line_overflow= truncated=False ...``, every count zero or
+not, and a ``[locust] WARN`` line where one is not — so a driver can hold
+"nothing dropped"; plain reference ``locust_tpu/index_reference.py``.
+``sort`` is TeraSort: IN holds fixed-width binary
 records (gensort's: 100 bytes, the first 10 the key), OUT gets every one
 of them ordered by key as unsigned bytes, equal keys in input order; a
 size that is no whole number of records, or an empty IN, is an error and
@@ -36,7 +50,8 @@ exit status 2, and no OUT is written.  An OUT that is already there is
 written over in place and cut to size at the end (``serde.write_records``).
 
 ``--mesh`` selects the sharded engines (ShardedPageRank — rank state
-O(nodes/n_dev) per device —, DistributedInvertedIndex, and for ``sort``
+O(nodes/n_dev) per device —, DistributedInvertedIndex — a fixed pair table
+a shard, the index a dict printed a row at a time —, and for ``sort``
 the mesh record sort: IN's blocks dealt round the devices, every record
 through one all-to-all to the device that owns its key range, OUT
 written shard after shard, the same bytes, one ``shard d: n records``
@@ -210,13 +225,12 @@ def run_pagerank(args) -> int:
     return 0
 
 
-def _load_docs(args):
+def _docs_config(args):
     import jax
 
     from locust_tpu.config import EngineConfig, default_sort_mode
-    from locust_tpu.io import loader
 
-    cfg = EngineConfig(
+    return EngineConfig(
         block_lines=args.block_lines,
         line_width=args.line_width,
         key_width=args.key_width,
@@ -226,22 +240,90 @@ def _load_docs(args):
         # --sort-mode overrides it, same as the WordCount CLI.
         sort_mode=args.sort_mode or default_sort_mode(jax.default_backend()),
     )
-    rows = loader.load_rows(args.filename, cfg.line_width)
-    return cfg, rows
+
+
+def _load_docs(args):
+    from locust_tpu.io import loader
+
+    cfg = _docs_config(args)
+    return cfg, loader.load_rows(args.filename, cfg.line_width)
+
+
+def _lines_cut(path: str, rows: np.ndarray) -> int:
+    """Lines of ``path`` longer than the rows' width, which the loader
+    cut.  Only a line that fills its row to the last byte can have been
+    longer, and a file whose lines fit has none: the file is read again,
+    and its line lengths taken, only where there is such a row."""
+    width = rows.shape[1]
+    full = np.flatnonzero(rows[:, -1])
+    if not full.size:
+        return 0
+    with open(path, "rb") as f:
+        data = np.frombuffer(f.read(), np.uint8)
+    ends = np.flatnonzero(data == ord("\n"))
+    if data[-1] != ord("\n"):
+        ends = np.append(ends, data.size)
+    starts = np.concatenate([[0], ends[:-1] + 1])
+    length = ends - starts
+    # a CR before the LF is not content (the loader strips it)
+    length -= (length > 0) & (data[np.maximum(ends - 1, 0)] == ord("\r"))
+    return int(np.count_nonzero(length[full] > width))
 
 
 def run_index(args) -> int:
-    cfg, rows = _load_docs(args)
+    from locust_tpu.io import loader
     from locust_tpu.plan import index_plan
-    from locust_tpu.plan.compile import compile_plan
+    from locust_tpu.plan.compile import compile_plan, render_postings
 
+    t0 = time.perf_counter()
+    cfg = _docs_config(args)
     # Plan-compiled: the source node derives the line->doc sharding
     # (``i // lines_per_doc``, the module contract above) and the
-    # compiler lowers onto build_inverted_index[_mesh].
-    index = compile_plan(
-        index_plan(args.lines_per_doc), cfg, mesh=args.mesh
-    ).run(rows, render=False).value
-    return _print_rendered("postings", index, args.limit)
+    # compiler lowers onto apps.inverted_index (build_index, a collect
+    # whose result is arrays; DistributedInvertedIndex under --mesh).
+    plan = compile_plan(index_plan(args.lines_per_doc), cfg, mesh=args.mesh)
+    if args.trace_out:  # main's entry to the first cli.load, once it is over
+        obs.span_at("cli.setup", args.entered, time.time())
+    with obs.span("cli.load"):
+        with obs.span("index.read") as sp:
+            rows = loader.load_rows(args.filename, cfg.line_width)
+            sp.set(bytes=os.path.getsize(args.filename), lines=rows.shape[0])
+        cut_lines = _lines_cut(args.filename, rows)
+        print(f"[locust] {rows.shape[0]} lines loaded", file=sys.stderr)
+    with obs.span("cli.run"):
+        res = plan.run(rows, render=False, finalize=False)
+    index = res.value
+    if isinstance(index, dict):  # --mesh: the shards' union, a dict
+        return _print_rendered("postings", index, args.limit)
+    # What the job dropped or cut, in the WordCount CLI's own words
+    # (emit_overflow=, truncated=): a driver holds "nothing dropped" by
+    # this line, so every count is on it, zero or not.
+    print(
+        f"[locust] index: words={len(index)} pairs={index.postings.shape[0]} "
+        f"docs={-(-rows.shape[0] // args.lines_per_doc)} "
+        f"emit_overflow={index.dropped_tokens} key_overflow={index.cut_keys} "
+        f"line_overflow={cut_lines} truncated=False "
+        f"store_rows={index.store_rows} grows={index.grows} "
+        f"total={(time.perf_counter() - t0) * 1e3:.1f} ms",
+        file=sys.stderr,
+    )
+    if index.dropped_tokens or index.cut_keys or cut_lines:
+        print(
+            "[locust] WARN: the index is NOT the file's: "
+            f"{index.dropped_tokens} token(s) past --emits-per-line "
+            f"{cfg.emits_per_line} have no posting, {index.cut_keys} key(s) "
+            f"past --key-width {cfg.key_width} are indexed by their head, "
+            f"{cut_lines} line(s) past --line-width {cfg.line_width} were cut",
+            file=sys.stderr,
+        )
+    with obs.span("cli.output"):
+        with obs.span("index.render", words=len(index)) as sp:
+            out = render_postings(index, args.limit)
+            sp.set(bytes=len(out))
+        with obs.span("index.write", bytes=len(out)):
+            sys.stdout.buffer.write(out)
+            sys.stdout.buffer.flush()
+    return 0
 
 
 def _print_rendered(op: str, value, limit) -> int:

@@ -194,6 +194,70 @@ def _write_digits(out: np.ndarray, cols, v: np.ndarray) -> None:
         v = q
 
 
+def render_postings(words: np.ndarray, offsets: np.ndarray,
+                    postings: np.ndarray) -> bytes | None:
+    """Host-side: an inverted index as arrays (CSR) -> the
+    ``word<TAB>d1,d2,...<LF>`` bytes the CLI prints, a line a word in the
+    order given, in numpy, with no Python object a posting; byte-equal to
+    ``plan.compile.iter_rendered("postings", ...)`` over the same index as
+    a dict.  None where the arrays hold what this layout cannot spell (a
+    negative doc id, a word with no posting): the caller then joins a row
+    at a time.
+
+    Two matrices, each flattened and rid of its NULs by one boolean take:
+    a posting's cell is its decimal digits, leading zeros NUL, and its
+    separator (a comma, LF after a word's last); a word's is its NUL-padded
+    key and TAB.  The two streams are then laid into one buffer under a
+    mask that alternates a word's key bytes and its postings' bytes.
+
+    Args:
+      words: uint8 ``[n_words, width]`` NUL-padded keys, none with a NUL
+        inside, in print order.
+      offsets: ``[n_words + 1]``, word ``w``'s postings are
+        ``postings[offsets[w]:offsets[w + 1]]``.
+      postings: int32 ``[n_pairs]`` doc ids.
+    """
+    n_words, width = words.shape
+    if n_words == 0:
+        return b""
+    offsets = np.asarray(offsets, dtype=np.int64)
+    n = postings.shape[0]
+    if int(np.diff(offsets).min()) < 1 or int(postings.min()) < 0:
+        return None
+    v = postings.astype(np.uint32)
+    digits = len(str(int(v.max())))
+    cells = np.empty((n, digits + 1), dtype=np.uint8)
+    cell_len = np.full(n, 2, dtype=np.uint8)  # the last digit and the separator
+    ten, zero = np.uint32(10), np.uint32(ord("0"))
+    q = v
+    for place in range(digits):
+        rest = q // ten
+        digit = q - rest * ten + zero
+        if place:  # a leading zero is a NUL, dropped below
+            shown = v >= np.uint32(10 ** place)
+            digit *= shown
+            cell_len += shown
+        cells[:, digits - 1 - place] = digit
+        q = rest
+    cells[:, digits] = ord(",")
+    cells[offsets[1:] - 1, digits] = ord("\n")
+    flat = cells.ravel()
+    posting_bytes = flat[flat != 0]
+    keys = np.empty((n_words, width + 1), dtype=np.uint8)
+    keys[:, :width] = words
+    keys[:, width] = ord("\t")
+    flat = keys.ravel()
+    key_bytes = flat[flat != 0]
+    lens = np.empty(2 * n_words, dtype=np.int64)
+    lens[0::2] = np.count_nonzero(words, axis=1) + 1
+    lens[1::2] = np.add.reduceat(cell_len, offsets[:-1], dtype=np.int64)
+    is_key = np.repeat(np.tile(np.array([True, False]), n_words), lens)
+    out = np.empty(is_key.size, dtype=np.uint8)
+    out[is_key] = key_bytes
+    out[~is_key] = posting_bytes
+    return out.tobytes()
+
+
 _RANK_CHARS = 14  # a rank as plan.compile.rank_row spells it: d.dddddddde-XX
 # 10**k as the nearest double for every two-digit decimal exponent e
 # (k = 8 - e; a float32's e lies in -45 .. 38).

@@ -63,6 +63,14 @@ NAMES = {
     "pagerank.h2d": "span",         # pagerank: src and dst put on the device and waited for (arg bytes)
     "pagerank.iterate": "span",     # pagerank: the iterate program dispatched and waited for (its child engine.sync what=iterate); under --mesh ShardedPageRank's whole run (args nodes, edges, iters)
     "pagerank.d2h": "span",         # pagerank: the rank vector brought down (arg bytes)
+    "index.read": "span",           # index CLI: the text read whole and padded to rows (loader.load_rows; args bytes, lines)
+    "index.h2d": "span",            # index: a block's lines and doc ids handed up (device_put returns at once; arg bytes)
+    "index.map": "span",            # index: a GROUP of blocks launched — tokenise, in-block dedup, the survivors appended to the pair store (arg blocks)
+    "index.grow": "span",           # index: the pair store grown a step ahead of a group (args from_rows, to_rows, pairs)
+    "index.collect": "span",        # index: the store ordered ONCE, deduplicated across blocks and cut into CSR; its child engine.sync what=index.collect is the job's wait for the device (arg rows)
+    "index.d2h": "span",            # index: postings, word keys and offsets brought down (arg bytes)
+    "index.render": "span",         # index CLI: the postings' word<TAB>d,d,...<LF> lines made into one buffer from arrays (bytes_ops.render_postings; args words, bytes)
+    "index.write": "span",          # index CLI: that buffer written and flushed (arg bytes)
     "sort.mesh.split": "span",      # mesh record sort: sample, gather, splitters and their one sync (args samples, splitters)
     "sort.mesh.exchange": "span",   # mesh record sort: bucket, bin, all-to-all, the bin counts read back (args bin_rows, attempt, worst_bin)
     "sort.mesh.retry": "span",      # mesh record sort: parent of an exchange redone with larger bins (args from_bin_rows, to_bin_rows, worst_bin)
@@ -110,6 +118,11 @@ NAMES = {
     "pagerank.nodes": "counter",    # pagerank: dense node slots (largest id + 1, or --num-nodes)
     "pagerank.iterations": "counter",  # pagerank: rounds run (benchmarks' closed_loop_cli_edges holds a traced job to the configuration's count by it)
     "pagerank.parse.native": "counter",  # pagerank: edge lists parsed by the native pass (0 where the library did not load or the file was not clean)
+    "index.pairs": "counter",       # index: distinct (word, doc) pairs = postings out
+    "index.words": "counter",       # index: distinct words
+    "index.docs": "counter",        # index: runs of equal doc ids over the lines (the documents, for ids that follow the lines)
+    "index.dropped_tokens": "counter",  # index: tokens past emits_per_line, whose postings are missing
+    "index.grows": "counter",       # index: growth steps the pair store took
     "sort.records": "counter",      # record sort: records staged on the device
     "sort.bytes_out": "counter",    # record sort: bytes written to OUT
     "sort.mesh.retries": "counter",          # mesh record sort: exchanges redone because a bin overflowed

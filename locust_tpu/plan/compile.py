@@ -264,16 +264,20 @@ class CompiledPlan:
         carries the device table) — for the CLI, which prints its table
         from ordered rows (``RunResult.to_host_rows``) and whose staged
         map node only dumps the raw table: the pair list would be paid
-        and discarded.  Only a plan whose sink consumes the wordcount
-        fold directly may skip it.
+        and discarded.  For the single-device index fold it leaves
+        ``value`` the index AS ARRAYS (``apps.inverted_index.Postings``)
+        and builds no dict — an ``int`` a posting that the CLI, which
+        prints from the arrays, would pay and discard.  Only a plan whose
+        sink consumes one of those two folds directly may skip it.
         """
         stage = self._stages[self._stages[self._root][2]]
         if not finalize and not (
-            stage[0] == "fold" and stage[1] == "wordcount"
+            stage[0] == "fold" and stage[1] in ("wordcount", "index")
         ):
             raise PlanError(
                 "finalize=False is only meaningful for a sink fed by "
-                "the wordcount fold (other stages need the decoded value)"
+                "the wordcount or the index fold (other stages need the "
+                "decoded value)"
             )
         if not finalize and render:
             # There is no decoded value to render — a None would reach
@@ -766,14 +770,15 @@ class _RunCtx:
                 index = build_inverted_index_mesh(
                     rows, ids, make_mesh(), cfg
                 )
-            else:
-                from locust_tpu.apps.inverted_index import (
-                    build_inverted_index,
-                )
+                self._acct[sid] = (len(index), False, 0)
+                return index
+            from locust_tpu.apps.inverted_index import build_index
 
-                index = build_inverted_index(rows, ids, cfg)
-            self._acct[sid] = (len(index), False, 0)
-            return index
+            # The collect's result is arrays (CSR); the dict spelling is
+            # built from them, and only for a caller that asks for it.
+            index = build_index(rows, ids, cfg)
+            self._acct[sid] = (len(index), False, index.dropped_tokens)
+            return index.to_dict() if self.finalize else index
         raise PlanError(  # pragma: no cover - _FOLDS is closed
             f"unknown fold {fold!r} (source {src_node.id!r})"
         )
@@ -927,7 +932,25 @@ def _render(op: str, value) -> bytes:
     the byte-identity contract serve plan results ride."""
     if op == "ranks":
         return render_ranks(value)
+    if op == "postings" and not isinstance(value, dict):
+        return render_postings(value)
     return b"".join(iter_rendered(op, value))
+
+
+def render_postings(index, limit: int | None = None) -> bytes:
+    """An index AS ARRAYS (``apps.inverted_index.Postings``) as the
+    ``postings`` sink spells it, ``word<TAB>d1,d2,...<LF>`` a word in byte
+    order (its first ``limit`` words): rendered from the arrays
+    (``bytes_ops.render_postings``), a row at a time from the dict only
+    for an index that holds what the array layout cannot spell."""
+    from locust_tpu.core import bytes_ops
+
+    if limit is not None:
+        index = index.head(limit)
+    out = bytes_ops.render_postings(index.words, index.offsets, index.postings)
+    if out is None:
+        out = b"".join(iter_rendered("postings", index.to_dict()))
+    return out
 
 
 def render_ranks(ranks) -> bytes:

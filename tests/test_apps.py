@@ -102,6 +102,25 @@ def test_inverted_index_mismatched_doc_ids_raises():
         build_inverted_index([b"a", b"b"], np.arange(3), cfg)
 
 
+@pytest.mark.parametrize("block_lines", [1, 2, 8])
+def test_build_index_is_the_index_as_arrays(block_lines):
+    """CSR: words in byte order, offsets into ascending distinct doc ids —
+    whatever the block size cuts the documents into."""
+    from locust_tpu.apps.inverted_index import build_index
+    from locust_tpu.core import bytes_ops
+
+    cfg = EngineConfig(block_lines=block_lines, line_width=64, emits_per_line=8)
+    index = build_index(list(DOCS.values()), np.asarray(list(DOCS.keys()), np.int32), cfg)
+    want = py_inverted_index(DOCS)
+    words = bytes_ops.rows_to_strings(index.words)
+    assert words == sorted(want) and len(index) == len(want)
+    assert index.offsets.tolist() == np.concatenate(
+        [[0], np.cumsum([len(want[w]) for w in words])]).tolist()
+    assert index.postings.tolist() == [d for w in words for d in want[w]]
+    assert index.to_dict() == want and index.head(2).to_dict() == {w: want[w] for w in words[:2]}
+    assert (index.dropped_tokens, index.cut_keys) == (0, 0)
+
+
 # ------------------------------------------------------- distributed index
 
 def test_distributed_inverted_index_matches_oracle():

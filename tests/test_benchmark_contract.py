@@ -78,7 +78,29 @@ def _lowered_text(eng: MapReduceEngine) -> tuple[str, ...]:
     lowered += _lowered_record_sort(eng)
     lowered += _toy_mesh_record_sort()[1]
     lowered += (_lowered_pagerank(),)
+    lowered += _lowered_index(cfg)
     return tuple(low.as_text() for low in lowered)
+
+
+def _lowered_index(cfg: EngineConfig) -> tuple:
+    """The ``index`` command's five programs at toy shapes: a block, a
+    store of four blocks' emits."""
+    from locust_tpu.apps.inverted_index import _build_index_programs
+
+    progs = engine._programs_for(("index", cfg), lambda: _build_index_programs(cfg))
+    lines = jax.ShapeDtypeStruct((cfg.block_lines, cfg.line_width), jnp.uint8)
+    ids = jax.ShapeDtypeStruct((cfg.block_lines,), jnp.int32)
+    head, counts = jax.eval_shape(progs.block, lines, ids)
+    fill = jax.ShapeDtypeStruct((), jnp.int32)
+    store = jax.eval_shape(lambda: KVBatch.empty(4 * cfg.emits_per_block, cfg.key_lanes))
+    collected = jax.eval_shape(progs.collect, store, fill)
+    return (
+        progs.block.lower(lines, ids),
+        progs.append.lower(store, counts, head, counts),
+        progs.grow.lower(store, rows=8 * cfg.emits_per_block),
+        progs.collect.lower(store, fill),
+        progs.cut.lower(collected[0], collected[2], collected[3], rows=cfg.emits_per_block),
+    )
 
 
 def _lowered_pagerank():
@@ -143,7 +165,7 @@ def _toy_mesh_record_sort():
 def program_names() -> dict[str, set[str]]:
     """Module names of the programs the cells run (the default path's
     four, the mesh's step, the record sort's four, the mesh record
-    sort's six and pagerank's one), as the
+    sort's six, pagerank's one and the index's five), as the
     device trace's ``XLA Modules`` line will show them: of a
     configuration's ``first`` engine, which builds them, and of a later
     one, which takes the process's (``shared``, engine._programs_for) —
@@ -169,6 +191,8 @@ def cli_stderr(tmp_path_factory) -> str:
         assert table.count(b"\n") == 15  # ten numbers and five words
         said.append(err)
     said.append(_mesh_sort_stderr(path.parent))
+    _, err, _ = chip_smoke.run_cli(["index", str(path), "--block-lines", "8"])
+    said.append(err)
     return "\n".join(said)
 
 
@@ -221,7 +245,8 @@ def _metric_cases():
         with open(path) as f:
             spec = json.load(f)
         by_program = spec["reader"] in (
-            "xla_module", "roofline", "roofline_job", "roofline_pagerank_job") or (
+            "xla_module", "roofline", "roofline_job", "roofline_pagerank_job",
+            "roofline_index_job") or (
             spec["reader"] == "roofline_device_job" and "programs" in spec)
         for which in ("first", "shared") if by_program else (None,):
             name = os.path.basename(path)
@@ -253,7 +278,7 @@ def test_layer_metric_reads_a_name_the_program_still_has(path, which, request):
             _assert_patterns_match(spec["programs"], fixture("program_names")[which], "programs")
         else:
             _assert_patterns_match(spec["ops"], fixture("mesh_record_op_names"), "ops")
-    elif reader in ("roofline_job", "roofline_pagerank_job"):
+    elif reader in ("roofline_job", "roofline_pagerank_job", "roofline_index_job"):
         _assert_patterns_match(spec["programs"], fixture("program_names")[which], "programs")
     elif reader == "roofline":
         names = fixture("program_names")[which]
@@ -266,7 +291,7 @@ def test_layer_metric_reads_a_name_the_program_still_has(path, which, request):
         )
     elif reader == "counter":
         assert spec["name"] in METRICS, f"{spec['name']!r} is not a registered metric"
-    elif reader == "stderr_regex":
+    elif reader in ("stderr_regex", "stderr_number"):
         err = fixture("cli_stderr")
         assert re.search(spec["pattern"], err), (
             f"/{spec['pattern']}/ matches nothing cli.main printed:\n{err}"

@@ -239,6 +239,87 @@ def test_pagerank_round_is_one_gather_fusion_on_the_chip(pagerank_text_and_stats
     assert fusions[0] + " = " not in text[text.index("\nENTRY "):]   # in the scan's body
 
 
+INDEX_STORE_ROWS = 10_485_760  # the capacity indexzipf.batch's store ends at
+
+
+def _index_programs_and_shapes(one_chip):
+    """``indexzipf.batch``'s programs and the shapes they take at the
+    capacity the cell's pair store ends at."""
+    from locust_tpu.apps.inverted_index import _build_index_programs
+    from locust_tpu.core.kv import KVBatch
+
+    cfg = EngineConfig(block_lines=4096, map_impl="einsum", **WIDTHS)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def batch(rows):
+        return KVBatch(shape((rows, cfg.key_lanes), jnp.uint32),
+                       shape((rows,), jnp.int32), shape((rows,), jnp.bool_))
+
+    return (_build_index_programs(cfg), cfg, shape, batch(INDEX_STORE_ROWS),
+            batch(cfg.emits_per_block))
+
+
+def test_index_store_programs_compile_at_the_cells_shape(one_chip):
+    """The pair store's own programs — a block's head appended at the fill,
+    a growth step, the cut that gathers a million words' keys and offsets —
+    at the cell's capacity: no sort in them, a second or two each, and
+    together with the store well inside a chip."""
+    progs, cfg, shape, store, head = _index_programs_and_shapes(one_chip)
+    counts, n = shape((3,), jnp.int32), INDEX_STORE_ROWS
+    for lowered in (
+        progs.append.lower(store, counts, head, counts),
+        progs.grow.lower(jax.tree.map(
+            lambda x: shape((n // 2, *x.shape[1:]), x.dtype), store), rows=n),
+        progs.cut.lower(shape((n, cfg.key_lanes), jnp.uint32), shape((n,), jnp.int32),
+                        shape((n,), jnp.int32), rows=1 << 20),
+    ):
+        compiled = lowered.compile()
+        stats = compiled.memory_analysis()
+        assert " sort(" not in compiled.as_text()
+        assert stats.argument_size_in_bytes + stats.temp_size_in_bytes < 2 << 30
+
+
+def test_index_collect_orders_the_store_with_one_sort_in_a_loop():
+    """What the chip's trace shows as five store-sized sorts is ONE in the
+    program: the collect holds three ``sort`` equations whatever the block
+    count — the radix pass's stable three-operand one inside its loop, and
+    the two narrow ones that move postings and word starts to the front —
+    because the chip's compiler takes time with the square of a sort's
+    operands (PERF.md section 6, PR 45: ten operands did not compile in a
+    quarter of an hour)."""
+    from locust_tpu.apps.inverted_index import RADIX_KEYS, _build_index_programs
+    from locust_tpu.core.kv import KVBatch
+
+    cfg = EngineConfig(block_lines=8, line_width=32, key_width=32, emits_per_line=4)
+    jaxpr = jax.make_jaxpr(_build_index_programs(cfg).collect)(
+        KVBatch.empty(256, cfg.key_lanes), jnp.int32(7)).jaxpr
+    sorts = _eqns(jaxpr, "sort", [])
+    assert sorted(len(e.invars) for e in sorts) == [1, 2, RADIX_KEYS + 1]
+    loops = _eqns(jaxpr, "scan", [])  # a fori_loop of a known length
+    assert len(loops) == 1 and loops[0].params["length"] == (cfg.key_lanes + 2) // RADIX_KEYS
+    in_loop = _eqns(loops[0].params["jaxpr"].jaxpr, "sort", [])
+    assert len(in_loop) == 1 and len(in_loop[0].invars) == RADIX_KEYS + 1
+    assert in_loop[0].params["is_stable"] and in_loop[0].params["num_keys"] == RADIX_KEYS
+
+
+@pytest.mark.slow
+def test_index_block_and_collect_compile_at_the_cells_shape(one_chip):
+    """The two programs that hold sorts, at the cell's shapes: the block
+    program (tokenise, the five-operand in-block sort, the compaction) in
+    about 200 s and the collect in about 150 s on the sandbox's CPU — outside
+    tier-1 like every whole-program compile.  The collect with its operands
+    and temporaries stays under a fifth of a chip."""
+    progs, cfg, shape, store, _ = _index_programs_and_shapes(one_chip)
+    progs.block.lower(shape((cfg.block_lines, cfg.line_width), jnp.uint8),
+                      shape((cfg.block_lines,), jnp.int32)).compile()
+    compiled = progs.collect.lower(store, shape((), jnp.int32)).compile()
+    stats = compiled.memory_analysis()
+    assert (stats.argument_size_in_bytes + stats.output_size_in_bytes
+            + stats.temp_size_in_bytes) < 3 << 30
+
+
 def test_check_kernels_match_chip_smoke():
     """Every kernel chip_smoke.py runs on the chip has a compile case
     above, by name."""

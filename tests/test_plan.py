@@ -526,6 +526,33 @@ def test_index_plan_byte_identical_single_and_mesh():
     assert mpres.value == midx
 
 
+@pytest.mark.parametrize("mesh", [False, True])
+def test_index_plan_unfinalized_is_arrays_on_one_device_and_the_dict_on_the_mesh(mesh):
+    """``finalize=False`` (the CLI's way): the single-device index fold
+    hands its CSR arrays on and builds no dict; the mesh's value is the
+    shards' union, a dict either way.  Both render to the same bytes."""
+    from locust_tpu.apps.inverted_index import Postings
+    from locust_tpu.plan.compile import _render
+
+    rows = _rows()
+    plan = compile_plan(index_plan(2), CFG, mesh=mesh)
+    want = plan.run(rows)
+    raw = plan.run(rows, render=False, finalize=False)
+    assert raw.output is None and raw.distinct == want.distinct == len(want.value)
+    assert isinstance(raw.value, dict if mesh else Postings)
+    assert _render("postings", raw.value) == want.output
+    if not mesh:
+        assert raw.value.to_dict() == want.value
+        assert raw.value.offsets[-1] == raw.value.postings.shape[0]
+
+
+def test_unfinalized_runs_are_for_the_wordcount_and_index_folds_alone():
+    with pytest.raises(PlanError, match="wordcount or the index fold"):
+        compile_plan(tfidf_plan(2), CFG).run(_rows(), render=False, finalize=False)
+    with pytest.raises(PlanError, match="requires render=False"):
+        compile_plan(index_plan(2), CFG).run(_rows(), finalize=False)
+
+
 def test_pagerank_plan_byte_identical_single_and_mesh():
     from locust_tpu.apps.pagerank import ShardedPageRank, pagerank
     from locust_tpu.parallel.mesh import make_mesh
