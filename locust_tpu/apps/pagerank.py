@@ -9,8 +9,12 @@ TPU-native formulation: node ids ARE the keys, so the shuffle degenerates to
 a dense ``segment_sum`` into a ``[num_nodes]`` vector — no byte keys, no
 sort.  What is a function of the NODE is computed over the nodes: a round
 multiplies ``ranks * inv_deg`` once (the emit every out-edge of a node
-carries) and the edges take it in ONE gather — a gathered word costs a v5e
-some 7 ns, the product over the nodes nothing beside it (PERF.md §6, PR 42).
+carries) and the edges take it in ONE gather (PERF.md §6, PR 42).  A v5e
+pays a gather by the index and not by the byte — a gathered word costs it
+6.6 ns, a gathered 128-lane row 2.2 — so the edges gather ROWS of the share
+laid out ``[N / 128, 128]`` and select a lane, a fixed-size chunk of edges at
+a time (``_gather_chunks`` has the account; PERF.md §6, PR 46): the same
+bits as ``share[src]`` on every backend, one spelling everywhere.
 Iterations run under ``lax.scan`` (static trip count) or a
 ``while_loop`` on the L1 residual.  Distributed: edges shard across the
 mesh, each device computes a partial dense contribution vector, and the
@@ -31,16 +35,80 @@ from jax.sharding import PartitionSpec as P
 from locust_tpu.parallel.mesh import DATA_AXIS
 
 
-def _contributions(src, dst, ranks, inv_deg, num_nodes):
-    """Dense map+reduce of one iteration: sum_d rank[s]/deg[s].
+LANES = 128
+# Edges gathered a step of the round's inner loop.  Chosen on the chip
+# (PERF.md section 6, PR 46): at 65,536 the chip's compiler keeps a chunk's
+# ``[CHUNK, 128]`` rows out of HBM and the program's temporaries do not grow
+# with the edges.
+CHUNK = 65_536
 
-    The share is multiplied over the NODES and the edges gather it once:
-    the same two float32 operands an edge as ``ranks[src] * inv_deg[src]``,
-    so the same bits (tests/test_pagerank_cli.py holds both), from one load
-    an edge.
+
+def _edge_chunks(src):
+    """int32 ids ``[E]`` as the ``[chunks, chunk]`` unsigned ids that
+    ``_gather_chunks`` walks: at most ``CHUNK`` a chunk (a graph smaller
+    than a chunk is one short chunk), padded with id 0 to a whole number of
+    chunks.  A caller that loops pads ONCE, before its loop."""
+    edges = src.shape[0]
+    chunk = max(1, min(CHUNK, edges))
+    return jnp.pad(src.astype(jnp.uint32), (0, -edges % chunk)).reshape(-1, chunk)
+
+
+def _gather_chunks(share, chunks):
+    """``share[ids]`` for float32 ``share`` ``[N]`` and every id of
+    ``chunks`` (``_edge_chunks``), flat, the padding's values included —
+    spelled as a gather of 128-lane ROWS and a lane select.
+
+    A v5e pays a gather by the index and not by the byte: a word an index
+    costs it 6.6 ns, a 128-lane row 2.2 (PERF.md section 6, PRs 42 and 46).
+    So the share is laid out as a ``[ceil(N / 128), 128]`` table (padded
+    with zeros), an edge takes row ``id >> 7`` whole and keeps lane
+    ``id & 127``.  The select is ``where`` + ``sum`` over the lanes: one
+    value and 127 zeros sum to that value's bits on every backend (a share
+    is never ``-0.0``), where a product with a one-hot would let an ``inf``
+    or a ``nan`` of a NEIGHBOUR in the row through, and a dot on the MXU is
+    not float32 unless asked.
+
+    The rows of all edges at once would be ``E x 512`` bytes (2.6 GB at 5 M
+    edges), so ``lax.map`` walks the edges a chunk at a time and only one
+    chunk's rows exist.
+
+    The ids are read as unsigned and the row index clamps, so nothing
+    wraps: a negative id or one at or past ``N`` reads the table's zero
+    padding or a lane of its last row, not ``share[-1]`` as numpy's
+    indexing has it — callers pass valid ids.  A CPU pays some 0.15 us an
+    edge for this spelling where ``share[ids]`` costs it 0.004: accepted,
+    so that the tests run the code the chip runs.
     """
-    share = ranks * inv_deg
-    return jax.ops.segment_sum(share[src], dst, num_segments=num_nodes)
+    nodes = share.shape[0]
+    rows = -(-nodes // LANES)
+    table = jnp.pad(share, (0, rows * LANES - nodes)).reshape(rows, LANES)
+    lane = jnp.arange(LANES, dtype=jnp.uint32)
+
+    def pick(ids):
+        picked = jnp.where(lane == (ids & 127)[:, None], table[ids >> 7], 0.0)
+        return jnp.sum(picked, axis=1)
+
+    return jax.lax.map(pick, chunks).reshape(-1)
+
+
+def _gather_share(share, src):
+    """``share[src]`` for valid int32 ids ``src`` ``[E]``, gathered as rows
+    (``_gather_chunks``): the same bits in the same order."""
+    return _gather_chunks(share, _edge_chunks(src))[: src.shape[0]]
+
+
+def _contributions(chunks, dst, ranks, inv_deg, num_nodes):
+    """Dense map+reduce of one iteration: sum_d rank[s]/deg[s], the edges'
+    sources as ``_edge_chunks`` lays them out.
+
+    The share is multiplied over the NODES and the edges gather it once, as
+    rows: the same two float32 operands an edge as
+    ``ranks[src] * inv_deg[src]``, so the same bits
+    (tests/test_pagerank_cli.py holds both), in the same edge order into
+    the same scatter-add.
+    """
+    share = _gather_chunks(ranks * inv_deg, chunks)[: dst.shape[0]]
+    return jax.ops.segment_sum(share, dst, num_segments=num_nodes)
 
 
 @functools.partial(jax.jit, static_argnames=("num_nodes", "num_iters"))
@@ -53,8 +121,12 @@ def pagerank(
 ) -> jax.Array:
     """Single-device PageRank over int32 edge arrays ``[E]``.
 
-    Pass valid edges only (no padding); the distributed variant supports
-    masked edge padding for equal shard sizes.
+    Pass valid edges only (no padding; ids in ``[0, num_nodes)`` — the CLI
+    refuses the others before the device): the share is gathered as rows
+    (``_gather_chunks``), under which an id out of range reads a zero or a
+    lane of the table's last row and not, as numpy's indexing would have it,
+    a wrap to ``share[-1]``.  The distributed variant supports masked edge
+    padding for equal shard sizes.
     """
     deg = jax.ops.segment_sum(
         jnp.ones_like(src, dtype=jnp.float32), src, num_segments=num_nodes
@@ -62,9 +134,10 @@ def pagerank(
     inv_deg = jnp.where(deg > 0, 1.0 / jnp.maximum(deg, 1.0), 0.0)
     dangling = deg == 0
     ranks0 = jnp.full((num_nodes,), 1.0 / num_nodes, dtype=jnp.float32)
+    chunks = _edge_chunks(src)
 
     def body(ranks, _):
-        contrib = _contributions(src, dst, ranks, inv_deg, num_nodes)
+        contrib = _contributions(chunks, dst, ranks, inv_deg, num_nodes)
         dangling_mass = jnp.sum(jnp.where(dangling, ranks, 0.0))
         ranks_new = (1.0 - damping) / num_nodes + damping * (
             contrib + dangling_mass / num_nodes
@@ -99,9 +172,10 @@ def pagerank_step(
     num_nodes: int,
 ) -> jax.Array:
     """ONE ``pagerank`` iteration as a standalone jit, bit-identical to
-    the scan body above (both go through ``_contributions``: one gather
-    an edge, of the share multiplied over the nodes).  ``damping`` is a
-    TRACED f32 operand on
+    the scan body above (both go through ``_contributions``: one row
+    gathered an edge, of the share multiplied over the nodes; this one
+    lays its ``src`` out in chunks every call, the scan once).
+    ``damping`` is a TRACED f32 operand on
     purpose: the fused kernel traces it too, so ``(1-damping)/n``
     computes in f32 on device — marking it static would constant-fold
     that expression in python float64 and change the low bits (pinned
@@ -113,7 +187,7 @@ def pagerank_step(
     full step's — segment_sum contributions land only on in-range dst,
     and the dangling/teleport terms are global scalars either way.
     """
-    contrib = _contributions(src, dst, ranks, inv_deg, num_nodes)
+    contrib = _contributions(_edge_chunks(src), dst, ranks, inv_deg, num_nodes)
     dangling_mass = jnp.sum(jnp.where(dangling, ranks, 0.0))
     return (1.0 - damping) / num_nodes + damping * (
         contrib + dangling_mass / num_nodes
@@ -140,7 +214,7 @@ class DistributedPageRank:
 
         def step(src, dst, mask, ranks, inv_deg, dangling_vec):
             # Local partial: masked edges contribute 0.
-            w = (ranks * inv_deg)[src] * mask
+            w = _gather_share(ranks * inv_deg, src) * mask
             partial = jax.ops.segment_sum(w, dst, num_segments=num)
             contrib = jax.lax.psum(partial, axis_name)          # the combine
             local_dangling = jnp.sum(jnp.where(dangling_vec, ranks, 0.0))
@@ -199,7 +273,7 @@ class ShardedPageRank:
     the graph is static, so the entire routing plan (slot ids, receive
     maps) is computed ONCE on the host and the device step is just
 
-      gather local shares (ONE gather) -> segment_sum into send slots ->
+      gather local shares (ONE gather, as rows) -> segment_sum into send slots ->
       lax.all_to_all -> segment_sum into the local rank block -> damp,
 
     with the dangling-mass correction as a scalar psum.  Because slots
@@ -320,7 +394,7 @@ class ShardedPageRank:
             ranks_l, inv_deg_l = ranks_l[0], inv_deg_l[0]
             dangling_l, valid_l = dangling_l[0], valid_l[0]
 
-            w = (ranks_l * inv_deg_l)[src_l] * mask
+            w = _gather_share(ranks_l * inv_deg_l, src_l) * mask
             send = jax.ops.segment_sum(
                 w, send_seg, num_segments=n_dev * cap + 1
             )[: n_dev * cap].reshape(n_dev, cap)

@@ -25,6 +25,7 @@ whole-program compiles are a scratch script's job, not a test's.
 
 import contextlib
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -200,43 +201,79 @@ def test_tokenize_block_einsum_branch_compiles(one_chip):
 PAGERANK_EDGES = 5_105_039
 
 
+def _pagerank_compiled(one_chip, edges):
+    from locust_tpu.apps.pagerank import pagerank
+
+    ids = jax.ShapeDtypeStruct((edges,), jnp.int32, sharding=one_chip)
+    with _persistent_cache_off():  # a module's fixture runs before a test's
+        return pagerank.lower(
+            ids, ids, num_nodes=916_428, num_iters=20, damping=0.85
+        ).compile()
+
+
 @pytest.fixture(scope="module")
 def pagerank_text_and_stats(one_chip):
     """``pagerank5M.batch``'s one program at its own shape — web-Google's
     5,105,039 edges over 916,428 ids, 20 rounds, damping traced as the
-    plan passes it (3.4 s on a described v5e, PR 41), compiled once for
-    the cases below."""
-    from locust_tpu.apps.pagerank import pagerank
-
-    edges = jax.ShapeDtypeStruct((PAGERANK_EDGES,), jnp.int32, sharding=one_chip)
-    with _persistent_cache_off():  # a module's fixture runs before a test's
-        compiled = pagerank.lower(
-            edges, edges, num_nodes=916_428, num_iters=20, damping=0.85
-        ).compile()
+    plan passes it (3.4 s on a described v5e, PR 41; 5 s with the chunks'
+    loop, PR 46), compiled once for the cases below."""
+    compiled = _pagerank_compiled(one_chip, PAGERANK_EDGES)
     return compiled.as_text(), compiled.memory_analysis()
 
 
-def test_pagerank_iterate_compiles_at_the_cells_shape(pagerank_text_and_stats):
+def test_pagerank_iterate_compiles_at_the_cells_shape(pagerank_text_and_stats, one_chip):
     """A change that breaks the shape, or blows its memory past a chip's,
-    shows on the CPU."""
+    shows on the CPU.  The 1 GiB is what refuses the share's rows gathered
+    for ALL the edges at once (2.6 GB of temporaries; PERF.md §6, PR 46),
+    and four times the edges must cost words an edge more, never rows: the
+    rows exist a chunk at a time."""
+    from locust_tpu.apps.pagerank import CHUNK, LANES
+
     text, stats = pagerank_text_and_stats
     assert "while" in text and "scatter" in text  # one scan, the scatter-add inside
     assert stats.argument_size_in_bytes + stats.temp_size_in_bytes < 1 << 30
+    more = _pagerank_compiled(one_chip, 4 * PAGERANK_EDGES).memory_analysis()
+    grown = more.temp_size_in_bytes - stats.temp_size_in_bytes
+    assert grown < 3 * PAGERANK_EDGES * 16 + CHUNK * LANES * 4, grown
 
 
-def test_pagerank_round_is_one_gather_fusion_on_the_chip(pagerank_text_and_stats):
-    """The chip's compiler keeps the node-sized product out of the gather:
-    the whole program holds ONE fusion that yields a float an edge (the
-    gather of ``ranks * inv_deg``; the parent's two were 58 + 38 ms a
-    round where one is 39, PERF.md §6, PR 42), and it stands in the loop."""
-    import re
+def _computations(text):
+    """``{name: its lines}`` of every computation of an HLO module's text."""
+    found, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            found[name] = []
+        elif name:
+            found[name].append(line)
+    return found
+
+
+def test_pagerank_round_gathers_rows_in_an_inner_loop_on_the_chip(pagerank_text_and_stats):
+    """The chip pays a gather by the index: a word an edge cost it 33.8 ms
+    a round, a 128-lane row an edge a third of that (PERF.md §6, PRs 42
+    and 46).  So the whole program holds ONE gather, it yields a chunk's
+    rows and no word an edge, and its fusion stands in the body of the loop
+    over the chunks, which stands in the scan's."""
+    from locust_tpu.apps.pagerank import CHUNK, LANES
 
     text, _ = pagerank_text_and_stats
-    fusions = re.findall(
-        rf"^\s*(?:ROOT )?(%\S+) = f32\[{PAGERANK_EDGES}\]\S* fusion\(.*$", text, re.M
-    )
-    assert len(fusions) == 1, fusions
-    assert fusions[0] + " = " not in text[text.index("\nENTRY "):]   # in the scan's body
+    gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", text)
+    assert gathers == [f"f32[{CHUNK},{LANES}]"], gathers
+    comps = _computations(text)
+
+    def holder(pattern):
+        """The one computation with a line that matches ``pattern``."""
+        held = [n for n, lines in comps.items() if any(re.search(pattern, x) for x in lines)]
+        assert len(held) == 1, (pattern, held)
+        return held[0]
+
+    rows = rf"= f32\[{CHUNK},{LANES}\]\S* fusion\("
+    chunks_loop = holder(rows)
+    scan_body = holder(rf" while\(.*body={re.escape(chunks_loop)}[,\s]")
+    entry = holder(rf" while\(.*body={re.escape(scan_body)}[,\s]")
+    assert f"ENTRY {entry} " in text
 
 
 INDEX_STORE_ROWS = 10_485_760  # the capacity indexzipf.batch's store ends at

@@ -22,7 +22,10 @@ import pytest
 from helpers import native_ingest_missing
 
 from locust_tpu import cli, pagerank_reference
-from locust_tpu.apps.pagerank import _contributions, pagerank, pagerank_prep, pagerank_step
+from locust_tpu.apps.pagerank import (
+    CHUNK, LANES, _contributions, _edge_chunks, _gather_share, pagerank, pagerank_prep,
+    pagerank_step,
+)
 from locust_tpu.core import bytes_ops
 from locust_tpu.io import native_ingest
 from locust_tpu.plan import PlanError
@@ -33,6 +36,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
 import rmat_edges  # noqa: E402
+
+# ``locust_tpu.apps.pagerank`` the MODULE: the package exports the function
+# under the same name.
+pagerank_module = sys.modules[pagerank.__module__]
 
 with open(os.path.join(REPO, "benchmarks", "configs", "pagerank-rmat-5M.json")) as _f:
     CONFIG = json.load(_f)
@@ -479,6 +486,19 @@ def small_graph(request, tmp_path):
     return src, dst, n
 
 
+def _poisoned(ranks, src):
+    """``ranks`` with an ``inf`` and a ``nan`` at nodes NO edge gathers
+    from, each in a 128-lane row that holds a node some edge does."""
+    ranks = ranks.copy()
+    sourced = np.zeros(ranks.size, bool)
+    sourced[src] = True
+    row_named = np.repeat(np.add.reduceat(sourced, np.arange(0, ranks.size, LANES)) > 0, LANES)
+    beside = np.flatnonzero(~sourced & row_named[:ranks.size])
+    assert beside.size >= 2
+    ranks[beside[0::2]], ranks[beside[1::2]] = np.inf, np.nan
+    return ranks
+
+
 def test_one_gather_of_the_share_is_the_two_gather_round_bit_for_bit(small_graph):
     src, dst, n = small_graph
     if n != CONFIG["probe"]["ids"]:    # the probe's edges are distinct, the others' repeat
@@ -489,13 +509,20 @@ def test_one_gather_of_the_share_is_the_two_gather_round_bit_for_bit(small_graph
     def two_gathers(src, dst, ranks, inv_deg):
         return jax.ops.segment_sum(ranks[src] * inv_deg[src], dst, num_segments=n)
 
-    one_gather = jax.jit(_contributions, static_argnums=4)
+    @jax.jit
+    def one_gather(src, dst, ranks, inv_deg):
+        return _contributions(_edge_chunks(src), dst, ranks, inv_deg, n)
+
     rng = np.random.default_rng(n)
-    for ranks in (np.full(n, 1.0 / n, np.float32),
-                  (rng.random(n) ** 4 / n).astype(np.float32)):
-        got = np.asarray(one_gather(src, dst, ranks, inv_deg, n))
+    skewed = (rng.random(n) ** 4 / n).astype(np.float32)
+    # The rows an edge takes whole hold its neighbours too: an inf or a nan
+    # in a lane NO edge selects must not reach any sum (the ``where``; a
+    # product with a one-hot would let it through).
+    for ranks in (np.full(n, 1.0 / n, np.float32), skewed, _poisoned(skewed, src)):
+        got = np.asarray(one_gather(src, dst, ranks, inv_deg))
         want = np.asarray(two_gathers(src, dst, ranks, inv_deg))
         assert got.dtype == np.float32 and got.tobytes() == want.tobytes()
+        assert np.isfinite(got).all()
 
 
 @pytest.mark.parametrize("rounds", [1, 4])
@@ -510,35 +537,104 @@ def test_the_scan_is_as_many_steps_bit_for_bit(small_graph, rounds):
     assert np.asarray(whole).tobytes() == np.asarray(ranks).tobytes()
 
 
-def _edge_sized_gathers(jaxpr, edges, in_loop=False):
-    """(inside a loop's body, outside any) counts of the ``gather``
-    equations of ``jaxpr`` whose result is ``[edges]``, sub-programs
-    included."""
-    inside = outside = 0
+def _gathers(jaxpr, in_loops=0):
+    """``(result shape, loops it stands inside)`` of every ``gather``
+    equation of ``jaxpr``, sub-programs included."""
+    found = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "gather" and eqn.outvars[0].aval.shape == (edges,):
-            inside, outside = inside + in_loop, outside + (not in_loop)
-        loop = in_loop or eqn.primitive.name in ("scan", "while")
+        if eqn.primitive.name == "gather":
+            found.append((eqn.outvars[0].aval.shape, in_loops))
+        depth = in_loops + (eqn.primitive.name in ("scan", "while"))
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else (value,):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    a, b = _edge_sized_gathers(sub, edges, loop)
-                    inside, outside = inside + a, outside + b
-    return inside, outside
+                    found += _gathers(sub, depth)
+    return found
 
 
-def test_a_round_of_the_program_holds_one_edge_sized_gather():
-    """The guard against the second gather coming back: what is a function
-    of the node is computed over the nodes, and the edges gather ONE word."""
-    src, dst, n = _hub_graph(3001, 50_000, 2)
+def test_a_round_of_the_program_gathers_rows_a_chunk_at_a_time():
+    """The guard against a one-word gather coming back (a gathered word
+    costs a v5e three times a gathered row): no gather of the program yields
+    a word an edge or a word an edge of a chunk, and ONE yields a chunk's
+    128-lane rows, inside the round's inner loop."""
+    src, dst, n = _hub_graph(3001, 2 * CHUNK + 7, 2)        # three chunks, the last padded
     program = jax.make_jaxpr(
         lambda s, d, damping: pagerank(s, d, num_nodes=n, num_iters=3, damping=damping)
     )(src, dst, np.float32(0.85))
-    inside, outside = _edge_sized_gathers(program.jaxpr, src.size)
-    assert inside == 1 and outside <= 1
+    assert _gathers(program.jaxpr) == [((CHUNK, LANES), 2)]   # the scan's round, the chunks' loop
     node = np.zeros(n, np.float32)
     step = jax.make_jaxpr(
         lambda s, d, r, i, g, damping: pagerank_step(s, d, r, i, g, damping, num_nodes=n)
     )(src, dst, node, node, node > 0, np.float32(0.85))
-    assert _edge_sized_gathers(step.jaxpr, src.size) == (0, 1)
+    assert _gathers(step.jaxpr) == [((CHUNK, LANES), 1)]
+
+
+# ------------------------------------------- the share gathered as rows (PR 46)
+
+def _share_and_ids(nodes, edges, seed):
+    """A share vector and ``edges`` ids into it: the even ids are named, the
+    odd ones — neighbours in the same rows — hold ``inf`` and ``nan``."""
+    rng = np.random.default_rng([nodes, edges, seed])
+    share = (rng.random(nodes) ** 4).astype(np.float32)
+    share[1::4], share[3::4] = np.inf, np.nan
+    src = 2 * rng.integers(0, (nodes + 1) // 2, edges)
+    return share, src.astype(np.int32)
+
+
+@pytest.mark.parametrize("nodes", [1, 127, 128, 129, 916_428])
+@pytest.mark.parametrize(
+    "edges", [0, 1, CHUNK - 1, CHUNK, CHUNK + 1, 3 * CHUNK + 7],
+    ids=["none", "one", "chunk-less-1", "chunk", "chunk-and-1", "3-chunks-and-7"])
+def test_the_row_gather_is_the_one_word_gather_bit_for_bit(nodes, edges):
+    share, src = _share_and_ids(nodes, edges, 1)
+    got = np.asarray(jax.jit(_gather_share)(share, src))
+    assert got.dtype == np.float32 and got.shape == (edges,)
+    assert got.tobytes() == share[src].tobytes() and np.isfinite(got).all()
+
+
+@pytest.mark.parametrize("chunk", [1, 8, 1000])
+@pytest.mark.parametrize("nodes", [129, 916_428])
+def test_the_row_gather_holds_at_any_chunk_size(nodes, chunk, monkeypatch):
+    """Many chunks, the last one short by all but seven (or by none)."""
+    monkeypatch.setattr(pagerank_module, "CHUNK", chunk)
+    share, src = _share_and_ids(nodes, 5 * chunk + 7, chunk)
+    # A jit of its own: one of ``_gather_share`` could answer from a trace
+    # of these shapes made under another CHUNK.
+    got = np.asarray(jax.jit(lambda share, src: _gather_share(share, src))(share, src))
+    assert got.tobytes() == share[src].tobytes()
+
+
+def test_an_id_out_of_range_reads_the_padding_or_the_last_row():
+    """Callers pass valid ids (the CLI refuses the others before the
+    device).  What the row spelling makes of the rest, so that nobody has to
+    guess: ids are read unsigned and the row index clamps — never a wrap to
+    ``share[-1]`` as numpy's indexing has it."""
+    share = np.arange(1, 130, dtype=np.float32)              # two rows, 127 zeros of padding
+    ids = np.array([-1, 129, 1000, 2 ** 31 - 1, 128, -2 ** 31], np.int32)
+    got = np.asarray(_gather_share(share, ids))
+    assert got.tolist() == [0.0, 0.0, 0.0, 0.0, 129.0, 129.0]
+
+
+def test_the_cli_prints_the_bytes_the_one_word_gather_printed(
+        graph, capsysbinary, monkeypatch):
+    """Same bits, not merely inside the tolerance: one seeded graph through
+    the CLI under the row spelling and under ``share[src]``, which is what
+    every release before PR 46 ran."""
+    argv = ["pagerank", graph[0], "--backend", "cpu"]
+    assert cli.main(argv) == 0
+    rows = capsysbinary.readouterr().out
+    words = []
+
+    def one_word(share, chunks):
+        words.append(chunks.size)
+        return share[chunks.reshape(-1)]
+
+    monkeypatch.setattr(pagerank_module, "_gather_chunks", one_word)
+    pagerank.clear_cache()                 # its trace of this shape holds the rows
+    try:
+        assert cli.main(argv) == 0
+    finally:
+        pagerank.clear_cache()             # and now the words
+    assert words == [-(-graph[1].size // CHUNK) * CHUNK]    # traced once: every edge, in whole chunks
+    assert capsysbinary.readouterr().out == rows and rows.count(b"\n") == graph[3]
