@@ -18,15 +18,21 @@ One device (``build_index``), static shapes throughout:
      re-sorted a block (a collect has no combiner that keeps state small:
      the fold this replaced re-sorted the whole pair set every block and
      held 163,840 pairs).
-  4. Collect, once: the store ordered by (dead, every key lane, doc) —
-     the columns themselves, no hash: a radix sort two columns a pass
-     (``_order_rows``), a number of store-sized sorts that the key width
-     fixes and the block count does not move — so words stand in byte
-     order and a word's docs ascending; equal neighbours are the
-     duplicates of a document that spans blocks.  Postings and word
-     starts are compacted by two narrow sorts; the result is CSR
-     (``Postings``: words, offsets, postings), the dict spelling built
-     from it for those who ask.
+  4. Collect, once: the store GROUPED by (dead, hash64(key)) — one
+     five-operand sort that carries the doc id and the row index, and
+     one gather of whole key rows — so equal keys stand together; a row
+     whose full key differs from its neighbour's starts a word ENTRY.
+     The entries (a sixteenth as many rows as pairs, at 64 lines a
+     document) are ordered by their bytes (``_order_rows``) and ranked,
+     entries of equal bytes — the pieces of a word that a 64-bit
+     collision interleaved with another — alike; the rank goes back to
+     the rows and one two-key sort by (rank, doc) puts words in byte
+     order and a word's docs ascending, equal neighbours being the
+     duplicates of a document that spans blocks.  No hash decides what is
+     listed: it decides which rows meet before the full-key compare.
+     Postings and word starts are compacted by two narrow sorts; the
+     result is CSR (``Postings``: words, offsets, postings), the dict
+     spelling built from it for those who ask.
 
 The mesh variant (``DistributedInvertedIndex``) still carries a fixed,
 per-shard pair table that it dedups every round.
@@ -113,6 +119,16 @@ COLLECT_GROUP_BLOCKS = 16
 WORD_ROWS = 1 << 16
 
 
+def _front(marked: jax.Array) -> jax.Array:
+    """The indices of the ``marked`` rows in ascending order at the front,
+    then the unmarked rows' indices plus the length: one sort of ONE
+    operand (its keys are distinct, so it need not be stable — a stable
+    sort pays the chip another operand)."""
+    n = marked.shape[0]
+    row = jnp.arange(n, dtype=jnp.int32)
+    return jax.lax.sort(jnp.where(marked, row, row + n), is_stable=False)
+
+
 # Key columns a pass of ``_order_rows`` sorts by.
 RADIX_KEYS = 2
 
@@ -126,9 +142,12 @@ def _order_rows(keys: list[jax.Array]) -> jax.Array:
     last pair first.  Why not one sort with every column a key: the chip's
     compiler takes time with the SQUARE of a sort's operands (6 s for one,
     88 s for five, 295 s for ten on the sandbox's CPU for a described v5e,
-    and the ten-column sort the collect would need did not compile in
-    fourteen minutes; PERF.md section 6, PR 45), and a program every job
-    needs must not take a quarter of an hour to get."""
+    and a ten-column sort did not compile in fourteen minutes; PERF.md
+    section 6, PR 45), and a program every job needs must not take a
+    quarter of an hour to get.  A pass pays a one-word gather a column
+    (8.6 ns a row on a v5e, as much as a whole key row), so this orders
+    the collect's word ENTRIES — a million rows at the benchmark's size —
+    and never the pair store (PERF.md section 6, PR 47)."""
     n = keys[0].shape[0]
     if len(keys) % RADIX_KEYS:  # a constant column orders nothing
         keys = [jnp.zeros(n, jnp.uint32)] * (-len(keys) % RADIX_KEYS) + keys
@@ -155,8 +174,8 @@ class _IndexPrograms:
     block: Callable    # (lines, doc ids) -> (block's distinct pairs at its head, counts [its pairs, tokens dropped, keys cut])
     append: Callable   # (store, totals [fill, dropped, cut], a block's pairs, its counts) -> (store, totals)  [store donated]
     grow: Callable     # (store, rows) -> store with empty rows appended
-    collect: Callable  # (store, fill) -> (sorted key lanes, postings, word starts, pairs before, n_pairs, n_words)
-    cut: Callable      # (sorted key lanes, word starts, pairs before, rows) -> (word key bytes, offsets)
+    collect: Callable  # (store, fill) -> (key lanes and doc ids grouped by hash, the word entries' first rows, n_live, n_entries)
+    cut: Callable      # (grouped key lanes, doc ids, entries' first rows, n_live, rows) -> (word key bytes, offsets, postings, n_pairs, n_words)
 
 
 def _build_index_programs(cfg: EngineConfig) -> _IndexPrograms:
@@ -168,15 +187,24 @@ def _build_index_programs(cfg: EngineConfig) -> _IndexPrograms:
     boundary mask on full keys, and the survivors moved to the block's
     head by a one-operand sort of their row indices.  ``append``: that
     head written into the resident pair store at its fill.  ``collect``,
-    ONCE a job: the whole store ordered by (dead, key lane 0 .. L-1, doc
-    id) — the columns themselves, by a radix sort over them
-    (``_order_rows``), so no hash decides anything: words come out in
-    byte order, a word's doc ids ascending, and neighbours that are equal
-    in every column are the duplicates a document spread over two blocks
-    leaves.  Two narrow sorts then move the distinct pairs' doc ids (the
-    postings) and the words' first rows to the front.  ``cut``: the
-    words' key bytes and posting offsets gathered for as many rows as the
-    job has words (a capacity of ``core.kv.rows_to_hold``'s ladder)."""
+    ONCE a job: the whole store GROUPED by (dead, hash64(key)) — the one
+    store-sized sort with more than two operands, five, the doc id and the
+    row index its payload — and the key rows gathered by it, ONE gather of
+    eight lanes a row; a live row whose full key differs from the row
+    before starts a word ENTRY, and the entries' first rows move to the
+    front.  An entry is a word, or a piece of one where a 64-bit collision
+    interleaved two words in one hash run.  ``cut``, for as many rows as
+    the job has entries (a capacity of ``core.kv.rows_to_hold``'s ladder):
+    the entries ordered by their key lanes (``_order_rows``: the radix
+    sort, four passes over the ENTRIES) and given their dense rank, equal
+    bytes one rank — which folds a collision's pieces back, nothing after
+    it looks at a hash; the rank carried to every row of its entry (the
+    ranks' differences scattered to the entries' first rows and summed
+    along the store); one two-key sort by (rank, doc id), so words stand in
+    byte order, a word's doc ids ascending, and neighbours equal in both
+    are the duplicates a document spread over two blocks leaves; then the
+    distinct pairs' doc ids (the postings), the words' posting offsets and
+    their key bytes, each moved to the front by a narrow sort."""
     n_lanes = cfg.key_lanes
     key_w, emits = cfg.key_width, cfg.emits_per_line
 
@@ -232,33 +260,68 @@ def _build_index_programs(cfg: EngineConfig) -> _IndexPrograms:
         row = jnp.arange(n, dtype=jnp.int32)
         # Past the fill lies the last block's tail and rows never written.
         live = store.valid & (row < fill)
-        perm = _order_rows(
-            [(~live).astype(jnp.uint32),
-             *(store.key_lanes[:, j] for j in range(n_lanes)),
-             # int32 order as unsigned order: the sign bit flipped
-             jax.lax.bitcast_convert_type(store.values, jnp.uint32)
-             ^ jnp.uint32(0x80000000)]
+        h1, h2 = packing.hash_pair(store.key_lanes)
+        # The doc id rides the sort as payload: nothing one word wide is
+        # gathered over the store.
+        _, _, _, docs, perm = jax.lax.sort(
+            ((~live).astype(jnp.uint32), h1, h2, store.values, row),
+            num_keys=3, is_stable=False,
         )
-        live = live[perm]
         s_lanes = store.key_lanes[perm]
-        docs = store.values[perm]
-        word_new = live & _word_starts(s_lanes)
-        keep = live & (word_new | (docs != jnp.roll(docs, 1)))
+        n_live = jnp.sum(live.astype(jnp.int32))  # dead rows sort last
+        starts = (row < n_live) & _word_starts(s_lanes)
+        entry_rows = _front(starts)
+        return (s_lanes, docs, entry_rows, n_live,
+                jnp.sum(starts.astype(jnp.int32)))
+
+    def index_cut(s_lanes: jax.Array, docs: jax.Array, entry_rows: jax.Array,
+                  n_live: jax.Array, rows: int):
+        n = s_lanes.shape[0]
+        row = jnp.arange(n, dtype=jnp.int32)
+        slot = jnp.arange(rows, dtype=jnp.int32)
+        # The entries, ordered by their bytes.  A slot past the last entry
+        # takes the largest key: ties stand in input order, so the entries
+        # fill the ordered slots' head even beside a word of 0xFF bytes.
+        at = entry_rows[:rows]  # an entry's first row; n or more past the last
+        is_entry = at < n
+        n_entries = jnp.sum(is_entry.astype(jnp.int32))
+        e_lanes = jnp.where(
+            is_entry[:, None], s_lanes[jnp.where(is_entry, at, at - n)],
+            jnp.uint32(0xFFFFFFFF))
+        order = _order_rows([e_lanes[:, j] for j in range(n_lanes)])
+        o_lanes = e_lanes[order]
+        # Entries of equal bytes — the pieces a hash collision cut a word
+        # into — take one rank: the word's place in byte order.
+        word_new = (slot < n_entries) & _word_starts(o_lanes)
+        _, rank = jax.lax.sort(
+            (order, jnp.cumsum(word_new.astype(jnp.int32)) - 1),
+            num_keys=1, is_stable=False)
+        # The rank to every row of its entry: the ranks' differences at
+        # the entries' first rows, summed along the store (int32 wraps
+        # exactly) — a million scattered words, where a gather by the
+        # rows' entry numbers would be one word a store row.
+        steps = rank - jnp.pad(rank, (1, 0))[:-1]
+        row_rank = jnp.cumsum(
+            jnp.zeros(n, jnp.int32).at[at].add(steps, mode="drop"))
+        live = row < n_live  # dead rows stand last, before this sort and after
+        row_rank = jnp.where(live, row_rank, jnp.int32(2**31 - 1))
+        # Words in byte order, a word's docs ascending; equal neighbours
+        # are the duplicates of a document that spans blocks.
+        row_rank, docs = jax.lax.sort(
+            (row_rank, docs), num_keys=2, is_stable=False)
+        first = live & ((row == 0) | (row_rank != jnp.roll(row_rank, 1)))
+        keep = live & (first | (docs != jnp.roll(docs, 1)))
         kept = keep.astype(jnp.int32)
         before = jnp.cumsum(kept) - kept  # distinct pairs ahead of a row
         _, postings = jax.lax.sort(
-            (jnp.where(keep, row, row + n), docs), num_keys=1
-        )
-        word_rows = jnp.sort(jnp.where(word_new, row, row + n))
-        return (s_lanes, postings, word_rows, before, jnp.sum(kept),
-                jnp.sum(word_new.astype(jnp.int32)))
-
-    def index_cut(s_lanes: jax.Array, word_rows: jax.Array,
-                  before: jax.Array, rows: int):
-        n = s_lanes.shape[0]
-        at = word_rows[:rows]
-        at = jnp.where(at >= n, at - n, at)  # past the last word: any row
-        return packing.unpack_keys(s_lanes[at]), before[at]
+            (jnp.where(keep, row, row + n), docs), num_keys=1, is_stable=False)
+        word_rows = _front(first)[:rows]
+        offsets = before[jnp.where(word_rows < n, word_rows, word_rows - n)]
+        # The distinct words' keys, to the ordered slots' head.
+        head = _front(word_new)
+        words = o_lanes[jnp.where(head < rows, head, head - rows)]
+        return (packing.unpack_keys(words), offsets, postings,
+                jnp.sum(kept), jnp.sum(word_new.astype(jnp.int32)))
 
     return _IndexPrograms(
         block=jax.jit(index_block),
@@ -390,14 +453,16 @@ def build_index(
                 store, totals = programs.append(
                     store, totals, *programs.block(*on_device))
     with obs.span("index.collect", rows=cap):
-        s_lanes, postings, word_rows, before, n_pairs, n_words = (
-            programs.collect(store, totals[0])
-        )
+        s_lanes, docs, entry_rows, n_live, n_entries = programs.collect(
+            store, totals[0])
+        with obs.span("engine.sync", what="index.entries"):
+            n_entries = int(n_entries)
+        word_cap = min(rows_to_hold(WORD_ROWS, n_entries), cap)
+        words, offsets, postings, n_pairs, n_words = programs.cut(
+            s_lanes, docs, entry_rows, n_live, rows=word_cap)
         with obs.span("engine.sync", what="index.collect"):
             n_pairs, n_words, (_, dropped, cut) = jax.tree.map(
                 int, jax.device_get((n_pairs, n_words, tuple(totals))))
-        word_cap = min(rows_to_hold(WORD_ROWS, n_words), cap)
-        words, offsets = programs.cut(s_lanes, word_rows, before, rows=word_cap)
     if dropped:
         # Missing postings make a silently-wrong index; surface it loudly
         # (the WordCount per-line drop is reference semantics, but an index
@@ -421,6 +486,7 @@ def build_index(
     # the lines (the CLI's i // lines_per_doc).
     obs.metric_inc("index.docs", int(np.count_nonzero(np.diff(ids))) + 1)
     obs.metric_inc("index.words", n_words)
+    obs.metric_inc("index.hash_splits", n_entries - n_words)
     obs.metric_inc("index.dropped_tokens", dropped)
     obs.metric_inc("index.grows", grows)
     return Postings(

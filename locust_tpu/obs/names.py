@@ -67,7 +67,7 @@ NAMES = {
     "index.h2d": "span",            # index: a block's lines and doc ids handed up (device_put returns at once; arg bytes)
     "index.map": "span",            # index: a GROUP of blocks launched — tokenise, in-block dedup, the survivors appended to the pair store (arg blocks)
     "index.grow": "span",           # index: the pair store grown a step ahead of a group (args from_rows, to_rows, pairs)
-    "index.collect": "span",        # index: the store ordered ONCE, deduplicated across blocks and cut into CSR; its child engine.sync what=index.collect is the job's wait for the device (arg rows)
+    "index.collect": "span",        # index: the store grouped by hash ONCE (jit_index_collect; child engine.sync what=index.entries reads the word entries' count), then the entries ordered by their bytes, the pairs by (rank, doc), deduplicated across blocks and cut into CSR (jit_index_cut; child engine.sync what=index.collect); the two syncs are the job's wait for the device (arg rows)
     "index.d2h": "span",            # index: postings, word keys and offsets brought down (arg bytes)
     "index.render": "span",         # index CLI: the postings' word<TAB>d,d,...<LF> lines made into one buffer from arrays (bytes_ops.render_postings; args words, bytes)
     "index.write": "span",          # index CLI: that buffer written and flushed (arg bytes)
@@ -120,6 +120,7 @@ NAMES = {
     "pagerank.parse.native": "counter",  # pagerank: edge lists parsed by the native pass (0 where the library did not load or the file was not clean)
     "index.pairs": "counter",       # index: distinct (word, doc) pairs = postings out
     "index.words": "counter",       # index: distinct words
+    "index.hash_splits": "counter", # index: word entries the collect made minus distinct words — how often a 64-bit hash collision split a word's rows (the cut folds the pieces back; 0 in a sound job)
     "index.docs": "counter",        # index: runs of equal doc ids over the lines (the documents, for ids that follow the lines)
     "index.dropped_tokens": "counter",  # index: tokens past emits_per_line, whose postings are missing
     "index.grows": "counter",       # index: growth steps the pair store took

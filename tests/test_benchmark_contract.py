@@ -99,7 +99,7 @@ def _lowered_index(cfg: EngineConfig) -> tuple:
         progs.append.lower(store, counts, head, counts),
         progs.grow.lower(store, rows=8 * cfg.emits_per_block),
         progs.collect.lower(store, fill),
-        progs.cut.lower(collected[0], collected[2], collected[3], rows=cfg.emits_per_block),
+        progs.cut.lower(*collected[:4], rows=cfg.emits_per_block),
     )
 
 

@@ -300,17 +300,15 @@ def _index_programs_and_shapes(one_chip):
 
 def test_index_store_programs_compile_at_the_cells_shape(one_chip):
     """The pair store's own programs — a block's head appended at the fill,
-    a growth step, the cut that gathers a million words' keys and offsets —
-    at the cell's capacity: no sort in them, a second or two each, and
-    together with the store well inside a chip."""
+    a growth step — at the cell's capacity: no sort in them, a second or
+    two each, and together with the store well inside a chip.  (The cut
+    holds sorts from PR 47 on: it compiles with the collect, below.)"""
     progs, cfg, shape, store, head = _index_programs_and_shapes(one_chip)
     counts, n = shape((3,), jnp.int32), INDEX_STORE_ROWS
     for lowered in (
         progs.append.lower(store, counts, head, counts),
         progs.grow.lower(jax.tree.map(
             lambda x: shape((n // 2, *x.shape[1:]), x.dtype), store), rows=n),
-        progs.cut.lower(shape((n, cfg.key_lanes), jnp.uint32), shape((n,), jnp.int32),
-                        shape((n,), jnp.int32), rows=1 << 20),
     ):
         compiled = lowered.compile()
         stats = compiled.memory_analysis()
@@ -318,43 +316,64 @@ def test_index_store_programs_compile_at_the_cells_shape(one_chip):
         assert stats.argument_size_in_bytes + stats.temp_size_in_bytes < 2 << 30
 
 
-def test_index_collect_orders_the_store_with_one_sort_in_a_loop():
-    """What the chip's trace shows as five store-sized sorts is ONE in the
-    program: the collect holds three ``sort`` equations whatever the block
-    count — the radix pass's stable three-operand one inside its loop, and
-    the two narrow ones that move postings and word starts to the front —
-    because the chip's compiler takes time with the square of a sort's
-    operands (PERF.md section 6, PR 45: ten operands did not compile in a
-    quarter of an hour)."""
+def test_index_collect_groups_the_store_and_orders_only_the_entries():
+    """The store is only GROUPED — one five-operand sort by (dead, hash64)
+    that carries the doc id and the row index, then ONE gather of whole key
+    rows — and the byte order is made on the word entries: the only loop
+    of sorts (``_order_rows``' stable three-operand pass) runs over the
+    cut's ``rows=``, a sixteenth of the store at the cell's size.  No sort
+    has more than five operands, because the chip's compiler takes time
+    with the square of them (PERF.md section 6, PR 45), and nothing one word
+    wide is gathered over the store: the chip pays a gather by the index
+    (8.6 ns a word, 2.4 a word of an eight-lane row)."""
     from locust_tpu.apps.inverted_index import RADIX_KEYS, _build_index_programs
     from locust_tpu.core.kv import KVBatch
 
     cfg = EngineConfig(block_lines=8, line_width=32, key_width=32, emits_per_line=4)
-    jaxpr = jax.make_jaxpr(_build_index_programs(cfg).collect)(
-        KVBatch.empty(256, cfg.key_lanes), jnp.int32(7)).jaxpr
-    sorts = _eqns(jaxpr, "sort", [])
-    assert sorted(len(e.invars) for e in sorts) == [1, 2, RADIX_KEYS + 1]
-    loops = _eqns(jaxpr, "scan", [])  # a fori_loop of a known length
-    assert len(loops) == 1 and loops[0].params["length"] == (cfg.key_lanes + 2) // RADIX_KEYS
+    progs, n, rows = _build_index_programs(cfg), 256, 64
+    collect = jax.make_jaxpr(progs.collect)(
+        KVBatch.empty(n, cfg.key_lanes), jnp.int32(7))
+    cut = jax.make_jaxpr(functools.partial(progs.cut, rows=rows))(*collect.out_avals[:4]).jaxpr
+    collect = collect.jaxpr
+
+    def store_sized(eqns):
+        return [e.outvars[0].aval.shape for e in eqns if e.outvars[0].aval.shape[0] == n]
+
+    assert sorted(len(e.invars) for e in _eqns(collect, "sort", [])) == [1, 5]
+    assert not _eqns(collect, "scan", []) and not _eqns(collect, "while", [])
+    assert store_sized(_eqns(collect, "gather", [])) == [(n, cfg.key_lanes)]
+    assert max(len(e.invars) for e in _eqns(cut, "sort", [])) <= 3
+    assert store_sized(_eqns(cut, "gather", [])) == []
+    loops = _eqns(cut, "scan", [])  # a fori_loop of a known length
+    assert len(loops) == 1 and loops[0].params["length"] == cfg.key_lanes // RADIX_KEYS
+    assert not _eqns(cut, "while", [])
     in_loop = _eqns(loops[0].params["jaxpr"].jaxpr, "sort", [])
     assert len(in_loop) == 1 and len(in_loop[0].invars) == RADIX_KEYS + 1
     assert in_loop[0].params["is_stable"] and in_loop[0].params["num_keys"] == RADIX_KEYS
+    assert {v.aval.shape for v in in_loop[0].invars} == {(rows,)}
 
 
 @pytest.mark.slow
 def test_index_block_and_collect_compile_at_the_cells_shape(one_chip):
-    """The two programs that hold sorts, at the cell's shapes: the block
+    """The three programs that hold sorts, at the cell's shapes: the block
     program (tokenise, the five-operand in-block sort, the compaction) in
-    about 200 s and the collect in about 150 s on the sandbox's CPU — outside
-    tier-1 like every whole-program compile.  The collect with its operands
-    and temporaries stays under a fifth of a chip."""
+    about 200 s, the collect (one five-operand sort over the store) in
+    about 140 s and the cut (the entries' radix pass and five narrow
+    sorts) in about 90 s on the sandbox's CPU — outside tier-1 like every
+    whole-program compile.  The collect and the cut with their operands and
+    temporaries each stay under a fifth of a chip."""
     progs, cfg, shape, store, _ = _index_programs_and_shapes(one_chip)
     progs.block.lower(shape((cfg.block_lines, cfg.line_width), jnp.uint8),
                       shape((cfg.block_lines,), jnp.int32)).compile()
-    compiled = progs.collect.lower(store, shape((), jnp.int32)).compile()
-    stats = compiled.memory_analysis()
-    assert (stats.argument_size_in_bytes + stats.output_size_in_bytes
-            + stats.temp_size_in_bytes) < 3 << 30
+    n = INDEX_STORE_ROWS
+    for lowered in (
+        progs.collect.lower(store, shape((), jnp.int32)),
+        progs.cut.lower(shape((n, cfg.key_lanes), jnp.uint32), shape((n,), jnp.int32),
+                        shape((n,), jnp.int32), shape((), jnp.int32), rows=1 << 20),
+    ):
+        stats = lowered.compile().memory_analysis()
+        assert (stats.argument_size_in_bytes + stats.output_size_in_bytes
+                + stats.temp_size_in_bytes) < 3 << 30
 
 
 def test_check_kernels_match_chip_smoke():

@@ -16,6 +16,7 @@ import os
 import re
 import sys
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -151,6 +152,60 @@ def test_a_forced_hash_collision_still_yields_two_words(monkeypatch):
     assert build_inverted_index(lines, docs, cfg) == want
 
 
+def _constant_hash(lanes):
+    h = jnp.zeros(lanes.shape[:-1], jnp.uint32)
+    return h, h
+
+
+def _key_length_parity_hash(lanes):
+    """Two hash runs: the words of an even and of an odd number of bytes."""
+    h = jnp.sum(packing.unpack_keys(lanes) != 0, axis=-1).astype(jnp.uint32) % 2
+    return h, h
+
+
+@pytest.mark.parametrize("hash_pair", [_constant_hash, _key_length_parity_hash, None],
+                         ids=["constant", "key-length-parity", "true"])
+def test_colliding_hashes_split_words_and_the_cut_folds_them_back(
+        text, monkeypatch, hash_pair):
+    """The collect groups the store by ``hash_pair`` and compares full keys
+    only between neighbours, so words that share a hash and alternate by
+    doc id are cut into pieces; the pieces meet again where the entries
+    are ordered by their bytes.  Several blocks, documents cut across
+    them, a store that grows: the reference byte for byte under any hash,
+    and ``index.hash_splits`` says how often a word was cut."""
+    from locust_tpu import obs
+
+    if hash_pair is not None:
+        monkeypatch.setattr(packing, "hash_pair", hash_pair)
+    _, lines = text
+    cfg = EngineConfig(block_lines=40)
+    docs = np.arange(LINES) // 64  # 40 lines a block: most documents span two
+    obs.disable()
+    obs.enable(process="collisions")
+    index = build_index(bytes_ops.strings_to_rows(lines, cfg.line_width), docs, cfg)
+    counters = obs.metrics_snapshot()["counters"]
+    obs.disable()
+    assert index.grows >= 1 and LINES // cfg.block_lines > 2 * inverted_index.COLLECT_GROUP_BLOCKS
+    want = index_reference.inverted_index(lines, 64)
+    assert plan_compile.render_postings(index) == index_reference.render(want)
+    assert counters["index.words"] == len(want)
+    assert (counters["index.hash_splits"] > 0) == (hash_pair is not None)
+
+
+@pytest.mark.parametrize("hash_pair", [_constant_hash, None], ids=["constant", "true"])
+def test_a_word_of_0xff_bytes_stands_last_beside_the_empty_slots(monkeypatch, hash_pair):
+    """The cut orders ``rows=`` slots of which the entries fill the head;
+    an empty slot takes the largest key there is, and a word may have it."""
+    if hash_pair is not None:
+        monkeypatch.setattr(packing, "hash_pair", hash_pair)
+    cfg = EngineConfig(block_lines=2, line_width=32, key_width=4, emits_per_line=4)
+    ff = b"\xff" * 4
+    lines = [ff + b" a", b"b " + ff, b"a", ff + b"\xff z", b""]
+    index = build_index(lines, np.asarray([0, 1, 1, 2, 3]), cfg)
+    assert index.to_dict() == {b"a": [0, 1], b"b": [1], b"z": [2], ff: [0, 1, 2]}
+    assert list(index.to_dict()) == [b"a", b"b", b"z", ff] and index.cut_keys == 1
+
+
 def _dict_render(index: dict) -> bytes:
     return b"".join(plan_compile.iter_rendered("postings", index))
 
@@ -260,11 +315,18 @@ def test_trace_holds_the_index_spans_and_counters(text, tmp_path, capsysbinary):
     for name, count in [("cli.setup", 1), ("cli.load", 1), ("index.read", 1), ("cli.run", 1),
                         ("index.h2d", blocks), ("index.map", groups), ("index.collect", 1),
                         ("index.d2h", 1), ("cli.output", 1), ("index.render", 1),
-                        ("index.write", 1), ("engine.sync", groups)]:
+                        ("index.write", 1),
+                        # a fill read a later group, the entries' count, the collect
+                        ("engine.sync", groups + 1)]:
         assert spans.count(name) == count, (name, spans.count(name))
+    by_id = {e["args"]["id"]: e for e in doc["traceEvents"] if e.get("ph") == "X"}
+    waits = [e["args"]["what"] for e in by_id.values() if e["name"] == "engine.sync"
+             and by_id[e["args"]["parent"]]["name"] == "index.collect"]
+    assert waits == ["index.entries", "index.collect"]
     counters = doc["otherData"]["metrics"]["counters"]
     want = index_reference.inverted_index(lines, 1)
     assert counters["index.words"] == len(want)
+    assert counters["index.hash_splits"] == 0
     assert counters["index.pairs"] == sum(map(len, want.values()))
     assert counters["index.docs"] == LINES
     assert counters["index.dropped_tokens"] == 0
