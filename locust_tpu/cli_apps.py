@@ -15,6 +15,7 @@ tests/test_plan.py).  These subcommands wire the existing apps:
   python -m locust_tpu index  <file> [--mesh] [--lines-per-doc K]
   python -m locust_tpu tfidf  <file> [--lines-per-doc K]
   python -m locust_tpu sort   <in> <out> [--mesh] [--record-bytes 100] [--key-bytes 10]
+  python -m locust_tpu join   <rankings> <uservisits> [--date-from D] [--date-to D]
 
 Edge-list format: one ``src dst`` pair of integer node ids per line;
 lines starting with ``#`` are comments (the web-Google / SNAP convention,
@@ -42,6 +43,22 @@ words — ``[locust] index: words= pairs= docs= emit_overflow=
 key_overflow= line_overflow= truncated=False ...``, every count zero or
 not, and a ``[locust] WARN`` line where one is not — so a driver can hold
 "nothing dropped"; plain reference ``locust_tpu/index_reference.py``.
+``join`` is HiBench's ``sql/join`` (Pavlo et al.'s Join Task), the one
+command with TWO inputs: RANKINGS (``pageURL,pageRank,avgDuration``) and
+USERVISITS (``sourceIP,destURL,visitDate,adRevenue,...``), both text, one
+row a line, fields apart by ``,``.  It prints one
+``sourceIP<TAB>avgPageRank<TAB>totalRevenue<LF>`` line for every sourceIP
+with a visit inside ``--date-from .. --date-to`` (both ends in) to a ranked
+page, ordered by the total descending, ties by the sourceIP's bytes; both
+numbers with nine significant digits, as ``pagerank`` prints a rank.  The
+field split, the date filter, the join on the URL's BYTES, the regrouping
+by sourceIP and the order all run on the device (``apps.join``); the sums
+are exact integers (adRevenue in millionths).  Its result line —
+``[locust] join: pages= visits= passed= matched= groups= pages_visited=
+line_overflow= key_overflow= malformed= truncated=False ...`` — says what
+the inner join dropped and what the fixed widths cut, every count zero or
+not, with a ``[locust] WARN`` line where one of the last four is not;
+plain reference ``locust_tpu/join_reference.py``.  No capacity is a flag.
 ``sort`` is TeraSort: IN holds fixed-width binary
 records (gensort's: 100 bytes, the first 10 the key), OUT gets every one
 of them ordered by key as unsigned bytes, equal keys in input order; a
@@ -71,7 +88,7 @@ import numpy as np
 
 from locust_tpu import obs  # jax-free; zero-overhead unless --trace-out
 
-SUBCOMMANDS = ("pagerank", "index", "tfidf", "sort")
+SUBCOMMANDS = ("pagerank", "index", "tfidf", "sort", "join")
 
 
 def _add_backend_flag(p: argparse.ArgumentParser,
@@ -137,6 +154,26 @@ def build_parser(cmd: str) -> argparse.ArgumentParser:
                             "one all-to-all to the device that owns its key "
                             "range (sampled splitters), the shards written "
                             "in turn — the same bytes in OUT")
+    elif cmd == "join":
+        from locust_tpu.plan.builders import DATE_FROM, DATE_TO
+
+        p.add_argument("rankings", metavar="RANKINGS",
+                       help="text, a row a line: pageURL,pageRank,avgDuration")
+        p.add_argument("uservisits", metavar="USERVISITS",
+                       help="text, a row a line: sourceIP,destURL,visitDate,"
+                            "adRevenue,... (visitDate YYYY-MM-DD, adRevenue a "
+                            "decimal of at most six places)")
+        p.add_argument("--date-from", default=DATE_FROM, metavar="YYYY-MM-DD",
+                       help="the first visitDate that passes")
+        p.add_argument("--date-to", default=DATE_TO, metavar="YYYY-MM-DD",
+                       help="the last visitDate that passes")
+        p.add_argument("--block-lines", type=int, default=4096)
+        p.add_argument("--line-width", type=int, default=256,
+                       help="bytes of a row on the device; a longer line is "
+                            "cut and counted (line_overflow=)")
+        p.add_argument("--key-width", type=int, default=128,
+                       help="bytes of a URL the join compares; a longer one "
+                            "is joined by its head and counted (key_overflow=)")
     else:
         p.add_argument("filename", help="input text file")
         p.add_argument("--lines-per-doc", type=int, default=1,
@@ -151,7 +188,7 @@ def build_parser(cmd: str) -> argparse.ArgumentParser:
         p.add_argument("--line-width", type=int, default=128)
         p.add_argument("--key-width", type=int, default=32)
         p.add_argument("--emits-per-line", type=int, default=20)
-    _add_backend_flag(p, sort_mode=cmd != "sort")
+    _add_backend_flag(p, sort_mode=cmd not in ("sort", "join"))
     return p
 
 
@@ -254,8 +291,12 @@ def _lines_cut(path: str, rows: np.ndarray) -> int:
     cut.  Only a line that fills its row to the last byte can have been
     longer, and a file whose lines fit has none: the file is read again,
     and its line lengths taken, only where there is such a row."""
-    width = rows.shape[1]
-    full = np.flatnonzero(rows[:, -1])
+    return _lines_longer(path, rows.shape[1], np.flatnonzero(rows[:, -1]))
+
+
+def _lines_longer(path: str, width: int, full: np.ndarray) -> int:
+    """Of the lines ``full`` of ``path`` (the rows filled to their last
+    byte), those longer than ``width``."""
     if not full.size:
         return 0
     with open(path, "rb") as f:
@@ -268,6 +309,26 @@ def _lines_cut(path: str, rows: np.ndarray) -> int:
     # a CR before the LF is not content (the loader strips it)
     length -= (length > 0) & (data[np.maximum(ends - 1, 0)] == ord("\r"))
     return int(np.count_nonzero(length[full] > width))
+
+
+def _visit_blocks(path: str, cfg, full: list):
+    """The UserVisits file's blocks as the job reads them — every pull a
+    ``join.read`` span of the thread that pulls —, the rows filled to
+    their last byte noted in ``full`` as they go by."""
+    from locust_tpu.io import loader
+
+    source = iter(loader.StreamingCorpus(path, cfg.line_width, cfg.block_lines))
+    at, end = 0, object()
+    while True:
+        with obs.span("join.read", table="uservisits") as sp:
+            blk = next(source, end)
+            if blk is not end:
+                sp.set(lines=blk.shape[0])
+        if blk is end:
+            return
+        full.append(np.flatnonzero(blk[:, -1]) + at)
+        at += blk.shape[0]
+        yield blk
 
 
 def run_index(args) -> int:
@@ -321,6 +382,71 @@ def run_index(args) -> int:
             out = render_postings(index, args.limit)
             sp.set(bytes=len(out))
         with obs.span("index.write", bytes=len(out)):
+            sys.stdout.buffer.write(out)
+            sys.stdout.buffer.flush()
+    return 0
+
+
+def run_join(args) -> int:
+    from locust_tpu.apps.join import GROUP_BLOCKS
+    from locust_tpu.io import loader
+    from locust_tpu.plan.compile import compile_plan, render_revenue
+
+    t0 = time.perf_counter()
+    cfg = args.cfg
+    # Plan-compiled: two delimited sources with distinct inputs, and the
+    # chain map -> join -> shuffle -> reduce -> sort lowered onto apps.join.
+    plan = compile_plan(args.plan, cfg)
+    if args.trace_out:  # main's entry to the first cli.load, once it is over
+        obs.span_at("cli.setup", args.entered, time.time())
+    with obs.span("cli.load"):
+        # Rankings whole: the page table is sized from its line count.
+        # UserVisits is only OPENED here and read inside the run, a group
+        # of blocks ahead of the device on a reader thread, never held
+        # whole (as the default WordCount path reads its file).
+        with obs.span("join.read", table="rankings") as sp:
+            pages = loader.load_rows(args.rankings, cfg.line_width)
+            sp.set(bytes=os.path.getsize(args.rankings), lines=pages.shape[0])
+        cut_lines = _lines_cut(args.rankings, pages)
+        os.stat(args.uservisits)  # a missing file is this span's error
+        full: list = []
+        visits = loader.prefetch_blocks(
+            _visit_blocks(args.uservisits, cfg, full), depth=GROUP_BLOCKS)
+    with obs.span("cli.run"):
+        joined = plan.run({"rankings": pages, "uservisits": visits},
+                          render=False).value
+    cut_lines += _lines_longer(args.uservisits, cfg.line_width,
+                               np.concatenate(full or [np.zeros(0, np.int64)]))
+    print(f"[locust] {joined.pages} + {joined.visits} lines loaded",
+          file=sys.stderr)
+    obs.metric_inc("join.line_overflow", cut_lines)
+    # What the inner join dropped and what the fixed widths cut, in the
+    # index CLI's own words: a driver holds "nothing cut" by this line, so
+    # every count is on it, zero or not.
+    print(
+        f"[locust] join: pages={joined.pages} visits={joined.visits} "
+        f"passed={joined.passed} matched={joined.matched} "
+        f"groups={len(joined)} pages_visited={joined.pages_visited} "
+        f"line_overflow={cut_lines} key_overflow={joined.cut_keys} "
+        f"malformed={joined.malformed} truncated=False "
+        f"store_rows={joined.store_rows} grows={joined.grows} "
+        f"total={(time.perf_counter() - t0) * 1e3:.1f} ms",
+        file=sys.stderr,
+    )
+    if cut_lines or joined.cut_keys or joined.malformed:
+        print(
+            "[locust] WARN: the table is NOT the files': "
+            f"{cut_lines} line(s) past --line-width {cfg.line_width} were cut, "
+            f"{joined.cut_keys} key(s) past --key-width {cfg.key_width} (a "
+            f"sourceIP's 16 bytes) are joined by their head, "
+            f"{joined.malformed} row(s) whose fields do not parse take no part",
+            file=sys.stderr,
+        )
+    with obs.span("cli.output"):
+        with obs.span("join.render", rows=len(joined)) as sp:
+            out = render_revenue(joined)
+            sp.set(bytes=len(out))
+        with obs.span("join.write", bytes=len(out)):
             sys.stdout.buffer.write(out)
             sys.stdout.buffer.flush()
     return 0
@@ -431,6 +557,20 @@ def main(cmd: str, argv, entered: float) -> int:
         except (OSError, ValueError) as e:
             print(f"locust_tpu: error: {e}", file=sys.stderr)
             return 2
+    elif cmd == "join":
+        from locust_tpu.config import EngineConfig
+        from locust_tpu.plan import join_visits_plan
+
+        # The widths by EngineConfig's own checks, the dates by the plan's.
+        try:
+            args.cfg = EngineConfig(block_lines=args.block_lines,
+                                    line_width=args.line_width, key_width=args.key_width)
+            args.plan = join_visits_plan(args.date_from, args.date_to)
+            if args.date_from > args.date_to:
+                raise ValueError("--date-from lies after --date-to")
+        except ValueError as e:
+            print(f"locust_tpu: error: {e}", file=sys.stderr)
+            return 2
     elif cmd != "pagerank" and args.lines_per_doc < 1:
         print("locust_tpu: error: --lines-per-doc must be >= 1",
               file=sys.stderr)
@@ -456,6 +596,8 @@ def main(cmd: str, argv, entered: float) -> int:
             return run_index(args)
         if cmd == "sort":
             return run_sort(args, source)
+        if cmd == "join":
+            return run_join(args)
         return run_tfidf(args)
     except (OSError, ValueError) as e:
         print(f"locust_tpu: error: {e}", file=sys.stderr)

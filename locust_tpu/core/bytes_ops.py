@@ -124,6 +124,136 @@ def itoa_bytes(values: jax.Array, width: int = 12) -> jax.Array:
     return jnp.where(k < ndig[..., None], ascii_digits, jnp.uint8(0))
 
 
+
+# ---------------------------------------------------------------- fields
+# Delimited rows (PR 48): a line is fields apart by ONE delimiter byte —
+# a Hive table's ``FIELDS TERMINATED BY ','`` — where every map before split
+# on the reference's word delimiters.  Everything below is whole-array
+# arithmetic over the ``[rows, width]`` bytes: a column read a digit would
+# have XLA recompute the producer a column (core/packing._salted_fold's
+# lesson), so a number is parsed by static weights over an ALIGNED field.
+
+
+def field_ends(lines: jax.Array, delimiter: int, n_fields: int):
+    """Where the first ``n_fields`` fields of every row end.
+
+    Args:
+      lines: uint8 ``[rows, W]``, NUL-padded.
+      delimiter: the field delimiter, a byte value.
+    Returns:
+      ``(ends, n_found, length)``: int32 ``[rows, n_fields]`` — field k is
+      ``row[ends[k-1] + 1 : ends[k]]`` (field 0 starts at 0), its end the
+      k-th delimiter or, past the row's last, the row's length —, the
+      fields the row holds up to ``n_fields`` (a row of no delimiter holds
+      one) and the row's length.
+    """
+    w = lines.shape[-1]
+    col = jnp.arange(w, dtype=jnp.int32)
+    length = byte_length(lines)
+    is_delim = (lines == jnp.uint8(delimiter)) & (col < length[:, None])
+    ordinal = jnp.cumsum(is_delim.astype(jnp.int32), axis=-1)
+    ends = jnp.stack(
+        [jnp.min(jnp.where(is_delim & (ordinal == k + 1), col, length[:, None]),
+                 axis=-1) for k in range(n_fields)], axis=-1)
+    n_found = jnp.minimum(ordinal[:, -1] + 1, n_fields)
+    return ends, n_found, length
+
+
+def shift_left(rows: jax.Array, by: jax.Array, width: int) -> jax.Array:
+    """``rows[i, by[i] : by[i] + width]``, zeros past the row's end: a
+    per-row ``my_strcpy`` from a dynamic start with NO gather — a barrel
+    shifter, one select a bit of ``by`` (a gather along the minor axis is
+    the slowest thing a TPU does to a row).  Largest shift first, and after
+    the bit of 2^b only the columns a smaller shift can still bring in are
+    kept, so a narrow field costs about two passes over the row.
+
+    Args:
+      rows: uint8 ``[n, W]``; by: int32 ``[n]``, clipped to ``0 .. W``.
+    """
+    n, w = rows.shape
+    by = jnp.clip(by.astype(jnp.int32), 0, w)
+    x = rows
+    for b in range(max(w, 1).bit_length() - 1, -1, -1):
+        step = 1 << b
+        moved = jnp.pad(x[:, step:], ((0, 0), (0, min(step, x.shape[1]))))
+        x = jnp.where(((by >> b) & 1).astype(bool)[:, None], moved, x)
+        x = x[:, : width + step - 1]  # what shifts under 2^b can still reach
+    if x.shape[1] < width:
+        x = jnp.pad(x, ((0, 0), (0, width - x.shape[1])))
+    return x
+
+
+def _digits(field: jax.Array):
+    d = field.astype(jnp.int32) - ord("0")
+    return d, (d >= 0) & (d <= 9)
+
+
+def _weighted_digits(field: jax.Array, inside: jax.Array):
+    """(the decimal number the ``inside`` columns of ``field`` spell, the
+    last column the units; whether every one of them is a digit)."""
+    d_max = field.shape[-1]
+    if d_max > 9:
+        raise ValueError(f"{d_max} digits do not fit an int32")
+    d, is_digit = _digits(field)
+    weights = jnp.asarray([10 ** (d_max - 1 - j) for j in range(d_max)], jnp.int32)
+    return (jnp.sum(jnp.where(inside, d, 0) * weights, axis=-1),
+            jnp.all(~inside | is_digit, axis=-1))
+
+
+def parse_uint_right(field: jax.Array, n_digits: jax.Array):
+    """A decimal number whose LAST digit stands in ``field``'s last column.
+
+    Args:
+      field: uint8 ``[n, D]`` (D <= 9): the ``D`` bytes that end where the
+        number ends — whatever stands before its first digit is ignored.
+      n_digits: int32 ``[n]``, the number's length.
+    Returns:
+      ``(value int32, ok)``: ok where ``1 <= n_digits <= D`` and every one of
+      them is a digit.
+    """
+    d_max = field.shape[-1]
+    col = jnp.arange(d_max, dtype=jnp.int32)
+    value, digits = _weighted_digits(field, col >= d_max - n_digits[:, None])
+    return value, (n_digits >= 1) & (n_digits <= d_max) & digits
+
+
+def parse_fraction_left(field: jax.Array, n_digits: jax.Array):
+    """The digits after a decimal point, ``field``'s first ``n_digits``
+    columns, in units of ``10 ** -D`` (D = ``field``'s width, at most 9):
+    ``.5`` over six columns is 500,000.  ``n_digits`` 0 is no fraction: 0, ok.
+    """
+    d_max = field.shape[-1]
+    col = jnp.arange(d_max, dtype=jnp.int32)
+    value, digits = _weighted_digits(field, col < n_digits[:, None])
+    return value, (n_digits >= 0) & (n_digits <= d_max) & digits
+
+
+_DATE_WEIGHTS = (10**7, 10**6, 10**5, 10**4, 0, 10**3, 10**2, 0, 10, 1)
+
+
+def parse_date(field: jax.Array, length: jax.Array):
+    """``YYYY-MM-DD`` in ``field``'s first ten columns as the integer
+    ``yyyymmdd`` (dates compare as they do), and whether it IS a date of the
+    proleptic Gregorian calendar (``datetime.date``'s: year 1 .. 9999, the
+    month's own days, leap years by the 4/100/400 rule).
+
+    Args:
+      field: uint8 ``[n, >= 10]``; length: int32 ``[n]``, the field's length.
+    """
+    d, is_digit = _digits(field[:, :10])
+    dash = jnp.asarray([w == 0 for w in _DATE_WEIGHTS])
+    shaped = (length == 10) & jnp.all(
+        jnp.where(dash, field[:, :10] == ord("-"), is_digit), axis=-1)
+    ymd = jnp.sum(jnp.where(dash, 0, d) * jnp.asarray(_DATE_WEIGHTS, jnp.int32), axis=-1)
+    year, month, day = ymd // 10000, (ymd // 100) % 100, ymd % 100
+    leap = (year % 4 == 0) & ((year % 100 != 0) | (year % 400 == 0))
+    thirty = (month == 4) | (month == 6) | (month == 9) | (month == 11)
+    days = jnp.where(month == 2, 28 + leap.astype(jnp.int32), 31 - thirty.astype(jnp.int32))
+    ok = (shaped & (year >= 1) & (month >= 1) & (month <= 12)
+          & (day >= 1) & (day <= days))
+    return ymd, ok
+
+
 def rows_to_strings(rows: np.ndarray) -> list[bytes]:
     """Host-side: NUL-padded uint8 rows -> Python bytes (up to first NUL).
 
@@ -268,24 +398,21 @@ _POW10 = np.array(
 )
 
 
-def render_rank_rows(ranks: np.ndarray) -> bytes | None:
-    """Host-side: a rank vector -> the ``id<TAB>rank<LF>`` bytes of every
-    node in id order, in numpy, with no Python object a row; byte-equal
-    to ``plan.compile.rank_row`` a row.  None where the vector holds what
+def _nine_digits(x: np.ndarray) -> np.ndarray | None:
+    """Non-negative float64 ``[n]`` (n > 0) -> uint8 ``[n, 14]``, each row
+    the number as ``format(x, ".8e")`` spells it (``d.dddddddde-XX``), in
+    numpy with no Python object a row.  None where the vector holds what
     the fixed-width layout cannot spell (a negative, inf, nan, a
-    three-digit exponent): the caller then joins ``rank_row`` a row.
+    three-digit exponent).
 
-    A rank is scaled to a nine-digit integer in float64 (exact for a
+    A number is scaled to a nine-digit integer in float64 (exact for a
     float32 up to 4e-16 relative), rounded, and its digits written by
     division.  Where the scaled value lies within 1e-5 of a half — a few
     rows in a million — the double cannot say which way the exact decimal
     rounds, and that row's fourteen characters come from Python's own
     formatting.
     """
-    x = np.asarray(ranks, dtype=np.float64)
     n = x.shape[0]
-    if n == 0:
-        return b""
     if not np.isfinite(x).all() or x.min() < 0:
         return None
     live = x > 0
@@ -304,14 +431,7 @@ def render_rank_rows(ranks: np.ndarray) -> bytes | None:
     mant[carried] = 100_000_000
     e[carried] += 1
 
-    id_digits = len(str(n - 1))
-    out = np.empty((n, id_digits + _RANK_CHARS + 2), dtype=np.uint8)
-    _write_digits(out, range(id_digits - 1, -1, -1), np.arange(n, dtype=np.uint32))
-    for col in range(id_digits - 1):  # ids are 0 .. n-1: the short ones lead
-        out[:10 ** (id_digits - 1 - col), col] = 0  # NUL, dropped below
-    out[:, id_digits] = ord("\t")
-    out[:, -1] = ord("\n")
-    rank = out[:, id_digits + 1:-1]
+    rank = np.empty((n, _RANK_CHARS), dtype=np.uint8)
     # d.dddddddd: column 1 is the point
     _write_digits(rank, (9, 8, 7, 6, 5, 4, 3, 2, 0), mant)
     ten, zero = np.uint32(10), np.uint32(ord("0"))
@@ -323,6 +443,62 @@ def render_rank_rows(ranks: np.ndarray) -> bytes | None:
     rank[:, 13] = mag % ten + zero
     for i in unsure:
         rank[i] = np.frombuffer(format(x[i], ".8e").encode(), np.uint8)
+    return rank
+
+
+def render_rank_rows(ranks: np.ndarray) -> bytes | None:
+    """Host-side: a rank vector -> the ``id<TAB>rank<LF>`` bytes of every
+    node in id order, in numpy, with no Python object a row; byte-equal
+    to ``plan.compile.rank_row`` a row.  None where the vector holds what
+    the fixed-width layout cannot spell (``_nine_digits``): the caller then
+    joins ``rank_row`` a row.
+    """
+    x = np.asarray(ranks, dtype=np.float64)
+    n = x.shape[0]
+    if n == 0:
+        return b""
+    digits = _nine_digits(x)
+    if digits is None:
+        return None
+    id_digits = len(str(n - 1))
+    out = np.empty((n, id_digits + _RANK_CHARS + 2), dtype=np.uint8)
+    _write_digits(out, range(id_digits - 1, -1, -1), np.arange(n, dtype=np.uint32))
+    for col in range(id_digits - 1):  # ids are 0 .. n-1: the short ones lead
+        out[:10 ** (id_digits - 1 - col), col] = 0  # NUL, dropped below
+    out[:, id_digits] = ord("\t")
+    out[:, -1] = ord("\n")
+    out[:, id_digits + 1:-1] = digits
+    flat = out.ravel()
+    return flat[flat != 0].tobytes()
+
+
+def render_revenue_rows(ips: np.ndarray, averages: np.ndarray,
+                        totals: np.ndarray) -> bytes | None:
+    """Host-side: the join's table -> one ``sourceIP<TAB>avgPageRank<TAB>
+    totalRevenue<LF>`` line a row, both numbers as ``format(x, ".8e")``
+    spells them, in numpy.  None where a number cannot be spelled in the
+    fixed-width layout (``_nine_digits``) or a sourceIP holds a NUL inside:
+    the caller then formats a row at a time.
+
+    Args:
+      ips: uint8 ``[n, W]``, NUL-padded.  averages, totals: float64 ``[n]``.
+    """
+    n = ips.shape[0]
+    if n == 0:
+        return b""
+    if ((ips[:, :-1] == 0) & (ips[:, 1:] != 0)).any():
+        return None
+    avg = _nine_digits(np.asarray(averages, np.float64))
+    total = _nine_digits(np.asarray(totals, np.float64))
+    if avg is None or total is None:
+        return None
+    w = ips.shape[1]
+    out = np.empty((n, w + 2 * _RANK_CHARS + 3), dtype=np.uint8)
+    out[:, :w] = ips
+    out[:, w] = out[:, w + 1 + _RANK_CHARS] = ord("\t")
+    out[:, w + 1:w + 1 + _RANK_CHARS] = avg
+    out[:, w + 2 + _RANK_CHARS:-1] = total
+    out[:, -1] = ord("\n")
     flat = out.ravel()
     return flat[flat != 0].tobytes()
 

@@ -71,6 +71,13 @@ NAMES = {
     "index.d2h": "span",            # index: postings, word keys and offsets brought down (arg bytes)
     "index.render": "span",         # index CLI: the postings' word<TAB>d,d,...<LF> lines made into one buffer from arrays (bytes_ops.render_postings; args words, bytes)
     "index.write": "span",          # index CLI: that buffer written and flushed (arg bytes)
+    "join.read": "span",            # join CLI: the Rankings file read whole and padded to rows (loader.load_rows; args table, bytes, lines), then a PULL of a UserVisits block by the reader thread, the file read as the job goes (cli_apps._visit_blocks; args table, lines)
+    "join.map": "span",             # join: the Rankings blocks launched, or a GROUP of UserVisits blocks — fields split, the date filter, the rows projected and written into the page table / appended to the visit store, which is grown ahead of the group where it must (args table, blocks, rows)
+    "join.h2d": "span",             # join: a block's byte rows handed up (device_put returns at once; arg bytes)
+    "join.probe": "span",           # join: the probe program dispatched — pages and passed visits grouped by hash64(URL), matched by a compare of the full key lanes, regrouped by sourceIP, summed as exact integers, ordered by the total — and waited for (child engine.sync what=join.probe reads the counts; args pages, rows)
+    "join.d2h": "span",             # join: the ordered groups cut to their ladder size and brought down (arg bytes)
+    "join.render": "span",          # join CLI: the sourceIP<TAB>avgPageRank<TAB>totalRevenue<LF> lines made into one buffer from arrays (bytes_ops.render_revenue_rows; args rows, bytes)
+    "join.write": "span",           # join CLI: that buffer written and flushed (arg bytes)
     "sort.mesh.split": "span",      # mesh record sort: sample, gather, splitters and their one sync (args samples, splitters)
     "sort.mesh.exchange": "span",   # mesh record sort: bucket, bin, all-to-all, the bin counts read back (args bin_rows, attempt, worst_bin)
     "sort.mesh.retry": "span",      # mesh record sort: parent of an exchange redone with larger bins (args from_bin_rows, to_bin_rows, worst_bin)
@@ -124,6 +131,15 @@ NAMES = {
     "index.docs": "counter",        # index: runs of equal doc ids over the lines (the documents, for ids that follow the lines)
     "index.dropped_tokens": "counter",  # index: tokens past emits_per_line, whose postings are missing
     "index.grows": "counter",       # index: growth steps the pair store took
+    "join.pages": "counter",        # join: lines of the Rankings file
+    "join.visits": "counter",       # join: lines of the UserVisits file
+    "join.passed": "counter",       # join: well-formed visits whose visitDate lies in the window
+    "join.matched": "counter",      # join: passed visits whose destURL is a page's (passed - matched were dropped: a key on one side only)
+    "join.groups": "counter",       # join: sourceIPs in the table = lines out
+    "join.line_overflow": "counter",  # join CLI: lines of either file past --line-width, cut by the loader
+    "join.key_overflow": "counter", # join: URLs past --key-width (sourceIPs past 16 bytes), joined by their head
+    "join.malformed": "counter",    # join: rows of either file whose fields do not parse, or do not end inside the row; they take no part
+    "join.grows": "counter",        # join: growth steps the visit store took
     "sort.records": "counter",      # record sort: records staged on the device
     "sort.bytes_out": "counter",    # record sort: bytes written to OUT
     "sort.mesh.retries": "counter",          # mesh record sort: exchanges redone because a bin overflowed

@@ -12,6 +12,7 @@ lazily, and jax enters only when a compiled plan actually runs.
 
 from locust_tpu.plan.builders import (  # noqa: F401
     index_plan,
+    join_visits_plan,
     pagerank_plan,
     records_sort_plan,
     tfidf_plan,

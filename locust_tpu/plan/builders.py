@@ -77,3 +77,28 @@ def records_sort_plan(record_bytes: int = RECORD_BYTES,
         node("order", "sort", "by_key", ("records",), key_bytes=key_bytes),
         node("out", "sink", "records", ("order",)),
     ))
+
+
+# HiBench's window (sql/join's query): a year, both ends in.
+DATE_FROM, DATE_TO = "1999-01-01", "2000-01-01"
+
+
+def join_visits_plan(date_from: str = DATE_FROM, date_to: str = DATE_TO) -> Plan:
+    """HiBench's ``sql/join`` — Pavlo et al.'s Join Task: UserVisits
+    filtered by date and joined with Rankings on the URL, regrouped by
+    sourceIP into ``avg(pageRank)`` and ``sum(adRevenue)``, ordered by the
+    sum descending.  Two sources of delimited rows with distinct inputs,
+    and two shuffles in a row on different keys: the join's on the URL,
+    the reduce's on a VALUE of the join's output (apps/join.py)."""
+    return Plan((
+        node("rankings", "source", "delimited", input="rankings"),
+        node("uservisits", "source", "delimited", input="uservisits"),
+        node("visits", "map", "select_visits", ("uservisits",),
+             date_from=date_from, date_to=date_to),
+        node("pages", "map", "select_pages", ("rankings",)),
+        node("ranked", "join", "inner", ("visits", "pages")),
+        node("by_ip", "shuffle", "by_key", ("ranked",)),
+        node("revenue", "reduce", "sum_avg", ("by_ip",)),
+        node("order", "sort", "by_value", ("revenue",)),
+        node("out", "sink", "revenue", ("order",)),
+    ))

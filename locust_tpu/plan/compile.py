@@ -26,6 +26,11 @@ already trusts —
     permuted by the sorted index — no combiner, every record kept;
   * ``join(inner)`` merges two terminal tables on key — a host fold
     over device-built tables, like every other table-level finalize;
+  * ``map(select_visits)`` and ``map(select_pages)`` over two delimited
+    sources, ``join(inner)`` of their keyed ROWS, ``shuffle(by_key) →
+    reduce(sum_avg)`` and ``sort(by_value)`` fuse into the device join of
+    ``apps.join`` (HiBench's sql/join): field split, filter, join on the
+    URL's bytes, regrouping by sourceIP and the order all on the device;
   * ``sink`` renders the terminal value to the EXACT bytes the
     hand-wired CLI drivers print (the byte-identity contract the tests
     pin).
@@ -116,11 +121,17 @@ class CompiledPlan:
             self._stages: dict[str, tuple] = {}
             self._root = self._lower(self._sink.id)
         if cfg is None and any(
-            n.kind == "source" and n.op in ("text", "records")
+            n.kind == "source" and n.op in ("text", "records", "delimited")
             for n in plan.nodes
         ):
             raise PlanError(
-                "a plan with a text or records source needs an EngineConfig"
+                "a plan with a text, records or delimited source needs an "
+                "EngineConfig"
+            )
+        if mesh and any(s[0] == "visit_join" for s in self._stages.values()):
+            raise PlanError(
+                "the rows join has no mesh lowering: one device holds both "
+                "tables whole"
             )
         if mesh and self._needs_mesh_guard():
             raise PlanError(
@@ -190,6 +201,8 @@ class CompiledPlan:
                 f"node {n.id!r}: shuffle must feed a reduce node (the "
                 "engine's one-sort fold groups and combines together)"
             )
+        elif n.kind == "sort" and n.op == "by_value":
+            stage = self._lower_visit_join(n)
         elif n.kind == "sort":
             src_id = self._lower(n.inputs[0])
             src = self._by_id[src_id]
@@ -207,6 +220,13 @@ class CompiledPlan:
                     f"source's record_bytes {record_bytes}"
                 )
             stage = ("record_sort", src_id, record_bytes, key_bytes)
+        elif n.kind == "join" and self._lowered.node_types()[nid] != "table":
+            # Typing lets only shuffle(by_key) consume a rows join, and
+            # only the chain _lower_visit_join matches reach a sink.
+            raise PlanError(  # pragma: no cover - typing owns this
+                f"node {n.id!r}: a join of keyed rows must feed "
+                "shuffle -> reduce(sum_avg) -> sort(by_value)"
+            )
         elif n.kind == "join":
             left = self._lower(n.inputs[0])
             right = self._lower(n.inputs[1])
@@ -227,6 +247,28 @@ class CompiledPlan:
             raise PlanError(f"node {n.id!r}: unknown kind {n.kind!r}")
         self._stages[nid] = stage
         return nid
+
+    def _lower_visit_join(self, order: Node) -> tuple:
+        """``sort(by_value) <- reduce(sum_avg) <- shuffle(by_key) <-
+        join(inner) <- (map(select_visits) <- source, map(select_pages) <-
+        source)``: the whole chain is ONE lowered stage, ``apps.join`` —
+        the typing admits no other producer of any link, so walking the
+        inputs is the match."""
+        by_id = self._by_id
+        reducer = by_id[order.inputs[0]]
+        join = by_id[by_id[reducer.inputs[0]].inputs[0]]
+        visits, pages = (by_id[i] for i in join.inputs)
+        from locust_tpu.plan.builders import DATE_FROM, DATE_TO
+
+        date_from = visits.param("date_from", DATE_FROM)
+        date_to = visits.param("date_to", DATE_TO)
+        if date_from > date_to:
+            raise PlanError(
+                f"node {visits.id!r}: date_from {date_from} lies after "
+                f"date_to {date_to}"
+            )
+        return ("visit_join", self._lower(visits.inputs[0]),
+                self._lower(pages.inputs[0]), date_from, date_to)
 
     # ----------------------------------------------------------- execution
 
@@ -501,6 +543,14 @@ class _RunCtx:
             self._acct[sid] = (out.n_records, False, 0)
         elif kind == "join":
             out = self._eval_join(sid, stage)
+        elif kind == "visit_join":
+            from locust_tpu.apps.join import join_tables
+
+            out = join_tables(
+                self.eval(stage[2]), self.eval(stage[1]), self.cp.cfg,
+                date_from=stage[3], date_to=stage[4],
+            )
+            self._acct[sid] = (len(out), False, 0)
         elif kind == "pagerank":
             out = self._eval_pagerank(sid, stage)
         else:  # pragma: no cover - render handled by run()
@@ -535,6 +585,14 @@ class _RunCtx:
             if isinstance(data, StagedRecords):  # CompiledPlan.load_records
                 return data
             return self.cp._record_sorter().load(data)
+        if n.op == "delimited":
+            # io.loader.load_rows' rows, or an iterator of a file's blocks
+            # (StreamingCorpus): handed on as they are.
+            if isinstance(data, (np.ndarray, collections.abc.Iterator)):
+                return data
+            from locust_tpu.core import bytes_ops
+
+            return bytes_ops.strings_to_rows(list(data), self.cp.cfg.line_width)
         if isinstance(data, collections.abc.Iterator):
             # The corpus as an iterator of host row blocks
             # (io.loader.StreamingCorpus): handed on as rows are, never
@@ -920,6 +978,10 @@ def iter_rendered(op: str, value):
     elif op == "ranks":
         for i in range(value.shape[0]):
             yield rank_row(i, value[i])
+    elif op == "revenue":
+        for ip, avg, total in zip(value.ips, value.averages, value.totals):
+            yield (ip.tobytes().rstrip(b"\0")
+                   + f"\t{avg:.8e}\t{total:.8e}\n".encode())
     elif op == "records":  # the records themselves, a sorted block a row
         for block in value.host_blocks():
             yield memoryview(block)
@@ -934,6 +996,8 @@ def _render(op: str, value) -> bytes:
         return render_ranks(value)
     if op == "postings" and not isinstance(value, dict):
         return render_postings(value)
+    if op == "revenue":
+        return render_revenue(value)
     return b"".join(iter_rendered(op, value))
 
 
@@ -950,6 +1014,21 @@ def render_postings(index, limit: int | None = None) -> bytes:
     out = bytes_ops.render_postings(index.words, index.offsets, index.postings)
     if out is None:
         out = b"".join(iter_rendered("postings", index.to_dict()))
+    return out
+
+
+def render_revenue(joined) -> bytes:
+    """The join's table (``apps.join.Joined``) as the ``revenue`` sink
+    spells it, ``sourceIP<TAB>avgPageRank<TAB>totalRevenue<LF>`` a row, both
+    numbers with nine significant digits: rendered from the arrays
+    (``bytes_ops.render_revenue_rows``), a row at a time only where they
+    hold what the fixed-width layout cannot spell."""
+    from locust_tpu.core import bytes_ops
+
+    out = bytes_ops.render_revenue_rows(
+        joined.ips, joined.averages, joined.totals)
+    if out is None:
+        out = b"".join(iter_rendered("revenue", joined))
     return out
 
 

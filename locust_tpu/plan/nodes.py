@@ -48,26 +48,27 @@ PLAN_VERSION = 1
 # fall off the distributed surface (stale/unknown SOLO_ONLY entries are
 # findings too).
 NODE_KINDS = (
-    "source",   # ingest: corpus text, an edge list or fixed-width records
-    "map",      # per-record transform / emit (or a table-level rescore)
+    "source",   # ingest: corpus text, an edge list, fixed-width records or delimited rows
+    "map",      # per-record transform / emit (or a table-level rescore, or a filter + projection of fields)
     "shuffle",  # group records by key (the Process-stage sort)
     "reduce",   # combine each group into one row
-    "sort",     # order records by key, every one kept (no combiner)
-    "join",     # inner-join two tables on key
+    "sort",     # order records by key (or a table's rows by value), every one kept (no combiner)
+    "join",     # inner-join two tables, or two sets of keyed rows, on key
     "iterate",  # a fixed-point loop over a static structure
     "sink",     # render the terminal table to output bytes
 )
 
 # Operations per kind — the second closed tier under the kind registry.
 NODE_OPS = {
-    "source": ("text", "edges", "records"),
-    "map": ("tokenize_count", "tokenize_pairs", "tfidf_score"),
+    "source": ("text", "edges", "records", "delimited"),
+    "map": ("tokenize_count", "tokenize_pairs", "tfidf_score",
+            "select_visits", "select_pages"),
     "shuffle": ("by_key",),
-    "reduce": ("sum", "collect_docs"),
-    "sort": ("by_key",),
+    "reduce": ("sum", "collect_docs", "sum_avg"),
+    "sort": ("by_key", "by_value"),
     "join": ("inner",),
     "iterate": ("pagerank",),
-    "sink": ("table", "tfidf", "postings", "ranks", "records"),
+    "sink": ("table", "tfidf", "postings", "ranks", "records", "revenue"),
 }
 
 # Dataflow typing: (kind, op) -> [(input types, output type), ...].
@@ -78,26 +79,44 @@ _SIGNATURES = {
     ("source", "text"): (((), "rows"),),
     ("source", "edges"): (((), "edges"),),
     ("source", "records"): (((), "records"),),
+    # Rows of fields apart by ',' (a Hive table's text file, as HiBench declares it).
+    ("source", "delimited"): (((), "field_rows"),),
     ("map", "tokenize_count"): ((("rows",), "emits"),),
     ("map", "tokenize_pairs"): ((("rows",), "pair_emits"),),
     ("map", "tfidf_score"): ((("pair_table",), "scores"),),
+    # UserVisits rows: the first four fields found, the rows whose
+    # visitDate lies in the window kept, (sourceIP, destURL, adRevenue)
+    # projected — keyed by destURL.  Rankings rows: (pageURL, pageRank).
+    ("map", "select_visits"): ((("field_rows",), "visit_rows"),),
+    ("map", "select_pages"): ((("field_rows",), "page_rows"),),
     ("shuffle", "by_key"): (
         (("emits",), "grouped"),
         (("pair_emits",), "grouped_pairs"),
+        # by sourceIP, a VALUE of the join's output: the second shuffle
+        (("ranked_visits",), "grouped_visits"),
     ),
     ("reduce", "sum"): (
         (("grouped",), "table"),
         (("grouped_pairs",), "pair_table"),
     ),
     ("reduce", "collect_docs"): ((("grouped_pairs",), "postings"),),
+    # sum(adRevenue), sum(pageRank) and count a key: a sum and an average
+    ("reduce", "sum_avg"): ((("grouped_visits",), "revenue_table"),),
     ("sort", "by_key"): ((("records",), "sorted_records"),),
-    ("join", "inner"): ((("table", "table"), "table"),),
+    ("sort", "by_value"): ((("revenue_table",), "ordered_revenue"),),
+    ("join", "inner"): (
+        (("table", "table"), "table"),
+        # keyed ROWS, the left side many-to-one: each visit gets its
+        # page's rank; a key on one side only is dropped
+        (("visit_rows", "page_rows"), "ranked_visits"),
+    ),
     ("iterate", "pagerank"): ((("edges",), "ranks"),),
     ("sink", "table"): ((("table",), "output"),),
     ("sink", "tfidf"): ((("scores",), "output"),),
     ("sink", "postings"): ((("postings",), "output"),),
     ("sink", "ranks"): ((("ranks",), "output"),),
     ("sink", "records"): ((("sorted_records",), "output"),),
+    ("sink", "revenue"): ((("ordered_revenue",), "output"),),
 }
 
 # Per-(kind, op) parameter schema: name -> validator returning the
@@ -142,6 +161,18 @@ def _input_name(v):
     return v
 
 
+def _iso_date(v):
+    import datetime
+
+    if not isinstance(v, str) or not re.fullmatch(r"\d{4}-\d{2}-\d{2}", v):
+        raise ValueError(f"must be a date YYYY-MM-DD, got {v!r}")
+    try:
+        datetime.date.fromisoformat(v)
+    except ValueError as e:
+        raise ValueError(f"must be a date of the calendar, got {v!r} ({e})")
+    return v
+
+
 def _join_combine(v):
     if v not in JOIN_COMBINES:
         raise ValueError(f"must be one of {JOIN_COMBINES}, got {v!r}")
@@ -152,6 +183,8 @@ _PARAM_SCHEMA = {
     ("source", "text"): {"lines_per_doc": _pos_int, "input": _input_name},
     ("source", "edges"): {"input": _input_name},
     ("source", "records"): {"record_bytes": _pos_int, "input": _input_name},
+    ("source", "delimited"): {"input": _input_name},
+    ("map", "select_visits"): {"date_from": _iso_date, "date_to": _iso_date},
     ("sort", "by_key"): {"key_bytes": _pos_int},
     ("join", "inner"): {"combine": _join_combine},
     ("iterate", "pagerank"): {"num_iters": _iters, "damping": _damping},
@@ -447,6 +480,15 @@ def _validate(plan: Plan) -> None:
                 f"{in_types} (accepts: "
                 f"{[w for w, _ in _SIGNATURES[(n.kind, n.op)]]})"
             )
+
+    # Two delimited sources are two TABLES: fed one input they would join a
+    # file with itself under the other's schema — loud instead.
+    tables = [n.param("input", "corpus") for n in nodes
+              if n.kind == "source" and n.op == "delimited"]
+    if len(set(tables)) != len(tables):
+        raise PlanError(
+            f"delimited sources must name distinct inputs, got {sorted(tables)}"
+        )
 
     sinks = [n for n in nodes if n.kind == "sink"]
     if len(sinks) != 1:

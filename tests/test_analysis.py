@@ -644,7 +644,8 @@ def test_r009_real_registry_mutation_fails_the_gate(tmp_path):
         "locust_tpu/distributor/master.py",
         "locust_tpu/distributor/worker.py",
         "locust_tpu/cli.py",
-        "locust_tpu/cli_apps.py",       # emits pagerank.read, index.read / .render / .write
+        "locust_tpu/cli_apps.py",       # emits pagerank.read, index.read / .render / .write, join.read / .render / .write
+        "locust_tpu/apps/join.py",      # emits join.map / .h2d / .probe / .d2h and the join.* counters
         "locust_tpu/apps/inverted_index.py",  # emits index.map / .collect and the index.* counters
         "locust_tpu/obs/programs.py",  # emits engine.program.* via span_at
         "locust_tpu/serve/daemon.py",  # emits the serve.* spans/metrics

@@ -79,7 +79,30 @@ def _lowered_text(eng: MapReduceEngine) -> tuple[str, ...]:
     lowered += _toy_mesh_record_sort()[1]
     lowered += (_lowered_pagerank(),)
     lowered += _lowered_index(cfg)
+    lowered += _lowered_join(cfg)
     return tuple(low.as_text() for low in lowered)
+
+
+def _lowered_join(cfg: EngineConfig) -> tuple:
+    """The ``join`` command's five programs at toy shapes: a block, a page
+    table of two blocks, a visit store of four."""
+    from locust_tpu.apps.join import IP_LANES, _build_join_programs
+
+    progs = engine._programs_for(("join", cfg), lambda: _build_join_programs(cfg))
+    lanes = cfg.key_width // 4
+    lines = jax.ShapeDtypeStruct((cfg.block_lines, cfg.line_width), jnp.uint8)
+    table = jax.ShapeDtypeStruct((2 * cfg.block_lines, lanes + 2), jnp.uint32)
+    store = jax.ShapeDtypeStruct((4 * cfg.block_lines, lanes + IP_LANES + 2), jnp.uint32)
+    counts = jax.ShapeDtypeStruct((3,), jnp.int32)
+    fill = jax.ShapeDtypeStruct((), jnp.int32)
+    groups, _ = jax.eval_shape(progs.probe, table, store, fill)
+    return (
+        progs.map_pages.lower(table, counts, lines, fill),
+        progs.map_visits.lower(store, counts, lines, jax.ShapeDtypeStruct((2,), jnp.int32)),
+        progs.grow.lower(store, rows=8 * cfg.block_lines),
+        progs.probe.lower(table, store, fill),
+        progs.cut.lower(groups, rows=cfg.block_lines),
+    )
 
 
 def _lowered_index(cfg: EngineConfig) -> tuple:
@@ -165,7 +188,7 @@ def _toy_mesh_record_sort():
 def program_names() -> dict[str, set[str]]:
     """Module names of the programs the cells run (the default path's
     four, the mesh's step, the record sort's four, the mesh record
-    sort's six, pagerank's one and the index's five), as the
+    sort's six, pagerank's one, the index's five and the join's five), as the
     device trace's ``XLA Modules`` line will show them: of a
     configuration's ``first`` engine, which builds them, and of a later
     one, which takes the process's (``shared``, engine._programs_for) —
@@ -192,6 +215,15 @@ def cli_stderr(tmp_path_factory) -> str:
         said.append(err)
     said.append(_mesh_sort_stderr(path.parent))
     _, err, _ = chip_smoke.run_cli(["index", str(path), "--block-lines", "8"])
+    said.append(err)
+    visits = path.parent / "uservisits.txt"
+    visits.write_bytes(b"".join(
+        b"10.0.0.%d,http://ten/%d,1999-06-0%d,%d.500000,agent,USA,en-us,word,3\n"
+        % (i % 3, i % 4, i % 9 + 1, i) for i in range(10)))
+    ranks = path.parent / "rankings.txt"
+    ranks.write_bytes(b"".join(b"http://ten/%d,%d,7\n" % (i, i + 1) for i in range(4)))
+    table, err, _ = chip_smoke.run_cli(["join", str(ranks), str(visits), "--block-lines", "8"])
+    assert table.count(b"\n") == 3  # three sourceIPs
     said.append(err)
     return "\n".join(said)
 
@@ -246,7 +278,7 @@ def _metric_cases():
             spec = json.load(f)
         by_program = spec["reader"] in (
             "xla_module", "roofline", "roofline_job", "roofline_pagerank_job",
-            "roofline_index_job") or (
+            "roofline_index_job", "roofline_join_job") or (
             spec["reader"] == "roofline_device_job" and "programs" in spec)
         for which in ("first", "shared") if by_program else (None,):
             name = os.path.basename(path)
@@ -278,7 +310,8 @@ def test_layer_metric_reads_a_name_the_program_still_has(path, which, request):
             _assert_patterns_match(spec["programs"], fixture("program_names")[which], "programs")
         else:
             _assert_patterns_match(spec["ops"], fixture("mesh_record_op_names"), "ops")
-    elif reader in ("roofline_job", "roofline_pagerank_job", "roofline_index_job"):
+    elif reader in ("roofline_job", "roofline_pagerank_job", "roofline_index_job",
+                    "roofline_join_job"):
         _assert_patterns_match(spec["programs"], fixture("program_names")[which], "programs")
     elif reader == "roofline":
         names = fixture("program_names")[which]

@@ -376,6 +376,87 @@ def test_index_block_and_collect_compile_at_the_cells_shape(one_chip):
                 + stats.temp_size_in_bytes) < 3 << 30
 
 
+# ``joinvisits.batch``: blocks of 4,096 rows of 256 bytes, keys of 128; the
+# page table of 30 blocks (120,000 lines) and the visit store at the
+# capacity the cell's job ends at (one growth step up from 65,536).
+JOIN_PAGE_ROWS, JOIN_STORE_ROWS = 122_880, 131_072
+
+
+def _join_programs_and_shapes(one_chip):
+    from locust_tpu.apps.join import IP_LANES, _build_join_programs
+
+    cfg = EngineConfig(block_lines=4096, line_width=256, key_width=128)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    lanes = cfg.key_width // 4
+    return (_build_join_programs(cfg), cfg, shape,
+            shape((JOIN_PAGE_ROWS, lanes + 2), jnp.uint32),
+            shape((JOIN_STORE_ROWS, lanes + IP_LANES + 2), jnp.uint32))
+
+
+def test_join_map_programs_compile_at_the_cells_shape(one_chip):
+    """The device-side field parser at the cell's block: the delimiter
+    scan, the barrel shifters, the date and the numbers by static weights,
+    the passed rows' one-operand sort and their row gather — no gather
+    along a row's bytes, nothing held in HBM beside the table it writes
+    into, a few seconds each to compile.  The store's growth step and the
+    result's cut hold no sort."""
+    progs, cfg, shape, table, store = _join_programs_and_shapes(one_chip)
+    lines, counts = shape((cfg.block_lines, cfg.line_width), jnp.uint8), shape((3,), jnp.int32)
+    for lowered, sorts in (
+        (progs.map_pages.lower(table, counts, lines, shape((), jnp.int32)), 0),
+        (progs.map_visits.lower(store, counts, lines, shape((2,), jnp.int32)), 1),
+        (progs.grow.lower(shape((JOIN_STORE_ROWS // 2, store.shape[1]), jnp.uint32),
+                          rows=JOIN_STORE_ROWS), 0),
+        (progs.cut.lower(shape((JOIN_STORE_ROWS, 9), jnp.uint32), rows=1 << 15), 0),
+    ):
+        compiled = lowered.compile()
+        assert compiled.as_text().count(" sort(") == sorts
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_join_probe_sorts_three_operands_and_walks_only_on_a_collision():
+    """The probe's one sort over pages and visits has THREE operands (the
+    two hash words and the tagged row index, all keys: the chip's compiler
+    takes time with the square of them), the regrouping and the order run
+    ``_order_rows``' stable three-operand passes, and the only loop of
+    unknown length is the collision walk."""
+    from locust_tpu.apps.inverted_index import RADIX_KEYS
+    from locust_tpu.apps.join import IP_LANES, _build_join_programs
+
+    cfg = EngineConfig(block_lines=8, line_width=64, key_width=32)
+    lanes = cfg.key_width // 4
+    probe = jax.make_jaxpr(_build_join_programs(cfg).probe)(
+        jnp.zeros((16, lanes + 2), jnp.uint32),
+        jnp.zeros((32, lanes + IP_LANES + 2), jnp.uint32), jnp.int32(5)).jaxpr
+    assert len(_eqns(probe, "while", [])) == 1
+    loops = _eqns(probe, "scan", [])
+    assert [lp.params["length"] for lp in loops] == [(1 + IP_LANES + 1) // RADIX_KEYS, 2]
+    in_loops = []
+    for lp in loops:
+        (inner,) = _eqns(lp.params["jaxpr"].jaxpr, "sort", [])
+        assert len(inner.invars) == RADIX_KEYS + 1 and inner.params["is_stable"]
+        in_loops.append(inner)
+    top = [e for e in _eqns(probe, "sort", []) if not any(e is i for i in in_loops)]
+    assert sorted(len(e.invars) for e in top) == [1, 3]
+    assert [e.params["num_keys"] for e in top if len(e.invars) == 3] == [3]
+
+
+@pytest.mark.slow
+def test_join_probe_compiles_at_the_cells_shape(one_chip):
+    """The probe at the cell's shapes — 253,952 rows of 32 key lanes through
+    one three-operand sort, two key-row gathers and the collision walk, then
+    131,072 rows regrouped: about 75 s on the sandbox's CPU, outside tier-1
+    like every whole-program compile; with operands and temporaries under
+    a tenth of a chip."""
+    progs, _, shape, table, store = _join_programs_and_shapes(one_chip)
+    stats = progs.probe.lower(table, store, shape((), jnp.int32)).compile().memory_analysis()
+    assert (stats.argument_size_in_bytes + stats.output_size_in_bytes
+            + stats.temp_size_in_bytes) < 3 << 29
+
+
 def test_check_kernels_match_chip_smoke():
     """Every kernel chip_smoke.py runs on the chip has a compile case
     above, by name."""

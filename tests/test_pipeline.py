@@ -415,7 +415,7 @@ def test_cli_choices_are_sort_modes():
     assert choices(cli.build_parser()) == SORT_MODES
     for cmd in cli_apps.SUBCOMMANDS:
         parser = cli_apps.build_parser(cmd)
-        if cmd == "sort":  # the record sort has one spelling, and no flag
+        if cmd in ("sort", "join"):  # one spelling each (order_by_lanes; the join's own sorts), and no flag
             assert "--sort-mode" not in parser._option_string_actions
         else:
             assert choices(parser) == SORT_MODES

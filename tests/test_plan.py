@@ -24,6 +24,7 @@ from locust_tpu.plan import (
     from_doc,
     from_json,
     index_plan,
+    join_visits_plan,
     node,
     pagerank_plan,
     records_sort_plan,
@@ -161,8 +162,11 @@ def test_sort_plan_types_and_lowers_onto_the_record_sort():
       node("o", "sort", "by_key", ("r",), key_bytes=0),
       node("k", "sink", "records", ("o",))], "key_bytes"),
     ([node("r", "source", "records"),
-      node("o", "sort", "by_value", ("r",)),
+      node("o", "sort", "by_rank", ("r",)),
       node("k", "sink", "records", ("o",))], "unknown op"),
+    ([node("r", "source", "records"),  # an op from PR 48 on, of a table's rows
+      node("o", "sort", "by_value", ("r",)),
+      node("k", "sink", "records", ("o",))], "cannot consume"),
     ([node("r", "source", "records", width=100),
       node("o", "sort", "by_key", ("r",)),
       node("k", "sink", "records", ("o",))], "unknown param"),
@@ -725,3 +729,110 @@ def test_pagerank_cli_accepts_parity_flags(tmp_path):
     assert proc.returncode == 0, proc.stderr[-800:]
     assert trace.exists()
     assert len(proc.stdout.splitlines()) == 3
+
+
+# ----------------------------------------------------- the rows join (PR 48)
+
+
+def _join_nodes(**override):
+    """``join_visits_plan``'s nodes, one of them replaced."""
+    nodes = {n.id: n for n in join_visits_plan().nodes}
+    nodes.update(override)
+    return tuple(nodes.values())
+
+
+def test_join_visits_plan_types_round_trips_and_stays_solo():
+    """Two delimited sources with distinct inputs, two shuffles in a row on
+    different keys: the signatures validate, the plan round-trips through
+    JSON, and the distributed planner keeps it on the solo path by a NAMED
+    refusal (its sink is fed by a sort, a SOLO_ONLY kind)."""
+    from locust_tpu.plan.distribute import plan_shape
+
+    plan = join_visits_plan()
+    assert plan.node_types() == {
+        "rankings": "field_rows", "uservisits": "field_rows",
+        "visits": "visit_rows", "pages": "page_rows", "ranked": "ranked_visits",
+        "by_ip": "grouped_visits", "revenue": "revenue_table",
+        "order": "ordered_revenue", "out": "output",
+    }
+    again = from_json(plan.canonical_json())
+    assert again == plan and again.fingerprint() == plan.fingerprint()
+    assert join_visits_plan("1999-01-01", "1999-06-30").fingerprint() != plan.fingerprint()
+    assert plan_shape(plan) == (None, "solo_only_kind")
+
+
+def test_join_inner_keeps_its_two_signatures_apart():
+    """``join(inner)`` over two TABLES is the host fold it was; over keyed
+    ROWS it is the device join.  Neither takes the other's inputs."""
+    from locust_tpu.plan.nodes import _SIGNATURES
+
+    assert _SIGNATURES[("join", "inner")] == (
+        (("table", "table"), "table"),
+        (("visit_rows", "page_rows"), "ranked_visits"),
+    )
+    with pytest.raises(PlanError, match="cannot consume"):  # the sides swapped
+        Plan(_join_nodes(ranked=node("ranked", "join", "inner", ("pages", "visits"))))
+    with pytest.raises(PlanError, match="cannot consume"):  # a table sink on rows
+        Plan(_join_nodes(out=node("out", "sink", "table", ("order",))))
+    with pytest.raises(PlanError, match="cannot consume"):  # the order skipped
+        Plan(tuple(n for n in _join_nodes(out=node("out", "sink", "revenue", ("revenue",)))
+                   if n.id != "order"))
+
+
+def test_two_delimited_sources_with_one_input_are_refused():
+    with pytest.raises(PlanError, match="distinct inputs"):
+        Plan(_join_nodes(uservisits=node("uservisits", "source", "delimited",
+                                         input="rankings")))
+    with pytest.raises(PlanError, match="distinct inputs"):  # both at the default
+        Plan(_join_nodes(rankings=node("rankings", "source", "delimited"),
+                         uservisits=node("uservisits", "source", "delimited")))
+
+
+@pytest.mark.parametrize("params, wrong", [
+    (dict(date_from="1999-1-1"), "must be a date YYYY-MM-DD"),
+    (dict(date_to="1999-02-30"), "a date of the calendar"),
+    (dict(date_from=19990101), "must be a date YYYY-MM-DD"),
+    (dict(window="1999"), "unknown param"),
+])
+def test_select_visits_params_are_validated(params, wrong):
+    with pytest.raises(PlanError, match=wrong):
+        Plan(_join_nodes(visits=node("visits", "map", "select_visits", ("uservisits",), **params)))
+
+
+def test_the_rows_join_refuses_what_it_does_not_lower():
+    with pytest.raises(PlanError, match="unknown param"):  # ',' is the format, no option
+        Plan(_join_nodes(rankings=node("rankings", "source", "delimited",
+                                       input="rankings", delimiter="|")))
+    with pytest.raises(PlanError, match="lies after"):
+        compile_plan(join_visits_plan("2000-01-02", "2000-01-01"), EngineConfig())
+    with pytest.raises(PlanError, match="needs an EngineConfig"):
+        compile_plan(join_visits_plan())
+    with pytest.raises(PlanError, match="no mesh lowering"):
+        compile_plan(join_visits_plan(), EngineConfig(), mesh=True)
+
+
+def test_join_visits_plan_runs_onto_the_device_join():
+    """The whole chain is one lowered stage; its sink renders the table the
+    plain reference prints, from lists of lines as from padded rows."""
+    from locust_tpu import join_reference
+    from locust_tpu.core import bytes_ops
+
+    pages = [b"http://a,10,1", b"http://b,30,1", b"http://c,50,1"]
+    visits = [b"1.1.1.1,http://a,1999-03-03,1.500000,x", b"1.1.1.1,http://b,1999-03-04,2.250000,x",
+              b"2.2.2.2,http://b,2000-01-01,9.000000,x", b"2.2.2.2,http://b,2000-01-02,9.000000,x",
+              b"3.3.3.3,http://z,1999-03-03,4.000000,x"]
+    cfg = EngineConfig(block_lines=4, line_width=64, key_width=32)
+    compiled = compile_plan(join_visits_plan(), cfg)
+    assert [ln.split(":")[1].split("(")[0].strip() for ln in compiled.explain().splitlines()] == [
+        "source", "source", "visit_join", "render"]
+    want = join_reference.render(join_reference.join(pages, visits).rows)
+    assert want == b"2.2.2.2\t3.00000000e+01\t9.00000000e+00\n1.1.1.1\t2.00000000e+01\t3.75000000e+00\n"
+    res = compiled.run({"rankings": pages, "uservisits": visits})
+    assert res.output == want and res.distinct == 2 and not res.truncated
+    rows = {name: bytes_ops.strings_to_rows(lines, 64)
+            for name, lines in (("rankings", pages), ("uservisits", visits))}
+    assert compiled.run(rows).output == want
+    with pytest.raises(PlanError, match="no input named"):
+        compiled.run({"rankings": pages})
+    with pytest.raises(PlanError, match="distinct inputs"):
+        compiled.run_corpus(b"one corpus\n")
