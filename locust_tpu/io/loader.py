@@ -416,13 +416,14 @@ class StreamingCorpus:
     bounded memory.
 
     ``load_rows`` materializes the whole corpus — fine for hamlet, fatal
-    for the 1GB+ north star (BASELINE.json).  This reader holds one
-    ``chunk_bytes`` window plus one carried partial line at a time, the
-    streaming upgrade of the reference's whole-file ``loadFile`` slicing
-    (reference MapReduce/src/main.cu:40-64).  Uses the native windowed
-    scanner (native/ingest.cpp ``ingest_load_window``) when built, else a
-    pure-Python chunked read; both honor the ``[line_start, line_end)``
-    node-shard slice.
+    for the 1GB+ north star (BASELINE.json).  This reader holds a block
+    at a time, the streaming upgrade of the reference's whole-file
+    ``loadFile`` slicing (reference MapReduce/src/main.cu:40-64).  Uses
+    the native windowed scanner (native/ingest.cpp: the file opened once,
+    ``ingest_window`` a block) when built and the path is a regular file,
+    else a pure-Python chunked read that holds one ``chunk_bytes`` window
+    plus one carried partial line; both honor the ``[line_start,
+    line_end)`` node-shard slice.
 
     A line longer than ``chunk_bytes`` is truncated to ``line_width``
     (the device contract anyway) and its remainder skipped — progress is
@@ -507,11 +508,14 @@ class StreamingCorpus:
                 data = carry + chunk
                 lines = data.split(b"\n")
                 carry = lines.pop()  # partial (or empty) trailing piece
-                if len(carry) > self.line_width:
+                if len(carry) > self.line_width + 1:
                     # Keep only the prefix the device can see (the row is
                     # truncated to line_width anyway); bounds memory for
                     # pathologically long lines while the rest streams past.
-                    carry = carry[: self.line_width]
+                    # ONE byte more than the row: cut to the row itself, a
+                    # '\r' at the cut would become the line's last byte
+                    # and be stripped as a CRLF's, where it is data.
+                    carry = carry[: self.line_width + 1]
                 for ln in lines:
                     if end is not None and line_no >= end:
                         break

@@ -81,9 +81,13 @@ def _load() -> ctypes.CDLL:
                 ctypes.c_long,
                 ctypes.c_long,
             ]
-            lib.ingest_load_window.restype = ctypes.c_long
-            lib.ingest_load_window.argtypes = [
-                ctypes.c_char_p,
+            lib.ingest_open.restype = ctypes.c_void_p
+            lib.ingest_open.argtypes = [ctypes.c_char_p]
+            lib.ingest_close.restype = None
+            lib.ingest_close.argtypes = [ctypes.c_void_p]
+            lib.ingest_window.restype = ctypes.c_long
+            lib.ingest_window.argtypes = [
+                ctypes.c_void_p,
                 ctypes.POINTER(ctypes.c_long),
                 ctypes.POINTER(ctypes.c_long),
                 ctypes.POINTER(ctypes.c_ubyte),
@@ -173,7 +177,8 @@ def load_rows(
     start = max(line_start, 0) if line_start >= 0 else 0
     end = total if line_end < 0 else min(line_end, total)
     n_rows = max(end - start, 0)
-    out = np.zeros((n_rows, line_width), dtype=np.uint8)
+    # The scanner writes every byte of the rows it returns.
+    out = np.empty((n_rows, line_width), dtype=np.uint8)
     if n_rows == 0:
         return out
     wrote = lib.ingest_load_rows(
@@ -263,33 +268,43 @@ def iter_blocks(
     line_end: int = -1,
 ):
     """Yield ``[<=block_lines, line_width]`` row blocks via the native
-    windowed scanner (bounded memory; see ingest.cpp ingest_load_window).
-    Every block is a fresh array: the default path keeps a group of them
-    queued ahead of the device (``engine.timed_run``)."""
+    windowed scanner: the file opened ONCE (``ingest_open``: one
+    descriptor and one 1 MB read buffer), a window of it scanned a block
+    (``ingest_window``), closed when the generator ends or is closed —
+    nothing of the file outlives the consumer.  A path that is no regular
+    file (a FIFO, ``/dev/stdin``) is an ``OSError`` before the first
+    block.  Every block is a fresh array: the default path keeps a group
+    of them queued ahead of the device (``engine.timed_run``)."""
     lib = _load()
+    handle = lib.ingest_open(str(path).encode())
+    if not handle:
+        raise OSError(f"native ingest cannot open {path!r}")
     offset = ctypes.c_long(0)
     line_no = ctypes.c_long(0)
-    while True:
-        # The scanner zero-fills ``out`` itself before it writes a line.
-        out = np.empty((block_lines, line_width), dtype=np.uint8)
-        wrote = lib.ingest_load_window(
-            str(path).encode(),
-            ctypes.byref(offset),
-            ctypes.byref(line_no),
-            out.ctypes.data_as(ctypes.POINTER(ctypes.c_ubyte)),
-            block_lines,
-            line_width,
-            line_start,
-            line_end,
-        )
-        if wrote < 0:
-            raise OSError(f"native ingest failed to read {path!r}")
-        if wrote == 0:
-            return
-        if wrote < block_lines:
-            # A window comes back short only at the end of the file or of
-            # the slice: the call that would find nothing left is spared
-            # (a third of a two-block job's reads).
-            yield out[:wrote]
-            return
-        yield out
+    try:
+        while True:
+            # The scanner writes every byte of ``out``.
+            out = np.empty((block_lines, line_width), dtype=np.uint8)
+            wrote = lib.ingest_window(
+                handle,
+                ctypes.byref(offset),
+                ctypes.byref(line_no),
+                out.ctypes.data_as(ctypes.POINTER(ctypes.c_ubyte)),
+                block_lines,
+                line_width,
+                line_start,
+                line_end,
+            )
+            if wrote < 0:
+                raise OSError(f"native ingest failed to read {path!r}")
+            if wrote == 0:
+                return
+            if wrote < block_lines:
+                # A window comes back short only at the end of the file or
+                # of the slice: the call that would find nothing left is
+                # spared (a third of a two-block job's reads).
+                yield out[:wrote]
+                return
+            yield out
+    finally:
+        lib.ingest_close(handle)

@@ -2,41 +2,138 @@
 //
 // TPU-native equivalent of the reference's host ingest (loadFile,
 // reference MapReduce/src/main.cu:40-64): the reference reads with a
-// getline loop into 204-byte structs; here one buffered read + a single
-// scan splits lines and pads them straight into the caller's contiguous
-// [max_lines, width] uint8 buffer, which the Python side hands to
-// jnp.asarray with zero further copies.  Honors the same [line_start,
-// line_end) node-shard slice (main.cu:47-54) and fixes the reference's
-// dropped-final-line off-by-one (SURVEY.md Q1).
+// getline loop into 204-byte structs; here the file is opened ONCE, read
+// by pread a buffer at a time at the scan's own offset, and ONE line loop
+// (scan_lines) finds each line's end with memchr and copies it straight
+// into the caller's contiguous [max_lines, width] uint8 buffer, which the
+// Python side hands to jnp.asarray with zero further copies.  Honors the
+// same [line_start, line_end) node-shard slice (main.cu:47-54) and fixes
+// the reference's dropped-final-line off-by-one (SURVEY.md Q1).
+//
+// pread and not a mapping: on the chip hosts' sandboxed kernel a page of a
+// mapped memory file costs 7 us to fault in, so the mapped scan of a 180 MB
+// file took 0.31 s where this one takes 0.05 (PERF.md section 6, PR 49).
 //
 // Exposed via a C ABI for ctypes (no pybind11 in this toolchain).
 
+#include <climits>
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
 
+#include <fcntl.h>
+#include <sys/stat.h>
+#include <unistd.h>
+
 namespace {
 
-// Reads the whole file; returns malloc'd buffer (caller frees) or nullptr.
-char* read_file(const char* path, long* size_out) {
-  FILE* f = std::fopen(path, "rb");
-  if (!f) return nullptr;
-  std::fseek(f, 0, SEEK_END);
-  long size = std::ftell(f);
-  std::fseek(f, 0, SEEK_SET);
-  char* buf = static_cast<char*>(std::malloc(size > 0 ? size : 1));
-  if (!buf) {
-    std::fclose(f);
-    return nullptr;
+// What a Source reads at a time.  A line longer than this keeps its first
+// `width` bytes while the rest streams past, so a row is narrower than it.
+const long kBuffer = 1 << 20;
+
+// A regular file behind one descriptor: buf holds its bytes
+// [base, base + got), and at_eof says that they reach the file's end.
+struct Source {
+  int fd;
+  unsigned char* buf;
+  long base, got;
+  bool at_eof;
+};
+
+// Opens `path`; false for anything that is no regular file — no such file,
+// a FIFO, a terminal, a device — which the callers report as their I/O
+// error, and io/loader answers with its Python reader.  Asked by stat
+// BEFORE the open: a FIFO opened and closed here would take its writer's
+// reader away (EPIPE) before the Python reader opens it.
+bool open_source(const char* path, Source* s) {
+  struct stat st;
+  if (stat(path, &st) != 0 || !S_ISREG(st.st_mode)) return false;
+  const int fd = open(path, O_RDONLY | O_CLOEXEC);
+  if (fd < 0) return false;
+  *s = {fd, static_cast<unsigned char*>(std::malloc(kBuffer)), 0, 0, false};
+  if (s->buf) return true;
+  close(fd);
+  return false;
+}
+
+void close_source(const Source& s) {
+  close(s.fd);
+  std::free(s.buf);
+}
+
+// Fills the buffer from file offset `off` on; false on a read error.
+bool fill(Source* s, long off) {
+  s->base = off;
+  s->got = 0;
+  s->at_eof = false;
+  while (s->got < kBuffer && !s->at_eof) {
+    const ssize_t n = pread(s->fd, s->buf + s->got,
+                            static_cast<size_t>(kBuffer - s->got), off + s->got);
+    if (n < 0) return false;
+    s->at_eof = n == 0;
+    s->got += n;
   }
-  long got = static_cast<long>(std::fread(buf, 1, size, f));
-  std::fclose(f);
-  if (got != size) {
-    std::free(buf);
-    return nullptr;
+  return true;
+}
+
+// THE line loop, under every entry point that splits lines.  From byte
+// *pos (a line's first) and line number *line of the file: a line ends at
+// its '\n' or at the end of the file (a trailing fragment without a
+// newline is a line — the Q1 fix); the lines numbered in [start, end)
+// (either < 0 = unbounded) are kept, up to max_rows of them.  A kept line
+// goes to its row of out[max_rows][width]: ONE trailing '\r' stripped
+// (CRLF), then cut to `width`, the rest of the row zeroed — so a '\r' is
+// dropped only where it is the line's true last byte and lies inside the
+// row; at the cut of an over-long line it is data.  With out == nullptr
+// the kept lines are only counted.  Stops at a line boundary — max_rows
+// kept, line `end` reached, or the file's end — and leaves both cursors
+// there.  Returns the lines kept, or -1 on a read error.
+long scan_lines(Source* s, long* pos, long* line, unsigned char* out,
+                long max_rows, long width, long start, long end) {
+  if (width >= kBuffer) return -1;
+  long p = *pos, n = *line, row = 0;
+  while (end < 0 || n < end) {
+    const bool want = n >= start;
+    if (want && row >= max_rows) break;
+    if (p < s->base || p >= s->base + s->got) {
+      if (s->at_eof && p >= s->base) break;  // the file's end
+      if (!fill(s, p)) return -1;
+      if (s->got == 0) break;
+    }
+    const unsigned char* at = s->buf + (p - s->base);
+    long left = s->base + s->got - p;
+    const void* lf = std::memchr(at, '\n', static_cast<size_t>(left));
+    if (!lf && !s->at_eof && p > s->base) {
+      // Cut by the buffer's end: once more, from the line's first byte.
+      if (!fill(s, p)) return -1;
+      at = s->buf;
+      left = s->got;
+      lf = std::memchr(at, '\n', static_cast<size_t>(left));
+    }
+    // The line's bytes in the buffer: all of them, or — of a line longer
+    // than the buffer — more than a row keeps.
+    long len = lf ? static_cast<const unsigned char*>(lf) - at : left;
+    p += lf ? len + 1 : len;
+    if (want) {
+      if (out) {
+        if (len > 0 && at[len - 1] == '\r') --len;
+        if (len > width) len = width;
+        unsigned char* dst = out + row * width;
+        std::memcpy(dst, at, static_cast<size_t>(len));
+        std::memset(dst + len, 0, static_cast<size_t>(width - len));
+      }
+      ++row;
+    }
+    ++n;
+    while (!lf && !s->at_eof) {  // the rest of such a line streams past
+      if (!fill(s, p)) return -1;
+      lf = std::memchr(s->buf, '\n', static_cast<size_t>(s->got));
+      p += lf ? static_cast<const unsigned char*>(lf) - s->buf + 1 : s->got;
+    }
   }
-  *size_out = size;
-  return buf;
+  *pos = p;
+  *line = n;
+  return row;
 }
 
 }  // namespace
@@ -46,143 +143,64 @@ extern "C" {
 // Number of lines in the file ('\n'-separated; a trailing fragment without
 // a newline counts — the Q1 fix).  Returns -1 on I/O error.
 long ingest_count_lines(const char* path) {
-  long size = 0;
-  char* buf = read_file(path, &size);
-  if (!buf) return -1;
-  long lines = 0;
-  bool in_line = false;
-  for (long i = 0; i < size; ++i) {
-    if (buf[i] == '\n') {
-      ++lines;
-      in_line = false;
-    } else {
-      in_line = true;
-    }
-  }
-  if (in_line) ++lines;
-  std::free(buf);
+  Source s;
+  if (!open_source(path, &s)) return -1;
+  long pos = 0, line = 0;
+  const long lines = scan_lines(&s, &pos, &line, nullptr, LONG_MAX, 0, -1, -1);
+  close_source(s);
   return lines;
 }
 
 // Load lines [line_start, line_end) into out[max_lines][width], NUL-padded,
-// '\r' stripped at line end, content truncated to width.  Negative
+// '\r' stripped at line end, content truncated to width; every byte of the
+// rows it returns is written, so `out` needs no zeroing first.  Negative
 // start/end mean "whole file" (reference CLI default, main.cu:369-374).
 // Returns rows written, or -1 on I/O error.
 long ingest_load_rows(const char* path, unsigned char* out, long max_lines,
                       long width, long line_start, long line_end) {
-  long size = 0;
-  char* buf = read_file(path, &size);
-  if (!buf) return -1;
-  long start = line_start < 0 ? 0 : line_start;
-  long end = line_end < 0 ? -1 : line_end;  // -1 = unbounded
-
-  std::memset(out, 0, static_cast<size_t>(max_lines) * width);
-  long line = 0, row = 0;
-  long pos = 0;
-  while (pos <= size - 1 || (pos == 0 && size == 0)) {
-    if (pos >= size) break;
-    // Find line extent [pos, eol).
-    long eol = pos;
-    while (eol < size && buf[eol] != '\n') ++eol;
-    if (line >= start && (end < 0 || line < end) && row < max_lines) {
-      long len = eol - pos;
-      if (len > 0 && buf[pos + len - 1] == '\r') --len;  // CRLF
-      if (len > width) len = width;
-      std::memcpy(out + row * width, buf + pos, len);
-      ++row;
-    }
-    ++line;
-    pos = eol + 1;
-    if (end >= 0 && line >= end) break;
-  }
-  std::free(buf);
-  return row;
+  Source s;
+  if (!open_source(path, &s)) return -1;
+  long pos = 0, line = 0;
+  const long rows = scan_lines(&s, &pos, &line, out, max_lines, width,
+                               line_start, line_end);
+  close_source(s);
+  return rows;
 }
 
-// Streaming window scan: resume at byte *inout_offset / line *inout_line,
-// fill out[max_lines][width] (NUL-padded, '\r' stripped, truncated to
-// width), honoring the [line_start, line_end) slice.  Advances the two
-// cursors to the exact resume point (always a line boundary) and returns
-// rows written — 0 means EOF or slice end.  Unlike ingest_load_rows, the
-// file is NEVER materialized: one fixed 1MB read buffer regardless of
-// file or line length (a line longer than the buffer keeps only its first
-// `width` bytes while the remainder streams past), which is what lets the
-// 1GB+ north-star corpus (BASELINE.json) run in bounded RSS.
-long ingest_load_window(const char* path, long* inout_offset,
-                        long* inout_line, unsigned char* out, long max_lines,
-                        long width, long line_start, long line_end) {
-  FILE* f = std::fopen(path, "rb");
-  if (!f) return -1;
-  if (std::fseek(f, *inout_offset, SEEK_SET) != 0) {
-    std::fclose(f);
-    return -1;
-  }
-  const long start = line_start < 0 ? 0 : line_start;
-  const long end = line_end;  // < 0 = unbounded
-  long line = *inout_line;
-  long row = 0;
-  long consumed = 0;  // bytes folded into COMPLETED (or EOF-final) lines
-  long linelen = 0;   // bytes seen of the in-progress line
-  std::memset(out, 0, static_cast<size_t>(max_lines) * width);
+// The streaming reader's file: opened by ingest_open, scanned a window at
+// a time by ingest_window, closed by ingest_close.  The file is never
+// materialized: one fixed 1 MB read buffer regardless of file or line
+// length, which is what lets the 1GB+ north-star corpus (BASELINE.json)
+// run in bounded RSS.  nullptr where the path is no regular file that
+// opens (open_source).
+void* ingest_open(const char* path) {
+  Source s;
+  if (!open_source(path, &s)) return nullptr;
+  return new Source(s);
+}
 
-  const long B = 1 << 20;
-  unsigned char* buf = static_cast<unsigned char*>(std::malloc(B));
-  if (!buf) {
-    std::fclose(f);
-    return -1;
-  }
-  bool done = false;
-  bool in_line = false;
-  while (!done) {
-    long got = static_cast<long>(std::fread(buf, 1, B, f));
-    if (got <= 0) break;  // EOF
-    for (long i = 0; i < got; ++i) {
-      const bool want = line >= start && (end < 0 || line < end);
-      if (end >= 0 && line >= end) {
-        done = true;
-        break;
-      }
-      if (!in_line && want && row >= max_lines) {
-        done = true;  // capacity reached at a line boundary: resume here
-        break;
-      }
-      const unsigned char c = buf[i];
-      ++consumed;
-      if (c == '\n') {
-        if (want) {
-          long len = linelen < width ? linelen : width;
-          // Strip the CRLF '\r' only when it actually is the line's last
-          // byte; at a truncated position (linelen > width) it is data.
-          if (linelen <= width && len > 0 &&
-              out[row * width + len - 1] == '\r')
-            out[row * width + len - 1] = 0;
-          ++row;
-        }
-        ++line;
-        linelen = 0;
-        in_line = false;
-      } else {
-        in_line = true;
-        if (want && linelen < width) out[row * width + linelen] = c;
-        ++linelen;
-      }
-    }
-  }
-  if (in_line && !done) {  // trailing fragment without '\n' (Q1 fix)
-    const bool want = line >= start && (end < 0 || line < end);
-    if (want && row < max_lines) {
-      long len = linelen < width ? linelen : width;
-      if (linelen <= width && len > 0 && out[row * width + len - 1] == '\r')
-        out[row * width + len - 1] = 0;
-      ++row;
-    }
-    ++line;
-  }
-  std::free(buf);
-  std::fclose(f);
-  *inout_offset += consumed;
-  *inout_line = line;
-  return row;
+void ingest_close(void* handle) {
+  Source* s = static_cast<Source*>(handle);
+  close_source(*s);
+  delete s;
+}
+
+// One window: resume at byte *inout_offset / line *inout_line, fill
+// out[max_lines][width] (scan_lines' rows), honoring the [line_start,
+// line_end) slice.  Advances the two cursors to the exact resume point
+// (always a line boundary) and returns rows written — 0 means EOF or
+// slice end, -1 a read error.  The rows past a short window's last line
+// are zeroed: every byte of `out` is written by every call.
+long ingest_window(void* handle, long* inout_offset, long* inout_line,
+                   unsigned char* out, long max_lines, long width,
+                   long line_start, long line_end) {
+  const long rows =
+      scan_lines(static_cast<Source*>(handle), inout_offset, inout_line, out,
+                 max_lines, width, line_start, line_end);
+  if (rows >= 0)
+    std::memset(out + rows * width, 0,
+                static_cast<size_t>(max_lines - rows) * width);
+  return rows;
 }
 
 // Single-pass streaming caps measure: max token bytes + max tokens/line

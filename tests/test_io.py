@@ -245,6 +245,199 @@ def test_cr_semantics_canonical(tmp_path, use_native):
     assert got == [ln[:w] for ln in want]
 
 
+# ------------------------------------------ one line loop, three readers
+
+_W = 8  # the row width of every case below
+
+
+def _soup(seed: int, pieces: int) -> bytes:
+    rng = np.random.default_rng(seed)
+    parts = [b"\n", b"\r", b"\r\n", b"\x00", b"a", b"bc", b" ", b"q" * (_W - 1),
+             b"q" * _W, b"q" * (_W + 1)]
+    return b"".join(parts[i] for i in rng.integers(0, len(parts), pieces))
+
+
+# name -> the file's bytes: what the three readers must agree on, byte for
+# byte (PR 49: the native scanner finds its lines with memchr in a 1 MB
+# buffer it fills by pread; ``_iter_python`` and the Python ``load_rows``
+# are the oracles).
+_FILL = (b"x" * 63 + b"\n") * 16383  # 64 bytes short of the scanner's buffer
+SCANNER_CASES = {
+    "crlf": b"a\r\nbb\r\n\r\nccc\r\n",
+    "cr_twice_and_alone": b"a\rb\ntwo\r\r\n\r\n\r",
+    # '\r' one byte before the cut, exactly at it (the row's last byte),
+    # and the first byte past it — of lines that go on: data, every time.
+    "cr_before_the_cut": b"x" * (_W - 2) + b"\ryyyy\nz\n",
+    "cr_at_the_cut": b"x" * (_W - 1) + b"\ryyyy\nz\n",
+    "cr_past_the_cut": b"x" * _W + b"\ryyyy\nz\n",
+    # ... and as the line's true last byte there: a CRLF's, stripped.
+    "crlf_at_the_cut": b"x" * (_W - 1) + b"\r\n" + b"x" * _W + b"\r\n"
+                       + b"x" * (_W + 1) + b"\r\nz\r\n",
+    "width_exactly": b"x" * _W + b"\n" + b"y" * (_W + 1) + b"\n" + b"z" * (_W - 1) + b"\n",
+    "over_a_megabyte": b"ab\n" + b"L" * ((1 << 20) + 17) + b"\ncd\n"
+                       + b"M" * ((1 << 20) + 3) + b"\r\nef",
+    # the Python reader's 64 KB test chunk ends INSIDE this line, right
+    # before its LF: its carried prefix must not end in the cut's '\r'
+    "cr_at_the_cut_of_a_carried_line": b"x" * (_W - 1) + b"\r" + b"y" * ((1 << 16) - _W)
+                                       + b"\nrest\n",
+    # the native reader's 1 MB buffer ends INSIDE a line, between a CRLF's
+    # two bytes, and right after a line's LF
+    "a_line_across_the_buffers_end": _FILL + b"y" * 100 + b"\r\nzz\n",
+    "a_crlf_across_the_buffers_end": _FILL + b"y" * 63 + b"\r\nzz\r\n",
+    "an_lf_at_the_buffers_end": _FILL + b"y" * 63 + b"\nzz",
+    "empty_lines": b"\n\na\n\n\nb\n\n",
+    "lfs_only": b"\n" * 7,
+    "no_lf_at_the_end": b"a\nbb\nccc",
+    "no_lf_and_a_cr_at_the_end": b"a\nbb\r",
+    "one_line_no_lf": b"alone",
+    "empty_file": b"",
+    "nul_bytes": b"a\x00b\n\x00\n\x00\x00c\r\n" + b"\x00" * (_W + 2) + b"\nd",
+    "soup_1": _soup(1, 300),
+    "soup_2": _soup(2, 300),
+}
+
+
+@pytest.fixture(params=["file", "memfd"])
+def scanner_path(request, tmp_path):
+    """A way to hand the readers some bytes by a path: a file, or an
+    anonymous memory file by its ``/proc/self/fd/N`` (how the benchmark's
+    drivers hand the CLI its input)."""
+    import os
+
+    fds = []
+
+    def put(data: bytes) -> str:
+        if request.param == "file":
+            p = tmp_path / "scanned.txt"
+            p.write_bytes(data)
+            return str(p)
+        if not hasattr(os, "memfd_create") or not os.path.isdir("/proc/self/fd"):
+            pytest.skip("no os.memfd_create / no /proc/self/fd here")
+        fd = os.memfd_create("scanned")
+        fds.append(fd)
+        os.write(fd, data)
+        return f"/proc/self/fd/{fd}"
+
+    yield put
+    for fd in fds:
+        os.close(fd)
+
+
+@pytest.mark.parametrize("case", SCANNER_CASES)
+def test_the_native_scanner_is_the_python_readers_byte_for_byte(case, scanner_path):
+    """Native window reader == ``_iter_python`` == ``load_rows`` (native
+    and Python), block for block and byte for byte, at ``block_lines`` of
+    1, of 3 and of more lines than the file, over EVERY ``[line_start,
+    line_end)`` of a small file — so each starts and ends inside a block,
+    on its edge, and past the file — and ``count_lines`` by the split."""
+    from locust_tpu.io import native_ingest
+
+    if not native_ingest.available():
+        pytest.skip("native build unavailable")
+    data = SCANNER_CASES[case]
+    path = scanner_path(data)
+    n = len(data.split(b"\n")) - (data == b"" or data.endswith(b"\n"))
+    assert native_ingest.count_lines(path) == n
+    assert loader.count_lines(path) == n
+    edges = range(-1, n + 2) if n <= 8 else (-1, 0, 1, 2, 3, n // 2, n - 1, n, n + 3)
+    sizes = (1, 3, n + 5)
+    if n > 1000:  # a megabyte of short lines: fewer, larger readings
+        edges, sizes = (-1, n - 3, n - 1, n + 3), (4096, n + 5)
+    for block_lines in sizes:
+        for start in edges:
+            for end in edges:
+                what = f"{case}: block_lines {block_lines}, [{start}, {end})"
+                want = loader.load_rows(path, _W, start, end, use_native=False)
+                got = native_ingest.load_rows(path, _W, start, end)
+                assert got.shape == want.shape and np.array_equal(got, want), what
+                py = list(loader.StreamingCorpus(
+                    path, _W, block_lines, start, end, chunk_bytes=1 << 16,
+                    use_native=False))
+                nat = list(native_ingest.iter_blocks(path, _W, block_lines, start, end))
+                assert [b.shape for b in nat] == [b.shape for b in py], what
+                assert all(np.array_equal(a, b) for a, b in zip(nat, py)), what
+                rows = np.concatenate(nat) if nat else np.zeros((0, _W), np.uint8)
+                assert np.array_equal(rows, want), what
+
+
+def _descriptors_of(path: str) -> int:
+    import os
+
+    real, n = os.path.realpath(path), 0
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            n += os.readlink(f"/proc/self/fd/{fd}") == real
+        except OSError:  # the listing's own descriptor, closed by now
+            pass
+    return n
+
+
+def test_an_abandoned_stream_leaves_no_descriptor_and_no_reader(tmp_path):
+    """``prefetch_blocks`` over a ``StreamingCorpus`` dropped after two
+    blocks: the native scanner's descriptor of the file is closed (its
+    buffer freed with it) and the reader thread is gone — nothing
+    outlives the consumer."""
+    import os
+    import threading
+    import time as _time
+
+    from locust_tpu.io import native_ingest
+
+    if not native_ingest.available() or not os.path.isdir("/proc/self/fd"):
+        pytest.skip("native build or /proc/self/fd unavailable")
+    p = tmp_path / "streamed.txt"
+    p.write_bytes(b"".join(b"line %d\n" % i for i in range(4000)))
+    before = threading.active_count()
+    assert _descriptors_of(str(p)) == 0
+    it = loader.prefetch_blocks(iter(loader.StreamingCorpus(str(p), 16, 8)), depth=2)
+    first, second = next(it), next(it)
+    assert bytes_ops.rows_to_strings(second)[0] == b"line 8"
+    assert _descriptors_of(str(p)) == 1  # the file IS held open while it is read
+    it.close()  # what dropping it does
+    deadline = _time.time() + 5
+    while threading.active_count() > before and _time.time() < deadline:
+        _time.sleep(0.02)
+    assert threading.active_count() <= before
+    assert _descriptors_of(str(p)) == 0
+    # ... and a stream read to its end closes its file as well.
+    assert sum(b.shape[0] for b in loader.StreamingCorpus(str(p), 16, 8)) == 4000
+    assert _descriptors_of(str(p)) == 0
+
+
+def test_a_path_that_is_no_regular_file_goes_to_the_python_reader(tmp_path):
+    """A FIFO: the native open refuses it BEFORE the first block (and
+    without opening it: its writer keeps its reader), ``StreamingCorpus``
+    reads it with ``_iter_python``, the blocks are the file's."""
+    import os
+    import threading
+
+    from locust_tpu.io import native_ingest
+
+    if not native_ingest.available() or not hasattr(os, "mkfifo"):
+        pytest.skip("native build or os.mkfifo unavailable")
+    data = b"first\r\nsecond line is long\n\nlast"
+    plain, fifo = tmp_path / "plain.txt", tmp_path / "fifo"
+    plain.write_bytes(data)
+    os.mkfifo(fifo)
+    with pytest.raises(OSError):
+        next(native_ingest.iter_blocks(str(fifo), _W, 2))
+    with pytest.raises(OSError):
+        native_ingest.count_lines(str(fifo))
+
+    def write():
+        with open(fifo, "wb") as f:  # blocks until the reader opens
+            f.write(data)
+
+    t = threading.Thread(target=write, daemon=True)
+    t.start()
+    got = list(loader.StreamingCorpus(str(fifo), _W, 2))
+    t.join(timeout=5)
+    assert not t.is_alive()
+    want = list(loader.StreamingCorpus(str(plain), _W, 2))
+    assert len(got) == len(want) == 2
+    assert all(np.array_equal(a, b) for a, b in zip(got, want))
+
+
 # ------------------------------------------------------------- native TSV
 
 class TestNativeTsvParity:

@@ -432,6 +432,57 @@ def test_a_traced_job_holds_the_joins_spans_and_counters(pair, capsysbinary, tmp
         assert counters["join." + name] == int(line[name]), name
 
 
+@pytest.mark.parametrize("reader", ["slow", "fast"])
+def test_the_read_ahead_counters_say_who_waited_for_whom(
+        pair, capsysbinary, tmp_path, monkeypatch, reader):
+    """``engine.ingest.blocks_waited`` / ``blocks_ahead`` of a join job:
+    a reader slower than the job's own thread (its source sleeps) has
+    every UserVisits block waited for, a faster one is ahead of it, and
+    the two always sum to the blocks of the file — what a trace's reader
+    takes the two names to mean (PR 49)."""
+    import time
+
+    from locust_tpu import cli_apps
+    from locust_tpu.io import loader
+
+    assert run_join(capsysbinary, *pair)[0] == 0   # the programs made: no compile below
+    blocks = -(-VISITS // 256)
+    visit_blocks = cli_apps._visit_blocks
+    pause = {"slow": (0.1, 0.0), "fast": (0.0, 0.05)}[reader]
+
+    def paced(path, cfg, full):
+        for blk in visit_blocks(path, cfg, full):
+            time.sleep(pause[0])   # on the reader thread, before the hand-over
+            yield blk
+
+    def consumed(gen):
+        for blk in gen:
+            yield blk
+            time.sleep(pause[1])   # on the job's own thread, between its pulls
+
+    real_prefetch = loader.prefetch_blocks
+    monkeypatch.setattr(cli_apps, "_visit_blocks", paced)
+    monkeypatch.setattr(loader, "prefetch_blocks",
+                        lambda blocks, depth=2: consumed(real_prefetch(blocks, depth)))
+    trace = tmp_path / "trace.json"
+    rc, out, _ = run_join(capsysbinary, *pair, "--trace-out", str(trace))
+    assert rc == 0
+    doc = json.loads(trace.read_text())
+    counters = doc["otherData"]["metrics"]["counters"]
+    ahead = counters.get("engine.ingest.blocks_ahead", 0)
+    waited = counters.get("engine.ingest.blocks_waited", 0)
+    assert ahead + waited == blocks
+    waits = [e for e in doc["traceEvents"]
+             if e.get("ph") == "X" and e["name"] == "engine.ingest.wait"]
+    # every block waited for is a wait span; the pull that finds the end may be one more
+    assert waited <= len(waits) <= waited + 1
+    if reader == "slow":
+        assert waited >= blocks - 2
+    else:
+        # the first pull starts the reader and may wait for it; the rest were read ahead
+        assert ahead >= blocks - 2
+
+
 # ------------------------------------------------ the device's field parser
 
 
